@@ -1,0 +1,233 @@
+"""Ask the chip's compiler about the served path's programs — without a
+chip.  Each test lowers one program of the SQL -> tserver -> device path
+at the SERVED shapes for a described (not attached) v5e and compiles it
+with the TPU compiler installed here: what the compiler would refuse or
+stall on at first contact with the chip fails here instead, at no chip
+time.  Nothing runs, so these tests say nothing about results or speed.
+
+`jax.default_backend()` still says `cpu` during such a compile, so the
+TPU arm of each backend branch is steered from here (f32 value lanes,
+`unroll` group strategy, Pallas `interpret=False`), never through an
+option of the program.
+
+The topology is described inside a module-scoped fixture — not at
+import, not in conftest.py — so that under several xdist workers only
+the worker that is given this file loads the TPU library.
+"""
+import os
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+from yugabyte_db_tpu.utils import flags
+
+#: what the server uses: `streaming_chunk_rows` and `compaction_chunk_rows`
+SCAN_ROWS = 1 << 20
+MERGE_ROWS = 524_288
+
+#: a program whose compile outlasts this does not, for a machine that
+#: starts with an empty cache, compile (ISSUE 22 §2).  The bound is
+#: doubled against the 60 s target because the driver runs this file
+#: next to five other workers on eight cores.
+COMPILE_BUDGET_S = 120.0
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def tpu_arms():
+    """The arms `jax.default_backend() != "cpu"` would pick."""
+    old = {k: flags.get(k)
+           for k in ("device_float_dtype", "scan_group_strategy")}
+    flags.set_flag("device_float_dtype", "float32")
+    flags.set_flag("scan_group_strategy", "unroll")
+    yield
+    for k, v in old.items():
+        flags.set_flag(k, v)
+
+
+def _compile(lower):
+    """Compile what `lower()` lowers, inside the budget."""
+    t0 = time.time()
+    compiled = lower().compile()
+    secs = time.time() - t0
+    print(f"compiled in {secs:.1f}s")
+    assert secs < COMPILE_BUDGET_S, f"compile took {secs:.0f}s"
+    return compiled
+
+
+def _consts(query, aggs):
+    from yugabyte_db_tpu.ops.expr import collect_constants
+    consts = []
+    if query.where is not None:
+        collect_constants(query.where, consts)
+    for a in aggs:
+        if a.expr is not None:
+            collect_constants(a.expr, consts)
+    return consts
+
+
+def _scan_args(query, n_total):
+    """(avg-expanded aggs, static_sums, example args, example rows): the
+    argument list `ScanKernel.run` and `__graft_entry__.entry()` build,
+    on a small batch with the TPU arm's dtypes.  A described device can
+    hold no array, so callers turn these into shapes."""
+    from __graft_entry__ import _example_batch
+    from yugabyte_db_tpu.ops.device_batch import build_batch
+    from yugabyte_db_tpu.ops.scan import (_expand_avg, _group_strategy,
+                                          _static_scales)
+    aggs = tuple(_expand_avg(query.aggs))
+    batch = build_batch(_example_batch(), sorted(query.columns))
+    assert batch.cols[2].dtype == jnp.float32       # l_extendedprice
+    assert batch.key_hash.dtype == jnp.uint64 and batch.ht.dtype == jnp.uint64
+    assert _group_strategy() == "unroll"
+    static_sums, scale_args = _static_scales(
+        aggs, batch.col_bounds, n_total, batch.cols)
+    args = (batch.cols, batch.nulls,
+            [jnp.asarray(c) for c in _consts(query, aggs)],
+            batch.valid, batch.key_hash, batch.ht, batch.write_id,
+            batch.tombstone, jnp.uint64(1 << 63), scale_args)
+    return aggs, static_sums, args, batch.padded_rows
+
+
+def _shapes(tree, small: int, rows_shape, row_sharding, scalar_sharding):
+    """`tree` as ShapeDtypeStructs: every `small`-row vector becomes
+    `rows_shape` on `row_sharding`, anything else keeps its shape."""
+    def one(x):
+        x = jnp.asarray(x)
+        if x.shape == (small,):
+            return jax.ShapeDtypeStruct(rows_shape, x.dtype,
+                                        sharding=row_sharding)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                    sharding=scalar_sharding)
+    return jax.tree_util.tree_map(one, tree)
+
+
+@pytest.mark.parametrize("mvcc_mode", ["visible", "dedup"])
+@pytest.mark.parametrize("query_name", ["q6", "q1"])
+def test_scan_kernel_compiles(one_chip, tpu_arms, query_name, mvcc_mode):
+    """Flat (Q6) and grouped (Q1) scan, single-version (`visible`) and
+    multi-version (`dedup`: the sort) MVCC, f32 value lanes and u64
+    hash/time lanes, at the streaming bucket."""
+    from yugabyte_db_tpu.models import tpch
+    from yugabyte_db_tpu.ops.scan import _build_kernel
+    query = {"q6": tpch.TPCH_Q6, "q1": tpch.TPCH_Q1}[query_name]
+    aggs, static_sums, args, small = _scan_args(query, SCAN_ROWS)
+    fn = _build_kernel(query.where, aggs, query.group, mvcc_mode,
+                       static_sums=static_sums, strategy="unroll")
+    shapes = _shapes(args, small, (SCAN_ROWS,), one_chip, one_chip)
+    compiled = _compile(lambda: jax.jit(fn).lower(*shapes))
+    assert ("sort" in compiled.as_text()) == (mvcc_mode == "dedup")
+
+
+def test_sort_grouped_kernel_compiles(one_chip, tpu_arms):
+    """Q1 grouped by sort + segments (HashGroupSpec): the GROUP BY route
+    of a table that was never ANALYZEd."""
+    from yugabyte_db_tpu.models import tpch
+    from yugabyte_db_tpu.ops.scan import HashGroupSpec, _build_kernel
+    q = tpch.TPCH_Q1
+    aggs, static_sums, args, small = _scan_args(q, SCAN_ROWS)
+    fn = _build_kernel(
+        q.where, aggs, HashGroupSpec((tpch.RETFLAG, tpch.LINESTATUS)),
+        "visible", static_sums=static_sums, strategy="unroll")
+    shapes = _shapes(args, small, (SCAN_ROWS,), one_chip, one_chip)
+    assert "sort" in _compile(lambda: jax.jit(fn).lower(*shapes)).as_text()
+
+
+@pytest.mark.parametrize("key_words", [2, 3])
+def test_chunk_merge_kernel_compiles(one_chip, key_words):
+    """The compaction frontier merge at `compaction_chunk_rows`."""
+    from yugabyte_db_tpu.ops.compaction import chunk_merge_kernel
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    m, u64, u32, b = MERGE_ROWS, jnp.uint64, jnp.uint32, jnp.bool_
+    compiled = _compile(lambda: chunk_merge_kernel.lower(
+        s((m, key_words), u64), s((m,), u64), s((m,), u32), s((m,), b),
+        s((m,), b),
+        s((key_words,), u64), s((), u64), s((), u32), s((), b),
+        s((key_words,), u64), s((), u64), s((), u32), s((), b), s((), b),
+        s((), u64), num_dk_words=key_words))
+    assert "sort" in compiled.as_text()
+
+
+@pytest.mark.parametrize("query_name", ["q6", "q1"])
+def test_pallas_generic_scan_compiles(one_chip, query_name):
+    """The opt-in (`tpu_pallas_scan`) hand-blocked scan through Mosaic,
+    flat and grouped, as `ScanKernel._try_pallas` builds it."""
+    from yugabyte_db_tpu.models import tpch
+    from yugabyte_db_tpu.ops.expr import compile_expr, const_count
+    from yugabyte_db_tpu.ops.pallas_scan import build_generic_scan
+    from yugabyte_db_tpu.ops.scan import _expand_avg
+    query = {"q6": tpch.TPCH_Q6, "q1": tpch.TPCH_Q1}[query_name]
+    aggs = tuple(_expand_avg(query.aggs))
+    consts = _consts(query, aggs)
+    off = const_count(query.where)
+    agg_fns = []
+    for a in aggs:
+        if a.expr is None:
+            agg_fns.append((a.op, None))
+            continue
+        agg_fns.append((a.op, compile_expr(a.expr, offset=off)))
+        off += const_count(a.expr)
+    group = query.group
+    col_order = tuple(sorted(query.columns))
+    run = build_generic_scan(
+        query.where, agg_fns,
+        group.cols if group is not None else None,
+        group.num_groups if group is not None else None,
+        col_order, col_order, len(consts), interpret=False)
+
+    def s(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    rows = [s((SCAN_ROWS,)) for _ in col_order]
+    compiled = _compile(lambda: run.lower(s((len(consts),)), rows, rows,
+                                          s((SCAN_ROWS,))))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_distributed_scan_compiles_for_four_chips(topo, tpu_arms):
+    """The four-shard `distributed_scan_aggregate` program (Q1) on a
+    mesh of the described devices: the combine is an all-reduce."""
+    from yugabyte_db_tpu.models.tpch import TPCH_Q1 as q
+    from yugabyte_db_tpu.parallel.distributed_scan import \
+        DistributedScanKernel
+    from yugabyte_db_tpu.parallel.mesh import (BLOCKS_AXIS, TABLETS_AXIS,
+                                               TabletMesh)
+    tm = TabletMesh(Mesh(np.array(topo.devices).reshape(4, 1),
+                         (TABLETS_AXIS, BLOCKS_AXIS)))
+    aggs, static_sums, args, small = _scan_args(q, SCAN_ROWS * 4)
+    col_sig = tuple(sorted((cid, str(v.dtype))
+                           for cid, v in args[0].items()))
+    sig = (id(tm.mesh), None, None, None, "visible", SCAN_ROWS, col_sig,
+           static_sums, "unroll")
+    fn = DistributedScanKernel()._get(sig, tm, q.where, aggs, q.group,
+                                      "visible", static_sums, "unroll")
+    shapes = _shapes(
+        args, small, (4, 1, SCAN_ROWS),
+        NamedSharding(tm.mesh, P(TABLETS_AXIS, BLOCKS_AXIS, None)),
+        NamedSharding(tm.mesh, P()))
+    compiled = _compile(lambda: fn.lower(*shapes))
+    assert "all-reduce" in compiled.as_text()

@@ -34,7 +34,7 @@ _DEFINE_FUNCS = {"DEFINE", "DEFINE_RUNTIME", "define_flag",
                  "REGISTRY.define", "flags.DEFINE", "flags.DEFINE_RUNTIME"}
 _AUTO_FUNCS = {"DEFINE_AUTO", "flags.DEFINE_AUTO"}
 _READ_METHODS = {"get", "on_change"}
-_DOC_GLOBS = ("COVERAGE.md", "ANALYSIS.md", "README.md", "ROADMAP.md")
+_DOC_GLOBS = ("COVERAGE.md", "ANALYSIS.md", "README.md")
 _DOC_DEFAULT_RE = r"`?%s`?\s*\(default[:\s]+([^)]+)\)"
 # matches "default 5", "default: 5", "(default 5)", "default=5",
 # "defaults to 9", "default is True" — the claimed value must LOOK like

@@ -990,7 +990,8 @@ class YBClient:
 
     # --- leader routing with retry ---------------------------------------
     async def _call_leader(self, ct: CachedTable, tablet_id: str,
-                           method: str, payload, max_tries: int = 8):
+                           method: str, payload, max_tries: int = 8,
+                           timeout: float = 10.0):
         loc = next(l for l in ct.locations if l.tablet_id == tablet_id)
         last_err: Optional[Exception] = None
         for attempt in range(max_tries):
@@ -1003,7 +1004,7 @@ class YBClient:
             for addr in addrs:
                 try:
                     return await self.messenger.call(
-                        addr, "tserver", method, payload, timeout=10.0)
+                        addr, "tserver", method, payload, timeout=timeout)
                 except RpcError as e:
                     last_err = e
                     if e.code == "TABLET_SPLIT":

@@ -20,7 +20,20 @@ _SRC = os.path.join(_NATIVE_DIR, "ybtpu_hot.c")
 # (repo snapshots travel across hosts; see hostfp.py)
 from ..hostfp import host_fingerprint as _host_fp  # noqa: E402
 
-_SO = os.path.join(_NATIVE_DIR, f"ybtpu_hot.{_host_fp()}.so")
+
+def _src_tag() -> str:
+    """Short hash of the C source: whatever loads was built from the
+    file git would commit (a copied tree keeps no mtime order, so file
+    times cannot say whether a .so is stale)."""
+    import hashlib
+    try:
+        with open(_SRC, "rb") as f:
+            return hashlib.sha1(f.read()).hexdigest()[:8]
+    except OSError:
+        return "nosrc"
+
+
+_SO = os.path.join(_NATIVE_DIR, f"ybtpu_hot.{_host_fp()}.{_src_tag()}.so")
 
 _MOD = None
 _TRIED = False
@@ -59,10 +72,7 @@ def load():
         return _MOD
     _TRIED = True
     try:
-        stale = (not os.path.exists(_SO)
-                 or (os.path.exists(_SRC)
-                     and os.path.getmtime(_SO) < os.path.getmtime(_SRC)))
-        if stale and not _build():
+        if not os.path.exists(_SO) and not _build():
             return None
         spec = importlib.util.spec_from_file_location("ybtpu_hot", _SO)
         mod = importlib.util.module_from_spec(spec)

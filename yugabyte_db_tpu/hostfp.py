@@ -1,16 +1,15 @@
-"""Host fingerprint for compiled-artifact cache keys.
+"""Host fingerprint for the auto-built native libraries' file names.
 
-Compiled artifacts — the persistent XLA compilation cache and the
-auto-built native .so files — are only valid on hosts with the same CPU
-feature set. Benchmark/CI environments snapshot the repo directory
-(including ignored build products) across machines, and loading code
-compiled for another host ranges from silent slowdowns to SIGILL (the
-r03 bench tail warned exactly this). Keying every artifact path by a
-hash of the CPU identity makes a foreign artifact invisible rather than
-load-then-crash: the new host just rebuilds into its own namespace.
+The native .so files are built with -march=native, so each is only
+valid on hosts with the same CPU feature set. Benchmark/CI environments
+copy the repo directory (ignored build products included) across
+machines, and loading code compiled for another host ranges from silent
+slowdowns to SIGILL. Keying the file name by a hash of the CPU identity
+makes a foreign artifact invisible rather than load-then-crash: the new
+host just rebuilds into its own name. (The XLA compile cache is NOT
+keyed this way: TPU executables do not depend on the host's CPU.)
 
-Stdlib-only and import-cycle-free: this must be importable from the
-package __init__ before jax configuration.
+Stdlib-only and import-cycle-free.
 """
 from __future__ import annotations
 

@@ -5,8 +5,10 @@ retention decisions (reference: src/yb/rocksdb/db/compaction_job.cc:665
 ProcessKeyValueCompaction, src/yb/table/merger.cc MergingIterator,
 src/yb/docdb/docdb_compaction_context.cc:783 DocDBCompactionFeed) with:
 
-1. keys → fixed-width big-endian u64 word columns; one multi-key
-   `lax.sort` merges ALL input runs at once (keys carry the descending-
+1. keys → fixed-width big-endian u64 word columns; one stable
+   lexicographic device sort (ops/lexsort.py: single-key passes, which
+   the TPU compiler handles in bounded time where a multi-key `lax.sort`
+   does not) merges ALL input runs at once (keys carry the descending-
    encoded hybrid time suffix, so versions of a doc key come out
    newest-first automatically — the same trick the LSM relies on).
 2. the history-retention decision (reference:
@@ -30,6 +32,7 @@ import numpy as np
 
 from ..utils.hybrid_time import ENCODED_SIZE
 from ..dockv.key_encoding import ValueType
+from .lexsort import lex_order
 
 _HT_SUFFIX = ENCODED_SIZE + 1  # kHybridTime marker + 12 encoded bytes
 
@@ -133,14 +136,12 @@ def merge_gc_split_kernel(dk_words: jnp.ndarray,   # [N, Wd]
     """Same as merge_gc_kernel but with the HT split out (sort keys:
     dockey words asc, then ht desc, then write_id desc) — used when input
     runs had different key widths so suffixes were split before padding."""
-    n = dk_words.shape[0]
     first = jnp.where(valid, dk_words[:, 0], jnp.uint64(0xFFFFFFFFFFFFFFFF))
     inv_ht = jnp.uint64(0xFFFFFFFFFFFFFFFF) - ht
     inv_wid = jnp.uint32(0xFFFFFFFF) - wid
-    operands = (first,) + tuple(dk_words[:, i] for i in range(1, num_dk_words)) \
-        + (inv_ht, inv_wid, jnp.arange(n, dtype=jnp.int32))
-    sorted_ops = jax.lax.sort(operands, num_keys=num_dk_words + 2)
-    order = sorted_ops[-1]
+    order = lex_order(
+        (first,) + tuple(dk_words[:, i] for i in range(1, num_dk_words))
+        + (inv_ht, inv_wid))
     dk_s = dk_words[order]
     ht_s = ht[order]
     wid_s = wid[order]
@@ -282,19 +283,17 @@ def chunk_merge_kernel(dk_words: jnp.ndarray,    # [M, Wd] frontier rows
     exactly equal to the bound stays pending, because the bound is the
     first key of a block that has not been pulled yet and an exact
     duplicate of it may still arrive."""
-    n = dk_words.shape[0]
     first = jnp.where(valid, dk_words[:, 0], _U64_MAX)
     inv_ht = _U64_MAX - ht
     inv_wid = _U32_MAX - wid
-    operands = (first,) + tuple(dk_words[:, i] for i in range(1, num_dk_words)) \
-        + (inv_ht, inv_wid, jnp.arange(n, dtype=jnp.int32))
-    sorted_ops = jax.lax.sort(operands, num_keys=num_dk_words + 2)
-    order = sorted_ops[-1]
+    order = lex_order(
+        (first,) + tuple(dk_words[:, i] for i in range(1, num_dk_words))
+        + (inv_ht, inv_wid))
     dk_s = dk_words[order]
     ht_s = ht[order]
     wid_s = wid[order]
-    inv_ht_s = sorted_ops[num_dk_words]
-    inv_wid_s = sorted_ops[num_dk_words + 1]
+    inv_ht_s = inv_ht[order]
+    inv_wid_s = inv_wid[order]
     tomb_s = tombstone[order]
     valid_s = valid[order]
 

@@ -79,18 +79,13 @@ def q6_scan_pallas(qty, price, disc, shipdate, valid, scalars,
     Inputs must be f32 arrays padded to a BLOCK_ROWS multiple (valid=0 on
     padding). Returns (revenue_sum, match_count)."""
     from jax.experimental import pallas as pl
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        smem = pltpu.SMEM
-    except ImportError:   # cpu-only install
-        smem = None
+    from jax.experimental.pallas import tpu as pltpu
     n = qty.shape[0]
     grid = n // BLOCK_ROWS
     blk = pl.BlockSpec((BLOCK_ROWS,), _im1)
     # explicit shape + int32 index map: the default (map-less) SMEM spec
     # traces an i64 index map under x64, which Mosaic can't legalize
-    scalar_spec = (pl.BlockSpec((5,), _im1_0, memory_space=smem)
-                   if smem is not None else pl.BlockSpec((5,), _im1_0))
+    scalar_spec = pl.BlockSpec((5,), _im1_0, memory_space=pltpu.SMEM)
     sums, cnts = pl.pallas_call(
         _q6_kernel,
         grid=(grid,),
@@ -208,11 +203,7 @@ def build_generic_scan(where, agg_fns, group_cols, num_groups,
     agg_fns: [(op, compiled_expr_or_None)]; group_cols: GroupSpec cols
     tuple or None; col_order/null_order: cid tuples fixing ref order."""
     from jax.experimental import pallas as pl
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        smem = pltpu.SMEM
-    except ImportError:
-        smem = None
+    from jax.experimental.pallas import tpu as pltpu
     from .expr import compile_expr
     where_fn = compile_expr(where) if where is not None else None
     n_cols, n_nulls = len(col_order), len(null_order)
@@ -322,10 +313,8 @@ def build_generic_scan(where, agg_fns, group_cols, num_groups,
         n = valid.shape[0]
         grid = n // BLOCK_ROWS
         blk = pl.BlockSpec((BLOCK_ROWS,), _im1)
-        scalar_spec = (pl.BlockSpec((max(n_consts, 1),), _im1_0,
-                                    memory_space=smem)
-                       if smem is not None
-                       else pl.BlockSpec((max(n_consts, 1),), _im1_0))
+        scalar_spec = pl.BlockSpec((max(n_consts, 1),), _im1_0,
+                                   memory_space=pltpu.SMEM)
         if G is None:
             out_specs = tuple(
                 pl.BlockSpec((SUBLANES, LANES), _im2)

@@ -71,6 +71,7 @@ from .device_batch import DeviceBatch
 from .expr import collect_constants, compile_expr, expr_signature
 from .grouped_scan import (DictGroupSpec, ResolvedDictGroup,
                            grouped_reduce, resolve_group)
+from .lexsort import lex_order
 
 _UINT64_MAX = jnp.uint64(0xFFFFFFFFFFFFFFFF)
 
@@ -125,9 +126,8 @@ def _mvcc_visible_latest(key_hash, ht, write_id, tombstone, valid, read_ht):
     inv_vis = jnp.logical_not(visible).astype(jnp.uint8)
     inv_ht = _UINT64_MAX - ht
     inv_wid = jnp.uint32(0xFFFFFFFF) - write_id
-    idx = jnp.arange(n, dtype=jnp.int32)
-    s_kh, _, s_ht, s_wid, s_idx = jax.lax.sort(
-        (sort_kh, inv_vis, inv_ht, inv_wid, idx), num_keys=4)
+    s_idx = lex_order((sort_kh, inv_vis, inv_ht, inv_wid))
+    s_kh = sort_kh[s_idx]
     first = jnp.concatenate([jnp.array([True]), s_kh[1:] != s_kh[:-1]])
     vis_sorted = visible[s_idx]
     tomb_sorted = tombstone[s_idx]
@@ -425,12 +425,9 @@ def _build_kernel(where_node, agg_specs: Tuple[AggSpec, ...],
             G = group.max_groups
             inv = jnp.logical_not(mask).astype(jnp.uint8)
             gcols = [cols[cid] for cid in group.cols]
-            pos = jnp.arange(n, dtype=jnp.int32)
-            sorted_ = jax.lax.sort((inv, *gcols, pos),
-                                   num_keys=1 + len(gcols))
-            perm = sorted_[-1]
-            g_s = sorted_[1:-1]
-            valid_s = sorted_[0] == 0
+            perm = lex_order((inv, *gcols))
+            g_s = [g[perm] for g in gcols]
+            valid_s = inv[perm] == 0
             changed = g_s[0][1:] != g_s[0][:-1]
             for g in g_s[1:]:
                 changed = changed | (g[1:] != g[:-1])
@@ -636,51 +633,48 @@ class ScanKernel:
             return None
         key = ("pallas", sig)
         entry = self._cache.get(key)
-        if entry is False:
-            return None                 # known-failing shape
         col_order = tuple(sorted(needed))
         null_order = tuple(cid for cid in col_order
                            if cid in batch.nulls)
         entry_was_compiled = entry is None
-        try:
-            if entry is None:
-                from .expr import const_count
-                from .pallas_scan import build_generic_scan
-                off = const_count(where) if where is not None else 0
-                agg_fns = []
-                for a in aggs:
-                    if a.expr is None:
-                        agg_fns.append((a.op, None))
-                        continue
-                    agg_fns.append(
-                        (a.op, compile_expr(a.expr, offset=off)))
-                    off += const_count(a.expr)
-                interpret = jax.default_backend() == "cpu"
-                entry = build_generic_scan(
-                    where, agg_fns,
-                    group.cols if group is not None else None,
-                    group.num_groups if group is not None else None,
-                    col_order, null_order, len(consts),
-                    interpret=interpret)
-                self._cache[key] = entry
-                self.compiles += 1
-            carr = jnp.asarray(
-                np.asarray([float(c) for c in consts] or [0.0],
-                           np.float32))
-            col_arrs = [batch.cols[cid].astype(jnp.float32)
-                        for cid in col_order]
-            null_arrs = [batch.nulls[cid].astype(jnp.float32)
-                         for cid in null_order]
-            from ..utils import trace as _trace
-            with _trace.device_span("pallas_scan", signature=key,
-                                    compiled=entry_was_compiled,
-                                    bucket=batch.padded_rows,
-                                    rows=batch.n_rows):
-                outs = entry(carr, col_arrs, null_arrs,
-                             batch.valid.astype(jnp.float32))
-        except Exception:   # noqa: BLE001 — unsupported op inside the
-            self._cache[key] = False    # kernel: permanent XLA fallback
-            return None
+        # only typed PallasIneligible refusals route to the XLA kernel;
+        # a build or Mosaic compile error propagates — swallowing it
+        # would hide a kernel the chip's compiler refuses
+        if entry is None:
+            from .expr import const_count
+            from .pallas_scan import build_generic_scan
+            off = const_count(where) if where is not None else 0
+            agg_fns = []
+            for a in aggs:
+                if a.expr is None:
+                    agg_fns.append((a.op, None))
+                    continue
+                agg_fns.append(
+                    (a.op, compile_expr(a.expr, offset=off)))
+                off += const_count(a.expr)
+            interpret = jax.default_backend() == "cpu"
+            entry = build_generic_scan(
+                where, agg_fns,
+                group.cols if group is not None else None,
+                group.num_groups if group is not None else None,
+                col_order, null_order, len(consts),
+                interpret=interpret)
+            self._cache[key] = entry
+            self.compiles += 1
+        carr = jnp.asarray(
+            np.asarray([float(c) for c in consts] or [0.0],
+                       np.float32))
+        col_arrs = [batch.cols[cid].astype(jnp.float32)
+                    for cid in col_order]
+        null_arrs = [batch.nulls[cid].astype(jnp.float32)
+                     for cid in null_order]
+        from ..utils import trace as _trace
+        with _trace.device_span("pallas_scan", signature=key,
+                                compiled=entry_was_compiled,
+                                bucket=batch.padded_rows,
+                                rows=batch.n_rows):
+            outs = entry(carr, col_arrs, null_arrs,
+                         batch.valid.astype(jnp.float32))
         agg_parts, cnt_parts = outs[:-1], outs[-1]
         results = []
         for a, p in zip(aggs, agg_parts):

@@ -24,7 +24,7 @@ from ..ops.scan import (
     _rescale_outs, _static_scales,
 )
 from ..storage.columnar import ColumnarBlock
-from .mesh import BLOCKS_AXIS, TABLETS_AXIS, TabletMesh, shard_map_compat
+from .mesh import BLOCKS_AXIS, TABLETS_AXIS, TabletMesh
 
 
 @dataclass
@@ -203,10 +203,10 @@ class DistributedScanKernel:
         in_specs = (
             {k: spec3 for k in sig_cols(sig)}, {k: spec3 for k in sig_cols(sig)},
             P(), spec3, spec3, spec3, spec3, spec3, P(), P())
-        smapped = shard_map_compat(
+        smapped = jax.shard_map(
             shard_fn, mesh=tm.mesh, in_specs=in_specs,
             out_specs=(tuple(P() for _ in aggs), tuple(P() for _ in aggs),
-                       P()))
+                       P()), check_vma=False)
         fn = jax.jit(smapped)
         self._cache[sig] = fn
         self.compiles += 1

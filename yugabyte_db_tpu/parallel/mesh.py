@@ -19,26 +19,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions: the public `jax.shard_map`
-    (with `check_vma`) landed after 0.4.x; older images carry it as
-    `jax.experimental.shard_map.shard_map` (with `check_rep`). Every
-    shard_map in the engine goes through here so version drift is gated
-    in ONE place instead of at each call site."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-    try:
-        return _sm(fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
-    except TypeError:
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
 TABLETS_AXIS = "tablets"
 BLOCKS_AXIS = "blocks"
 

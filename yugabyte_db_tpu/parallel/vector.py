@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from .mesh import BLOCKS_AXIS, TABLETS_AXIS, TabletMesh, shard_map_compat
+from .mesh import BLOCKS_AXIS, TABLETS_AXIS, TabletMesh
 
 
 def sharded_exact_search(tm: TabletMesh, queries: np.ndarray,
@@ -47,10 +47,10 @@ def sharded_exact_search(tm: TabletMesh, queries: np.ndarray,
         neg2, pos = jax.lax.top_k(-alld, k)
         return -neg2, jnp.take_along_axis(alli, pos, axis=1)
 
-    fn = jax.jit(shard_map_compat(
+    fn = jax.jit(jax.shard_map(
         shard_fn, mesh=tm.mesh,
         in_specs=(P(), P(TABLETS_AXIS, BLOCKS_AXIS, None, None)),
-        out_specs=(P(), P())))
+        out_specs=(P(), P()), check_vma=False))
     d, i = fn(jnp.asarray(queries, jnp.float32),
               base_sharded.reshape(T, B, n_shard, -1))
     return np.asarray(d), np.asarray(i)

@@ -28,6 +28,9 @@ import sys
 from ..client import YBClient
 from ..docdb.wire import read_request_to_wire
 
+#: deadline of the flush/compact RPCs (compact_table, flush_table)
+MAINTENANCE_RPC_TIMEOUT_S = 3600.0
+
 
 # minimum positional args per command (commands absent here take 0)
 _MIN_ARGS = {
@@ -142,8 +145,11 @@ async def run_command(args) -> int:
         method = "compact" if cmd == "compact_table" else "flush"
         ct = await client._table(a[0])
         for l in ct.locations:
+            # a compaction of a real-size tablet outlasts the default
+            # 10 s RPC deadline, and a retry would start a second one
             r = await client._call_leader(ct, l.tablet_id, method,
-                                          {"tablet_id": l.tablet_id})
+                                          {"tablet_id": l.tablet_id},
+                                          timeout=MAINTENANCE_RPC_TIMEOUT_S)
             print(l.tablet_id, r)
     else:
         print(f"unknown command {cmd}", file=sys.stderr)
