@@ -502,6 +502,407 @@ class TestClusterSpanTree:
         run(main())
 
 
+def _short(name):
+    return name.split(":", 1)[0]
+
+
+def _trace_of(root):
+    """(spans of the trace `root` belongs to, {span_id: span})."""
+    spans = [s for s in TRACES.finished() if s.trace_id == root.trace_id]
+    return spans, {s.span_id: s for s in spans}
+
+
+class TestServedScanSpans:
+    """ISSUE 27: one statement is one trace rooted at `sql.execute`,
+    with a span at every layer boundary of the served scan."""
+
+    #: table B's scan rows: span -> its parent
+    PARENTS = {
+        "sql.parse": "sql.execute", "sql.plan": "sql.execute",
+        "client.scan": "sql.execute", "client.combine": "client.scan",
+        "rpc.c.tserver.read": "client.scan",
+        "rpc.s.tserver.read": "rpc.c.tserver.read",
+        "sched.queue.scan": "rpc.s.tserver.read",
+        "sched.dispatch.scan": "sched.queue.scan",
+        "tserver.read": "sched.dispatch.scan",
+        "docdb.read": "tserver.read",
+        "docdb.collect_blocks": "docdb.read", "docdb.batch": "docdb.read",
+        "device.scan": "docdb.read", "device.wait": "docdb.read",
+    }
+    QUERIES = {
+        "sum_where": ("SELECT sum(v), count(*) FROM t WHERE v < 100",
+                      "agg_pushdown"),
+        "group_by": ("SELECT g, sum(v), count(*) FROM t GROUP BY g",
+                     "grouped_pushdown"),
+    }
+
+    @staticmethod
+    async def _cluster(tmp_path, tablets=2):
+        from yugabyte_db_tpu.ql.executor import SqlSession
+        from yugabyte_db_tpu.tools.mini_cluster import MiniCluster
+        mc = await MiniCluster(str(tmp_path), num_tservers=1).start()
+        c = mc.client()
+        sql = SqlSession(c)
+        await sql.execute(
+            "CREATE TABLE t (k bigint, g varchar, v double, "
+            f"PRIMARY KEY (k)) WITH tablets = {tablets}")
+        await sql.execute("INSERT INTO t (k, g, v) VALUES " + ", ".join(
+            f"({i}, '{'ab'[i % 2]}', {i * 0.5})" for i in range(400)))
+        ct = await c._table("t", refresh=True)
+        for loc in ct.locations:     # SSTs: the columnar device path
+            await c._call_leader(ct, loc.tablet_id, "flush",
+                                 {"tablet_id": loc.tablet_id})
+        await sql.execute("ANALYZE t")
+        return mc, c, sql
+
+    @staticmethod
+    async def _stop(mc, c):
+        await c.messenger.shutdown()
+        await mc.shutdown()
+
+    @pytest.mark.parametrize("query", sorted(QUERIES))
+    def test_statement_is_one_trace_with_every_layer(self, tmp_path, query):
+        text, plan_route = self.QUERIES[query]
+
+        async def main():
+            flags.set_flag("tpu_min_rows_for_pushdown", 1)
+            mc, c, sql = await self._cluster(tmp_path)
+            try:
+                runs = []
+                for _ in range(2):
+                    with TRACES.trace("forced-root") as root:
+                        await sql.execute(text)
+                    runs.append(_trace_of(root))
+            finally:
+                flags.REGISTRY.reset("tpu_min_rows_for_pushdown")
+                await self._stop(mc, c)
+            for spans, by_id in runs:
+                names = {_short(s.name) for s in spans}
+                assert set(self.PARENTS) <= names, \
+                    set(self.PARENTS) - names
+                for s in spans:
+                    want = self.PARENTS.get(_short(s.name))
+                    if want is not None:
+                        assert _short(by_id[s.parent_id].name) == want, \
+                            (s.name, by_id[s.parent_id].name)
+                roots = [s for s in spans if s.name == "sql.execute"]
+                assert len(roots) == 1
+                assert roots[0].tags["stmt"] == "select"
+                assert roots[0].tags["rows"] >= 1
+                scan = next(s for s in spans if s.name == "client.scan")
+                reads = [s for s in spans
+                         if s.name == "rpc.c.tserver.read"
+                         and s.parent_id == scan.span_id]
+                assert scan.tags["tablets"] == len(reads) == 2
+                assert scan.tags["retries"] == 0
+                plan = next(s for s in spans if s.name == "sql.plan")
+                assert plan.tags["route"] == plan_route
+                assert plan.end_ns <= scan.start_ns
+                for s in spans:
+                    if s.name == "docdb.read":
+                        assert s.tags["route"] == "tpu_aggregate"
+                    if s.name == "sched.queue.scan":
+                        assert s.tags["wait_ms"] >= 0.0
+                        assert "cut_through" in s.tags
+                    if s.name == "device.wait":
+                        assert s.tags["thread"] == "loop"
+            caches = [[s.tags["cache"] for s in spans
+                       if s.name == "docdb.batch"] for spans, _ in runs]
+            assert caches == [["miss", "miss"], ["hit", "hit"]]
+            first = {_short(s.name): by_id[s.parent_id].name
+                     for spans, by_id in runs[:1] for s in spans
+                     if s.name.startswith("batch.")}
+            assert first == {"batch.build": "docdb.batch",
+                             "batch.h2d": "docdb.batch"}
+            assert not any(s.name.startswith("batch.")
+                           for s in runs[1][0])
+        run(main())
+
+    def test_unsampled_statement_records_nothing_and_adds_no_wait(
+            self, tmp_path, monkeypatch):
+        import jax
+        calls = []
+        real = jax.block_until_ready
+        monkeypatch.setattr(jax, "block_until_ready",
+                            lambda x: calls.append(1) or real(x))
+
+        async def main():
+            flags.set_flag("tpu_min_rows_for_pushdown", 1)
+            flags.set_flag("trace_sampling_rate", 0.0)
+            mc, c, sql = await self._cluster(tmp_path)
+            try:
+                text = self.QUERIES["sum_where"][0]
+                await sql.execute(text)          # compile, batch build
+                before = (len(TRACES.finished()), TRACES.evicted,
+                          len(calls))
+                res = await sql.execute(text)
+                assert (len(TRACES.finished()), TRACES.evicted,
+                        len(calls)) == before
+                assert res.rows[0]["count"] == 200
+                # the same statement, sampled: the wait is made explicit
+                flags.set_flag("trace_sampling_rate", 1.0)
+                await sql.execute(text)
+                assert len(calls) == before[2] + 2      # one per tablet
+            finally:
+                flags.REGISTRY.reset("tpu_min_rows_for_pushdown")
+                flags.REGISTRY.reset("trace_sampling_rate")
+                await self._stop(mc, c)
+        run(main())
+
+    def test_profiler_session_samples_and_mirrors_the_statement(
+            self, tmp_path):
+        """While the JAX profiler collects, a root is sampled at rate 0
+        and the span is in the profiler's trace under `ybtpu:<name>` with
+        the span's own duration."""
+        import jax
+        from jax.profiler import ProfileData
+
+        async def main():
+            flags.set_flag("trace_sampling_rate", 0.0)
+            mc, c, sql = await self._cluster(tmp_path / "cluster")
+            try:
+                with TRACES.span("unprofiled") as sp:
+                    assert not sp.sampled
+                since = time.perf_counter_ns()
+                opts = jax.profiler.ProfileOptions()
+                opts.python_tracer_level = 0
+                opts.host_tracer_level = 1
+                jax.profiler.start_trace(str(tmp_path / "trace"),
+                                         profiler_options=opts)
+                try:
+                    await sql.execute(self.QUERIES["sum_where"][0])
+                finally:
+                    jax.profiler.stop_trace()
+                with TRACES.span("after-the-session") as sp:
+                    assert not sp.sampled
+                return TRACES.finished(since)
+            finally:
+                flags.REGISTRY.reset("trace_sampling_rate")
+                await self._stop(mc, c)
+        spans = {s.span_id: s for s in run(main())}
+        roots = [s for s in spans.values() if s.name == "sql.execute"]
+        assert len(roots) == 1 and roots[0].parent_id == 0
+        import glob
+        path, = glob.glob(str(tmp_path / "trace" / "plugins" / "profile"
+                              / "*" / "*.xplane.pb"))
+        mirrored = {}
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith(trace_mod.MIRROR_PREFIX):
+                        assert plane.name.startswith("/host:")
+                        stats = dict(e.stats)
+                        mirrored[int(stats["span_id"])] = (
+                            e.name, e.duration_ns, int(stats["trace_id"]))
+        name, dur_ns, trace_id = mirrored[roots[0].span_id]
+        assert name == "ybtpu:sql.execute"
+        assert trace_id == roots[0].trace_id
+        # the span and its annotation start and end microseconds apart
+        assert abs(dur_ns - (roots[0].end_ns - roots[0].start_ns)) < 1e6
+        # every span of the statement is there, coroutines included
+        mine = {i for i, s in spans.items()
+                if s.trace_id == roots[0].trace_id}
+        assert mine <= set(mirrored)
+        assert {"ybtpu:client.scan", "ybtpu:docdb.read"} <= \
+            {mirrored[i][0] for i in mine}
+
+    def test_device_wait_publishes_its_wait_state(self):
+        from yugabyte_db_tpu.ops import scan as scan_mod
+        seen = []
+        real = scan_mod._rescale_outs
+
+        def spy(outs, scales):
+            seen.append(trace_mod.current_wait_state())
+            return real(outs, scales)
+        batch = TestDeviceTelemetry._batch()
+        from yugabyte_db_tpu.ops import AggSpec, scan_aggregate
+        scan_mod._rescale_outs = spy
+        try:
+            scan_aggregate(batch, None, (AggSpec("count"),))
+        finally:
+            scan_mod._rescale_outs = real
+        assert seen == ["Device_BlockUntilReady"]
+        from yugabyte_db_tpu.cluster.collector import classify_wait_state
+        assert classify_wait_state("Device_BlockUntilReady") == "device"
+        assert classify_wait_state("Device_Compile") == "compile"
+
+    def test_retries_are_counted_on_the_ambient_span(self):
+        with TRACES.trace("scan") as sp:
+            with TRACES.span("inner"):
+                pass
+            trace_mod.current_span().count("retries")
+            trace_mod.current_span().count("retries")
+        assert sp.tags["retries"] == 2
+        assert trace_mod.current_span().sampled is False   # none ambient
+        trace_mod.current_span().count("retries")          # goes nowhere
+
+
+class TestSpanRegistry:
+    """ISSUE 27: one clock, kept until read."""
+
+    def test_spans_stamp_perf_counter_ns(self):
+        t0 = time.perf_counter_ns()
+        with TRACES.trace("clocked") as sp:
+            TRACE("an event")
+        t1 = time.perf_counter_ns()
+        assert isinstance(sp.start_ns, int) and isinstance(sp.end_ns, int)
+        assert t0 <= sp.start_ns <= sp.end_ns <= t1
+        d = sp.to_dict()
+        assert d["finished"] and d["start_unix"] > 1e9
+        assert d["duration_ms"] == round((sp.end_ns - sp.start_ns) / 1e6, 3)
+        assert 0 <= d["events"][0][0] <= d["duration_ms"]
+
+    def test_finished_returns_the_interval_as_plain_tuples(self):
+        with TRACES.trace("before"):
+            pass
+        since = time.perf_counter_ns()
+        with TRACES.trace("inside", ) as a:
+            with TRACES.span("child") as b:
+                b.set_tag("k", 1)
+        until = time.perf_counter_ns()
+        with TRACES.trace("after"):
+            pass
+        got = TRACES.finished(since, until)
+        assert [s.name for s in got] == ["child", "inside"]
+        child = got[0]
+        assert isinstance(child, tuple)
+        assert child == ("child", a.trace_id, b.span_id, a.span_id,
+                         b.start_ns, b.end_ns, {"k": 1})
+        assert "after" in [s.name for s in TRACES.finished(since)]
+
+    def test_ring_counts_what_it_evicts(self):
+        reg = trace_mod.TraceRegistry()
+        flags.set_flag("tracez_keep", 3)
+        try:
+            for i in range(5):
+                with reg.span(f"s{i}", force=True):
+                    pass
+            assert [s.name for s in reg.finished()] == ["s2", "s3", "s4"]
+            assert reg.evicted == 2
+        finally:
+            flags.REGISTRY.reset("tracez_keep")
+        assert flags.get("tracez_keep") == 4096
+
+    def test_slow_threshold_still_reads_durations(self):
+        reg = trace_mod.TraceRegistry(slow_threshold_s=0.0)
+        with reg.span("slow-one", force=True):
+            pass
+        assert any("slow-one" in d for d in reg.rpcz()["recent_slow"])
+
+    def test_explicit_root_ignores_the_ambient_context(self):
+        with TRACES.trace("request") as req:
+            with TRACES.span("background", parent=None,
+                             force=True) as bg:
+                assert bg.trace_id != req.trace_id
+                assert bg.parent_id == 0
+
+
+class TestSchedulerQueueSpan:
+    """ISSUE 27: `sched.queue.<lane>` carries `wait_ms` (admission to
+    dequeue) and `cut_through`; the dispatch span is its child."""
+
+    def test_cut_through_and_queued(self):
+        from yugabyte_db_tpu.sched import Lane, RequestScheduler, ScanItem
+
+        async def main():
+            sched = RequestScheduler("ts-queue-span")
+            gate = asyncio.Event()
+
+            async def slow():
+                await gate.wait()
+                return {"n": 1}
+
+            async def fast():
+                return {"n": 2}
+            try:
+                with TRACES.trace("two-scans") as root:
+                    first = asyncio.create_task(sched.submit_grouped(
+                        Lane.SCAN, "sig-a", ScanItem(slow)))
+                    await asyncio.sleep(0)      # cut-through, in flight
+                    workers = sched.lanes[Lane.SCAN].cfg.workers
+                    sched.lanes[Lane.SCAN].inflight += workers  # lane full
+                    second = asyncio.create_task(sched.submit_grouped(
+                        Lane.SCAN, "sig-b", ScanItem(fast)))
+                    await asyncio.sleep(0.02)   # it waits in the queue
+                    sched.lanes[Lane.SCAN].inflight -= workers
+                    gate.set()
+                    assert (await first)["n"] == 1
+                    assert (await second)["n"] == 2
+            finally:
+                await sched.shutdown()
+            spans, by_id = _trace_of(root)
+            queues = sorted((s for s in spans
+                             if s.name == "sched.queue.scan"),
+                            key=lambda s: s.start_ns)
+            assert [q.tags["cut_through"] for q in queues] == [True, False]
+            assert queues[0].tags["wait_ms"] == 0.0
+            assert queues[1].tags["wait_ms"] > 0.0
+            for d in (s for s in spans if s.name == "sched.dispatch.scan"):
+                assert by_id[d.parent_id].name == "sched.queue.scan"
+            assert not any("sched.admit" in msg for s in TRACES.recent
+                           if s.trace_id == root.trace_id
+                           for _, msg in s.events)
+        run(main())
+
+
+class TestMaintenanceSpans:
+    """ISSUE 27: each non-empty periodic pass of the heartbeat loop is a
+    `tserver.maintenance` root; tick lateness feeds /metrics."""
+
+    def test_pass_is_a_root_span_and_an_empty_pass_leaves_none(
+            self, tmp_path):
+        from yugabyte_db_tpu.tserver import TabletServer
+
+        class Peer:
+            class tablet:
+                tablet_id = "t-1"
+
+        async def main():
+            ts = TabletServer("maint", str(tmp_path))
+            seen = []
+            flags.set_flag("trace_sampling_rate", 1.0)
+            try:
+                since = time.perf_counter_ns()
+                with TRACES.trace("some-request") as req:
+                    await ts._maintain("wal_gc", [Peer, Peer], seen.append)
+                    await ts._maintain("compaction", [], seen.append)
+
+                    async def boom(p):
+                        raise RuntimeError("one tablet fails")
+                    await ts._maintain("coordinator_sweep", [Peer], boom)
+            finally:
+                flags.REGISTRY.reset("trace_sampling_rate")
+            spans = [s for s in TRACES.finished(since)
+                     if s.name == "tserver.maintenance"]
+            assert [s.tags for s in spans] == [
+                {"what": "wal_gc", "tablets": 2},
+                {"what": "coordinator_sweep", "tablets": 1}]
+            assert seen == [Peer, Peer]
+            assert all(s.parent_id == 0 and s.trace_id != req.trace_id
+                       for s in spans)
+        run(main())
+
+    def test_tick_lateness_is_on_metrics(self, tmp_path):
+        from yugabyte_db_tpu.master import Master
+        from yugabyte_db_tpu.tserver import TabletServer
+
+        async def main():
+            master = Master(str(tmp_path / "m"))
+            maddr = await master.start()
+            ts = TabletServer("late", str(tmp_path / "ts"),
+                              master_addrs=[maddr])
+            await ts.start()
+            try:
+                await asyncio.sleep(0.5)     # two ticks of 0.2 s
+            finally:
+                await ts.shutdown()
+                await master.shutdown()
+            assert ts._m_tick_late.count() >= 1
+            assert "heartbeat_tick_late_ms" in \
+                metrics.REGISTRY.to_prometheus()
+        run(main())
+
+
 class TestEncryption:
     def test_cipher_roundtrip_random_access(self):
         cs = CipherStream(b"k" * 32, b"n" * 16)

@@ -20,6 +20,7 @@ from ..docdb.wire import (
     read_request_to_wire, read_response_from_wire, write_request_to_wire,
 )
 from ..dockv.partition import Partition
+from ..utils import trace as _trace
 from ..utils.tasks import cancel_and_drain
 # partial-combine rules + scalar unwrap shared with the bypass
 # session's host combine (ops/scan.py — one implementation, no drift)
@@ -730,6 +731,7 @@ class YBClient:
             except RpcError as e:
                 if e.code != "TABLET_SPLIT" or attempt == 3:
                     raise
+                _trace.current_span().count("retries")
                 await asyncio.sleep(0.2 * (attempt + 1))
                 ct = await self._table(table, refresh=True)
         raise RpcError("unreachable", "INTERNAL")
@@ -752,46 +754,53 @@ class YBClient:
         keep_all: skip the union-level LIMIT trim (callers that sort
         client-side need every tablet's top-N, not the first N of an
         arbitrary tablet order)."""
-        ct = await self._table(table)
-        req.table_id = ct.info.table_id
+        # child of the statement's `sql.execute`; a scan sent with no
+        # statement above it (DDL backfills, tools) roots its own trace
+        with _trace.TRACES.span("client.scan",
+                                tags={"retries": 0}) as sp:
+            ct = await self._table(table)
+            req.table_id = ct.info.table_id
 
-        async def one(loc: TabletLocation, ct2: CachedTable,
-                      window=None) -> ReadResponse:
-            rows: List[dict] = []
-            paging = None
-            first: Optional[ReadResponse] = None
-            while True:
-                r = ReadRequest(
-                    req.table_id, columns=req.columns, where=req.where,
-                    aggregates=req.aggregates, group_by=req.group_by,
-                    limit=req.limit, paging_state=paging,
-                    read_ht=req.read_ht, consistency=req.consistency,
-                    join=req.join, window=window)
-                payload = {"tablet_id": loc.tablet_id,
-                           "req": read_request_to_wire(r)}
-                resp = read_response_from_wire(await self._call_leader(
-                    ct2, loc.tablet_id, "read", payload))
-                if first is None:
-                    first = resp
-                rows.extend(resp.rows)
-                if resp.paging_state is None or req.aggregates:
-                    break
-                if req.limit is not None and len(rows) >= req.limit:
-                    break
-                paging = resp.paging_state
-            first.rows = rows
-            return first
+            async def one(loc: TabletLocation, ct2: CachedTable,
+                          window=None) -> ReadResponse:
+                rows: List[dict] = []
+                paging = None
+                first: Optional[ReadResponse] = None
+                while True:
+                    r = ReadRequest(
+                        req.table_id, columns=req.columns, where=req.where,
+                        aggregates=req.aggregates, group_by=req.group_by,
+                        limit=req.limit, paging_state=paging,
+                        read_ht=req.read_ht, consistency=req.consistency,
+                        join=req.join, window=window)
+                    payload = {"tablet_id": loc.tablet_id,
+                               "req": read_request_to_wire(r)}
+                    resp = read_response_from_wire(await self._call_leader(
+                        ct2, loc.tablet_id, "read", payload))
+                    if first is None:
+                        first = resp
+                    rows.extend(resp.rows)
+                    if resp.paging_state is None or req.aggregates:
+                        break
+                    if req.limit is not None and len(rows) >= req.limit:
+                        break
+                    paging = resp.paging_state
+                first.rows = rows
+                return first
 
-        async def go(ct2):
-            # the server-side window pushdown only holds on a single
-            # tablet (a window spans the whole table); with fan-out > 1
-            # per-tablet copies DROP the window so servers don't burn
-            # compute on partials the client must redo anyway
-            win = req.window if len(ct2.locations) == 1 else None
-            parts = await asyncio.gather(
-                *[one(l, ct2, win) for l in ct2.locations])
-            return self._combine(req, parts)
-        return await self._retry_on_split(table, go)
+            async def go(ct2):
+                # the server-side window pushdown only holds on a single
+                # tablet (a window spans the whole table); with fan-out > 1
+                # per-tablet copies DROP the window so servers don't burn
+                # compute on partials the client must redo anyway
+                win = req.window if len(ct2.locations) == 1 else None
+                sp.set_tag("tablets", len(ct2.locations))
+                parts = await asyncio.gather(
+                    *[one(l, ct2, win) for l in ct2.locations])
+                with _trace.TRACES.span("client.combine", child_only=True,
+                                        tags={"parts": len(parts)}):
+                    return self._combine(req, parts)
+            return await self._retry_on_split(table, go)
 
     # --- analytics bypass routing ----------------------------------------
     def set_bypass_provider(self, provider) -> None:
@@ -1011,6 +1020,7 @@ class YBClient:
                         # the tablet split under us: the caller must
                         # re-route by key against fresh locations
                         raise
+                    _trace.current_span().count("retries")
                     if e.code == "SERVICE_UNAVAILABLE":
                         # typed overload shed: honor the server's
                         # retry_after_ms (jittered exponential) instead
@@ -1028,6 +1038,7 @@ class YBClient:
                     raise
                 except (asyncio.TimeoutError, OSError) as e:
                     last_err = e
+                    _trace.current_span().count("retries")
                     continue
             if overload_s is not None:
                 # pure overload: the leader is alive, just shedding —

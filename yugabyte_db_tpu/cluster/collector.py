@@ -19,8 +19,8 @@ from typing import Dict, List, Optional, Sequence
 
 #: canonical wait-state -> attribution category.  The bench labels an
 #: over-spread round with the CATEGORY (flush/fsync/queue/compile/
-#: lock/cpu/scan) so thresholds and dashboards stay stable even as the
-#: state table grows.
+#: device/lock/cpu/scan) so thresholds and dashboards stay stable even
+#: as the state table grows.
 WAIT_CATEGORIES = {
     "Flush_SstWrite": "flush",
     "Flush_MemtableBackpressure": "flush",
@@ -33,7 +33,7 @@ WAIT_CATEGORIES = {
     "LeaderLease_Wait": "lock",
     "Lock_Wait": "lock",
     "Device_Compile": "compile",
-    "Device_BlockUntilReady": "compile",
+    "Device_BlockUntilReady": "device",
     "Compaction_Run": "flush",
     "Bypass_Scan": "scan",
     "OnCpu_Read": "cpu",

@@ -22,12 +22,13 @@ import numpy as np
 
 from ..dockv.key_encoding import ValueType
 from ..dockv.value import PrimitiveValue, ValueKind, unwrap_ttl
-from ..ops.device_batch import build_batch
+from ..ops.device_batch import batch_bytes, build_batch
 from ..ops.grouped_scan import DictGroupSpec
 from ..ops.scan import AggSpec, GroupSpec, HashGroupSpec, ScanKernel
 from ..storage.columnar import ColumnarBlock, fnv64_bytes
 from ..storage.lsm import LsmStore, WriteBatch
 from ..utils import flags
+from ..utils import trace as _trace
 from ..utils.hybrid_time import ENCODED_SIZE, DocHybridTime, HybridTime
 from .hotpath import load as _hot_mod
 from .table_codec import TableCodec
@@ -1162,15 +1163,29 @@ class DocReadOperation:
 
     # ---- scans -----------------------------------------------------------
     def execute(self, req: ReadRequest) -> ReadResponse:
-        if req.server_assigned_read_ht:
-            for _attempt in range(3):
-                try:
-                    return self._execute_once(req)
-                except ReadRestartError as e:
-                    req.read_ht = e.restart_ht
-        # explicit read points never restart; after 3 bumps serve at the
-        # last restart point without further bumps
-        return self._execute_once(req, allow_restart=False)
+        # one tablet's share of a read, as a span: block collection,
+        # batch formation, the kernel's dispatch and the wait for its
+        # result are its children; `route` is the path that served
+        with _trace.TRACES.span("docdb.read", child_only=True) as sp:
+            if req.server_assigned_read_ht:
+                for _attempt in range(3):
+                    try:
+                        return self._execute_once(req)
+                    except ReadRestartError as e:
+                        req.read_ht = e.restart_ht
+                        sp.count("restarts")
+            # explicit read points never restart; after 3 bumps serve at
+            # the last restart point without further bumps
+            return self._execute_once(req, allow_restart=False)
+
+    @staticmethod
+    def _served(route: str, resp: ReadResponse) -> ReadResponse:
+        """Name the route that served on the `docdb.read` span; the
+        first name wins (`streaming` inside `tpu_aggregate`)."""
+        sp = _trace.current_span()
+        if sp.sampled and "route" not in sp.tags:
+            sp.set_tag("route", route)
+        return resp
 
     def _execute_once(self, req: ReadRequest,
                       allow_restart: bool = True) -> ReadResponse:
@@ -1179,26 +1194,30 @@ class DocReadOperation:
             read_ht = req.read_ht if req.read_ht is not None else _MAX_HT
             row = self.get_row(req.pk_eq, read_ht)
             rows = [self._project(row, req.columns)] if row is not None else []
-            return ReadResponse(rows=rows, backend="cpu")
+            return self._served("point",
+                                ReadResponse(rows=rows, backend="cpu"))
         if req.pk_prefix is not None:
-            return self._prefix_scan(req)
+            return self._served("prefix", self._prefix_scan(req))
         if req.join is not None and req.aggregates:
-            return self._execute_join_aggregate(req)
+            return self._served("join", self._execute_join_aggregate(req))
         if (not req.aggregates and req.where is not None
                 and req.paging_state is None):
             got = self._hash_enumerated_read(req)
             if got is not None:
-                return self._serve_window(req, got)
+                return self._served("hash_enumerated",
+                                    self._serve_window(req, got))
         if req.aggregates and self._tpu_eligible(req):
             resp = self._execute_tpu_aggregate(req)
             if resp is not None:
-                return resp
+                return self._served("tpu_aggregate", resp)
         if (not req.aggregates and req.where is not None
                 and req.paging_state is None and self._tpu_eligible(req)):
             resp = self._execute_tpu_filter(req)
             if resp is not None:
-                return self._serve_window(req, resp)
-        return self._serve_window(req, self._execute_cpu(req))
+                return self._served("tpu_filter",
+                                    self._serve_window(req, resp))
+        return self._served("cpu",
+                            self._serve_window(req, self._execute_cpu(req)))
 
     def _serve_window(self, req: ReadRequest,
                       resp: ReadResponse) -> ReadResponse:
@@ -1407,27 +1426,33 @@ class DocReadOperation:
     def _collect_blocks(self) -> Optional[List[ColumnarBlock]]:
         """All columnar blocks across SSTs + a block built from memtable
         contents; None if any source can't provide columnar form."""
-        blocks: List[ColumnarBlock] = []
-        for r in self.store.ssts:
-            for i in range(r.num_blocks()):
-                cb = r.columnar_block(i)
+        with _trace.TRACES.span("docdb.collect_blocks",
+                                child_only=True) as sp:
+            sp.set_tag("step", "collect")
+            sp.set_tag("ssts", len(self.store.ssts))
+            blocks: List[ColumnarBlock] = []
+            for r in self.store.ssts:
+                for i in range(r.num_blocks()):
+                    cb = r.columnar_block(i)
+                    if cb is None:
+                        return None
+                    blocks.append(cb)
+            mem_entries = list(self.store._mem.iterate())
+            for m in self.store._frozen:
+                mem_entries += list(m.iterate())
+            if mem_entries:
+                mem_entries.sort()
+                cb = self.codec.columnar_builder(mem_entries)
                 if cb is None:
                     return None
+                cb.unique_keys = False  # overlaps SSTs in general
                 blocks.append(cb)
-        mem_entries = list(self.store._mem.iterate())
-        for m in self.store._frozen:
-            mem_entries += list(m.iterate())
-        if mem_entries:
-            mem_entries.sort()
-            cb = self.codec.columnar_builder(mem_entries)
-            if cb is None:
-                return None
-            cb.unique_keys = False  # overlaps SSTs in general
-            blocks.append(cb)
-        if len(self.store.ssts) > 1 or (mem_entries and self.store.ssts):
-            for b in blocks:
-                b.unique_keys = b.unique_keys and len(blocks) == 1
-        return blocks
+            if len(self.store.ssts) > 1 or (mem_entries
+                                            and self.store.ssts):
+                for b in blocks:
+                    b.unique_keys = b.unique_keys and len(blocks) == 1
+            sp.set_tag("blocks", len(blocks))
+            return blocks
 
     # --- string predicates on device (dictionary rewrite) -----------------
     class _Unrewritable(Exception):
@@ -1590,11 +1615,24 @@ class DocReadOperation:
         `needed` columns. `extra` extends the cache key — the zone-map
         prune signature rides here so a batch built from one predicate's
         pruned block set never serves another predicate."""
-        if self.device_cache is None:
+        miss = False
+
+        def build():
+            nonlocal miss
+            miss = True
             return build_batch(blocks, sorted(needed))
-        return self.device_cache.get_or_build(
-            self._batch_cache_key(needed) + extra,
-            lambda: build_batch(blocks, sorted(needed)))
+
+        with _trace.TRACES.span("docdb.batch", child_only=True) as sp:
+            if self.device_cache is None:
+                batch = build()
+            else:
+                batch = self.device_cache.get_or_build(
+                    self._batch_cache_key(needed) + extra, build)
+            if sp.sampled:
+                sp.set_tag("cache", "miss" if miss else "hit")
+                sp.set_tag("rows", batch.n_rows)
+                sp.set_tag("bytes", batch_bytes(batch))
+            return batch
 
     def _zone_prune(self, blocks, where, read_ht):
         """Zone-map block pruning for the monolithic pushdown paths:
@@ -1609,18 +1647,27 @@ class DocReadOperation:
         LAST_SCAN_PRUNE_STATS.update(stats)
         if where is None or not flags.get("zone_map_pruning"):
             return blocks, ()
-        # a read point ALWAYS flows into the kernel's MVCC selection in
-        # these paths (even _MAX_HT), so the chunk-safety proof is
-        # unconditionally required before dropping any block
-        from ..ops.stream_scan import chunk_safe_mvcc
-        if read_ht is not None and not chunk_safe_mvcc(blocks):
-            return blocks, ()
-        from ..ops.scan import zone_prune_blocks
-        kept, kept_idx = zone_prune_blocks(blocks, where)
-        if len(kept) == len(blocks):
-            return blocks, ()
-        LAST_SCAN_PRUNE_STATS["blocks_pruned"] = len(blocks) - len(kept)
-        return kept, ("zp", kept_idx)
+        # the second step of `docdb.collect_blocks`: which of the
+        # collected blocks the batch is formed from
+        with _trace.TRACES.span("docdb.collect_blocks",
+                                child_only=True) as sp:
+            sp.set_tag("step", "zone_prune")
+            sp.set_tag("blocks", len(blocks))
+            sp.set_tag("pruned", 0)
+            # a read point ALWAYS flows into the kernel's MVCC selection
+            # in these paths (even _MAX_HT), so the chunk-safety proof is
+            # unconditionally required before dropping any block
+            from ..ops.stream_scan import chunk_safe_mvcc
+            if read_ht is not None and not chunk_safe_mvcc(blocks):
+                return blocks, ()
+            from ..ops.scan import zone_prune_blocks
+            kept, kept_idx = zone_prune_blocks(blocks, where)
+            if len(kept) == len(blocks):
+                return blocks, ()
+            LAST_SCAN_PRUNE_STATS["blocks_pruned"] = \
+                len(blocks) - len(kept)
+            sp.set_tag("pruned", len(blocks) - len(kept))
+            return kept, ("zp", kept_idx)
 
     def _try_streaming_aggregate(self, req: ReadRequest, blocks, needed,
                                  read_ht: int):
@@ -1878,7 +1925,7 @@ class DocReadOperation:
         if resp is _SPILLED:
             return None     # over-cardinality: interpreted GROUP BY
         if resp is not None:
-            return resp
+            return self._served("streaming", resp)
         # zone-map pruning ahead of the monolithic batch build; the
         # restart window below still checks the FULL block list (a
         # pruned block's ambiguous-HT rows keep today's restart
@@ -2200,7 +2247,7 @@ class DocReadOperation:
         resp = self._try_streaming_filter(req, blocks, needed,
                                           proj_cols, read_ht)
         if resp is not None:
-            return resp
+            return self._served("streaming", resp)
         all_blocks = blocks
         blocks, prune_key = self._zone_prune(blocks, req.where, read_ht)
         try:

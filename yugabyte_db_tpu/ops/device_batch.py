@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..storage.columnar import ColumnarBlock
+from ..utils.trace import TRACES
 
 _BUCKETS = [1 << b for b in range(12, 24)]  # 4096 .. 8M rows
 
@@ -158,137 +159,148 @@ def build_batch(blocks: Sequence[ColumnarBlock],
     dictionary-encodes, decoding rows only as a last resort)."""
     n = sum(b.n for b in blocks)
     padded = pad_to or bucket_rows(max(n, 1))
-    cols: Dict[int, jnp.ndarray] = {}
-    nulls: Dict[int, jnp.ndarray] = {}
-    dicts: Dict[int, np.ndarray] = {}
-    col_bounds: Dict[int, Tuple[float, float]] = {}
-    copy_jobs: List[Tuple[np.ndarray, np.ndarray]] = []
-    host_cols: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
+    # `batch.build`: the host's gather and pad, up to the fused native
+    # copy; `batch.h2d`: the transfers to the device
+    with TRACES.span("batch.build", child_only=True):
+        cols: Dict[int, jnp.ndarray] = {}
+        nulls: Dict[int, jnp.ndarray] = {}
+        dicts: Dict[int, np.ndarray] = {}
+        col_bounds: Dict[int, Tuple[float, float]] = {}
+        copy_jobs: List[Tuple[np.ndarray, np.ndarray]] = []
+        host_cols: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
 
-    def fill(parts: List[np.ndarray],
-             out_dtype: Optional[np.dtype] = None) -> np.ndarray:
-        """Padded buffer filled from per-block parts; same-dtype
-        contiguous segments defer into the one fused native copy."""
-        dt = out_dtype or parts[0].dtype
-        out = np.zeros((padded,) + parts[0].shape[1:], dt)
-        pos = 0
-        for p in parts:
-            m = len(p)
-            if p.dtype == dt and p.flags["C_CONTIGUOUS"]:
-                copy_jobs.append((p, out[pos:pos + m]))
-            else:
-                out[pos:pos + m] = p    # converting assignment
-            pos += m
-        return out
-
-    for cid in columns:
-        if dict_plan is not None and cid in dict_plan.dicts:
-            # scan-global dictionary plan: per-block codes are already
-            # remapped into the shared dictionary — a pure int32 fill,
-            # no row-string decode, one dictionary for every chunk
-            code_parts = [dict_plan.block_codes(cid, b) for b in blocks]
-            nparts = [np.asarray(b.varlen[cid][2], bool)
-                      for b in blocks]
-            dicts[cid] = dict_plan.dicts[cid]
-            arr = fill(code_parts) if code_parts else \
-                np.zeros(padded, np.int32)
-            host_cols[cid] = (arr, fill(nparts) if nparts
-                              else np.zeros(padded, bool))
-            continue
-        if all(cid in b.varlen for b in blocks):
-            # string column: batch-global dictionary encoding — codes
-            # are order-preserving (sorted dict), so comparisons map to
-            # code space and LIKE maps to a host-built LUT.  The merge
-            # of per-block dictionaries (stored v2 dict lanes or the
-            # one-time byte-level unique) serves this without decoding
-            # rows; blocks that can't dictionary-encode fall back to
-            # the decode loop below
-            got = _dict_merge_column(blocks, cid)
-            if got is not None:
-                uniq, code_parts = got
-                null = np.concatenate(
-                    [np.asarray(b.varlen[cid][2], bool)
-                     for b in blocks])
-                dicts[cid] = uniq
-                arr = fill(code_parts)
-                host_cols[cid] = (arr, _pad(null, padded))
-                continue
-            vparts, nparts = [], []
-            for b in blocks:
-                try:
-                    vparts.append(varlen_strings(b, cid))
-                except UnicodeDecodeError:
-                    # BINARY payloads (or corrupt strings) don't
-                    # dictionary-encode; same contract as any other
-                    # non-columnar column — the caller falls back
-                    raise KeyError(
-                        f"column {cid} not dictionary-encodable")
-                nparts.append(np.asarray(b.varlen[cid][2], bool))
-            values = np.concatenate(vparts)
-            null = np.concatenate(nparts)
-            values = np.where(null, "", values)   # stable unique input
-            uniq, codes = np.unique(values, return_inverse=True)
-            dicts[cid] = uniq
-            cols[cid] = jnp.asarray(_pad(codes.astype(np.int32), padded))
-            nulls[cid] = jnp.asarray(_pad(null, padded))
-            continue
-        def lane_parts(src_blocks, with_nulls=True):
-            ps, nps = [], []
-            for b in src_blocks:
-                if cid in b.fixed:
-                    v, m = b.fixed[cid]
-                    ps.append(v)
-                    if with_nulls:
-                        nps.append(m)
-                elif cid in b.pk:
-                    ps.append(b.pk[cid])
-                    if with_nulls:
-                        nps.append(np.zeros(b.n, bool))
+        def fill(parts: List[np.ndarray],
+                 out_dtype: Optional[np.dtype] = None) -> np.ndarray:
+            """Padded buffer filled from per-block parts; same-dtype
+            contiguous segments defer into the one fused native copy."""
+            dt = out_dtype or parts[0].dtype
+            out = np.zeros((padded,) + parts[0].shape[1:], dt)
+            pos = 0
+            for p in parts:
+                m = len(p)
+                if p.dtype == dt and p.flags["C_CONTIGUOUS"]:
+                    copy_jobs.append((p, out[pos:pos + m]))
                 else:
-                    raise KeyError(
-                        f"column {cid} not available in columnar form")
-            return ps, nps
+                    out[pos:pos + m] = p    # converting assignment
+                pos += m
+            return out
 
-        parts, nparts = lane_parts(blocks)
-        stat_parts = (parts if bounds_blocks is None
-                      else lane_parts(bounds_blocks,
-                                      with_nulls=False)[0])
-        conv = (f64_conversion(stat_parts)
-                if stat_parts and stat_parts[0].dtype == np.float64
-                else None)
-        arr = fill(parts, conv)
-        stat_n = sum(len(p) for p in stat_parts)
-        if stat_n and arr.dtype.kind in "fiu":
-            # bounds from the parts (the padded tail is zeros and must
-            # not contaminate the stats the static SUM scales use)
-            col_bounds[cid] = (
-                float(min(p.min() for p in stat_parts if p.size)),
-                float(max(p.max() for p in stat_parts if p.size)))
-        host_cols[cid] = (arr, fill(nparts))
-    valid = np.zeros(padded, bool)
-    valid[:n] = True
-    mvcc_host = None
-    if with_mvcc:
-        mvcc_host = (fill([b.key_hash for b in blocks]),
-                     fill([b.ht for b in blocks]),
-                     fill([b.write_id for b in blocks]),
-                     fill([b.tombstone for b in blocks]))
-    from ..storage import native_lib
-    if copy_jobs and not native_lib.copy_multi(copy_jobs):
-        for s, d in copy_jobs:
-            d[:] = s
-    for cid, (arr, null) in host_cols.items():
-        cols[cid] = jnp.asarray(arr)
-        nulls[cid] = jnp.asarray(null)
-    batch = DeviceBatch(
-        n_rows=n, cols=cols, nulls=nulls, valid=jnp.asarray(valid),
-        unique_keys=all(b.unique_keys for b in blocks), dicts=dicts,
-        col_bounds=col_bounds)
-    if mvcc_host is not None:
-        batch.key_hash = jnp.asarray(mvcc_host[0])
-        batch.ht = jnp.asarray(mvcc_host[1])
-        batch.write_id = jnp.asarray(mvcc_host[2])
-        batch.tombstone = jnp.asarray(mvcc_host[3])
+        for cid in columns:
+            if dict_plan is not None and cid in dict_plan.dicts:
+                # scan-global dictionary plan: per-block codes are already
+                # remapped into the shared dictionary — a pure int32 fill,
+                # no row-string decode, one dictionary for every chunk
+                code_parts = [dict_plan.block_codes(cid, b) for b in blocks]
+                nparts = [np.asarray(b.varlen[cid][2], bool)
+                          for b in blocks]
+                dicts[cid] = dict_plan.dicts[cid]
+                arr = fill(code_parts) if code_parts else \
+                    np.zeros(padded, np.int32)
+                host_cols[cid] = (arr, fill(nparts) if nparts
+                                  else np.zeros(padded, bool))
+                continue
+            if all(cid in b.varlen for b in blocks):
+                # string column: batch-global dictionary encoding — codes
+                # are order-preserving (sorted dict), so comparisons map to
+                # code space and LIKE maps to a host-built LUT.  The merge
+                # of per-block dictionaries (stored v2 dict lanes or the
+                # one-time byte-level unique) serves this without decoding
+                # rows; blocks that can't dictionary-encode fall back to
+                # the decode loop below
+                got = _dict_merge_column(blocks, cid)
+                if got is not None:
+                    uniq, code_parts = got
+                    null = np.concatenate(
+                        [np.asarray(b.varlen[cid][2], bool)
+                         for b in blocks])
+                    dicts[cid] = uniq
+                    arr = fill(code_parts)
+                    host_cols[cid] = (arr, _pad(null, padded))
+                    continue
+                vparts, nparts = [], []
+                for b in blocks:
+                    try:
+                        vparts.append(varlen_strings(b, cid))
+                    except UnicodeDecodeError:
+                        # BINARY payloads (or corrupt strings) don't
+                        # dictionary-encode; same contract as any other
+                        # non-columnar column — the caller falls back
+                        raise KeyError(
+                            f"column {cid} not dictionary-encodable")
+                    nparts.append(np.asarray(b.varlen[cid][2], bool))
+                values = np.concatenate(vparts)
+                null = np.concatenate(nparts)
+                values = np.where(null, "", values)   # stable unique input
+                uniq, codes = np.unique(values, return_inverse=True)
+                dicts[cid] = uniq
+                cols[cid] = jnp.asarray(_pad(codes.astype(np.int32), padded))
+                nulls[cid] = jnp.asarray(_pad(null, padded))
+                continue
+            def lane_parts(src_blocks, with_nulls=True):
+                ps, nps = [], []
+                for b in src_blocks:
+                    if cid in b.fixed:
+                        v, m = b.fixed[cid]
+                        ps.append(v)
+                        if with_nulls:
+                            nps.append(m)
+                    elif cid in b.pk:
+                        ps.append(b.pk[cid])
+                        if with_nulls:
+                            nps.append(np.zeros(b.n, bool))
+                    else:
+                        raise KeyError(
+                            f"column {cid} not available in columnar form")
+                return ps, nps
+
+            parts, nparts = lane_parts(blocks)
+            stat_parts = (parts if bounds_blocks is None
+                          else lane_parts(bounds_blocks,
+                                          with_nulls=False)[0])
+            conv = (f64_conversion(stat_parts)
+                    if stat_parts and stat_parts[0].dtype == np.float64
+                    else None)
+            arr = fill(parts, conv)
+            stat_n = sum(len(p) for p in stat_parts)
+            if stat_n and arr.dtype.kind in "fiu":
+                # bounds from the parts (the padded tail is zeros and must
+                # not contaminate the stats the static SUM scales use)
+                col_bounds[cid] = (
+                    float(min(p.min() for p in stat_parts if p.size)),
+                    float(max(p.max() for p in stat_parts if p.size)))
+            host_cols[cid] = (arr, fill(nparts))
+        valid = np.zeros(padded, bool)
+        valid[:n] = True
+        mvcc_host = None
+        if with_mvcc:
+            mvcc_host = (fill([b.key_hash for b in blocks]),
+                         fill([b.ht for b in blocks]),
+                         fill([b.write_id for b in blocks]),
+                         fill([b.tombstone for b in blocks]))
+        from ..storage import native_lib
+        if copy_jobs and not native_lib.copy_multi(copy_jobs):
+            for s, d in copy_jobs:
+                d[:] = s
+    with TRACES.span("batch.h2d", child_only=True) as sp:
+        for cid, (arr, null) in host_cols.items():
+            cols[cid] = jnp.asarray(arr)
+            nulls[cid] = jnp.asarray(null)
+        batch = DeviceBatch(
+            n_rows=n, cols=cols, nulls=nulls, valid=jnp.asarray(valid),
+            unique_keys=all(b.unique_keys for b in blocks), dicts=dicts,
+            col_bounds=col_bounds)
+        if mvcc_host is not None:
+            batch.key_hash = jnp.asarray(mvcc_host[0])
+            batch.ht = jnp.asarray(mvcc_host[1])
+            batch.write_id = jnp.asarray(mvcc_host[2])
+            batch.tombstone = jnp.asarray(mvcc_host[3])
+        if sp.sampled:
+            # transfers are asynchronous: wait, so that the span times
+            # them and not their enqueue
+            jax.block_until_ready(
+                (cols, nulls, batch.valid, batch.key_hash, batch.ht,
+                 batch.write_id, batch.tombstone))
+            sp.set_tag("bytes", batch_bytes(batch))
     return batch
 
 
@@ -356,7 +368,7 @@ class DeviceBlockCache:
                 return self._map[key][0]
             self.misses += 1
         batch = builder()
-        size = _batch_bytes(batch)
+        size = batch_bytes(batch)
         with self._lock:
             if key in self._map:
                 # a racing builder (flush thread vs loop) landed the
@@ -387,7 +399,7 @@ class DeviceBlockCache:
             self._bytes = 0
 
 
-def _batch_bytes(b: DeviceBatch) -> int:
+def batch_bytes(b: DeviceBatch) -> int:
     total = b.valid.size * 1
     for a in list(b.cols.values()) + list(b.nulls.values()):
         total += a.size * a.dtype.itemsize

@@ -60,6 +60,7 @@ src/yb/docdb/pgsql_operation.cc:3153):
 """
 from __future__ import annotations
 
+import asyncio
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -126,7 +127,8 @@ def _mvcc_visible_latest(key_hash, ht, write_id, tombstone, valid, read_ht):
     inv_vis = jnp.logical_not(visible).astype(jnp.uint8)
     inv_ht = _UINT64_MAX - ht
     inv_wid = jnp.uint32(0xFFFFFFFF) - write_id
-    s_idx = lex_order((sort_kh, inv_vis, inv_ht, inv_wid))
+    with jax.named_scope("dedup_sort"):
+        s_idx = lex_order((sort_kh, inv_vis, inv_ht, inv_wid))
     s_kh = sort_kh[s_idx]
     first = jnp.concatenate([jnp.array([True]), s_kh[1:] != s_kh[:-1]])
     vis_sorted = visible[s_idx]
@@ -280,9 +282,10 @@ def masked_aggregate(group, agg_fns, prep, cols, nulls, consts, mask,
         # dict-key grouped aggregation (ops/grouped_scan.py): dense
         # stride encoding of scan-global dictionary codes, pow2 slot
         # bucket, spill-slot overflow detection
-        return grouped_reduce(group, agg_fns, prep, cols, nulls,
-                              consts, mask, domains, sum_scales,
-                              strategy)
+        with jax.named_scope("dict_group_reduce"):
+            return grouped_reduce(group, agg_fns, prep, cols, nulls,
+                                  consts, mask, domains, sum_scales,
+                                  strategy)
     if group is None:
         out, scales = [], []
         for i, (op, f) in enumerate(agg_fns):
@@ -554,6 +557,11 @@ class ScanKernel:
             raw = _build_kernel(where_node, aggs, group, mvcc_mode,
                                 static_sums=static_sums,
                                 strategy=strategy)
+            # a stable program name: a kept trace's "XLA Modules" line
+            # reads jit_scan_dedup..., not jit_fn
+            raw.__name__ = raw.__qualname__ = "_".join(
+                ["scan", mvcc_mode] + ([type(group).__name__.lower()]
+                                       if group is not None else []))
             fn = jax.jit(raw)
             self._cache[sig] = fn
             self.compiles += 1
@@ -779,8 +787,30 @@ class ScanKernel:
             )
         # (outs, scales, counts, mask[, gvals, n_groups | spill]) ->
         # rescale the fixed-point sums host-side; callers keep the
-        # historical shape (outs, counts, mask[, ...])
-        return (_rescale_outs(raw[0], raw[1]),) + tuple(raw[2:])
+        # historical shape (outs, counts, mask[, ...]).  The rescale is
+        # the first host read of the result, so this is where the host
+        # waits for the device: the `device.wait` span, and
+        # `Device_BlockUntilReady` for ASH.  A sampled span waits for
+        # every output first, so it holds the whole wait whatever the
+        # rescale reads.
+        with _trace.wait_status("Device_BlockUntilReady",
+                                component="device"), \
+                _trace.TRACES.span("device.wait", child_only=True) as sp:
+            if sp.sampled:
+                sp.set_tag("thread", _thread_kind())
+                jax.block_until_ready(raw)
+            outs = _rescale_outs(raw[0], raw[1])
+        return (outs,) + tuple(raw[2:])
+
+
+def _thread_kind() -> str:
+    """`loop` on a thread that runs an asyncio event loop (the wait
+    blocks every task of that loop), else `executor`."""
+    try:
+        asyncio.get_running_loop()
+        return "loop"
+    except RuntimeError:
+        return "executor"
 
 
 def _static_scales(aggs: Sequence[AggSpec],

@@ -13,6 +13,7 @@ distinct-group overflow.
 from __future__ import annotations
 
 import asyncio
+import contextlib
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -22,6 +23,7 @@ from ..client import YBClient
 from ..docdb.operations import ReadRequest, RowOp, eval_expr_py
 from ..rpc.messenger import RpcError
 from ..utils import flags
+from ..utils.trace import TRACES
 from ..docdb.table_codec import TableInfo
 from ..dockv.packed_row import ColumnSchema, ColumnType, TableSchema
 from ..dockv.partition import PartitionSchema
@@ -110,19 +112,35 @@ class SqlSession:
         # per-statement join-side schemas (label -> schema|None), set
         # by _select_join/_explain via _gather_join_schemas
         self._join_schemas: Dict[str, object] = {}
+        # the open `sql.plan` span of the SELECT being planned and the
+        # stack that ends it (see _select / _scan)
+        self._plan = None
 
     async def execute(self, sql: str) -> SqlResult:
-        return await self._dispatch(parse_statement(sql))
+        # the statement is the trace's root: parse, plan, the client's
+        # fan-out and every tablet RPC below share its trace_id
+        with TRACES.span("sql.execute") as sp:
+            with TRACES.span("sql.parse", child_only=True):
+                stmt = parse_statement(sql)
+            return await self._dispatch(stmt, sp)
 
     async def execute_script(self, sql: str) -> List[SqlResult]:
         """Multi-statement script: results in statement order
         (reference: the PG simple-query protocol runs whole scripts)."""
         from .parser import parse_script
-        return [await self._dispatch(s) for s in parse_script(sql)]
+        out = []
+        for s in parse_script(sql):
+            with TRACES.span("sql.execute") as sp:
+                out.append(await self._dispatch(s, sp))
+        return out
 
-    async def _dispatch(self, stmt) -> SqlResult:
+    async def _dispatch(self, stmt, sp) -> SqlResult:
+        """Run one parsed statement under its `sql.execute` span `sp`
+        (tags: the statement kind, rows returned, the stale-schema
+        retry)."""
+        sp.set_tag("stmt", type(stmt).__name__[:-len("Stmt")].lower())
         try:
-            return await self._dispatch_inner(stmt)
+            res = await self._dispatch_inner(stmt)
         except KeyError as orig:
             # an unknown column may just be a stale client schema cache
             # (ALTER through another node): binding precedes any write
@@ -136,7 +154,10 @@ class SqlSession:
                 await self.client._table(table, refresh=True)
             except Exception:   # noqa: BLE001 — not a real table (a
                 raise orig      # CTE or vtable): keep the original
-            return await self._dispatch_inner(stmt)
+            sp.set_tag("retried", True)
+            res = await self._dispatch_inner(stmt)
+        sp.set_tag("rows", len(res.rows))
+        return res
 
     async def _dispatch_inner(self, stmt) -> SqlResult:
         if isinstance(stmt, CreateTableStmt):
@@ -1692,6 +1713,31 @@ class SqlSession:
         return SqlResult(rows)
 
     async def _select(self, stmt: SelectStmt) -> SqlResult:
+        """A SELECT under its `sql.plan` span: bind, the ANALYZE-driven
+        choice of plan shape and the lowering to a `ReadRequest`, from
+        entry to the statement's first `client.scan` (`_scan` ends the
+        span there, tagged `route`).  A shape that reaches the tablets
+        another way (joins, kNN, views) ends it on return."""
+        with contextlib.ExitStack() as plan:
+            sp = plan.enter_context(TRACES.span("sql.plan",
+                                                child_only=True))
+            outer, self._plan = self._plan, (plan, sp)
+            try:
+                return await self._select_planned(stmt)
+            finally:
+                self._plan = outer
+
+    async def _scan(self, route: str, table: str, req: ReadRequest,
+                    **kw):
+        """`client.scan` for the SELECT being planned: planning ends
+        here, with the plan shape chosen as the span's `route`."""
+        if self._plan is not None:
+            plan, sp = self._plan
+            sp.set_tag("route", route)
+            plan.close()
+        return await self.client.scan(table, req, **kw)
+
+    async def _select_planned(self, stmt: SelectStmt) -> SqlResult:
         if stmt.order_by and any(
                 c.startswith("__ord:") for c, _ in stmt.order_by):
             # ORDER BY <ordinal> / ORDER BY <select-list expression>:
@@ -1867,7 +1913,7 @@ class SqlSession:
                          for _, op, e in agg_items) + \
                 tuple(AggSpec(op, self._bind(e, schema))
                       for op, e in refs)
-            resp = await self.client.scan(stmt.table, ReadRequest(
+            resp = await self._scan("agg_pushdown", stmt.table, ReadRequest(
                 "", where=where, aggregates=aggs, read_ht=read_ht))
             row = self._agg_row(stmt, resp.agg_values)
             row.update(self._hidden_agg_row(
@@ -1962,8 +2008,8 @@ class SqlSession:
         req = ReadRequest("", columns=tuple(columns), where=where,
                           read_ht=read_ht, limit=push_limit,
                           window=wwire)
-        resp = await self.client.scan(stmt.table, req,
-                                      keep_all=natural)
+        resp = await self._scan("row_scan", stmt.table, req,
+                                keep_all=natural)
         base_rows = resp.rows
         if self._txn is not None:
             base_rows = self._overlay_txn_writes(
@@ -2066,7 +2112,7 @@ class SqlSession:
             if e is not None:
                 self._collect_names(e, needed)
         cols = self._overlay_columns(sorted(needed), schema, where)
-        resp = await self.client.scan(stmt.table, ReadRequest(
+        resp = await self._scan("agg_clientside", stmt.table, ReadRequest(
             "", columns=tuple(cols), where=where, read_ht=read_ht))
         rows = self._overlay_txn_writes(stmt.table, schema, where,
                                         resp.rows)
@@ -3685,7 +3731,7 @@ class SqlSession:
         aggs = tuple(AggSpec(op, self._bind(e, schema))
                      for _, op, e in agg_items) + \
             tuple(AggSpec(op, self._bind(e, schema)) for op, e in refs)
-        resp = await self.client.scan(stmt.table, ReadRequest(
+        resp = await self._scan("grouped_pushdown", stmt.table, ReadRequest(
             "", where=where, aggregates=aggs, group_by=gspec,
             read_ht=read_ht))
         counts = np.asarray(resp.group_counts)
@@ -3794,7 +3840,7 @@ class SqlSession:
                    and self._txn.pending_writes(stmt.table))
         if overlay:
             cols = self._overlay_columns(cols, schema, where)
-        resp = await self.client.scan(stmt.table, ReadRequest(
+        resp = await self._scan("grouped_clientside", stmt.table, ReadRequest(
             "", columns=tuple(cols), where=where,
             read_ht=read_ht))
         scan_rows = resp.rows

@@ -43,12 +43,17 @@ from ..rpc.messenger import RECEIVED_AT, RpcError
 from ..utils import fault_injection as fi
 from ..utils import flags, metrics
 from ..utils.tasks import drain_all
-from ..utils.trace import TRACE, TRACES, wait_status
+from ..utils.trace import TRACES, wait_status
 from .batching import (PointReadItem, ScanItem, WriteItem,
                        dispatch_point_read_group, dispatch_scan_group,
                        dispatch_write_group)
 from .lanes import (DEFAULT_CONFIGS, Lane, LaneConfig,
                     classify_read as classify_read_wire)
+
+
+#: tags of a `sched.queue.<lane>` span that never queued (shared: the
+#: span copies them)
+_CUT_THROUGH = {"wait_ms": 0.0, "cut_through": True}
 
 
 class OverloadError(RpcError):
@@ -94,12 +99,13 @@ class _Group:
     re-queued for a fresh group once this one fills, and the two must
     dispatch independently."""
 
-    __slots__ = ("key", "items", "started")
+    __slots__ = ("key", "items", "started", "taken")
 
     def __init__(self, key):
         self.key = key
         self.items: List[tuple] = []
         self.started = False
+        self.taken: Optional[float] = None   # monotonic, at dequeue
 
 
 class _LaneState:
@@ -290,29 +296,45 @@ class RequestScheduler:
             # admission-only lane (TXN class — queueing txn control
             # behind txn control can deadlock), or cut-through on an
             # idle pooled lane: dispatch immediately
-            TRACE(f"sched.admit lane={st.lane.value} cut_through")
             st.inflight += 1
             t0 = time.monotonic()
             try:
-                return await run()
+                with TRACES.span(f"sched.queue.{st.lane.value}",
+                                 child_only=True, tags=_CUT_THROUGH):
+                    return await run()
             finally:
                 st.inflight -= 1
                 st.service_ms.update((time.monotonic() - t0) * 1e3)
         self._ensure_workers()
         fut = asyncio.get_running_loop().create_future()
         g = _Group(key=object())      # unique key: no batching
-        g.items.append((run, fut, cost_bytes, time.monotonic()))
+        now = time.monotonic()
+        g.items.append((run, fut, cost_bytes, now))
         st.queued += 1
         st.queued_bytes += cost_bytes
         st.m_depth.set(st.depth)
         st.queue.put_nowait(g)
-        # the queue span measures admission -> dequeue -> dispatch ->
-        # result for THIS request; the worker-side dispatch span (the
-        # shared execution) parents under the group's first member
+        return await self._await_queued(
+            st, g, fut, now, {"depth": st.depth, "cut_through": False})
+
+    async def _await_queued(self, st: _LaneState, g: _Group, fut, t_in,
+                            tags: dict, payload=None):
+        """Park one queued request on its future under its
+        `sched.queue.<lane>` span.  The span runs from admission to the
+        result, service included; `wait_ms` is the part before a worker
+        dequeued the group (what `sched_wait_us` counts).  The
+        worker-side `sched.dispatch.*` span (the shared execution) is
+        this span's child for the group's first member."""
         with TRACES.span(f"sched.queue.{st.lane.value}", child_only=True,
-                         tags={"depth": st.depth}):
-            with wait_status("SchedQueue_Wait", component="sched"):
-                return await fut
+                         tags=tags) as sp:
+            if sp.sampled and payload is not None:
+                payload.tctx = sp.context
+            try:
+                with wait_status("SchedQueue_Wait", component="sched"):
+                    return await fut
+            finally:
+                if g.taken is not None:
+                    sp.set_tag("wait_ms", (g.taken - t_in) * 1e3)
 
     # --- batched submission ----------------------------------------------
     async def submit_grouped(self, lane: Lane, key, payload, *,
@@ -334,14 +356,17 @@ class RequestScheduler:
         now = time.monotonic()
         if st.queued == 0 and st.inflight < (st.cfg.workers or 1) \
                 and not st.busy() and not fi.lane_armed(st.lane.value):
-            TRACE(f"sched.admit lane={st.lane.value} cut_through")
             st.inflight += 1
             st.m_batch.increment(1)
             st.m_occupancy.increment(100.0 / max(1, st.cfg.max_batch))
             fut = asyncio.get_running_loop().create_future()
             try:
-                await self._dispatch_group(
-                    st, [(payload, fut, cost_bytes, now)])
+                with TRACES.span(f"sched.queue.{st.lane.value}",
+                                 child_only=True, tags=_CUT_THROUGH) as sp:
+                    if sp.sampled:
+                        payload.tctx = sp.context
+                    await self._dispatch_group(
+                        st, [(payload, fut, cost_bytes, now)])
                 st.service_ms.update((time.monotonic() - now) * 1e3)
                 return fut.result()
             finally:
@@ -356,11 +381,10 @@ class RequestScheduler:
         g.items.append((payload, fut, cost_bytes, now))
         st.queued += 1
         st.queued_bytes += cost_bytes
-        with TRACES.span(f"sched.queue.{st.lane.value}", child_only=True,
-                         tags={"depth": st.depth,
-                               "group_members": len(g.items)}):
-            with wait_status("SchedQueue_Wait", component="sched"):
-                return await fut
+        return await self._await_queued(
+            st, g, fut, now,
+            {"depth": st.depth, "group_members": len(g.items),
+             "cut_through": False}, payload)
 
     # --- worker loop ------------------------------------------------------
     async def _worker(self, st: _LaneState):
@@ -431,7 +455,7 @@ class RequestScheduler:
         st.queued -= n
         st.queued_bytes -= sum(it[2] for it in items)
         st.inflight += n
-        now = time.monotonic()
+        now = g.taken = time.monotonic()
         for _, _, _, t_in in items:
             st.m_wait.increment((now - t_in) * 1e6)
         st.m_batch.increment(n)

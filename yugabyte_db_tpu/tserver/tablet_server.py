@@ -10,6 +10,7 @@ to the master.
 from __future__ import annotations
 
 import asyncio
+import inspect
 import json
 import os
 from typing import Dict, List, Optional, Tuple
@@ -28,7 +29,7 @@ from ..tablet.tablet import Tablet
 from ..tablet.tablet_peer import TabletPeer
 import logging
 
-from ..utils import flags
+from ..utils import flags, metrics
 from ..utils.fault_injection import TEST_CRASH_POINT
 from ..utils.hybrid_time import HybridClock
 from ..utils.tasks import cancel_and_drain
@@ -170,6 +171,11 @@ class TabletServer:
         # close (a crash leaves only unmanifested files the next open
         # sweeps — the lease discipline's crash half)
         self._bypass_sessions: set = set()
+        # how late each heartbeat tick woke against its 0.2 s sleep: the
+        # event loop's lag (work that blocks the loop delays every task
+        # on it, this one included)
+        self._m_tick_late = metrics.REGISTRY.entity(
+            "server", f"ts-{uuid}").histogram("heartbeat_tick_late_ms")
 
     # --- lifecycle --------------------------------------------------------
     async def start(self, host: str = "127.0.0.1", port: int = 0):
@@ -1497,6 +1503,7 @@ class TabletServer:
 
     async def _heartbeat_loop(self):
         self._register_ash_providers()
+        loop = asyncio.get_running_loop()
         ticks = 0
         while self._running:
             await self._heartbeat_once()
@@ -1506,48 +1513,61 @@ class TabletServer:
                 # live; server_main/ybtpud run the real thread
                 ASH.sample_once()
             ticks += 1
+            peers = list(self.peers.values())
             if ticks % 10 == 0:      # ~every 2s: txn coordinator sweep
-                for p in list(self.peers.values()):
-                    if p.coordinator is not None and p.is_leader():
-                        try:
-                            await p.coordinator.sweep()
-                        except Exception:
-                            log.exception("coordinator sweep failed")
+                await self._maintain(
+                    "coordinator_sweep",
+                    [p for p in peers
+                     if p.coordinator is not None and p.is_leader()],
+                    lambda p: p.coordinator.sweep())
             if ticks % 25 == 0:      # ~every 5s: WAL retention pass
-                for p in list(self.peers.values()):
-                    try:
-                        p.maybe_gc_log()
-                    except Exception:
-                        pass
+                await self._maintain("wal_gc", peers,
+                                     lambda p: p.maybe_gc_log())
             if ticks % 50 == 0:      # ~every 10s: background compaction
                 # (reference: full_compaction_manager.cc + the priority
                 # compaction pool; size-tiered trigger at >= 4 SSTs)
-                for p in list(self.peers.values()):
-                    try:
-                        if p.is_leader() and p.tablet.num_sst_files() >= 4:
-                            async def run(p=p):
-                                await asyncio.get_running_loop() \
-                                    .run_in_executor(
-                                        None, lambda: p.tablet.compact(
-                                            major=False))
-                            # maintenance lane: bounded + isolated from
-                            # the foreground lanes' dispatch slots
-                            await self.scheduler.submit(Lane.MAINTENANCE,
-                                                        run)
-                    except Exception:
-                        log.exception("background compaction failed for %s",
-                                      p.tablet.tablet_id)
+                await self._maintain(
+                    "compaction",
+                    [p for p in peers
+                     if p.is_leader() and p.tablet.num_sst_files() >= 4],
+                    self._background_compact)
                 # fold outgrown vector-index deltas back into the
                 # frozen IVF chunks (vector-LSM background compaction)
-                for p in list(self.peers.values()):
-                    try:
-                        if p.tablet.vector_indexes:
-                            await asyncio.get_running_loop().run_in_executor(
-                                None, p.tablet.maybe_rebuild_vector_indexes)
-                    except Exception:
-                        log.exception("vector index rebuild failed for %s",
-                                      p.tablet.tablet_id)
+                await self._maintain(
+                    "vector_fold",
+                    [p for p in peers if p.tablet.vector_indexes],
+                    lambda p: loop.run_in_executor(
+                        None, p.tablet.maybe_rebuild_vector_indexes))
+            due = loop.time() + 0.2
             await asyncio.sleep(0.2)
+            self._m_tick_late.increment((loop.time() - due) * 1e3)
+
+    async def _maintain(self, what: str, peers: list, fn) -> None:
+        """One periodic pass of the heartbeat loop over `peers`, each
+        through `fn(peer)` (awaited where it returns an awaitable), as
+        one `tserver.maintenance` root span: the pass runs on the loop
+        that serves reads, so a statement that stalled behind it can
+        name it.  A pass with nothing to do leaves no span."""
+        if not peers:
+            return
+        with TRACES.span("tserver.maintenance", parent=None,
+                         tags={"what": what, "tablets": len(peers)}):
+            for p in peers:
+                try:
+                    r = fn(p)
+                    if inspect.isawaitable(r):
+                        await r
+                except Exception:   # noqa: BLE001 — the loop goes on
+                    log.exception("%s failed for %s", what,
+                                  p.tablet.tablet_id)
+
+    async def _background_compact(self, p: TabletPeer) -> None:
+        async def run():
+            await asyncio.get_running_loop().run_in_executor(
+                None, lambda: p.tablet.compact(major=False))
+        # maintenance lane: bounded + isolated from the foreground
+        # lanes' dispatch slots
+        await self.scheduler.submit(Lane.MAINTENANCE, run)
 
     async def _heartbeat_once(self):
         report = {

@@ -17,6 +17,15 @@ This module is the system-wide observability layer (ISSUE 14):
   (rpc/messenger.py), which is how one user write becomes one
   cross-process span tree (client -> leader append/fsync -> follower
   append -> apply -> flush handoff).
+- ONE CLOCK: a span stamps ``time.perf_counter_ns()`` at start and
+  end (integers; ``start_unix`` stays for cross-process dumps).  While
+  the JAX profiler is collecting, every root is sampled whatever the
+  rate says and every sampled span is also entered as a
+  ``jax.profiler.TraceAnnotation("ybtpu:" + name, ...)``, so a
+  captured profile shows the program's spans on the device's own
+  timeline.  ``TRACES.finished(since_ns, until_ns)`` hands the
+  finished spans of an interval to in-process readers (the benchmark's
+  per-layer metrics); ``TRACES.evicted`` counts what the ring dropped.
 - EXECUTOR HOPS: a ``contextvars`` context does NOT survive
   ``run_in_executor`` / ``ThreadPoolExecutor.submit``.  Callers bridge
   explicitly: capture ``current_context()`` before the hop and wrap
@@ -39,10 +48,11 @@ import contextvars
 import itertools
 import os
 import random
+import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from typing import Deque, Dict, List, NamedTuple, Optional
 
@@ -60,6 +70,9 @@ class SpanContext(NamedTuple):
     sampled: bool
 
 
+#: name prefix of a span's mirror in the profiler's trace
+MIRROR_PREFIX = "ybtpu:"
+
 #: the ambient context after an UNSAMPLED root decision: children see
 #: "a trace exists and it is off" instead of re-rolling the sampler.
 _UNSAMPLED_CTX = SpanContext(0, 0, False)
@@ -69,6 +82,18 @@ _rng = random.Random(os.urandom(8))
 
 def _new_id() -> int:
     return _rng.getrandbits(63) or 1
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` while a JAX profiler session is
+    collecting, else None (``is_enabled`` is a static call that is False
+    outside a session).  This module never loads jax: while nothing in
+    the process has imported it there is no session either."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    ann = jax.profiler.TraceAnnotation
+    return ann if ann.is_enabled() else None
 
 
 def _flag(name: str, default):
@@ -89,11 +114,11 @@ class Trace:
     span_id: int = 0
     parent_id: int = 0
     sampled: bool = True
-    start: float = field(default_factory=time.monotonic)
+    start_ns: int = field(default_factory=time.perf_counter_ns)
     start_unix: float = field(default_factory=time.time)
     events: List[tuple] = field(default_factory=list)
     tags: Dict[str, object] = field(default_factory=dict)
-    done: Optional[float] = None
+    end_ns: Optional[int] = None
     dropped_events: int = 0
 
     #: per-span event cap: a chatty span (a tight loop calling TRACE)
@@ -102,14 +127,15 @@ class Trace:
     MAX_EVENTS = 512
 
     def add(self, message: str) -> None:
-        # monotonic-stamp fast path: stamps relative to `start`, and
+        # stamp fast path: seconds relative to `start_ns`, and
         # never throws — a late event (a thread racing finish(), or a
         # registry dump mid-append) degrades to a dropped event, not an
         # exception on the hot path it instruments
         try:
             if len(self.events) < self.MAX_EVENTS:
                 self.events.append(
-                    (time.monotonic() - self.start, message))
+                    ((time.perf_counter_ns() - self.start_ns) / 1e9,
+                     message))
             else:
                 self.dropped_events += 1
         except Exception:   # noqa: BLE001 — observability must not throw
@@ -125,12 +151,17 @@ class Trace:
     def context(self) -> SpanContext:
         return SpanContext(self.trace_id, self.span_id, self.sampled)
 
+    def count(self, key: str) -> None:
+        """Add one to a counting tag (retries, restarts)."""
+        self.set_tag(key, self.tags.get(key, 0) + 1)
+
     def finish(self) -> float:
-        self.done = time.monotonic()
-        return self.done - self.start
+        self.end_ns = time.perf_counter_ns()
+        return (self.end_ns - self.start_ns) / 1e9
 
     def duration_s(self) -> float:
-        return (self.done or time.monotonic()) - self.start
+        return ((self.end_ns or time.perf_counter_ns())
+                - self.start_ns) / 1e9
 
     def dump(self, events: Optional[list] = None) -> str:
         evs = list(self.events) if events is None else events
@@ -151,7 +182,7 @@ class Trace:
             "parent_id": self.parent_id, "name": self.name,
             "start_unix": self.start_unix,
             "duration_ms": round(self.duration_s() * 1e3, 3),
-            "finished": self.done is not None,
+            "finished": self.end_ns is not None,
             "tags": dict(self.tags),
             "events": [[round(dt * 1e3, 3), str(m)] for dt, m in evs],
         }
@@ -172,6 +203,9 @@ class _NoopSpan:
     def set_tag(self, key: str, value) -> None:
         pass
 
+    def count(self, key: str) -> None:
+        pass
+
     def finish(self) -> float:
         return 0.0
 
@@ -181,22 +215,41 @@ class _NoopSpan:
 
 
 _NOOP = _NoopSpan()
+_NO_WAIT = nullcontext()
+
+
+class SpanRecord(NamedTuple):
+    """One finished span as ``TRACES.finished`` hands it out: plain
+    data on the ``perf_counter_ns`` clock."""
+
+    name: str
+    trace_id: int
+    span_id: int
+    parent_id: int
+    start_ns: int
+    end_ns: int
+    tags: dict
 
 
 class TraceRegistry:
-    """Keeps recent finished spans for /rpcz + rpc_tracez."""
+    """Keeps recent finished spans for /rpcz, rpc_tracez and the
+    in-process readers of ``finished``."""
 
-    def __init__(self, keep: int = 200, slow_threshold_s: float = 0.5):
+    def __init__(self, keep: int = 4096, slow_threshold_s: float = 0.5):
         self.recent: Deque[Trace] = deque(maxlen=keep)
         self.active: Dict[int, Trace] = {}
         self.slow_threshold_s = slow_threshold_s
+        #: finished spans the ring dropped to make room: a reader that
+        #: needs every span of an interval checks this did not move
+        self.evicted = 0
         self._lock = threading.Lock()
         self._next = 0
 
     def _ensure_keep(self) -> None:
-        keep = int(_flag("tracez_keep", self.recent.maxlen or 200))
+        keep = int(_flag("tracez_keep", self.recent.maxlen or 4096))
         if keep > 0 and keep != self.recent.maxlen:
             with self._lock:
+                self.evicted += max(0, len(self.recent) - keep)
                 self.recent = deque(self.recent, maxlen=keep)
 
     @contextmanager
@@ -204,13 +257,19 @@ class TraceRegistry:
              child_only: bool = False, force: bool = False):
         """Open a span.
 
-        - parent="inherit" (default): child of the ambient context.
-        - No ambient context: a ROOT, sampled at
-          ``trace_sampling_rate`` (``force=True`` records regardless —
+        - parent="inherit" (default): child of the ambient context;
+          parent=None: a root whatever is ambient (background loops
+          whose task inherited some request's context).
+        - No parent context: a ROOT, sampled at
+          ``trace_sampling_rate`` — and always while the JAX profiler
+          is collecting (``force=True`` records regardless —
           the legacy ``trace()`` API and test harnesses use it;
           ``child_only=True`` refuses to root at all — for seams like
           raft broadcasts that are only meaningful inside a request).
         - Unsampled context: yields a shared no-op span.
+
+        A sampled span is mirrored into the profiler's trace while a
+        session collects (``ybtpu:<name>``, same start and end).
         """
         cur = _current_trace.get() if parent == "inherit" else parent
         pctx = cur.context if isinstance(cur, Trace) else cur
@@ -222,7 +281,8 @@ class TraceRegistry:
                 yield _NOOP
                 return
             rate = float(_flag("trace_sampling_rate", 0.0))
-            if rate <= 0.0 or _rng.random() >= rate:
+            if (rate <= 0.0 or _rng.random() >= rate) \
+                    and _profiler_annotation() is None:
                 token = _current_trace.set(_UNSAMPLED_CTX)
                 try:
                     yield _NOOP
@@ -241,14 +301,36 @@ class TraceRegistry:
             self._next += 1
             self.active[tid] = t
         token = _current_trace.set(t)
+        mirror = _profiler_annotation()
+        if mirror is not None:
+            mirror = mirror(MIRROR_PREFIX + name, trace_id=t.trace_id,
+                            span_id=t.span_id, parent_id=t.parent_id)
+            mirror.__enter__()
         try:
             yield t
         finally:
             t.finish()
+            if mirror is not None:
+                mirror.__exit__(None, None, None)
             _current_trace.reset(token)
+            self._ensure_keep()
             with self._lock:
                 self.active.pop(tid, None)
+                if len(self.recent) == self.recent.maxlen:
+                    self.evicted += 1
                 self.recent.append(t)
+
+    def finished(self, since_ns: int = 0,
+                 until_ns: Optional[int] = None) -> List[SpanRecord]:
+        """The finished spans still in the ring that BEGAN in
+        ``[since_ns, until_ns]`` (``perf_counter_ns``), oldest first."""
+        with self._lock:
+            spans = list(self.recent)
+        return [SpanRecord(t.name, t.trace_id, t.span_id, t.parent_id,
+                           t.start_ns, t.end_ns, dict(t.tags))
+                for t in spans
+                if t.start_ns >= since_ns
+                and (until_ns is None or t.start_ns <= until_ns)]
 
     @contextmanager
     def trace(self, name: str):
@@ -264,7 +346,7 @@ class TraceRegistry:
         with self._lock:
             act = [(t, list(t.events)) for t in self.active.values()]
             rec = [(t, list(t.events)) for t in self.recent
-                   if t.done and (t.done - t.start) > self.slow_threshold_s]
+                   if t.end_ns and t.duration_s() > self.slow_threshold_s]
         return {
             "active": [t.dump(evs) for t, evs in act],
             "recent_slow": [t.dump(evs) for t, evs in rec],
@@ -290,6 +372,13 @@ def TRACE(message: str) -> None:
     t = _current_trace.get()
     if isinstance(t, Trace):
         t.add(message)
+
+
+def current_span():
+    """The ambient span when this process opened it and it records;
+    the shared no-op span otherwise (tags set on it go nowhere)."""
+    t = _current_trace.get()
+    return t if isinstance(t, Trace) else _NOOP
 
 
 def current_context() -> Optional[SpanContext]:
@@ -345,20 +434,27 @@ def device_span(kind: str, signature=None, compiled: bool = False,
     """Per-kernel-launch telemetry: a span tagged {signature,
     compile|cache_hit, bucket, rows}, so a compile landing inside a
     measured round is VISIBLE in the trace instead of inferred from
-    compile counters.  One contextvar read when no sampled trace is
-    ambient — safe on the hot path."""
-    cur = _current_trace.get()
-    if not isinstance(cur, Trace):
-        yield None
-        return
-    sig = (f"{hash(signature) & 0xFFFFFFFFFFFFFFFF:016x}"
-           if signature is not None else None)
-    with TRACES.span(
-            f"device.{kind}", child_only=True,
-            tags={"signature": sig,
-                  "codepath": "compile" if compiled else "cache_hit",
-                  "bucket": bucket, "rows": rows}) as sp:
-        yield sp
+    compile counters.  It times the DISPATCH — jit cache lookup,
+    argument flattening, enqueue — and the compile when
+    ``codepath=compile`` (published to ASH as ``Device_Compile``); JAX
+    returns before the device finishes, so the device's own time is the
+    ``device.wait`` span around the first host read of the result.  One
+    contextvar read when no sampled trace is ambient — safe on the hot
+    path."""
+    with (wait_status("Device_Compile", component="device")
+          if compiled else _NO_WAIT):
+        cur = _current_trace.get()
+        if not isinstance(cur, Trace):
+            yield None
+            return
+        sig = (f"{hash(signature) & 0xFFFFFFFFFFFFFFFF:016x}"
+               if signature is not None else None)
+        with TRACES.span(
+                f"device.{kind}", child_only=True,
+                tags={"signature": sig,
+                      "codepath": "compile" if compiled else "cache_hit",
+                      "bucket": bucket, "rows": rows}) as sp:
+            yield sp
 
 
 # --- ASH ------------------------------------------------------------------
