@@ -87,27 +87,38 @@ def _consts(query, aggs):
     return consts
 
 
-def _scan_args(query, n_total):
+def _scan_args(query, n_total, mvcc_mode="visible"):
     """(avg-expanded aggs, static_sums, example args, example rows): the
     argument list `ScanKernel.run` and `__graft_entry__.entry()` build,
-    on a small batch with the TPU arm's dtypes.  A described device can
-    hold no array, so callers turn these into shapes."""
+    on a small batch with the TPU arm's dtypes; the MVCC lanes
+    (`args[4]`) are those `mvcc_lanes` hands out for `mvcc_mode`.  A
+    described device can hold no array, so callers turn these into
+    shapes."""
     from __graft_entry__ import _example_batch
     from yugabyte_db_tpu.ops.device_batch import build_batch
     from yugabyte_db_tpu.ops.scan import (_expand_avg, _group_strategy,
-                                          _static_scales)
+                                          _static_scales, mvcc_lanes)
     aggs = tuple(_expand_avg(query.aggs))
-    batch = build_batch(_example_batch(), sorted(query.columns))
+    batch = build_batch(_example_batch(), sorted(query.columns),
+                        multi_version=mvcc_mode == "linked")
     assert batch.cols[2].dtype == jnp.float32       # l_extendedprice
-    assert batch.key_hash.dtype == jnp.uint64 and batch.ht.dtype == jnp.uint64
+    assert batch.ht.dtype == jnp.uint64
     assert _group_strategy() == "unroll"
     static_sums, scale_args = _static_scales(
         aggs, batch.col_bounds, n_total, batch.cols)
+    mode, lanes = mvcc_lanes(batch, 1 << 63)
+    assert mode == mvcc_mode
+    assert (lanes[1] is not None) == (mode == "linked")
     args = (batch.cols, batch.nulls,
             [jnp.asarray(c) for c in _consts(query, aggs)],
-            batch.valid, batch.key_hash, batch.ht, batch.write_id,
-            batch.tombstone, jnp.uint64(1 << 63), scale_args)
+            batch.valid, lanes, jnp.uint64(1 << 63), scale_args)
     return aggs, static_sums, args, batch.padded_rows
+
+
+def _flat(args):
+    """`args` as the single-chip kernel takes them: the lanes spliced
+    in place."""
+    return args[:4] + tuple(args[4]) + args[5:]
 
 
 def _shapes(tree, small: int, rows_shape, row_sharding, scalar_sharding):
@@ -123,21 +134,24 @@ def _shapes(tree, small: int, rows_shape, row_sharding, scalar_sharding):
     return jax.tree_util.tree_map(one, tree)
 
 
-@pytest.mark.parametrize("mvcc_mode", ["visible", "dedup"])
+@pytest.mark.parametrize("mvcc_mode", ["visible", "linked"])
 @pytest.mark.parametrize("query_name", ["q6", "q1"])
 def test_scan_kernel_compiles(one_chip, tpu_arms, query_name, mvcc_mode):
     """Flat (Q6) and grouped (Q1) scan, single-version (`visible`) and
-    multi-version (`dedup`: the sort) MVCC, f32 value lanes and u64
-    hash/time lanes, at the streaming bucket."""
+    multi-version (`linked`: the `next_ht` lane) MVCC, f32 value lanes
+    and u64 time lanes, at the streaming bucket.  No served scan
+    program sorts: the mask is elementwise in either mode."""
     from yugabyte_db_tpu.models import tpch
     from yugabyte_db_tpu.ops.scan import _build_kernel
     query = {"q6": tpch.TPCH_Q6, "q1": tpch.TPCH_Q1}[query_name]
-    aggs, static_sums, args, small = _scan_args(query, SCAN_ROWS)
+    aggs, static_sums, args, small = _scan_args(query, SCAN_ROWS,
+                                                mvcc_mode)
     fn = _build_kernel(query.where, aggs, query.group, mvcc_mode,
                        static_sums=static_sums, strategy="unroll")
-    shapes = _shapes(args, small, (SCAN_ROWS,), one_chip, one_chip)
+    shapes = _shapes(_flat(args), small, (SCAN_ROWS,), one_chip, one_chip)
     compiled = _compile(lambda: jax.jit(fn).lower(*shapes))
-    assert ("sort" in compiled.as_text()) == (mvcc_mode == "dedup")
+    text = compiled.as_text()
+    assert "sort" not in text and "while" not in text
 
 
 def test_sort_grouped_kernel_compiles(one_chip, tpu_arms):
@@ -150,7 +164,7 @@ def test_sort_grouped_kernel_compiles(one_chip, tpu_arms):
     fn = _build_kernel(
         q.where, aggs, HashGroupSpec((tpch.RETFLAG, tpch.LINESTATUS)),
         "visible", static_sums=static_sums, strategy="unroll")
-    shapes = _shapes(args, small, (SCAN_ROWS,), one_chip, one_chip)
+    shapes = _shapes(_flat(args), small, (SCAN_ROWS,), one_chip, one_chip)
     assert "sort" in _compile(lambda: jax.jit(fn).lower(*shapes)).as_text()
 
 

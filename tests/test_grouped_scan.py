@@ -161,9 +161,8 @@ class TestGroupedParity:
                 AggSpec("min", C(3).node), AggSpec("max", C(3).node))
         plan = make_dict_plan(blocks, [1, 2, 3])
         kernel = ScanKernel()
-        batch = build_batch(blocks, [1, 2, 3], dict_plan=plan)
-        if len(blocks) > 1:
-            batch.unique_keys = False
+        batch = build_batch(blocks, [1, 2, 3], dict_plan=plan,
+                            multi_version=len(blocks) > 1)
         douts, dcounts, _, spill = kernel.run(batch, None, aggs, spec,
                                               None)
         assert int(spill) == 0

@@ -781,7 +781,7 @@ class TestSpanRegistry:
             assert reg.evicted == 2
         finally:
             flags.REGISTRY.reset("tracez_keep")
-        assert flags.get("tracez_keep") == 4096
+        assert flags.get("tracez_keep") == 32768
 
     def test_slow_threshold_still_reads_durations(self):
         reg = trace_mod.TraceRegistry(slow_threshold_s=0.0)

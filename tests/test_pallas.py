@@ -70,8 +70,7 @@ class TestPallasRoutedPath:
         valid[:n] = True
         nulls = {cid: jnp.zeros(padded, bool) for cid in cols}
         return DeviceBatch(cols=cols, nulls=nulls, valid=jnp.asarray(valid),
-                           key_hash=None, ht=None, write_id=None,
-                           tombstone=None, unique_keys=True, n_rows=n)
+                           n_rows=n)
 
     def _q6(self, kernel, batch):
         from yugabyte_db_tpu.ops import Expr

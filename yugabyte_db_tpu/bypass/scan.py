@@ -352,8 +352,9 @@ def _monolithic_twin(blocks, cols_sorted, where, aggs_run, group,
                      read_ht, kernel, pf, dict_out: dict = None):
     """The under-min_chunks shape, mirroring the RPC monolithic
     aggregate path bit-for-bit (zone-prune gate, single bucket over the
-    kept rows, unique_keys forced off for multi-block inputs, string
-    predicates rewritten against the batch dictionaries) so bypass
+    kept rows, string predicates rewritten against the batch
+    dictionaries; the blocks are proved one version a key, so no row
+    versions are linked and the mask is the `visible` one) so bypass
     results stay byte-identical whichever shape the row count picks.
     Dict-grouped scans return ``((outs, counts), batch dictionaries)``
     — the caller decodes slots through the same dictionaries the group
@@ -380,8 +381,6 @@ def _monolithic_twin(blocks, cols_sorted, where, aggs_run, group,
         # alone: a KeyError from kernel dispatch below would be a real
         # bug and must propagate, not masquerade as ineligibility.
         raise BypassIneligible(REASON_COLUMN_NOT_FIXED, str(e))
-    if len(blocks) > 1:
-        batch.unique_keys = False
     if dict_out is not None:
         dict_out["dicts"] = batch.dicts
     if batch.dicts and (where is not None

@@ -25,6 +25,7 @@ from ..dockv.value import PrimitiveValue, ValueKind, unwrap_ttl
 from ..ops.device_batch import batch_bytes, build_batch
 from ..ops.grouped_scan import DictGroupSpec
 from ..ops.scan import AggSpec, GroupSpec, HashGroupSpec, ScanKernel
+from ..ops.stream_scan import chunk_safe_mvcc
 from ..storage.columnar import ColumnarBlock, fnv64_bytes
 from ..storage.lsm import LsmStore, WriteBatch
 from ..utils import flags
@@ -1610,17 +1611,27 @@ class DocReadOperation:
                 self.store.write_generation(),
                 flags.get("device_float_dtype"))
 
-    def _cached_batch(self, blocks, needed, extra: tuple = ()):
+    def _cached_batch(self, blocks, needed, extra: tuple = (),
+                      collected=None):
         """Build (or fetch from the device cache) the columnar batch for
         `needed` columns. `extra` extends the cache key — the zone-map
         prune signature rides here so a batch built from one predicate's
-        pruned block set never serves another predicate."""
+        pruned block set never serves another predicate.
+        `collected`: the store's whole block list where `blocks` is its
+        zone-pruned part.  Unless it is proved one version a key (it is
+        not with several SSTs or a memtable overlay), the batch links
+        its row versions when it is built (`next_ht`) and is served
+        `linked`; a property of the store's contents, which the key
+        already names (SST paths, write generation), looked at on a miss
+        only."""
         miss = False
 
         def build():
             nonlocal miss
             miss = True
-            return build_batch(blocks, sorted(needed))
+            return build_batch(
+                blocks, sorted(needed),
+                multi_version=not chunk_safe_mvcc(collected or blocks))
 
         with _trace.TRACES.span("docdb.batch", child_only=True) as sp:
             if self.device_cache is None:
@@ -1657,7 +1668,6 @@ class DocReadOperation:
             # a read point ALWAYS flows into the kernel's MVCC selection
             # in these paths (even _MAX_HT), so the chunk-safety proof is
             # unconditionally required before dropping any block
-            from ..ops.stream_scan import chunk_safe_mvcc
             if read_ht is not None and not chunk_safe_mvcc(blocks):
                 return blocks, ()
             from ..ops.scan import zone_prune_blocks
@@ -1932,13 +1942,11 @@ class DocReadOperation:
         # behavior)
         kept, prune_key = self._zone_prune(blocks, req.where, read_ht)
         try:
-            batch = self._cached_batch(kept, needed, prune_key)
+            batch = self._cached_batch(kept, needed, prune_key,
+                                       collected=blocks)
         except KeyError:
             return None   # some column lacks columnar form → CPU path
         self._check_restart_window(blocks, read_ht)
-        # multiple overlapping sources → force dedup mode via unique_keys
-        if len(blocks) > 1:
-            batch.unique_keys = False
         where = req.where
         aggregates = req.aggregates
         if where is not None or any(a.expr is not None
@@ -2253,11 +2261,10 @@ class DocReadOperation:
         try:
             # same device cache as the aggregate path: repeated string-
             # predicate scans must not rebuild dictionaries per query
-            batch = self._cached_batch(blocks, needed, prune_key)
+            batch = self._cached_batch(blocks, needed, prune_key,
+                                       collected=all_blocks)
         except KeyError:
             return None
-        if len(all_blocks) > 1:
-            batch.unique_keys = False
         where = req.where
         if where is not None:
             try:

@@ -40,17 +40,21 @@ class DeviceBatch:
 
     cols / nulls: col_id -> [N] arrays (nulls True where SQL NULL).
     valid: [N] bool — False on padding rows and MVCC-invisible rows.
+    ht / tombstone: the MVCC lanes (absent when built without them).
+    next_ht: present exactly when the batch may hold several versions of
+    a key — per row the `ht` of the next newer version of its key among
+    the batch's rows (`link_versions`), which makes the kernel's
+    newest-visible-version mask one elementwise pass.  The doc-key hash
+    and the write id stay on the host: only the link reads them.
     """
 
     n_rows: int                      # true (unpadded) row count
     cols: Dict[int, jnp.ndarray]
     nulls: Dict[int, jnp.ndarray]
     valid: jnp.ndarray
-    key_hash: Optional[jnp.ndarray] = None
     ht: Optional[jnp.ndarray] = None
-    write_id: Optional[jnp.ndarray] = None
+    next_ht: Optional[jnp.ndarray] = None
     tombstone: Optional[jnp.ndarray] = None
-    unique_keys: bool = True
     # string columns ride as int32 dictionary CODES in `cols`; the
     # sorted dictionaries stay host-side here — predicates translate to
     # code space (order-preserving) or LUT gathers before compilation
@@ -124,14 +128,54 @@ def f64_conversion(parts) -> Optional[np.dtype]:
     return None if dd == np.float64 else dd
 
 
+#: `next_ht` of a row version that has no newer version in its batch
+HT_NONE = np.uint64(0xFFFFFFFFFFFFFFFF)
+
+
+def link_versions(key_hash: np.ndarray, ht: np.ndarray,
+                  write_id: np.ndarray) -> Tuple[np.ndarray, int]:
+    """(next_ht, superseded): per row the `ht` of the next newer version
+    of its key — the row that follows it in (key_hash, ht, write_id)
+    order — or HT_NONE where it is the newest; and how many rows have a
+    newer version.  Host-side and independent of any read time, so it is
+    computed once per batch: a version is then the newest visible one at
+    `read_ht` exactly when `ht <= read_ht < next_ht`.  Of two versions
+    with one `ht` the larger write id is the later; of exact duplicates
+    (a replayed write) the later row.  Only the rows that share their
+    hash with another row are ordered; the rest is one argsort."""
+    n = len(key_hash)
+    next_ht = np.full(n, HT_NONE, np.uint64)
+    order = np.argsort(key_hash)
+    s_kh = key_hash[order]
+    same = s_kh[1:] == s_kh[:-1]
+    if not same.any():
+        return next_ht, 0
+    shared = np.zeros(n, bool)
+    shared[order[1:][same]] = True
+    shared[order[:-1][same]] = True
+    rows = np.flatnonzero(shared)        # block order: ties keep it
+    rows = rows[np.lexsort((write_id[rows], ht[rows], key_hash[rows]))]
+    has_next = key_hash[rows[1:]] == key_hash[rows[:-1]]
+    next_ht[rows[:-1][has_next]] = ht[rows[1:][has_next]]
+    return next_ht, int(has_next.sum())
+
+
 def build_batch(blocks: Sequence[ColumnarBlock],
                 columns: Sequence[int],
                 with_mvcc: bool = True,
                 pad_to: Optional[int] = None,
                 bounds_blocks: Optional[Sequence[ColumnarBlock]] = None,
-                dict_plan=None) -> DeviceBatch:
+                dict_plan=None,
+                multi_version: bool = False) -> DeviceBatch:
     """Concatenate columnar blocks and ship the requested columns to
     device, padded to a row bucket.
+
+    ``multi_version``: the blocks may hold several versions of a key
+    (overlapping SSTs, a memtable overlay) even where each block is
+    unique-keyed by itself.  Such a batch — and any batch with a block
+    that is not unique-keyed — gets the ``next_ht`` lane
+    (:func:`link_versions`, span ``batch.version_link``); a batch
+    proved single-version ships without it.
 
     Batch formation is a single fused pass: every column (and MVCC
     lane) fills its padded host buffer directly — per-block segments of
@@ -271,35 +315,43 @@ def build_batch(blocks: Sequence[ColumnarBlock],
             host_cols[cid] = (arr, fill(nparts))
         valid = np.zeros(padded, bool)
         valid[:n] = True
-        mvcc_host = None
+        ht_host = tomb_host = next_host = None
         if with_mvcc:
-            mvcc_host = (fill([b.key_hash for b in blocks]),
-                         fill([b.ht for b in blocks]),
-                         fill([b.write_id for b in blocks]),
-                         fill([b.tombstone for b in blocks]))
+            ht_host = fill([b.ht for b in blocks])
+            tomb_host = fill([b.tombstone for b in blocks])
         from ..storage import native_lib
         if copy_jobs and not native_lib.copy_multi(copy_jobs):
             for s, d in copy_jobs:
                 d[:] = s
+        if with_mvcc and (multi_version
+                          or not all(b.unique_keys for b in blocks)):
+            with TRACES.span("batch.version_link",
+                             child_only=True) as sp:
+                next_host = np.full(padded, HT_NONE, np.uint64)
+                next_host[:n], superseded = link_versions(
+                    np.concatenate([b.key_hash for b in blocks]),
+                    ht_host[:n],
+                    np.concatenate([b.write_id for b in blocks]))
+                sp.set_tag("rows", n)
+                sp.set_tag("superseded", superseded)
     with TRACES.span("batch.h2d", child_only=True) as sp:
         for cid, (arr, null) in host_cols.items():
             cols[cid] = jnp.asarray(arr)
             nulls[cid] = jnp.asarray(null)
         batch = DeviceBatch(
             n_rows=n, cols=cols, nulls=nulls, valid=jnp.asarray(valid),
-            unique_keys=all(b.unique_keys for b in blocks), dicts=dicts,
-            col_bounds=col_bounds)
-        if mvcc_host is not None:
-            batch.key_hash = jnp.asarray(mvcc_host[0])
-            batch.ht = jnp.asarray(mvcc_host[1])
-            batch.write_id = jnp.asarray(mvcc_host[2])
-            batch.tombstone = jnp.asarray(mvcc_host[3])
+            dicts=dicts, col_bounds=col_bounds)
+        if with_mvcc:
+            batch.ht = jnp.asarray(ht_host)
+            batch.tombstone = jnp.asarray(tomb_host)
+        if next_host is not None:
+            batch.next_ht = jnp.asarray(next_host)
         if sp.sampled:
             # transfers are asynchronous: wait, so that the span times
             # them and not their enqueue
             jax.block_until_ready(
-                (cols, nulls, batch.valid, batch.key_hash, batch.ht,
-                 batch.write_id, batch.tombstone))
+                (cols, nulls, batch.valid, batch.ht, batch.next_ht,
+                 batch.tombstone))
             sp.set_tag("bytes", batch_bytes(batch))
     return batch
 
@@ -403,7 +455,7 @@ def batch_bytes(b: DeviceBatch) -> int:
     total = b.valid.size * 1
     for a in list(b.cols.values()) + list(b.nulls.values()):
         total += a.size * a.dtype.itemsize
-    for a in (b.key_hash, b.ht, b.write_id, b.tombstone):
+    for a in (b.ht, b.next_ht, b.tombstone):
         if a is not None:
             total += a.size * a.dtype.itemsize
     return total

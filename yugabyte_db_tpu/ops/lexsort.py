@@ -1,9 +1,14 @@
 """Stable lexicographic row order from single-key sort passes.
 
+Callers: the sort-grouped scan (`HashGroupSpec`, ops/scan.py) and the
+compaction merge (ops/compaction.py).  MVCC no longer sorts: a
+multi-version batch carries its versions' links from the host
+(ops/device_batch.py link_versions) and the scan's mask is elementwise.
+
 The TPU compiler's time over `lax.sort` grows steeply with the number
 of sort keys (the comparator is inlined into every stage of the sort
-network): the 4-key MVCC sort and the (key words + 2)-key compaction
-merge each took it minutes per row bucket.  A single u32 key plus the
+network): a 4-key sort and the (key words + 2)-key compaction merge
+each took it minutes per row bucket.  A single u32 key plus the
 carried permutation compiles in seconds, so the multi-key order is
 built the radix way — one stable single-key pass per 32-bit word,
 least significant word first — inside a `fori_loop`, which keeps ONE

@@ -54,7 +54,8 @@ from .join_scan import (BUILD_COL_BASE, JOIN_STATS, JoinIneligible,
                         normalize_join, probe_table)
 from .scan import (AggSpec, HashGroupSpec, _expand_avg, _group_strategy,
                    _rescale_outs, _static_scales, _sum_prep,
-                   _sum_prep_static, masked_aggregate, visibility_mask)
+                   _sum_prep_static, masked_aggregate, mvcc_lanes,
+                   visibility_mask)
 
 #: process-wide fused-plan accounting: compiles/launches from the plan
 #: kernel cache, fallbacks tallied by the routing layers
@@ -105,11 +106,11 @@ class FusedPlanKernel:
                 return q, s, None
             return _sum_prep(v, m, n_total)
 
-        def fn(cols, nulls, consts, valid, key_hash, ht, write_id,
-               tombstone, read_ht, sum_scales, group_domains, joins):
+        def fn(cols, nulls, consts, valid, ht, next_ht, tombstone,
+               read_ht, sum_scales, group_domains, joins):
             import jax.numpy as jnp
-            mask = visibility_mask(mvcc_mode, valid, key_hash, ht,
-                                   write_id, tombstone, read_ht)
+            mask = visibility_mask(mvcc_mode, valid, ht, next_ht,
+                                   tombstone, read_ht)
             if where_fn is not None:
                 wv, wn = where_fn(cols, nulls, consts)
                 mask = mask & wv
@@ -173,12 +174,7 @@ class FusedPlanKernel:
                     f"lane on device", stage=si)
             for bid in rt.build_cols:
                 avail[bid] = str(rt.payload_vals[bid].dtype)
-        if read_ht is None:
-            mvcc_mode = "none"
-        elif batch.unique_keys:
-            mvcc_mode = "visible"
-        else:
-            mvcc_mode = "dedup"
+        mvcc_mode, lanes = mvcc_lanes(batch, read_ht)
         consts: List = []
         if where is not None:
             collect_constants(where, consts)
@@ -240,24 +236,14 @@ class FusedPlanKernel:
             PLAN_STATS["cache_hits"] += 1
         self.launches += 1
         PLAN_STATS["launches"] += 1
-        zeros_u64 = jnp.zeros(batch.padded_rows, jnp.uint64)
-        zeros_u32 = jnp.zeros(batch.padded_rows, jnp.uint32)
-        zeros_b = jnp.zeros(batch.padded_rows, bool)
         from ..utils import trace as _trace
         with _trace.device_span("fused_plan", signature=sig,
                                 compiled=compiled,
                                 bucket=batch.padded_rows,
-                                rows=batch.n_rows):
+                                rows=batch.n_rows, mvcc=mvcc_mode):
             raw = fn(
                 batch.cols, batch.nulls,
-                [jnp.asarray(c) for c in consts], batch.valid,
-                batch.key_hash if batch.key_hash is not None
-                else zeros_u64,
-                batch.ht if batch.ht is not None else zeros_u64,
-                batch.write_id if batch.write_id is not None
-                else zeros_u32,
-                batch.tombstone if batch.tombstone is not None
-                else zeros_b,
+                [jnp.asarray(c) for c in consts], batch.valid, *lanes,
                 jnp.uint64(read_ht if read_ht is not None
                            else 0xFFFFFFFFFFFFFFFF),
                 scale_args, domain_args,
@@ -467,7 +453,8 @@ def monolithic_plan_aggregate(
         cache=None, cache_key: Optional[tuple] = None,
         grouped_out: Optional[dict] = None):
     """One-batch fused plan, mirroring the monolithic aggregate path
-    (zone-prune gate, unique_keys forced off for multi-block inputs,
+    (zone-prune gate, row versions linked unless the blocks are proved
+    one version a key,
     string predicates rewritten against the batch dictionaries).
     Returns ``(outs, counts)`` + grouped_out spill/dicts; raises
     KeyError when a probe column lacks columnar form (caller falls
@@ -477,21 +464,23 @@ def monolithic_plan_aggregate(
     cols_sorted = sorted(c for c in columns if c < BUILD_COL_BASE)
     kept = list(blocks)
     prune_key: tuple = ()
+    from .stream_scan import chunk_safe_mvcc
+    single_version = chunk_safe_mvcc(blocks)
     if where is not None and flags.get("zone_map_pruning"):
-        from .stream_scan import chunk_safe_mvcc
-        if read_ht is None or chunk_safe_mvcc(blocks):
+        if read_ht is None or single_version:
             from .scan import zone_prune_blocks
             kept, kept_idx = zone_prune_blocks(kept, where)
             if len(kept) != len(blocks):
                 prune_key = ("zp", kept_idx)
+
+    def build():
+        return build_batch(kept, cols_sorted,
+                           multi_version=not single_version)
+
     if cache is not None and cache_key is not None:
-        batch = cache.get_or_build(
-            cache_key + prune_key,
-            lambda: build_batch(kept, cols_sorted))
+        batch = cache.get_or_build(cache_key + prune_key, build)
     else:
-        batch = build_batch(kept, cols_sorted)
-    if len(blocks) > 1:
-        batch.unique_keys = False
+        batch = build()
     if where is not None or any(a.expr is not None for a in aggs):
         from ..docdb.operations import DocReadOperation
         where, aggs = DocReadOperation.rewrite_where_and_aggs(

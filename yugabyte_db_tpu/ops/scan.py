@@ -9,10 +9,15 @@ kernels:
   aggregates into one XLA program (VPU elementwise + MXU matmul for
   grouped aggregation via one-hot matrices).
 - MVCC visibility (hybrid-time <= read point, tombstones) is a vector
-  mask; when a batch may contain multiple versions of a key, the newest
-  visible version is selected with a device sort over (key_hash, ~ht) —
-  the same job IntentAwareIterator+DocRowwiseIterator do with seeks
-  (reference: src/yb/docdb/doc_rowwise_iterator.cc:687).
+  mask, and stays one elementwise pass when a batch may contain several
+  versions of a key: the batch then carries `next_ht`, the write time
+  of each row's next newer version (ops/device_batch.py link_versions,
+  computed once on the host when the batch is built), and a version is
+  the newest visible one exactly when `ht <= read_ht < next_ht` — the
+  job IntentAwareIterator+DocRowwiseIterator do with seeks (reference:
+  src/yb/docdb/doc_rowwise_iterator.cc:687).  No scan sorts for MVCC;
+  `lex_order` (ops/lexsort.py) is left to HashGroupSpec below and to
+  the compaction merge.
 - Kernels are cached by structural signature (expr shape, agg list,
   group spec, padded size, dtypes) — literals are runtime arguments, so
   re-running with different constants does NOT recompile (the
@@ -68,13 +73,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .device_batch import DeviceBatch
+from .device_batch import HT_NONE, DeviceBatch
 from .expr import collect_constants, compile_expr, expr_signature
 from .grouped_scan import (DictGroupSpec, ResolvedDictGroup,
                            grouped_reduce, resolve_group)
 from .lexsort import lex_order
-
-_UINT64_MAX = jnp.uint64(0xFFFFFFFFFFFFFFFF)
 
 
 @dataclass(frozen=True)
@@ -115,27 +118,6 @@ class HashGroupSpec:
     NULL group values are excluded, matching GroupSpec's device path."""
     cols: Tuple[int, ...]
     max_groups: int = 4096
-
-
-def _mvcc_visible_latest(key_hash, ht, write_id, tombstone, valid, read_ht):
-    """Mask of rows that are the newest visible, non-tombstone version of
-    their key at read_ht. Device equivalent of the MVCC seek dance."""
-    n = key_hash.shape[0]
-    visible = jnp.logical_and(valid, ht <= read_ht)
-    # sort so that per key: visible-newest first
-    sort_kh = jnp.where(valid, key_hash, _UINT64_MAX)
-    inv_vis = jnp.logical_not(visible).astype(jnp.uint8)
-    inv_ht = _UINT64_MAX - ht
-    inv_wid = jnp.uint32(0xFFFFFFFF) - write_id
-    with jax.named_scope("dedup_sort"):
-        s_idx = lex_order((sort_kh, inv_vis, inv_ht, inv_wid))
-    s_kh = sort_kh[s_idx]
-    first = jnp.concatenate([jnp.array([True]), s_kh[1:] != s_kh[:-1]])
-    vis_sorted = visible[s_idx]
-    tomb_sorted = tombstone[s_idx]
-    sel_sorted = first & vis_sorted & jnp.logical_not(tomb_sorted)
-    out = jnp.zeros(n, bool).at[s_idx].set(sel_sorted)
-    return out
 
 
 # sums over <= this many groups MAY unroll into per-group masked tree
@@ -252,19 +234,35 @@ def _grouped_extreme(v, m, gid, G: int, is_min: bool,
     return seg(masked, gid, G)
 
 
-def visibility_mask(mvcc_mode: str, valid, key_hash, ht, write_id,
-                    tombstone, read_ht):
+def mvcc_lanes(batch, read_ht):
+    """(mvcc_mode, (ht, next_ht, tombstone)) a batch is served with at
+    `read_ht`, decided by what the batch carries: no read point or no
+    MVCC lanes -> 'none'; a `next_ht` lane (the batch may hold several
+    versions of a key) -> 'linked'; else 'visible'.  A lane the mode
+    does not read is None, so no launch builds a placeholder for it."""
+    if read_ht is None or batch.ht is None:
+        return "none", (None, None, None)
+    if batch.next_ht is None:
+        return "visible", (batch.ht, None, batch.tombstone)
+    return "linked", (batch.ht, batch.next_ht, batch.tombstone)
+
+
+def visibility_mask(mvcc_mode: str, valid, ht, next_ht, tombstone,
+                    read_ht):
     """The MVCC row mask — THE one implementation shared by the scan
-    kernel and the fused plan kernel (ops/plan_fusion.py).  mvcc_mode:
-    'none' (valid only), 'visible' (ht filter, unique keys), 'dedup'
-    (full newest-visible-version selection)."""
-    import jax.numpy as jnp
+    kernel, the fused plan kernel (ops/plan_fusion.py) and the
+    distributed kernel; elementwise and in block order in every mode.
+    mvcc_mode: 'none' (valid only), 'visible' (ht filter, keys proved
+    single-version), 'linked' (newest visible version of each key: the
+    row is visible and its next newer version, `next_ht`, is not — the
+    all-ones sentinel says it has none, and keeps `read_ht` = MAX,
+    "latest", selecting the newest)."""
     if mvcc_mode == "none":
         return valid
+    mask = valid & (ht <= read_ht) & jnp.logical_not(tombstone)
     if mvcc_mode == "visible":
-        return valid & (ht <= read_ht) & jnp.logical_not(tombstone)
-    return _mvcc_visible_latest(key_hash, ht, write_id, tombstone,
-                                valid, read_ht)
+        return mask
+    return mask & ((next_ht == HT_NONE) | (next_ht > read_ht))
 
 
 def masked_aggregate(group, agg_fns, prep, cols, nulls, consts, mask,
@@ -368,8 +366,8 @@ def _build_kernel(where_node, agg_specs: Tuple[AggSpec, ...],
                   row_multiplier: int = 1,
                   static_sums: Tuple[bool, ...] = (),
                   strategy: str = "unroll"):
-    """mvcc_mode: 'none' (valid only), 'visible' (ht filter, unique keys),
-    'dedup' (full newest-visible-version selection).
+    """mvcc_mode: 'none' | 'visible' | 'linked' (see visibility_mask);
+    the kernel takes the lanes `mvcc_lanes` hands out for that mode.
 
     Returns a traceable fn whose result is
       (agg_outs, agg_scales, counts, mask[, gvals, n_groups])
@@ -408,10 +406,10 @@ def _build_kernel(where_node, agg_specs: Tuple[AggSpec, ...],
             return q, s, None
         return _sum_prep(v, m, n_total, axis_names)
 
-    def fn(cols, nulls, consts, valid, key_hash, ht, write_id, tombstone,
-           read_ht, sum_scales, group_domains=()):
-        mask = visibility_mask(mvcc_mode, valid, key_hash, ht, write_id,
-                               tombstone, read_ht)
+    def fn(cols, nulls, consts, valid, ht, next_ht, tombstone, read_ht,
+           sum_scales, group_domains=()):
+        mask = visibility_mask(mvcc_mode, valid, ht, next_ht, tombstone,
+                               read_ht)
         if where_fn is not None:
             wv, wn = where_fn(cols, nulls, consts)
             mask = mask & wv
@@ -558,7 +556,7 @@ class ScanKernel:
                                 static_sums=static_sums,
                                 strategy=strategy)
             # a stable program name: a kept trace's "XLA Modules" line
-            # reads jit_scan_dedup..., not jit_fn
+            # reads jit_scan_linked..., not jit_fn
             raw.__name__ = raw.__qualname__ = "_".join(
                 ["scan", mvcc_mode] + ([type(group).__name__.lower()]
                                        if group is not None else []))
@@ -716,12 +714,7 @@ class ScanKernel:
         a trailing spill count (nonzero = slot overflow, the caller
         must revert to the interpreted GROUP BY)."""
         aggs = tuple(_expand_avg(aggs))
-        if read_ht is None:
-            mvcc_mode = "none"
-        elif batch.unique_keys:
-            mvcc_mode = "visible"
-        else:
-            mvcc_mode = "dedup"
+        mvcc_mode, lanes = mvcc_lanes(batch, read_ht)
         consts: List = []
         if where is not None:
             collect_constants(where, consts)
@@ -762,25 +755,15 @@ class ScanKernel:
         fn = self._get(sig, where, aggs, group, mvcc_mode, static_sums,
                        strategy)
         compiled = self.compiles > pre
-        zeros_u64 = jnp.zeros(batch.padded_rows, jnp.uint64)
-        zeros_u32 = jnp.zeros(batch.padded_rows, jnp.uint32)
-        zeros_b = jnp.zeros(batch.padded_rows, bool)
         if isinstance(group, ResolvedDictGroup):
             from .grouped_scan import GROUPED_STATS
             GROUPED_STATS["launches"] += 1
         with _trace.device_span("scan", signature=sig, compiled=compiled,
                                 bucket=batch.padded_rows,
-                                rows=batch.n_rows):
+                                rows=batch.n_rows, mvcc=mvcc_mode):
             raw = fn(
                 batch.cols, batch.nulls,
-                [jnp.asarray(c) for c in consts], batch.valid,
-                batch.key_hash if batch.key_hash is not None
-                else zeros_u64,
-                batch.ht if batch.ht is not None else zeros_u64,
-                batch.write_id if batch.write_id is not None
-                else zeros_u32,
-                batch.tombstone if batch.tombstone is not None
-                else zeros_b,
+                [jnp.asarray(c) for c in consts], batch.valid, *lanes,
                 jnp.uint64(read_ht if read_ht is not None
                            else 0xFFFFFFFFFFFFFFFF),
                 scale_args, domain_args,

@@ -17,11 +17,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..ops.device_batch import DeviceBatch, bucket_rows, _pad, f64_conversion
+from ..ops.device_batch import (HT_NONE, bucket_rows, _pad,
+                                f64_conversion, link_versions)
 from ..ops.expr import collect_constants, expr_signature
 from ..ops.scan import (
     AggSpec, GroupSpec, _build_kernel, _expand_avg, _group_strategy,
-    _rescale_outs, _static_scales,
+    _rescale_outs, _static_scales, mvcc_lanes,
 )
 from ..storage.columnar import ColumnarBlock
 from .mesh import BLOCKS_AXIS, TABLETS_AXIS, TabletMesh
@@ -39,11 +40,12 @@ class ShardedBatch:
     # psum exactly over ICI with no in-kernel pmax round
     col_bounds: Dict[int, Tuple[float, float]]
     valid: jnp.ndarray
-    key_hash: jnp.ndarray
     ht: jnp.ndarray
-    write_id: jnp.ndarray
+    # per shard, as DeviceBatch.next_ht: present when some block is not
+    # unique-keyed.  Linking per shard is exact because one doc key
+    # lives in exactly one tablet shard and one block shard.
+    next_ht: Optional[jnp.ndarray]
     tombstone: jnp.ndarray
-    unique_keys: bool
     mesh: TabletMesh
 
     @property
@@ -126,16 +128,23 @@ def build_sharded_batch(tm: TabletMesh,
         v = np.zeros(pad, bool)
         v[:n] = True
         valid_rows.append(v)
+    ht = stack(lambda b: b.ht, np.uint64)
+    next_ht = None
+    if not all(b.unique_keys
+               for blocks in per_shard_blocks for b in blocks):
+        next_ht = np.full(ht.shape, HT_NONE, np.uint64)
+        key_hash = stack(lambda b: b.key_hash, np.uint64)
+        write_id = stack(lambda b: b.write_id, np.uint32)
+        for i, n in enumerate(ns):
+            next_ht[i, :n], _ = link_versions(
+                key_hash[i, :n], ht[i, :n], write_id[i, :n])
     return ShardedBatch(
         n_rows_per_shard=ns, cols=cols, nulls=nulls,
         col_bounds=col_bounds,
         valid=put(tm, np.stack(valid_rows)),
-        key_hash=put(tm, stack(lambda b: b.key_hash, np.uint64)),
-        ht=put(tm, stack(lambda b: b.ht, np.uint64)),
-        write_id=put(tm, stack(lambda b: b.write_id, np.uint32)),
+        ht=put(tm, ht),
+        next_ht=put(tm, next_ht) if next_ht is not None else None,
         tombstone=put(tm, stack(lambda b: b.tombstone, bool)),
-        unique_keys=all(b.unique_keys
-                        for blocks in per_shard_blocks for b in blocks),
         mesh=tm)
 
 
@@ -163,15 +172,16 @@ class DistributedScanKernel:
                               axis_names=axes, row_multiplier=S,
                               static_sums=static_sums, strategy=strategy)
 
-        def shard_fn(cols, nulls, consts, valid, key_hash, ht, wid, tomb,
-                     read_ht, sum_scales):
-            # local shard view: [1, 1, N] → [N]
-            sq = lambda a: a.reshape(a.shape[-1])
+        def shard_fn(cols, nulls, consts, valid, lanes, read_ht,
+                     sum_scales):
+            # local shard view: [1, 1, N] → [N]; a lane the mode does
+            # not read is None
+            sq = lambda a: None if a is None else a.reshape(a.shape[-1])
             lcols = {k: sq(v) for k, v in cols.items()}
             lnulls = {k: sq(v) for k, v in nulls.items()}
             outs, scales, counts, _ = local(
-                lcols, lnulls, consts, sq(valid), sq(key_hash), sq(ht),
-                sq(wid), sq(tomb), read_ht, sum_scales)
+                lcols, lnulls, consts, sq(valid), *map(sq, lanes),
+                read_ht, sum_scales)
             combined = []
             for a, o in zip(aggs, outs):
                 kind = _COMBINE["count" if a.expr is None else a.op]
@@ -202,7 +212,7 @@ class DistributedScanKernel:
         spec3 = P(TABLETS_AXIS, BLOCKS_AXIS, None)
         in_specs = (
             {k: spec3 for k in sig_cols(sig)}, {k: spec3 for k in sig_cols(sig)},
-            P(), spec3, spec3, spec3, spec3, spec3, P(), P())
+            P(), spec3, spec3, P(), P())
         smapped = jax.shard_map(
             shard_fn, mesh=tm.mesh, in_specs=in_specs,
             out_specs=(tuple(P() for _ in aggs), tuple(P() for _ in aggs),
@@ -218,13 +228,7 @@ class DistributedScanKernel:
             group: Optional[GroupSpec] = None,
             read_ht: Optional[int] = None):
         aggs = tuple(_expand_avg(aggs))
-        if read_ht is None:
-            mvcc_mode = "none"
-        elif batch.unique_keys:
-            mvcc_mode = "visible"
-        else:
-            mvcc_mode = "dedup"   # per-shard dedup: correct because one doc
-            # key lives in exactly one tablet shard and one block shard
+        mvcc_mode, lanes = mvcc_lanes(batch, read_ht)
         consts: List = []
         if where is not None:
             collect_constants(where, consts)
@@ -248,8 +252,7 @@ class DistributedScanKernel:
                        static_sums, strategy)
         outs, scales, counts = fn(
             batch.cols, batch.nulls,
-            [jnp.asarray(c) for c in consts], batch.valid,
-            batch.key_hash, batch.ht, batch.write_id, batch.tombstone,
+            [jnp.asarray(c) for c in consts], batch.valid, lanes,
             jnp.uint64(read_ht if read_ht is not None
                        else 0xFFFFFFFFFFFFFFFF),
             scale_args)

@@ -138,7 +138,7 @@ class ColumnarBlock:
       varlen    {col id: (end_offsets uint32 [n], heap bytes, null_mask)}
       unique_keys  True when every doc key appears exactly once in this
                    block (post-compaction / bulk-load blocks) — enables
-                   the no-dedup scan fast path.
+                   the scan path that links no row versions.
       keys      optional full encoded SubDocKeys (incl. HT suffix) as an
                 [N, L] uint8 matrix — present on columnar-only blocks
                 (bulk loads), where the KV row region is omitted

@@ -499,11 +499,12 @@ DEFINE_RUNTIME("ash_sample_interval_ms", 50,
                "tools/server_main in every server process). Cheap by "
                "construction: one pass over the active-wait table + "
                "registered providers per tick.")
-DEFINE_RUNTIME("tracez_keep", 4096,
+DEFINE_RUNTIME("tracez_keep", 32768,
                "Finished spans retained per process for rpc_tracez / "
                "rpcz dumps and TRACES.finished (bounded ring; oldest "
-               "evicted and counted in TRACES.evicted). One fully "
-               "sampled 51-s scan window is ~2,500 spans.")
+               "evicted and counted in TRACES.evicted). A statement "
+               "of four tablet scans is ~53 spans, and a fully "
+               "sampled window now holds some ten of them a second.")
 
 # --- incremental materialized views (matview/; ISSUE 17) ------------------
 DEFINE_RUNTIME("matview_enabled", True,

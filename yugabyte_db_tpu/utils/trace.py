@@ -235,7 +235,7 @@ class TraceRegistry:
     """Keeps recent finished spans for /rpcz, rpc_tracez and the
     in-process readers of ``finished``."""
 
-    def __init__(self, keep: int = 4096, slow_threshold_s: float = 0.5):
+    def __init__(self, keep: int = 32768, slow_threshold_s: float = 0.5):
         self.recent: Deque[Trace] = deque(maxlen=keep)
         self.active: Dict[int, Trace] = {}
         self.slow_threshold_s = slow_threshold_s
@@ -246,7 +246,7 @@ class TraceRegistry:
         self._next = 0
 
     def _ensure_keep(self) -> None:
-        keep = int(_flag("tracez_keep", self.recent.maxlen or 4096))
+        keep = int(_flag("tracez_keep", self.recent.maxlen or 32768))
         if keep > 0 and keep != self.recent.maxlen:
             with self._lock:
                 self.evicted += max(0, len(self.recent) - keep)
@@ -430,9 +430,10 @@ def use_context(ctx: Optional[SpanContext]):
 
 @contextmanager
 def device_span(kind: str, signature=None, compiled: bool = False,
-                bucket=None, rows=None):
+                bucket=None, rows=None, mvcc=None):
     """Per-kernel-launch telemetry: a span tagged {signature,
-    compile|cache_hit, bucket, rows}, so a compile landing inside a
+    compile|cache_hit, bucket, rows} and, on a scan, the MVCC mode it
+    was served with (`mvcc`), so a compile landing inside a
     measured round is VISIBLE in the trace instead of inferred from
     compile counters.  It times the DISPATCH — jit cache lookup,
     argument flattening, enqueue — and the compile when
@@ -449,11 +450,13 @@ def device_span(kind: str, signature=None, compiled: bool = False,
             return
         sig = (f"{hash(signature) & 0xFFFFFFFFFFFFFFFF:016x}"
                if signature is not None else None)
-        with TRACES.span(
-                f"device.{kind}", child_only=True,
-                tags={"signature": sig,
-                      "codepath": "compile" if compiled else "cache_hit",
-                      "bucket": bucket, "rows": rows}) as sp:
+        tags = {"signature": sig,
+                "codepath": "compile" if compiled else "cache_hit",
+                "bucket": bucket, "rows": rows}
+        if mvcc is not None:
+            tags["mvcc"] = mvcc
+        with TRACES.span(f"device.{kind}", child_only=True,
+                         tags=tags) as sp:
             yield sp
 
 
