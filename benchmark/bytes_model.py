@@ -11,14 +11,17 @@ from . import tpch
 COLUMN_BYTES = {"l_quantity": 8, "l_extendedprice": 8, "l_discount": 8,
                 "l_tax": 8, "l_shipdate": 4, "l_returnflag": 1,
                 "l_linestatus": 1}
-KEY_HASH_BYTES = 8        # u64 doc-key hash: MVCC needs it to find versions
 HYBRID_TIME_BYTES = 8     # u64 write time: visibility at the read time
+# u64 write time of the key's next newer version (`next_ht`): with it the
+# newest visible version is one elementwise mask.  Until PR 28 the kernel
+# read the doc-key hash here, also 8 B, and sorted by it
+NEXT_HT_BYTES = 8
 VALID_BYTES = 1           # bool: padding and tombstones
 
 
 def scan_row_bytes(query: str) -> int:
     return (sum(COLUMN_BYTES[c] for c in tpch.QUERY_COLUMNS[query])
-            + KEY_HASH_BYTES + HYBRID_TIME_BYTES + VALID_BYTES)
+            + HYBRID_TIME_BYTES + NEXT_HT_BYTES + VALID_BYTES)
 
 
 def scan_bytes(rows: int, query: str) -> int:
@@ -33,5 +36,7 @@ def merge_bytes(input_file_bytes: int, output_file_bytes: int) -> int:
     return input_file_bytes + output_file_bytes
 
 
-def least_seconds(nbytes: float, peak: dict) -> float:
-    return nbytes / peak["hbm_bytes_per_s"]
+def least_seconds(nbytes: float, peak: dict, chips: int = 1) -> float:
+    """`nbytes` spread evenly over `chips`, each moving its share at the
+    peak HBM rate."""
+    return nbytes / (chips * peak["hbm_bytes_per_s"])

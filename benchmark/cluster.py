@@ -20,13 +20,35 @@ def require(ok, *detail) -> None:
         raise AssertionError(" ".join(map(str, detail)) or "check failed")
 
 
+def batch_devices(batch) -> set:
+    """The devices a cached batch sits on, by `.devices()` of every lane
+    it holds.  A `DeviceBatch` has one device; the lanes of a
+    `parallel.distributed_scan.ShardedBatch` are arrays sharded over its
+    mesh, whose `.devices()` is every device that holds a shard."""
+    lanes = [getattr(batch, name, None)
+             for name in ("valid", "ht", "next_ht", "tombstone")]
+    for group in ("cols", "nulls"):
+        lanes += list(getattr(batch, group, {}).values())
+    return {d for lane in lanes if hasattr(lane, "devices")
+            for d in lane.devices()}
+
+
+def batches_on(batches: list, devices: list) -> bool:
+    """Every batch, and every shard of a sharded one, sits on the cell's
+    devices, and together they cover all of them: a four-chip cell whose
+    table sits on one chip is not on its devices."""
+    held, cell = [batch_devices(b) for b in batches], set(devices)
+    return bool(held) and all(h and h <= cell for h in held) \
+        and set().union(*held) == cell
+
+
 class Cluster:
     """RF1, one tserver.  `flags` are the program's runtime flags the
-    configuration states (the deployment's own settings); `device` is the
-    chip the run was given; `data` is whatever the loader returns."""
+    configuration states (the deployment's own settings); `devices` are
+    the chips the cell was given; `data` is whatever the loader returns."""
 
-    def __init__(self, flags: dict, device):
-        self.flags, self.device = dict(flags), device
+    def __init__(self, flags: dict, devices: list):
+        self.flags, self.devices = dict(flags), list(devices)
         self.data = None
         self.sessions: list = []    # the window's clients, made in warm-up
         self.master = self.ts = self.client = self.sql = None
@@ -105,13 +127,10 @@ class Cluster:
         from yugabyte_db_tpu.tablet.tablet import _DEVICE_CACHE
         with _DEVICE_CACHE._lock:
             batches = [b for b, _ in _DEVICE_CACHE._map.values()]
-        devs = set()
-        for b in batches:
-            devs |= set(b.valid.devices())
-            for c in b.cols.values():
-                devs |= set(c.devices())
+        held = set().union(*map(batch_devices, batches))
         return {"cached_batches": len(batches),
                 "batch_rows": sorted({b.padded_rows for b in batches}),
                 "value_dtypes": sorted({str(c.dtype) for b in batches
                                         for c in b.cols.values()}),
-                "on_device": bool(batches) and devs == {self.device}}
+                "batches_on": sorted(str(d) for d in held),
+                "on_device": batches_on(batches, self.devices)}

@@ -127,7 +127,7 @@ async def verify(cluster, traffic: dict, rec, checks) -> None:
     # the merge kernel
     checks.note("batches_off_device",
                 0 if cluster.device_evidence()["on_device"] else 1)
-    merged = (cluster.device.platform == "cpu"
+    merged = (cluster.devices[0].platform == "cpu"
               or rec.counters["merge_kernel.calls"] > 0)
     checks.note("merge_off_device", 0 if merged else 1)
 

@@ -48,13 +48,24 @@ def say(obj: dict) -> None:
 
 
 def device_info(chips: int, rehearse: bool):
+    """The cell's devices, the first `chips` JAX has, and what the result
+    line says of them."""
     import jax
     devs = jax.devices()
     info = {"platform": devs[0].platform, "kind": devs[0].device_kind,
-            "count": len(devs)}
+            "count": len(devs), "chips": chips}
     if not rehearse and (info["platform"] != "tpu" or len(devs) < chips):
         raise NoChip(f"the cell needs {chips} TPU chip(s); JAX found {info}")
-    return devs[0], info
+    return devs[:chips], info
+
+
+def memory_peaks(devices: list) -> dict:
+    """The peak on the fullest of the cell's chips, and each chip's."""
+    by_device = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+                 for d in devices]
+    return {"memory_peak_bytes": max((b for b in by_device if b is not None),
+                                     default=None),
+            "memory_peak_bytes_by_device": by_device}
 
 
 class _Compiles:
@@ -114,18 +125,19 @@ async def _measure(cell, cluster, rec, seconds, traced, keep_trace):
         if keep_trace:
             os.makedirs(keep_trace, exist_ok=True)
             shutil.copy(path, keep_trace)
-        return trace_reduce.reduce(trace_reduce.load_xplane(path))
+        return trace_reduce.reduce(trace_reduce.load_xplane(path),
+                                   cell.chips)
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
 
 
-async def _run(cell, args, device, info) -> dict:
+async def _run(cell, args, devices, info) -> dict:
     on_tpu = info["platform"] == "tpu"
     traced = bool(args.trace)
     seconds = (min(args.seconds, float(cell.traffic["trace_seconds"]))
                if traced else args.seconds)
     compiles = _Compiles()
-    cluster = Cluster(cell.config.get("flags", {}), device)
+    cluster = Cluster(cell.config.get("flags", {}), devices)
     rec = Recorder(traced)
     checks = Checks(cell.config["limits"])
     try:
@@ -147,7 +159,7 @@ async def _run(cell, args, device, info) -> dict:
         trace = await _measure(cell, cluster, rec, seconds, traced,
                                args.keep_trace)
         in_window = len(compiles.secs) - n
-        stats = device.memory_stats() or {}
+        dev = {**info, **memory_peaks(devices)}
         say({"step": "window", "seconds": round(rec.window_s, 3),
              "compiles_in_window": in_window, "counters": rec.counters,
              "ssts_per_tablet_before_after": rec.ssts_per_tablet,
@@ -162,7 +174,6 @@ async def _run(cell, args, device, info) -> dict:
         await cluster.shutdown()
         compiles.close()
     attempted, failed = cell.driver.attempted_failed(rec)
-    dev = {**info, "memory_peak_bytes": stats.get("peak_bytes_in_use")}
     metrics, result = {}, {}
     if on_tpu and not traced:
         values = {"setup_s": setup_s,
@@ -177,7 +188,8 @@ async def _run(cell, args, device, info) -> dict:
             value = manifest.load_module(cell.readers[m["name"]]).read(ctx)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
-        dev.update(busy_s=trace["busy_s"], window_s=trace["window_s"])
+        dev.update(busy_s=trace["busy_s"], window_s=trace["window_s"],
+                   devices_busy=trace["devices_busy"])
         result["breakdown"] = {"device_ops": trace["device_ops"],
                                "idle_gaps": trace["idle_gaps"]}
     compared = checks.table()
@@ -210,18 +222,20 @@ def run_cell(argv=None) -> dict:
     ap.add_argument("--keep-trace", default=None,
                     help="directory to copy the raw .xplane.pb into")
     args = ap.parse_args(argv)
-    m = manifest.with_deferred(manifest.load())   # a deferred cell runs too
-    cell = manifest.Cell(m, args.workload)
+    # this file's own checkout, so that a copy of the benchmark runs its own
+    # manifest and files; a deferred cell runs too
+    m = manifest.with_deferred(manifest.load(_ROOT), _ROOT)
+    cell = manifest.Cell(m, args.workload, _ROOT)
     if args.seconds is None:
         args.seconds = float(m["run_seconds"])
     import yugabyte_db_tpu  # noqa: F401 — x64, platform, compile cache
-    device, info = device_info(cell.chips, args.rehearse)
+    devices, info = device_info(cell.chips, args.rehearse)
     import jax
     say({"step": "devices", **info, "workload": cell.name,
          "seed": args.seed, "seconds": args.seconds, "trace": args.trace,
          "rows": args.rows or "the configuration's",
          "compile_cache_dir": jax.config.jax_compilation_cache_dir})
-    return asyncio.run(_run(cell, args, device, info))
+    return asyncio.run(_run(cell, args, devices, info))
 
 
 def main(argv=None) -> int:

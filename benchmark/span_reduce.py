@@ -170,7 +170,7 @@ def idle_by_span(ctx):
     """(idle seconds of the traced window, {span name: idle seconds with
     that span innermost}, the names below a statement's root) — or None
     without a trace, spans, or a common clock."""
-    if ctx.trace is None or not ctx.trace["busy"]:
+    if ctx.trace is None or not any(ctx.trace["busy"]):
         return None
     spans = window_spans(ctx)
     trees = statement_trees(spans, ctx.rec.of("stmt", ok_only=False))

@@ -1,13 +1,16 @@
 """From the profiler's trace to numbers: device busy time as the union of
 the intervals in which an operation ran, the idle share, the busy time
-inside the benchmark's own spans, the operations that took most time and
-the idle gaps by the span they fell in.
+inside the benchmark's own spans, the time of every operation and of every
+program by name, and the idle gaps by the span they fell in — each a chip,
+over the chips the cell was given, whether or not all of them worked.
 
 Works on plain data — planes as `{"name", "lines": [{"name", "events":
 [(name, start_ns, duration_ns), ...]}]}` — so a test can hand it a few
 events; `load_xplane` makes that from an `.xplane.pb` with nothing but
-JAX.  Kernels have no stable names yet, so device time is attributed by
-the benchmark's span, not by kernel name.
+JAX.  The metrics of `BENCHMARK.json` attribute device time by the
+benchmark's span; `ops_by_name` and `busy_by_program` are there for a
+reader that asks for one operation (`all-reduce`) or one program
+(`jit_scan_linked`).
 
     python -m benchmark.trace_reduce <file.xplane.pb>     # look at a trace
 """
@@ -16,6 +19,7 @@ from __future__ import annotations
 import bisect
 import glob
 import os
+import re
 import sys
 
 from .record import SPAN_PREFIX
@@ -25,6 +29,9 @@ _DEVICE_PREFIXES = ("/device:TPU:", "/device:GPU:")
 # the line of a device plane that holds one event per executed operation;
 # the others ("XLA Modules", "Steps", ...) cover the same time again
 OPS_LINE = "XLA Ops"
+# one event per launched program, named `jit_<function>(<fingerprint>)`
+MODULES_LINE = "XLA Modules"
+_FINGERPRINT = re.compile(r"\(\d+\)$")
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -111,17 +118,34 @@ def _label(spans: list, t: float) -> str:
     return best[0] if best else "between spans"
 
 
-def reduce(planes: list, top: int = 10) -> dict:
+def program_events(plane: dict) -> list:
+    """A device plane's launches, one event a program run, the name
+    without its fingerprint: its `XLA Modules` line."""
+    return [(_FINGERPRINT.sub("", name), s, d) for l in plane["lines"]
+            if l["name"] == MODULES_LINE for name, s, d in l["events"]
+            if d > 0]
+
+
+def reduce(planes: list, chips: int = 1, top: int = 10) -> dict:
     """The reduction every per-layer reader takes its numbers from.
-    Seconds in the numbers, nanoseconds in the intervals.  `busy_s` is
-    averaged over the device planes that ran anything.  The window is the
-    `bench:trace_window` span, or where the trace has none, from the first
-    to the last event seen.  `spans` are the benchmark's spans inside the
-    window, `busy` each device's merged busy intervals; `busy_in_spans`
-    and `span_count` read them."""
+    Seconds in the numbers, nanoseconds in the intervals.  `chips` is how
+    many the cell was given: `busy_s`, the operations, the programs and
+    the idle gaps are seconds a chip over all of them, and a chip whose
+    plane ran nothing, or that has no plane, is idle for the whole window;
+    `devices_busy` says how many ran anything.  More busy planes than
+    chips is an error: the cell used a chip it did not ask for.  The
+    window is the `bench:trace_window` span, or where the trace has none,
+    from the first to the last event seen.  `spans` are the benchmark's
+    spans inside the window, `busy` each chip's merged busy intervals;
+    `busy_in_spans` and `span_count` read them.  `device_ops` are the
+    `top` longest of `ops_by_name`, names cut for the result line."""
     spans = bench_spans(planes)
-    devices = [op_events(p) for p in planes if is_device_plane(p)]
-    devices = [ev for ev in devices if ev]
+    working = [(p, ev) for p in planes if is_device_plane(p)
+               for ev in [op_events(p)] if ev]
+    if len(working) > chips:
+        raise ValueError(f"{len(working)} device planes ran operations, "
+                         f"the cell has {chips} chip(s)")
+    devices = [ev for _, ev in working]
     window = next(((a, b) for name, a, b in spans if name == WINDOW_SPAN),
                   None)
     if window is None:
@@ -133,20 +157,25 @@ def reduce(planes: list, top: int = 10) -> dict:
     lo, hi = window
     inner = [(name, max(a, lo), min(b, hi)) for name, a, b in spans
              if name != WINDOW_SPAN and b > lo and a < hi]
-    out = {"window_s": (hi - lo) / 1e9, "devices": len(devices),
-           "busy_s": 0.0, "spans": inner, "busy": [],
-           "device_ops": [], "idle_gaps": []}
+    out = {"window_s": (hi - lo) / 1e9, "chips": chips,
+           "devices_busy": len(devices), "busy_s": 0.0, "spans": inner,
+           "busy": [], "device_ops": [], "idle_gaps": []}
     ops: dict = {}
+    programs: dict = {}
     gaps: dict = {}
-    cuts = sorted({t for _, a, b in inner for t in (a, b)})
-    for events in devices:
-        busy = clip(merge([[s, s + d] for _, s, d in events]), lo, hi)
-        out["busy"].append(busy)
-        out["busy_s"] += total(busy) / 1e9 / len(devices)
+
+    def add(totals, events):
         for name, s, d in events:
             if s + d > lo and s < hi:
-                ops[name] = ops.get(name, 0.0) + \
-                    (min(s + d, hi) - max(s, lo)) / 1e9 / len(devices)
+                totals[name] = totals.get(name, 0.0) + \
+                    (min(s + d, hi) - max(s, lo)) / 1e9 / chips
+
+    cuts = sorted({t for _, a, b in inner for t in (a, b)})
+    for events in devices + [[]] * (chips - len(devices)):
+        busy = clip(merge([[s, s + d] for _, s, d in events]), lo, hi)
+        out["busy"].append(busy)
+        out["busy_s"] += total(busy) / 1e9 / chips
+        add(ops, events)
         edge = lo
         for a, b in busy + [[hi, hi]]:
             # an idle gap, cut where a span begins or ends inside it
@@ -155,14 +184,17 @@ def reduce(planes: list, top: int = 10) -> dict:
                 end = cuts[i] if i < len(cuts) and cuts[i] < a else a
                 label = _label(inner, (edge + end) / 2)
                 gaps[label] = gaps.get(label, 0.0) + \
-                    (end - edge) / 1e9 / len(devices)
+                    (end - edge) / 1e9 / chips
                 edge, i = end, i + 1
             edge = max(edge, b)
+    for p, _ in working:
+        add(programs, program_events(p))
 
     def rank(d):       # an operation's name is its whole HLO line: cut it
         return [[k[:160], v] for k, v in
                 sorted(d.items(), key=lambda kv: -kv[1])[:top]]
     out["device_ops"], out["idle_gaps"] = rank(ops), rank(gaps)
+    out["ops_by_name"], out["busy_by_program"] = ops, programs
     return out
 
 
@@ -180,7 +212,7 @@ def span_count(red: dict, prefix: str) -> int:
 
 def busy_in_spans(red: dict, prefix: str) -> float:
     """Device busy seconds inside the union of the spans whose name starts
-    with `prefix`, averaged over the devices; overlapping spans (two
+    with `prefix`, averaged over the cell's chips; overlapping spans (two
     clients) count their shared time once."""
     inside = merge([[a, b] for name, a, b in red["spans"]
                     if name.startswith(prefix)])
@@ -221,5 +253,11 @@ if __name__ == "__main__":
         _path = find_xplane(_path)
     _planes = load_xplane(_path)
     print(summary(_planes))
-    print({k: v for k, v in reduce(_planes).items()
-           if k not in ("spans", "busy")})
+    # by hand nobody says how many chips the cell had: the planes there are
+    _red = reduce(_planes, max(1, sum(map(is_device_plane, _planes))))
+    for _key in ("busy_by_program", "ops_by_name"):
+        print(f"{_key} (seconds a chip):")
+        for _name, _s in sorted(_red[_key].items(), key=lambda kv: -kv[1]):
+            print(f"  {_s:12.6f} s  {_name[:160]}")
+    print({k: v for k, v in _red.items()
+           if k not in ("spans", "busy", "ops_by_name", "busy_by_program")})

@@ -64,38 +64,42 @@ def test_a_tablet_left_out_is_not_correct(cell, monkeypatch):
     assert value > limit
 
 
-@pytest.mark.parametrize("which", ["every", "window_only"])
-def test_a_compaction_that_changes_nothing_is_not_correct(which, monkeypatch):
-    """`window_only`: set-up's compactions and the one the check sends
-    after the window are real; only the timed ones return having done
-    nothing, so it is their own SST counts that have to catch it."""
-    from yugabyte_db_tpu.tablet.tablet import Tablet
-    from benchmark.drivers import refresh_compact as driver
-    real, in_window = Tablet.compact, [which == "every"]
-    monkeypatch.setattr(
-        Tablet, "compact", lambda self, major=True:
-        None if in_window[0] else real(self, major))
-    real_window = driver.window
-
-    async def window(*a, **kw):
-        in_window[0] = True
-        try:
-            return await real_window(*a, **kw)
-        finally:
-            in_window[0] = which == "every"
-
+def _around_the_window(monkeypatch, around) -> None:
+    """The refresh driver's `window` as the run loads it (by its file,
+    anew each run), called through `around(real_window, cluster, traffic,
+    seconds, rec)`."""
     from benchmark import manifest
     real_load = manifest.load_module
 
     def load_module(path):
         mod = real_load(path)
         if path.endswith("drivers/refresh_compact.py"):
-            nonlocal real_window
             real_window = mod.window
-            mod.window = window
+            mod.window = lambda *a: around(real_window, *a)
         return mod
 
     monkeypatch.setattr(manifest, "load_module", load_module)
+
+
+@pytest.mark.parametrize("which", ["every", "window_only"])
+def test_a_compaction_that_changes_nothing_is_not_correct(which, monkeypatch):
+    """`window_only`: set-up's compactions and the one the check sends
+    after the window are real; only the timed ones return having done
+    nothing, so it is their own SST counts that have to catch it."""
+    from yugabyte_db_tpu.tablet.tablet import Tablet
+    real, in_window = Tablet.compact, [which == "every"]
+    monkeypatch.setattr(
+        Tablet, "compact", lambda self, major=True:
+        None if in_window[0] else real(self, major))
+
+    async def window(real_window, *a):
+        in_window[0] = True
+        try:
+            return await real_window(*a)
+        finally:
+            in_window[0] = which == "every"
+
+    _around_the_window(monkeypatch, window)
     result = run.run_cell(["--workload", "refresh_compact", *ARGS])
     assert result["correct"] is False
     value, limit = result["compared"]["ssts_after_compact"]
@@ -103,17 +107,30 @@ def test_a_compaction_that_changes_nothing_is_not_correct(which, monkeypatch):
 
 
 def test_an_acknowledged_insert_that_was_dropped_is_not_correct(monkeypatch):
-    from yugabyte_db_tpu.ql.executor import SqlSession
+    """One acknowledged INSERT, the window's first, is never applied; the
+    loader's, the warm-up's and the window's two later ones are sound.
+    The window is three whole iterations whatever the host's speed (each
+    the driver's own window with a deadline one iteration outlasts): the
+    read-back draws half of its 16 orders from the seed among all that
+    were inserted, so what it draws depends on how many iterations there
+    were, and `q1_count_diff` alone sees a lost write it did not draw."""
+    from yugabyte_db_tpu.ql.executor import SqlResult, SqlSession
     real, seen = SqlSession._insert, [0]
 
     async def lossy(self, stmt):
         seen[0] += 1
-        if seen[0] == 4:             # acknowledged, never applied
-            from yugabyte_db_tpu.ql.executor import SqlResult
+        if seen[0] == 3:     # the loader's and the warm-up's come first
             return SqlResult([], "INSERT 0 0")
         return await real(self, stmt)
 
+    async def three_iterations(real_window, cluster, traffic, seconds, rec):
+        for _ in range(3):
+            await real_window(cluster, traffic, 1e-3, rec)
+
     monkeypatch.setattr(SqlSession, "_insert", lossy)
+    _around_the_window(monkeypatch, three_iterations)
     result = run.run_cell(["--workload", "refresh_compact", *ARGS])
+    assert seen[0] >= 5                  # 2 before the window, 3 inside
     assert result["correct"] is False
     assert {"readback_missing", "q1_count_diff"} <= _bad(result)
+    assert result["compared"]["readback_value_diff"] == [0.0, 0]

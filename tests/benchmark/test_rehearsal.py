@@ -8,14 +8,21 @@ import pytest
 
 from benchmark import manifest, run
 
-M = manifest.with_deferred(manifest.load())
-CELLS = [w["name"] for w in M["workloads"]]
-ROWS = "24000"       # 6,000 rows a tablet: above the device path's floor
+
+
+def full():
+    """The manifest with the cells kept for a later PR."""
+    return manifest.with_deferred(manifest.load())
+
+
+CELLS = [w["name"] for w in full()["workloads"]]
 
 
 def rehearse(cell, *more):
+    """At the rows the cell's own configuration states for a rehearsal."""
+    rows = manifest.Cell(full(), cell).config["rehearsal"]["rows"]
     return run.run_cell(["--workload", cell, "--seed", "2147484001",
-                         "--seconds", "2", "--rows", ROWS, "--rehearse",
+                         "--seconds", "2", "--rows", str(rows), "--rehearse",
                          *more])
 
 
@@ -30,7 +37,9 @@ def test_rehearsal_is_correct_and_reports_no_metric(cell, capsys):
     assert result["compiles_in_window"] == 0
     assert steps[5]["compiles_in_window"] == 0 and steps[5]["errors"] == []
     ssts_before, ssts_after = steps[5]["ssts_per_tablet_before_after"]
-    assert max(ssts_before) < 4          # under the background trigger
+    # under the background compaction trigger (a driver that records no
+    # SST counts has no tablets to compact)
+    assert max(ssts_before, default=0) < 4
     assert result["attempted"] > 0 and result["failed"] == 0
     assert result["metrics"] == {}            # a CPU run names no metric
     assert result["device"]["platform"] == "cpu"
@@ -38,7 +47,7 @@ def test_rehearsal_is_correct_and_reports_no_metric(cell, capsys):
     assert all(v is not None and v <= limit
                for v, limit in result["compared"].values())
     json.dumps(result, allow_nan=False)
-    limits = manifest.Cell(M, cell).config["limits"]
+    limits = manifest.Cell(full(), cell).config["limits"]
     assert set(result["compared"]) == set(limits)
 
 

@@ -188,10 +188,10 @@ def test_manifest_holds_the_seven_entries_and_validates():
     m = manifest.load()
     manifest.validate(m)
     mine = [x for x in m["per_layer"] if x["name"] in NEW]
-    assert [x["name"] for x in mine] == NEW
-    assert [x["name"] for x in m["per_layer"]][-7:] == NEW   # appended
+    assert [x["name"] for x in mine] == NEW      # a later PR appends its own
     assert {x["source"] for x in mine} == {"program_span"}
-    assert all(x["workloads"] == ["scan_power"]
+    # a later cell joins a metric by its name in the list: `scan_power` stays
+    assert all("scan_power" in x["workloads"]
                and x["moves"] == "scan_rows_per_s" for x in mine)
     assert set(manifest.Cell(m, "scan_power").readers) >= set(NEW)
 

@@ -1,11 +1,15 @@
 """The yardstick's arithmetic: the seeded dbgen-shaped generator, the plain
 reference and its float32 control, the byte counts behind both rooflines, the table
 of peaks, and the bookkeeping that decides `correct`."""
+import glob
+import os
+import types
+
 import numpy as np
 import pytest
 
 from benchmark import bytes_model, manifest, peaks, tpch
-from benchmark.record import Checks
+from benchmark.record import Checks, Recorder
 
 LIMITS = manifest.load_json(
     manifest.ROOT + "/benchmark/configs/tpch_sf1_scan.json")["limits"]
@@ -147,8 +151,8 @@ def test_a_misshapen_answer_is_not_compared_in_part(rows, gap):
 
 
 def test_scan_bytes_by_hand():
-    # Q6 reads three doubles and a date (28 bytes), plus key hash 8,
-    # hybrid time 8, valid 1: 45 bytes a row; Q1 reads four doubles, a
+    # Q6 reads three doubles and a date (28 bytes), plus hybrid time 8,
+    # next_ht 8, valid 1: 45 bytes a row; Q1 reads four doubles, a
     # date and two one-character flags (38): 55 bytes a row
     assert bytes_model.scan_row_bytes("q6") == 45
     assert bytes_model.scan_row_bytes("q1") == 55
@@ -186,3 +190,29 @@ def test_checks_hold_each_number_to_its_own_limit():
     silent.note("a", 0)
     assert not silent.correct()           # a limit nothing was held to
     assert not Checks({}).correct()
+
+
+
+READERS = sorted(glob.glob(os.path.join(
+    manifest.ROOT, "benchmark", "layer_metrics", "*.py")))
+
+
+@pytest.mark.parametrize("path", READERS,
+                         ids=[os.path.basename(p)[:-3] for p in READERS])
+def test_a_reader_that_finds_nothing_to_read_returns_nothing(path):
+    """Every reader under `layer_metrics/`, one a metric, on a run that
+    left it nothing: no trace, then a trace in which no chip of the cell's
+    four ran anything, a recorder with no span and no counter.  It says
+    `None`, and the harness leaves the metric out of the line: never a 0
+    for a time, a share of a roofline or of the window."""
+    read = manifest.load_module(path).read
+    nothing = {"window_s": 5.0, "chips": 4, "devices_busy": 0, "busy_s": 0.0,
+               "spans": [], "busy": [[], [], [], []], "device_ops": [],
+               "idle_gaps": [], "ops_by_name": {}, "busy_by_program": {}}
+    for trace in (None, nothing):
+        ctx = types.SimpleNamespace(
+            trace=trace, rec=Recorder(traced=False),
+            cell=types.SimpleNamespace(chips=4),
+            peak=peaks.lookup("TPU v5 lite"),
+            data=types.SimpleNamespace(table_rows=6_007_215))
+        assert read(ctx) is None
