@@ -7,8 +7,12 @@ objects, `SqlSession.execute`, the `flush` and `compact` RPCs.  Forces no
 platform, probes nothing, sets no cache directory: it imports JAX, reads
 `jax.devices()`, and goes.
 
-    python chip_smoke.py [--sf 1.0] [--seed 0]     # one chip
-    python chip_smoke.py --chips 4                 # only the 4-chip path
+    python chip_smoke.py [--sf 1.0] [--seed 0]
+
+Four chips have one way in, the served one: a tserver started with
+`tserver_device_chips=4` (docdb/mesh_read.py), which the benchmark's cell
+`mesh4_q1_psum` runs (`python3 benchmark/run.py --workload mesh4_q1_psum`;
+`--rehearse --rows 48000` on four of the CPU's virtual devices).
 
 Every phase prints one JSON line; the LAST line of stdout is
 
@@ -417,85 +421,11 @@ class Smoke:
             await self.shutdown()
 
 
-def four_chips(sf: float, seed: int) -> None:
-    """The path that exists only across chips, and what it is compared
-    with: Q1 over the SF rows as four tablet shards through
-    `distributed_scan_aggregate` on a mesh of the real devices, against
-    the four shards' partials combined on the host and `numpy_reference`."""
-    from yugabyte_db_tpu.docdb.table_codec import TableCodec
-    from yugabyte_db_tpu.models.tpch import (TPCH_Q1 as q, generate_lineitem,
-                                             lineitem_info)
-    from yugabyte_db_tpu.ops.device_batch import build_batch
-    from yugabyte_db_tpu.ops.scan import (ScanKernel, _expand_avg,
-                                          combine_agg_partials)
-    from yugabyte_db_tpu.parallel import tablet_mesh
-    from yugabyte_db_tpu.parallel.distributed_scan import (
-        build_sharded_batch, distributed_scan_aggregate)
-    from yugabyte_db_tpu.utils.hybrid_time import HybridTime
-    t0 = time.time()
-    require(len(jax.devices()) >= 4, jax.devices())
-    tm = tablet_mesh(num_tablet_shards=4, devices=jax.devices()[:4])
-    data = generate_lineitem(sf, seed)
-    n = len(data["rowid"])
-    codec = TableCodec(lineitem_info())
-    edges = np.linspace(0, n, 5).astype(int)
-    per_shard = [codec.bulk_blocks({k: v[a:b] for k, v in data.items()},
-                                   HybridTime.from_micros(100 + s))
-                 for s, (a, b) in enumerate(zip(edges[:-1], edges[1:]))]
-    read_ht = HybridTime.from_micros(10_000).value
-    batch = build_sharded_batch(tm, per_shard, sorted(q.columns))
-    shard_devs = {s.device for s in batch.valid.addressable_shards}
-    require(len(shard_devs) == 4, shard_devs)
-    for c in batch.cols.values():
-        require({s.device for s in c.addressable_shards} == shard_devs)
-    runs = []
-    for _ in range(3):
-        t1 = time.time()
-        outs, counts = distributed_scan_aggregate(
-            batch, q.where, q.aggs, q.group, read_ht=read_ht)
-        outs = [np.asarray(o) for o in outs]
-        runs.append(round(time.time() - t1, 4))
-    # the comparison: each shard alone on its own device, host combine
-    kernel, parts, cparts = ScanKernel(), [], []
-    for s, blocks in enumerate(per_shard):
-        with jax.default_device(jax.devices()[s]):
-            o, c, _ = kernel.run(build_batch(blocks, sorted(q.columns)),
-                                 q.where, q.aggs, q.group, read_ht)
-        parts.append([np.asarray(x) for x in o])
-        cparts.append(np.asarray(c))
-    host, hcounts = combine_agg_partials(tuple(_expand_avg(q.aggs)),
-                                         parts, cparts)
-    # counts and the integer-valued SUM are exact on both sides; the
-    # fractional SUMs are int64 fixed point at a scale derived from the
-    # rows and bounds each side sees (global vs per shard), so they
-    # agree to the granule, not to the bit
-    require((np.asarray(counts) == hcounts).all(), counts, hcounts)
-    require((outs[0] == np.asarray(host[0])).all(), outs[0], host[0])
-    require((outs[4] == np.asarray(host[4])).all(), outs[4], host[4])
-    for a, b in zip(outs[1:4], host[1:4]):
-        np.testing.assert_allclose(a, np.asarray(b), rtol=1e-9)
-    ref = Smoke._reference(data)["q1"]
-    for g in range(6):
-        qty, price, cnt, disc, charge = ref[g]
-        require(int(np.asarray(counts)[g]) == cnt)
-        require(float(outs[0][g]) == qty)
-        for got, want in ((outs[1][g], price), (outs[2][g], disc),
-                          (outs[3][g], charge)):
-            require(abs(float(got) - want) <= 1e-5 * abs(want), got, want)
-    Smoke.say({"phase": "four_chips", "ok": True, "rows": n,
-               "seconds": round(time.time() - t0, 3),
-               "shard_devices": sorted(str(d) for d in shard_devs),
-               "rows_per_shard": batch.n_rows_per_shard,
-               "psum_equals_host_combine": True,
-               "cold_s": runs[0], "warm_s": runs[1:]})
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--sf", type=float, default=1.0,
                     help="TPC-H scale factor (1.0 = 6,000,000 rows)")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
     args = ap.parse_args(argv)
 
     import yugabyte_db_tpu  # noqa: F401 — x64, platform, compile cache
@@ -513,10 +443,7 @@ def main(argv=None) -> int:
                "compile_cache_files": cache_files()})
     ok = True
     try:
-        if args.chips == 4:
-            four_chips(args.sf, args.seed)
-        else:
-            asyncio.run(Smoke(args.sf, args.seed).run())
+        asyncio.run(Smoke(args.sf, args.seed).run())
     except Exception:   # noqa: BLE001 — reported, and the run FAILS
         traceback.print_exc()
         ok = False

@@ -1180,11 +1180,22 @@ class TestCallGraph:
             p.parent.mkdir(parents=True, exist_ok=True)
             p.write_text(src)
         cache = str(tmp_path / ".analyze_cache")
-        i1 = ProjectIndex(str(tmp_path), roots=("pkg",), cache_dir=cache)
-        g1 = i1.call_graph()
+        # the two timed builds run with the cyclic collector off: a full
+        # pass (50-90 ms) inside the cached build of ~15 ms flips the
+        # comparison, and where it falls depends on how much the rest of
+        # the test run allocated (PERF.md section 7)
+        import gc
+        gc.disable()
+        try:
+            i1 = ProjectIndex(str(tmp_path), roots=("pkg",),
+                              cache_dir=cache)
+            g1 = i1.call_graph()
+            i2 = ProjectIndex(str(tmp_path), roots=("pkg",),
+                              cache_dir=cache)
+            g2 = i2.call_graph()
+        finally:
+            gc.enable()
         assert g1.stats["cache_misses"] == len(files)
-        i2 = ProjectIndex(str(tmp_path), roots=("pkg",), cache_dir=cache)
-        g2 = i2.call_graph()
         assert g2.stats["cache_hits"] == len(files)
         assert g2.stats["cache_misses"] == 0
         # the cached run must actually be cheaper, not just "hit"

@@ -245,3 +245,80 @@ def test_distributed_scan_compiles_for_four_chips(topo, tpu_arms):
         NamedSharding(tm.mesh, P()))
     compiled = _compile(lambda: fn.lower(*shapes))
     assert "all-reduce" in compiled.as_text()
+
+
+#: `mesh4_q1_psum`: two tablets of 7.5M rows a chip, padded to one bucket
+MESH_SHARD_ROWS = 1 << 24
+
+
+def test_served_mesh_scan_compiles_for_four_chips(topo, tmp_path):
+    """The programs one tserver that owns four chips launches for Q6 and
+    Q1 through SQL (`docdb/mesh_read.py`): `linked` mask with `next_ht`
+    per shard, float64 lanes, Q1 grouped by two text columns as codes of
+    a global dictionary, every additive partial in one all-reduce — at
+    the shard size of `mesh4_q1_psum`, on a mesh of the described chips.
+    The statements run here first, on four of the CPU's devices, to take
+    each program's own arguments; what is compiled is the TPU's arm
+    (`unroll`)."""
+    import asyncio
+    from test_mesh_scan import TABLE, Served
+    from benchmark import tpch
+    from yugabyte_db_tpu.parallel.distributed_scan import \
+        DistributedScanKernel
+    from yugabyte_db_tpu.parallel.mesh import (BLOCKS_AXIS, TABLETS_AXIS,
+                                               TabletMesh)
+    seen = []
+
+    class Recording(DistributedScanKernel):
+        def _get(self, *key):
+            fn = super()._get(*key)
+
+            def call(*args):
+                seen.append((key, args))
+                return fn(*args)
+            return call
+
+    async def statements():
+        async with Served(str(tmp_path), 4) as t:
+            t.ts.mesh_reader.kernel = Recording()
+            for q in ("q6", "q1"):
+                await t.sql.execute(tpch.SQL[q].format(name=TABLE))
+
+    flags.set_flag("scan_group_strategy", "unroll")
+    try:
+        asyncio.run(statements())
+    finally:
+        for f in ("scan_group_strategy", "tserver_device_chips",
+                  "device_float_dtype", "tpu_min_rows_for_pushdown"):
+            flags.REGISTRY.reset(f)
+    assert [key[5] for key, _ in seen] == ["linked", "linked"]
+    tm = TabletMesh(Mesh(np.array(topo.devices).reshape(4, 1),
+                         (TABLETS_AXIS, BLOCKS_AXIS)))
+    rows = NamedSharding(tm.mesh, P(TABLETS_AXIS, BLOCKS_AXIS, None))
+    everywhere = NamedSharding(tm.mesh, P())
+
+    def shape(x):
+        x = jnp.asarray(x)
+        if x.ndim == 3:
+            return jax.ShapeDtypeStruct((4, 1, MESH_SHARD_ROWS), x.dtype,
+                                        sharding=rows)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=everywhere)
+
+    for (sig, _, where, aggs, group, mode, static_sums, strategy), args \
+            in seen:
+        assert strategy == "unroll"
+        assert {str(v.dtype) for v in args[0].values()} \
+            == {"float64", "int32"}
+        fn = DistributedScanKernel()._get(
+            (id(tm.mesh),) + sig[1:], tm, where, aggs, group, mode,
+            static_sums, strategy)
+        compiled = _compile(lambda: fn.lower(
+            *jax.tree_util.tree_map(shape, args)))
+        text = compiled.as_text()
+        assert text.count("all-reduce(") + text.count("all-reduce-start(") \
+            == 1, "every additive partial rides one all-reduce"
+        assert "sort" not in text and "while" not in text
+        mem = compiled.memory_analysis()
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            < 8 << 30
+        print(group, mem.argument_size_in_bytes, mem.temp_size_in_bytes)

@@ -61,6 +61,18 @@ REGISTRY: Tuple[dict, ...] = (
         ],
     },
     {
+        "key_builder": (f"{_DOCDB}/mesh_read.py", "MeshReader._cache_key"),
+        "roots": [("yugabyte_db_tpu/parallel/distributed_scan.py",
+                   "build_sharded_batch")],
+        "key_helpers": [],
+        "allow": {},
+        "must_mention": [
+            ("write_generation", "batch must rebuild after writes"),
+            ("device_float_dtype", "runtime dtype switch must re-key "
+                                   "the sharded batch too"),
+        ],
+    },
+    {
         "key_builder": (f"{_OPS}/stream_scan.py",
                         "streaming_scan_aggregate.build"),
         "roots": [(f"{_OPS}/stream_scan.py",

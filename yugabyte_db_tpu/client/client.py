@@ -9,7 +9,7 @@ the same combine pggate does (reference: pg_doc_op.h:117).
 from __future__ import annotations
 
 import asyncio
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -43,12 +43,38 @@ def _overload_backoff_s(e: Exception, attempt: int,
     return min(cap_s, base) * random.uniform(0.5, 1.0)
 
 
+def _mesh_groups(req: ReadRequest, locations: list) -> tuple:
+    """(groups, alone): the tablets of an aggregate scan that go out as
+    one `read_tablets` RPC a server — two or more led by a server that
+    owns several chips — and those that go one RPC a tablet.  With no
+    such server (`tserver_device_chips` 1 everywhere) every tablet is
+    alone."""
+    if (not req.aggregates or req.join is not None
+            or req.paging_state is not None
+            or not any(l.chips for l in locations)):
+        return [], locations
+    by_leader: Dict[str, list] = {}
+    for l in locations:
+        # the leader where the master has reported one; a tablet's only
+        # replica leads it
+        leader = l.leader or (l.replicas[0][0] if len(l.replicas) == 1
+                              else None)
+        if leader is not None and l.chips.get(leader, 1) > 1:
+            by_leader.setdefault(leader, []).append(l)
+    groups = [g for g in by_leader.values() if len(g) > 1]
+    grouped = {id(l) for g in groups for l in g}
+    return groups, [l for l in locations if id(l) not in grouped]
+
+
 @dataclass
 class TabletLocation:
     tablet_id: str
     partition: Partition
     replicas: List[Tuple[str, Tuple[str, int]]]   # (ts_uuid, addr)
     leader: Optional[str] = None
+    # ts_uuid -> the chips that replica's server owns, where it owns
+    # more than one (the master passes on what the server reports)
+    chips: Dict[str, int] = field(default_factory=dict)
 
     def leader_addr(self) -> Optional[Tuple[str, int]]:
         for u, a in self.replicas:
@@ -429,7 +455,9 @@ class YBClient:
                                     bytes.fromhex(l["partition"][1])),
                 replicas=[(r["ts_uuid"], tuple(r["addr"]))
                           for r in l["replicas"] if r["addr"]],
-                leader=l.get("leader")))
+                leader=l.get("leader"),
+                chips={r["ts_uuid"]: r["chips"]
+                       for r in l["replicas"] if r.get("chips")}))
         from ..docdb.wire import _expr_from_wire
         cached = CachedTable(info, TableCodec(info), locs,
                              resp.get("indexes") or {},
@@ -788,6 +816,30 @@ class YBClient:
                 first.rows = rows
                 return first
 
+            async def many(group: List[TabletLocation],
+                           ct2: CachedTable) -> List[ReadResponse]:
+                """The tablets one multi-chip server leads, as ONE read:
+                its answer over all of them (combined on its chips), or
+                each tablet's own where it served them one by one.  A
+                tablet's rows are in exactly one part either way: if
+                the server refuses the call, its tablets are asked one
+                by one; a call that timed out is sent again (a cold
+                build outlasts the deadline, the retry finds the batch
+                cached), as a tablet's own read is."""
+                payload = {"tablet_ids": [l.tablet_id for l in group],
+                           "req": read_request_to_wire(req)}
+                try:
+                    got = await self._call_leader(
+                        ct2, group[0].tablet_id, "read_tablets", payload)
+                except RpcError as e:
+                    if e.code == "TABLET_SPLIT":
+                        raise
+                    return list(await asyncio.gather(
+                        *[one(l, ct2) for l in group]))
+                if "mesh" in got:
+                    return [read_response_from_wire(got["mesh"])]
+                return [read_response_from_wire(p) for p in got["parts"]]
+
             async def go(ct2):
                 # the server-side window pushdown only holds on a single
                 # tablet (a window spans the whole table); with fan-out > 1
@@ -795,8 +847,12 @@ class YBClient:
                 # compute on partials the client must redo anyway
                 win = req.window if len(ct2.locations) == 1 else None
                 sp.set_tag("tablets", len(ct2.locations))
-                parts = await asyncio.gather(
-                    *[one(l, ct2, win) for l in ct2.locations])
+                groups, alone = _mesh_groups(req, ct2.locations)
+                got = await asyncio.gather(
+                    *[many(g, ct2) for g in groups],
+                    *[one(l, ct2, win) for l in alone])
+                parts = [p for g in got[:len(groups)] for p in g] \
+                    + list(got[len(groups):])
                 with _trace.TRACES.span("client.combine", child_only=True,
                                         tags={"parts": len(parts)}):
                     return self._combine(req, parts)

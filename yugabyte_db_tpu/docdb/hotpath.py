@@ -50,11 +50,19 @@ def _build() -> bool:
     inc = sysconfig.get_paths()["include"]
     try:
         # -march=native is safe: the output path is host-fingerprinted,
-        # so this .so can never load on a different CPU
-        subprocess.run(
-            ["g++", "-O3", "-march=native", "-shared", "-fPIC",
-             f"-I{inc}", _SRC, "-o", _SO],
-            check=True, capture_output=True, timeout=120)
+        # so this .so can never load on a different CPU.  The compiler
+        # writes to a name of this process's own and the rename publishes
+        # it whole: a process that finds `_SO` never loads half of it
+        tmp = f"{_SO}.{os.getpid()}.tmp"
+        try:
+            subprocess.run(
+                ["g++", "-O3", "-march=native", "-shared", "-fPIC",
+                 f"-I{inc}", _SRC, "-o", tmp],
+                check=True, capture_output=True, timeout=120)
+            os.replace(tmp, _SO)
+        finally:
+            if os.path.exists(tmp):
+                os.unlink(tmp)
         return True
     except subprocess.CalledProcessError as e:
         last_build_error = (e.stderr or b"")[-2000:].decode(

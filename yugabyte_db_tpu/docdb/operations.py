@@ -937,15 +937,16 @@ class DocReadOperation:
         return best
 
     def _find_best(self, prefix: bytes, read_ht: int, restart_hi,
-                   mems, ssts):
+                   mems, ssts, keys_only: bool = False):
         """Newest visible version tuple (ht, write_id, key, value,
-        block, pos) of one doc key across the snapshot, or None."""
+        block, pos) of one doc key across the snapshot, or None.
+        `keys_only`: as `SstReader.point_find`."""
         best = self._mem_best(prefix, read_ht, restart_hi, mems)
         h = fnv64_bytes(prefix)
         for r in ssts:
             if not r.may_contain_hash(h):
                 continue
-            found = r.point_find(prefix, read_ht, restart_hi)
+            found = r.point_find(prefix, read_ht, restart_hi, keys_only)
             if found is None:
                 continue
             if found[0] == "restart":
@@ -1024,6 +1025,28 @@ class DocReadOperation:
         if best is None:
             return None
         return self._decode_best(best, read_ht)
+
+    def key_is_live(self, pk_row: Dict[str, object], read_ht: int,
+                    allow_restart: bool = False) -> bool:
+        """`get_row(pk_row, read_ht) is not None` for a caller that
+        wants no row — an INSERT's uniqueness gate.  Each SST's bloom
+        filter first, then only the block the key would sit in, decoded
+        without its value columns: a key that is new to the tablet costs
+        no block at all but for the filter's false positives, where
+        `get_row` builds a point reader over every block of every SST
+        of the tablet the first time it is asked."""
+        prefix = self.codec.doc_key_prefix(pk_row)
+        restart_hi = read_ht + _skew_window_ht() if allow_restart else None
+        mems, ssts = self.store.read_snapshot()
+        best = self._find_best(prefix, read_ht, restart_hi, mems, ssts,
+                               keys_only=True)
+        if best is None:
+            return False
+        cb, pos = best[4], best[5]
+        if cb is not None:
+            # (a block with TTL'd rows has no columnar form)
+            return not cb.tombstone[pos]
+        return self._decode_best(best, read_ht) is not None
 
     def multi_get(self, pk_rows: Sequence[Dict[str, object]],
                   read_ht: int, allow_restart: bool = False,
@@ -1424,9 +1447,14 @@ class DocReadOperation:
         from dataclasses import replace
         return replace(req, where=where, aggregates=aggs)
 
-    def _collect_blocks(self) -> Optional[List[ColumnarBlock]]:
+    def _collect_blocks(self, columns=None
+                        ) -> Optional[List[ColumnarBlock]]:
         """All columnar blocks across SSTs + a block built from memtable
-        contents; None if any source can't provide columnar form."""
+        contents; None if any source can't provide columnar form.
+        `columns`: the value-column ids the caller will read — the SST
+        blocks are then projected to them (`SstReader.projected_block`),
+        decoded for this caller alone and not through the SST's block
+        cache, which a table larger than it only churns."""
         with _trace.TRACES.span("docdb.collect_blocks",
                                 child_only=True) as sp:
             sp.set_tag("step", "collect")
@@ -1434,7 +1462,8 @@ class DocReadOperation:
             blocks: List[ColumnarBlock] = []
             for r in self.store.ssts:
                 for i in range(r.num_blocks()):
-                    cb = r.columnar_block(i)
+                    cb = (r.columnar_block(i) if columns is None
+                          else r.projected_block(i, columns))
                     if cb is None:
                         return None
                     blocks.append(cb)
@@ -1947,6 +1976,25 @@ class DocReadOperation:
         except KeyError:
             return None   # some column lacks columnar form → CPU path
         self._check_restart_window(blocks, read_ht)
+        return self.aggregate_on_batch(
+            req, batch,
+            lambda where, aggs, group: self.kernel.run(
+                batch, where, aggs, group, read_ht),
+            lambda *partials: self._monolithic_spill_merge(
+                req, req.group_by, batch, kept, *partials))
+
+    @classmethod
+    def aggregate_on_batch(cls, req: ReadRequest, batch, run,
+                           on_spill=None) -> Optional[ReadResponse]:
+        """The request's aggregates over one cached batch — a
+        `DeviceBatch`, or a `ShardedBatch` that covers several tablets
+        (docdb/mesh_read.py): string shapes rewritten into the batch's
+        code space, the kernel launched through `run(where, aggs,
+        group)` (what `ScanKernel.run` returns), the result decoded.
+        None = a shape the device cannot serve exactly; the caller
+        falls back.  `on_spill(expanded, minmax, aggs_run, outs, counts,
+        mask)` may serve a dictionary-grouped scan that overflowed its
+        slot budget."""
         where = req.where
         aggregates = req.aggregates
         if where is not None or any(a.expr is not None
@@ -1954,9 +2002,9 @@ class DocReadOperation:
             # runs even with no dictionaries: a leftover 'like' (or any
             # string shape the kernel can't compile) must fall back
             try:
-                where, aggregates = self.rewrite_where_and_aggs(
+                where, aggregates = cls.rewrite_where_and_aggs(
                     where, aggregates, batch.dicts)
-            except self._Unrewritable:
+            except cls._Unrewritable:
                 return None   # string column outside a rewritable shape
         # SQL NULL semantics for MIN/MAX over zero qualifying inputs:
         # the kernel returns a dtype sentinel there, so run a hidden
@@ -1975,8 +2023,8 @@ class DocReadOperation:
                 batch.dicts)
 
         if isinstance(req.group_by, HashGroupSpec):
-            outs, counts, _, gvals, n_groups = self.kernel.run(
-                batch, where, aggs_run, req.group_by, read_ht)
+            outs, counts, _, gvals, n_groups = run(
+                where, aggs_run, req.group_by)
             if int(n_groups) > req.group_by.max_groups:
                 return None     # distinct-group overflow: CPU fallback
             return ReadResponse(
@@ -1992,8 +2040,7 @@ class DocReadOperation:
             if any(c not in batch.dicts for c in gspec.cols) or \
                     domain_product(gspec, batch.dicts) >= 2 ** 31:
                 return None     # no dictionary / gid would wrap: CPU
-            outs, counts, mask, spill = self.kernel.run(
-                batch, where, aggs_run, gspec, read_ht)
+            outs, counts, mask, spill = run(where, aggs_run, gspec)
             if int(spill) > 0:
                 # slot overflow on the MONOLITHIC dict-group route:
                 # same partial-spill merge as the streamed path — keep
@@ -2001,10 +2048,10 @@ class DocReadOperation:
                 # the spilled rows on the interpreted fold.  The kernel
                 # mask already folds visibility/WHERE/group-null, so
                 # the spilled row set replays host-side for free.
-                if flags.get("grouped_spill_merge_enabled"):
-                    resp = self._monolithic_spill_merge(
-                        req, gspec, batch, kept, expanded, minmax,
-                        aggs_run, outs, counts, mask)
+                if on_spill is not None \
+                        and flags.get("grouped_spill_merge_enabled"):
+                    resp = on_spill(expanded, minmax, aggs_run, outs,
+                                    counts, mask)
                     if resp is not None:
                         GROUPED_STATS["spill_merges"] += 1
                         return resp
@@ -2015,8 +2062,7 @@ class DocReadOperation:
             return ReadResponse(agg_values=outs_c,
                                 group_counts=counts_c,
                                 group_values=gvals, backend="tpu")
-        outs, counts, _ = self.kernel.run(
-            batch, where, aggs_run, req.group_by, read_ht)
+        outs, counts, _ = run(where, aggs_run, req.group_by)
         return ReadResponse(agg_values=_nullify(outs),
                             group_counts=np.asarray(counts),
                             backend="tpu")

@@ -12,6 +12,8 @@ columnar-only SSTs without a per-row Python loop.
 """
 from __future__ import annotations
 
+import functools
+import weakref
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -563,12 +565,48 @@ class TableCodec:
                                           block_rows=block_rows,
                                           partition=partition))
 
+    def _hashes_memo(self, columns: Dict[str, np.ndarray], hashes=None):
+        """The partition hashes of the last arrays loaded, for a table
+        loaded tablet by tablet: every tablet is handed every row
+        (`Tablet.bulk_load`), and a row's hash is the same for each.
+        Held by the hash columns' arrays themselves (weakly: the memo
+        goes with them) and by ~1,000 of their values, evenly spaced: a
+        buffer filled anew between two calls is told apart, a few values
+        changed in place are not.  With `hashes`, stores them."""
+        global _HASHES_MEMO
+        cols = self._pk_cols[:self.info.partition_schema.num_hash_columns]
+        arrs = [columns[c.name] for c in cols]
+        if not all(isinstance(a, np.ndarray) and a.ndim == 1 for a in arrs):
+            return None
+        shape = tuple((c.type, c.sort_desc, a.dtype.str, a.shape,
+                       a[::max(1, len(a) // 1024)].tobytes())
+                      for c, a in zip(cols, arrs))
+        if hashes is not None:
+            _HASHES_MEMO = ([weakref.ref(a, _drop_hashes_memo)
+                             for a in arrs], shape, hashes)
+            return hashes
+        refs, was, got = _HASHES_MEMO
+        if was == shape and len(refs) == len(arrs) and all(
+                r() is a for r, a in zip(refs, arrs)):
+            return got
+        return None
+
     def bulk_blocks_iter(self, columns: Dict[str, np.ndarray],
                          ht: HybridTime, block_rows: int = 65536,
                          partition=None):
-        """Turn user column arrays into sorted columnar-only blocks,
-        yielded one at a time so the ingest pipeline overlaps block k's
-        fused gather with block k-1's file write.
+        """The blocks of :meth:`bulk_block_makers`, made one at a time
+        and in order."""
+        for make in self.bulk_block_makers(columns, ht, block_rows,
+                                           partition):
+            yield make()
+
+    def bulk_block_makers(self, columns: Dict[str, np.ndarray],
+                          ht: HybridTime, block_rows: int = 65536,
+                          partition=None) -> list:
+        """Turn user column arrays into sorted columnar-only blocks: one
+        callable a block, in block order, each independent of the others
+        (the ingest pipeline makes and serializes several at once on its
+        threads, and writes them in order).
 
         Requirements (bulk fast path): every PK component fixed-width
         numeric. Varlen value columns are allowed.
@@ -584,22 +622,51 @@ class TableCodec:
         """
         n = len(next(iter(columns.values())))
         ps = self.info.partition_schema
-        pk_blocks = []
-        for c in self._pk_cols:
-            enc = _BULK_ENC[c.type](np.asarray(columns[c.name]), c.sort_desc)
-            pk_blocks.append(enc)
+
+        def encoded(c, rows=None):
+            arr = np.asarray(columns[c.name])
+            return _BULK_ENC[c.type](arr if rows is None else arr[rows],
+                                     c.sort_desc)
+
+        # a hash-partitioned table knows a row's tablet from its hash
+        # columns alone: those are encoded for every row, the rest of the
+        # key only for the rows this partition keeps (loading a table
+        # tablet by tablet hands every tablet every row)
+        nh = ps.num_hash_columns if ps.kind == "hash" else 0
+        late = nh > 0 and partition is not None
+        hashes = self._hashes_memo(columns) if late else None
+        pk_blocks = [] if hashes is not None else [
+            encoded(c) for c in
+            (self._pk_cols[:nh] if late else self._pk_cols)]
         if ps.kind == "hash":
-            nh = ps.num_hash_columns
-            hash_input = (pk_blocks[0] if nh == 1
-                          else np.concatenate(pk_blocks[:nh], axis=1))
-            hashes = bulk.fast_hash16_from_encoded(hash_input)
-            doc_keys = bulk.encode_doc_keys(hashes, pk_blocks, nh)
-            part_keys = hashes.astype(">u2").view(np.uint8).reshape(-1, 2)
+            if hashes is None:
+                hash_input = (pk_blocks[0] if nh == 1
+                              else np.concatenate(pk_blocks[:nh], axis=1))
+                # `bulk.fast_hash16_from_encoded` in one native pass:
+                # FNV-1a 64 of each row (same basis and prime), folded
+                # to 16 bits
+                h64 = _fnv_rows(hash_input)
+                hashes = ((h64 ^ (h64 >> np.uint64(32)))
+                          & np.uint64(0xFFFF)).astype(np.uint32)
+                if late:
+                    self._hashes_memo(columns, hashes)
+            doc_keys = (None if late else
+                        bulk.encode_doc_keys(hashes, pk_blocks, nh))
+            part_keys = None
         else:
             doc_keys = bulk.encode_doc_keys(None, pk_blocks, 0)
             part_keys = doc_keys
         keep = np.ones(n, bool)
-        if partition is not None:
+        if partition is not None and part_keys is None:
+            # a hash partition's bounds are 16-bit hash values, big-endian
+            # and zero-padded: compared as numbers, not byte by byte
+            if partition.start:
+                keep &= hashes >= int.from_bytes(
+                    partition.start.ljust(2, b"\x00")[:2], "big")
+            if partition.end:
+                keep &= hashes < int.from_bytes(
+                    partition.end.ljust(2, b"\x00")[:2], "big")
+        elif partition is not None:
             if partition.start:
                 lo = np.frombuffer(partition.start.ljust(part_keys.shape[1],
                                                          b"\x00"), np.uint8)
@@ -615,11 +682,19 @@ class TableCodec:
             idx = np.arange(n, dtype=np.int64)
         else:
             idx = np.nonzero(keep)[0]
-            doc_keys = doc_keys[idx]
+            if doc_keys is not None:
+                doc_keys = doc_keys[idx]
             if ps.kind == "hash":
                 hashes = hashes[idx]
         if not len(idx):
-            return
+            return []
+        if doc_keys is None:
+            # the whole key of the rows this partition keeps (their hash
+            # columns encoded again: a row in eight of a table loaded
+            # tablet by tablet)
+            rows = None if identity else idx
+            doc_keys = bulk.encode_doc_keys(
+                hashes, [encoded(c, rows) for c in self._pk_cols], nh)
         full = bulk.append_hybrid_times(
             doc_keys,
             np.full(len(idx), ht.value, np.uint64),
@@ -642,8 +717,8 @@ class TableCodec:
         arrs = {c.id: np.asarray(columns[c.name])
                 for c in self.schema.columns}
         dk_w = doc_keys.shape[1]
-        prev_last_dk = None
-        for s in range(0, len(order), block_rows):
+
+        def make(s: int) -> ColumnarBlock:
             ord_b = np.ascontiguousarray(order[s:s + block_rows])
             bn = len(ord_b)
             sel = ord_b if identity else np.ascontiguousarray(idx[ord_b])
@@ -665,10 +740,34 @@ class TableCodec:
                         pk[c.id] = out
                     else:
                         fixed[c.id] = (out, np.zeros(bn, bool))
+                elif arr.dtype.kind == "S" and arr.flags["C_CONTIGUOUS"]:
+                    # fixed-width byte strings ride the fused gather as
+                    # rows of a byte matrix
+                    mat = np.empty((bn, arr.dtype.itemsize), np.uint8)
+                    jobs.append((arr.view(np.uint8).reshape(len(arr), -1),
+                                 mat, sel, None))
+                    slow_cols.append((c, mat, True))
                 else:
-                    slow_cols.append((c, arr))
+                    slow_cols.append((c, arr, False))
             native_lib.gather_columns(jobs)
-            for c, arr in slow_cols:
+            for c, arr, gathered in slow_cols:
+                if gathered or arr.dtype.kind == "S":
+                    # fixed-width byte strings: a value is its bytes up
+                    # to the trailing NULs, so the heap is one masked
+                    # copy of the gathered rows' byte matrix
+                    mat = arr if gathered else \
+                        np.ascontiguousarray(arr[sel]).view(
+                            np.uint8).reshape(bn, -1)
+                    lens = np.char.str_len(mat.view(f"S{mat.shape[1]}")
+                                           ).reshape(bn)
+                    inside = mat != 0
+                    if int(np.count_nonzero(inside)) != int(lens.sum()):
+                        # a NUL inside a value
+                        inside = np.arange(mat.shape[1]) < lens[:, None]
+                    varlen[c.id] = (np.cumsum(lens).astype(np.uint32),
+                                    mat[inside].tobytes(),
+                                    np.zeros(bn, bool))
+                    continue
                 raws = [x.encode() if isinstance(x, str) else bytes(x)
                         for x in arr[sel]]
                 ends = np.cumsum([len(r) for r in raws]).astype(np.uint32)
@@ -681,10 +780,8 @@ class TableCodec:
             dk_b = keys_b[:, :dk_w]
             uniq = bool((dk_b[1:] != dk_b[:-1]).any(axis=1).all()) \
                 if bn > 1 else True
-            if prev_last_dk is not None and \
-                    prev_last_dk == dk_b[0].tobytes():
+            if s and bool((doc_keys[order[s - 1]] == dk_b[0]).all()):
                 uniq = False
-            prev_last_dk = dk_b[-1].tobytes()
             blk = ColumnarBlock.from_arrays(
                 schema_version=self.schema.version,
                 key_hash=kh_b,
@@ -698,7 +795,21 @@ class TableCodec:
             # no write-time verify; cotable prefixes would break the
             # replay (derive_keys refuses them)
             blk.keys_proven = self.info.cotable_id is None
-            yield blk
+            return blk
+
+        return [functools.partial(make, s)
+                for s in range(0, len(order), block_rows)]
+
+
+#: (weak references to the hash columns' arrays, what they were, their
+#: partition hashes) of the last bulk load: `TableCodec._hashes_memo`
+_HASHES_MEMO: tuple = ([], None, None)
+
+
+def _drop_hashes_memo(ref) -> None:
+    global _HASHES_MEMO
+    if any(r is ref for r in _HASHES_MEMO[0]):
+        _HASHES_MEMO = ([], None, None)
 
 
 def _rows_ge(mat: np.ndarray, bound: np.ndarray) -> np.ndarray:

@@ -494,6 +494,10 @@ class Master:
             "tablets": payload.get("tablets", []),
             "zone": payload.get("zone", "zone-default"),
         }
+        if payload.get("device_chips"):
+            # a server that owns several chips says so: the client may
+            # send it one read for all of a table's tablets it leads
+            self.tservers[uuid]["device_chips"] = payload["device_chips"]
         # track leadership reports for client routing; differentiate
         # the LEADER's wal_index across heartbeats into a per-tablet
         # write rate (EWMA — one noisy heartbeat gap must not fake a
@@ -944,7 +948,10 @@ class Master:
                 "replicas": [
                     {"ts_uuid": u,
                      "addr": list(self.tservers[u]["addr"])
-                     if u in self.tservers else None}
+                     if u in self.tservers else None,
+                     **({"chips": self.tservers[u]["device_chips"]}
+                        if self.tservers.get(u, {}).get("device_chips")
+                        else {})}
                     for u in ent["replicas"]],
                 "leader": ent.get("leader"),
             })

@@ -397,13 +397,22 @@ def _pad(arr: np.ndarray, n: int) -> np.ndarray:
 
 
 class DeviceBlockCache:
-    """LRU cache of device-resident DeviceBatches keyed by
-    (sst_path, block_range, column-set). Eviction by padded byte size."""
+    """LRU cache of device-resident batches keyed by (store, column set,
+    SST set, ...).  An entry is a `DeviceBatch` on one chip or a
+    `parallel.distributed_scan.ShardedBatch` whose lanes cover several;
+    bytes are accounted per chip, by the shards of the entry's lanes,
+    against a capacity that is per chip, and eviction frees the least
+    recently used entry that holds bytes on a chip over it.  With every
+    entry on one chip that is the plain LRU by padded byte size."""
 
     def __init__(self, capacity_bytes: int = 2 << 30):
-        self.capacity = capacity_bytes
-        self._map: OrderedDict[tuple, Tuple[DeviceBatch, int]] = OrderedDict()
+        self.capacity = capacity_bytes          # per chip
+        # key -> (batch, bytes over all chips); `benchmark/cluster.py`
+        # reads the batches here to say where the table sits
+        self._map: OrderedDict[tuple, Tuple[object, int]] = OrderedDict()
+        self._placed: Dict[tuple, Dict[object, int]] = {}  # key -> chip -> B
         self._bytes = 0
+        self._bytes_by_chip: Dict[object, int] = {}
         self.hits = 0
         self.misses = 0
         # invalidations arrive from flush/compaction executor threads
@@ -412,15 +421,48 @@ class DeviceBlockCache:
         # path would otherwise hit constantly)
         self._lock = threading.Lock()
 
-    def get_or_build(self, key: tuple, builder) -> DeviceBatch:
+    def bytes_by_chip(self) -> Dict[object, int]:
+        with self._lock:
+            return dict(self._bytes_by_chip)
+
+    @staticmethod
+    def _chip_metrics(chip):
+        from ..utils import metrics
+        return metrics.REGISTRY.entity(
+            "device_cache", f"chip-{getattr(chip, 'id', chip)}")
+
+    def _count(self, what: str, chips) -> None:
+        """`/metrics`: hits and misses of each chip an entry covers."""
+        for d in chips:
+            self._chip_metrics(d).counter(what).increment()
+
+    def _publish_bytes(self) -> None:
+        for d, b in self._bytes_by_chip.items():
+            self._chip_metrics(d).gauge("bytes").set(b)
+
+    def _drop(self, key: tuple) -> None:
+        _, size = self._map.pop(key)
+        self._bytes -= size
+        for d, b in self._placed.pop(key).items():
+            self._bytes_by_chip[d] -= b
+
+    def get_or_build(self, key: tuple, builder, chips=None):
+        """`chips`: the devices the entry covers when it is not on the
+        default one alone — named so that a hit or a miss is counted on
+        `/metrics` for each of them."""
         with self._lock:
             if key in self._map:
                 self.hits += 1
                 self._map.move_to_end(key)
+                if chips is not None:
+                    self._count("hits", chips)
                 return self._map[key][0]
             self.misses += 1
+        if chips is not None:
+            self._count("misses", chips)
         batch = builder()
-        size = batch_bytes(batch)
+        placed = batch_placement(batch)
+        size = sum(placed.values())
         with self._lock:
             if key in self._map:
                 # a racing builder (flush thread vs loop) landed the
@@ -429,33 +471,88 @@ class DeviceBlockCache:
                 self._map.move_to_end(key)
                 return self._map[key][0]
             self._map[key] = (batch, size)
+            self._placed[key] = placed
             self._bytes += size
-            while self._bytes > self.capacity and len(self._map) > 1:
-                _, (old, osize) = self._map.popitem(last=False)
-                self._bytes -= osize
-                del old
+            for d, b in placed.items():
+                self._bytes_by_chip[d] = self._bytes_by_chip.get(d, 0) + b
+            while len(self._map) > 1:
+                full = {d for d, b in self._bytes_by_chip.items()
+                        if b > self.capacity}
+                # least recently used entry with bytes on a full chip;
+                # never the one just inserted
+                victim = next((k for k in self._map if k != key
+                               and full & set(self._placed[k])), None)
+                if not full or victim is None:
+                    break
+                self._drop(victim)
+            if chips is not None:
+                self._publish_bytes()
         return batch
+
+    def get_covering(self, key: tuple, part: int, chips=None):
+        """The most recently used entry whose key is `key` but for
+        element `part`, a tuple that holds every member of `key[part]`
+        — a batch of more columns than asked for serves the fewer — or
+        None.  Counted as a hit."""
+        want = set(key[part])
+        with self._lock:
+            for k in reversed(self._map):
+                if (len(k) == len(key) and k[:part] == key[:part]
+                        and k[part + 1:] == key[part + 1:]
+                        and want <= set(k[part])):
+                    self.hits += 1
+                    self._map.move_to_end(k)
+                    if chips is not None:
+                        self._count("hits", chips)
+                    return self._map[k][0]
+        return None
 
     def invalidate_prefix(self, prefix: tuple) -> None:
         """Drop entries whose key starts with prefix (e.g. an SST was
-        compacted away)."""
+        compacted away) — and, for a one-element prefix (a store), the
+        multi-store entries that name it: their first key element is a
+        tuple that holds every member store."""
         with self._lock:
-            drop = [k for k in self._map if k[:len(prefix)] == prefix]
+            drop = [k for k in self._map if k[:len(prefix)] == prefix
+                    or (len(prefix) == 1 and isinstance(k[0], tuple)
+                        and prefix[0] in k[0])]
             for k in drop:
-                _, size = self._map.pop(k)
-                self._bytes -= size
+                self._drop(k)
 
     def clear(self):
         with self._lock:
             self._map.clear()
+            self._placed.clear()
             self._bytes = 0
+            self._bytes_by_chip.clear()
 
 
-def batch_bytes(b: DeviceBatch) -> int:
-    total = b.valid.size * 1
-    for a in list(b.cols.values()) + list(b.nulls.values()):
-        total += a.size * a.dtype.itemsize
-    for a in (b.ht, b.next_ht, b.tombstone):
-        if a is not None:
-            total += a.size * a.dtype.itemsize
-    return total
+def _lanes(b) -> list:
+    return [a for a in list(b.cols.values()) + list(b.nulls.values())
+            + [b.valid, b.ht, b.next_ht, b.tombstone] if a is not None]
+
+
+def batch_bytes(b) -> int:
+    return sum(a.size * a.dtype.itemsize for a in _lanes(b))
+
+
+def batch_placement(b) -> Dict[object, int]:
+    """chip -> bytes of the batch's lanes that sit on it, shard by shard:
+    one chip for a `DeviceBatch`, every chip of the mesh for a sharded
+    one."""
+    placed: Dict[object, int] = {}
+    for a in _lanes(b):
+        for sh in a.addressable_shards:
+            placed[sh.device] = placed.get(sh.device, 0) + sh.data.nbytes
+    return placed
+
+
+def chip_capacity(devices, share: float = 0.5,
+                  default: int = 2 << 30) -> int:
+    """The cache's capacity a chip for a server that owns `devices`:
+    `share` of the smallest chip's memory, by the device's own
+    `bytes_limit`; `default` where a device does not say (a CPU)."""
+    limits = [(d.memory_stats() or {}).get("bytes_limit") for d in devices]
+    if not limits or any(l is None for l in limits):
+        return default
+    return int(min(limits) * share)

@@ -798,17 +798,20 @@ def _thread_kind() -> str:
 
 def _static_scales(aggs: Sequence[AggSpec],
                    col_bounds: Dict[int, Tuple[float, float]],
-                   n_total: int, cols=None):
+                   n_total: int, cols=None, host: bool = False):
     """Per-agg static fixed-point scales from host column stats.
     Returns (static_flags, scale_args) — scale_args are runtime jnp
     scalars (0.0 placeholders for non-static entries) so changing data
-    bounds never recompiles the kernel. `cols` (col_id -> device array)
+    bounds never recompiles the kernel (`host`: numpy scalars instead,
+    for a launch that places its arguments itself). `cols` (col_id ->
+    device array)
     supplies dtypes: expressions touching f32 columns cap every
     intermediate interval at the f32 finite range, since an f32 product
     can overflow to Inf on device even when the final bound is small
     and the static path has no Inf fallback lane."""
     from .expr import expr_bound, referenced_columns
     flags_, scales = [], []
+    f32 = np.float32 if host else jnp.float32
     for a in aggs:
         s = None
         if a.op == "sum" and a.expr is not None and col_bounds:
@@ -826,7 +829,7 @@ def _static_scales(aggs: Sequence[AggSpec],
             if b is not None:
                 s = _scale_for(max(abs(b[0]), abs(b[1])), n_total)
         flags_.append(s is not None)
-        scales.append(jnp.float32(s if s is not None else 0.0))
+        scales.append(f32(s if s is not None else 0.0))
     return tuple(flags_), tuple(scales)
 
 

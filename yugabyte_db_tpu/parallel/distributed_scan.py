@@ -9,7 +9,8 @@ src/postgres yb_scan paths).
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+from concurrent.futures import ThreadPoolExecutor
+from dataclasses import dataclass, field, replace
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -17,14 +18,16 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..ops.device_batch import (HT_NONE, bucket_rows, _pad,
+from ..ops.device_batch import (HT_NONE, batch_bytes, bucket_rows,
                                 f64_conversion, link_versions)
 from ..ops.expr import collect_constants, expr_signature
+from ..ops.grouped_scan import DictGroupSpec, resolve_group
 from ..ops.scan import (
     AggSpec, GroupSpec, _build_kernel, _expand_avg, _group_strategy,
-    _rescale_outs, _static_scales, mvcc_lanes,
+    _rescale_outs, _static_scales, _thread_kind, mvcc_lanes,
 )
 from ..storage.columnar import ColumnarBlock
+from ..utils import trace as _trace
 from .mesh import BLOCKS_AXIS, TABLETS_AXIS, TabletMesh
 
 
@@ -47,6 +50,29 @@ class ShardedBatch:
     next_ht: Optional[jnp.ndarray]
     tombstone: jnp.ndarray
     mesh: TabletMesh
+    # text columns ride as int32 codes of these dictionaries, which are
+    # GLOBAL over the shards (one ops/grouped_scan.DictPlan over every
+    # shard's blocks): equal codes on two chips mean equal strings, so a
+    # dictionary-grouped partial psums slot by slot
+    dicts: Dict[int, np.ndarray] = field(default_factory=dict)
+    # the newest write time among the rows (host side): a read at or
+    # above it has no row inside its uncertainty window
+    max_ht: int = 0
+
+    def narrowed(self, columns) -> "ShardedBatch":
+        """This batch as one of `columns` alone (all of them among its
+        own): the same arrays on the chips, so a scan of fewer columns
+        runs the program it would run on a batch built for them."""
+        if set(columns) == set(self.cols):
+            return self
+        keep = lambda d: {c: d[c] for c in sorted(columns) if c in d}
+        return replace(
+            self, cols=keep(self.cols), nulls=keep(self.nulls),
+            col_bounds=keep(self.col_bounds), dicts=keep(self.dicts))
+
+    @property
+    def n_rows(self) -> int:
+        return sum(self.n_rows_per_shard)
 
     @property
     def padded_rows(self) -> int:
@@ -58,94 +84,150 @@ class ShardedBatch:
         return int(np.prod(self.valid.shape[:-1]))
 
 
+def _column_part(b: ColumnarBlock, cid: int) -> np.ndarray:
+    if cid in b.fixed:
+        return b.fixed[cid][0]
+    if cid in b.pk:
+        return b.pk[cid]
+    raise KeyError(f"column {cid} not available in columnar form")
+
+
 def build_sharded_batch(tm: TabletMesh,
                         per_shard_blocks: Sequence[Sequence[ColumnarBlock]],
-                        columns: Sequence[int]) -> ShardedBatch:
-    """Stack per-shard block lists into mesh-sharded [S, N] arrays. The
-    number of shard slots must equal the mesh size; short shards pad."""
-    S = tm.num_tablet_shards * tm.num_block_shards
+                        columns: Sequence[int], dict_plan=None,
+                        multi_version: bool = False) -> ShardedBatch:
+    """Per-shard block lists -> mesh-sharded [T, B, N] lanes, one shard a
+    device.  The number of shard slots must equal the mesh size; short
+    shards pad.  Each shard's lanes are filled, linked and put on its own
+    device by a thread of its own, so the host never holds a stacked copy
+    of the table.
+
+    ``dict_plan``: an ops/grouped_scan.DictPlan over EVERY shard's blocks
+    — its columns ride as codes of the plan's global dictionaries
+    (``ShardedBatch.dicts``).  ``multi_version``: the blocks of a shard
+    may hold several versions of a key even where each block is
+    unique-keyed by itself (several SSTs a tablet); such a batch gets
+    ``next_ht``, linked per shard.  Spans as `build_batch`:
+    ``batch.build`` (host fill), ``batch.version_link``, ``batch.h2d``."""
+    T, B = tm.num_tablet_shards, tm.num_block_shards
+    S = T * B
     if len(per_shard_blocks) != S:
         raise ValueError(f"need {S} shard block-lists, got "
                          f"{len(per_shard_blocks)}")
     ns = [sum(b.n for b in blocks) for blocks in per_shard_blocks]
     pad = bucket_rows(max(max(ns), 1))
+    every = [b for blocks in per_shard_blocks for b in blocks]
+    devices = list(tm.mesh.devices.reshape(-1))
+    dict_cols = set(dict_plan.dicts) if dict_plan is not None else set()
+    pool = ThreadPoolExecutor(max_workers=S,
+                              thread_name_prefix="shard-build")
 
-    def stack(get, dtype=None):
-        if dtype is None:
-            # take the real dtype from any nonempty shard so empty shards
-            # don't promote int columns to float64 via np.stack
-            for blocks in per_shard_blocks:
-                for b in blocks:
-                    dtype = get(b).dtype
-                    break
-                if dtype is not None:
-                    break
-        rows = []
-        for blocks, n in zip(per_shard_blocks, ns):
-            parts = [get(b) for b in blocks]
-            arr = (np.concatenate(parts) if parts
-                   else np.zeros(0, dtype or np.float32))
-            rows.append(_pad(arr, pad))
-        return np.stack(rows)
+    def fill(blocks, get, dtype) -> np.ndarray:
+        out, pos = np.zeros(pad, dtype), 0
+        for b in blocks:
+            part = get(b)
+            out[pos:pos + len(part)] = part
+            pos += len(part)
+        return out
 
-    cols: Dict[int, jnp.ndarray] = {}
-    nulls: Dict[int, jnp.ndarray] = {}
-    col_bounds: Dict[int, Tuple[float, float]] = {}
+    with pool, _trace.TRACES.span("batch.build", child_only=True) as sp:
+        # the device dtype is decided GLOBALLY (all shards must agree)
+        # with the single-device builder's policy: integer-valued f64
+        # columns ship as exact int32, fractional f64 follows the
+        # `device_float_dtype` flag; so are the bounds the static SUM
+        # scales come from, so every shard quantizes identically and the
+        # int64 partials psum exactly
+        plain = [cid for cid in columns if cid not in dict_cols]
+        dtypes: Dict[int, np.dtype] = {}
+        col_bounds: Dict[int, Tuple[float, float]] = {}
+        for cid in plain:
+            parts = [_column_part(b, cid) for b in every]
+            conv = f64_conversion(parts)
+            dtypes[cid] = np.dtype(conv if conv is not None else (
+                parts[0].dtype if parts else np.float32))
+            if dtypes[cid].kind in "fiu" and any(p.size for p in parts):
+                col_bounds[cid] = (
+                    float(min(p.min() for p in parts if p.size)),
+                    float(max(p.max() for p in parts if p.size)))
 
-    def put(tm, arr):
-        T, B = tm.num_tablet_shards, tm.num_block_shards
-        arr = arr.reshape(T, B, *arr.shape[1:])
-        return jax.device_put(arr, tm.tablet_block_sharding(
-            extra_dims=arr.ndim - 2))
+        def host_lanes(shard: int) -> dict:
+            blocks, n = per_shard_blocks[shard], ns[shard]
+            lanes = {"cols": {}, "nulls": {}}
+            for cid in plain:
+                lanes["cols"][cid] = fill(
+                    blocks, lambda b: _column_part(b, cid), dtypes[cid])
+                lanes["nulls"][cid] = fill(
+                    blocks, lambda b: (b.fixed[cid][1] if cid in b.fixed
+                                       else np.zeros(b.n, bool)), bool)
+            for cid in columns:
+                if cid in dict_cols:
+                    lanes["cols"][cid] = fill(
+                        blocks, lambda b: dict_plan.block_codes(cid, b),
+                        np.int32)
+                    lanes["nulls"][cid] = fill(
+                        blocks, lambda b: np.asarray(b.varlen[cid][2],
+                                                     bool), bool)
+            valid = np.zeros(pad, bool)
+            valid[:n] = True
+            lanes["valid"] = valid
+            lanes["ht"] = fill(blocks, lambda b: b.ht, np.uint64)
+            lanes["tombstone"] = fill(blocks, lambda b: b.tombstone, bool)
+            return lanes
 
-    for cid in columns:
-        # decide the device dtype GLOBALLY (all shards must agree) with
-        # the same policy as the single-device builder: integer-valued
-        # f64 columns ship as exact int32; fractional f64 follows the
-        # backend policy (f64 on CPU, f32 on TPU — sums stay exact via
-        # the kernel's int64 fixed-point accumulation)
-        conv = f64_conversion(
-            [b.fixed[cid][0] if cid in b.fixed else b.pk[cid]
-             for blocks in per_shard_blocks for b in blocks])
+        host = list(pool.map(host_lanes, range(S)))
+        max_ht = max((int(h["ht"][:n].max())
+                      for h, n in zip(host, ns) if n), default=0)
+        if multi_version or not all(b.unique_keys for b in every):
+            def link(shard: int) -> int:
+                blocks, n = per_shard_blocks[shard], ns[shard]
+                nxt = np.full(pad, HT_NONE, np.uint64)
+                superseded = 0
+                if n:
+                    nxt[:n], superseded = link_versions(
+                        np.concatenate([b.key_hash for b in blocks]),
+                        host[shard]["ht"][:n],
+                        np.concatenate([b.write_id for b in blocks]))
+                host[shard]["next_ht"] = nxt
+                return superseded
+            with _trace.TRACES.span("batch.version_link",
+                                    child_only=True) as lsp:
+                superseded = sum(pool.map(link, range(S)))
+                lsp.set_tag("rows", sum(ns))
+                lsp.set_tag("superseded", superseded)
+                lsp.set_tag("shards", S)
+        sp.set_tag("shards", S)
+        sp.set_tag("rows", sum(ns))
 
-        def getv(b, cid=cid, conv=conv):
-            v = b.fixed[cid][0] if cid in b.fixed else b.pk[cid]
-            return v.astype(conv) if conv is not None else v
+    sharding = tm.tablet_block_sharding(extra_dims=1)
 
-        def getn(b, cid=cid):
-            if cid in b.fixed:
-                return b.fixed[cid][1]
-            return np.zeros(b.n, bool)
-        stacked = stack(getv)
-        if stacked.size and stacked.dtype.kind in "fiu":
-            # padding zeros are included — harmless: masked rows
-            # contribute 0 to any SUM, the bound only sets the scale
-            col_bounds[cid] = (float(stacked.min()), float(stacked.max()))
-        cols[cid] = put(tm, stacked)
-        nulls[cid] = put(tm, stack(getn, bool))
-    valid_rows = []
-    for n in ns:
-        v = np.zeros(pad, bool)
-        v[:n] = True
-        valid_rows.append(v)
-    ht = stack(lambda b: b.ht, np.uint64)
-    next_ht = None
-    if not all(b.unique_keys
-               for blocks in per_shard_blocks for b in blocks):
-        next_ht = np.full(ht.shape, HT_NONE, np.uint64)
-        key_hash = stack(lambda b: b.key_hash, np.uint64)
-        write_id = stack(lambda b: b.write_id, np.uint32)
-        for i, n in enumerate(ns):
-            next_ht[i, :n], _ = link_versions(
-                key_hash[i, :n], ht[i, :n], write_id[i, :n])
-    return ShardedBatch(
-        n_rows_per_shard=ns, cols=cols, nulls=nulls,
-        col_bounds=col_bounds,
-        valid=put(tm, np.stack(valid_rows)),
-        ht=put(tm, ht),
-        next_ht=put(tm, next_ht) if next_ht is not None else None,
-        tombstone=put(tm, stack(lambda b: b.tombstone, bool)),
-        mesh=tm)
+    def put(lane: str, cid=None):
+        """One lane of every shard, each on its shard's device, as one
+        array sharded over the mesh."""
+        parts = [jax.device_put(
+            (h[lane] if cid is None else h[lane][cid]).reshape(1, 1, pad),
+            d) for h, d in zip(host, devices)]
+        return jax.make_array_from_single_device_arrays(
+            (T, B, pad), sharding, parts)
+
+    with _trace.TRACES.span("batch.h2d", child_only=True) as sp:
+        batch = ShardedBatch(
+            n_rows_per_shard=ns,
+            cols={cid: put("cols", cid) for cid in columns},
+            nulls={cid: put("nulls", cid) for cid in columns},
+            col_bounds=col_bounds, valid=put("valid"), ht=put("ht"),
+            next_ht=put("next_ht") if "next_ht" in host[0] else None,
+            tombstone=put("tombstone"), mesh=tm,
+            dicts=({cid: dict_plan.dicts[cid] for cid in columns
+                    if cid in dict_cols}), max_ht=max_ht)
+        if sp.sampled:
+            # transfers are asynchronous: wait, so that the span times
+            # them and not their enqueue
+            jax.block_until_ready(
+                (batch.cols, batch.nulls, batch.valid, batch.ht,
+                 batch.next_ht, batch.tombstone))
+            sp.set_tag("shards", S)
+            sp.set_tag("bytes", batch_bytes(batch) // S)
+    return batch
 
 
 _COMBINE = {"sum": "psum", "count": "psum", "min": "pmin", "max": "pmax"}
@@ -173,51 +255,61 @@ class DistributedScanKernel:
                               static_sums=static_sums, strategy=strategy)
 
         def shard_fn(cols, nulls, consts, valid, lanes, read_ht,
-                     sum_scales):
-            # local shard view: [1, 1, N] → [N]; a lane the mode does
+                     sum_scales, domains):
+            # local shard view: [1, 1, N] -> [N]; a lane the mode does
             # not read is None
             sq = lambda a: None if a is None else a.reshape(a.shape[-1])
             lcols = {k: sq(v) for k, v in cols.items()}
             lnulls = {k: sq(v) for k, v in nulls.items()}
-            outs, scales, counts, _ = local(
-                lcols, lnulls, consts, sq(valid), *map(sq, lanes),
-                read_ht, sum_scales)
+            got = local(lcols, lnulls, consts, sq(valid), *map(sq, lanes),
+                        read_ht, sum_scales, domains)
+            outs, scales, counts = got[:3]
+            # a dictionary-grouped kernel also counts the rows whose
+            # group fell past its slot budget: they add up like a count
+            spilled = got[4] if len(got) > 4 else jnp.int64(0)
+            kinds = [_COMBINE["count" if a.expr is None else a.op]
+                     for a in aggs]
+            # every additive partial of the launch rides ONE psum: the
+            # int64 lanes, the row counts, the spill count and each
+            # float fallback lane of a dynamic-scale SUM
+            fallbacks = [s[1] for s in scales if isinstance(s, tuple)]
+            added = jax.lax.psum(
+                ([o for o, k in zip(outs, kinds) if k == "psum"],
+                 counts, spilled, fallbacks), axes)
+            sums, fbs = iter(added[0]), iter(added[3])
             combined = []
-            for a, o in zip(aggs, outs):
-                kind = _COMBINE["count" if a.expr is None else a.op]
-                for ax in axes:
-                    if kind == "psum":
-                        o = jax.lax.psum(o, ax)
-                    elif kind == "pmin":
-                        o = jax.lax.pmin(o, ax)
-                    else:
-                        o = jax.lax.pmax(o, ax)
-                combined.append(o)
-            for ax in axes:
-                counts = jax.lax.psum(counts, ax)
-            # scales are identical on every shard (pmax'd vmax) and pass
-            # through replicated; each float-sum fallback lane is a
-            # per-shard partial that psums like the int64 lane
-            cscales = []
-            for s in scales:
-                if isinstance(s, tuple):
-                    fb = s[1]
-                    for ax in axes:
-                        fb = jax.lax.psum(fb, ax)
-                    cscales.append((s[0], fb))
+            for o, k in zip(outs, kinds):
+                if k == "psum":
+                    combined.append(next(sums))
+                elif k == "pmin":
+                    combined.append(jax.lax.pmin(o, axes))
                 else:
-                    cscales.append(s)
-            return tuple(combined), tuple(cscales), counts
+                    combined.append(jax.lax.pmax(o, axes))
+            # scales are identical on every shard (pmax'd vmax) and pass
+            # through replicated
+            cscales = [(s[0], next(fbs)) if isinstance(s, tuple) else s
+                       for s in scales]
+            return tuple(combined), tuple(cscales), added[1], added[2]
 
         spec3 = P(TABLETS_AXIS, BLOCKS_AXIS, None)
         in_specs = (
             {k: spec3 for k in sig_cols(sig)}, {k: spec3 for k in sig_cols(sig)},
-            P(), spec3, spec3, P(), P())
+            P(), spec3, spec3, P(), P(), P())
         smapped = jax.shard_map(
             shard_fn, mesh=tm.mesh, in_specs=in_specs,
             out_specs=(tuple(P() for _ in aggs), tuple(P() for _ in aggs),
-                       P()), check_vma=False)
-        fn = jax.jit(smapped)
+                       P(), P()), check_vma=False)
+
+        def mesh_scan(cols, nulls, consts, valid, lanes, read_ht,
+                      sum_scales, domains=()):
+            return smapped(cols, nulls, consts, valid, lanes, read_ht,
+                           sum_scales, domains)
+        # a stable program name, as `ScanKernel._get` gives the
+        # single-device program: jit_mesh_scan_linked_resolveddictgroup
+        mesh_scan.__name__ = mesh_scan.__qualname__ = "_".join(
+            ["mesh_scan", mvcc_mode] + ([type(group).__name__.lower()]
+                                        if group is not None else []))
+        fn = jax.jit(mesh_scan)
         self._cache[sig] = fn
         self.compiles += 1
         return fn
@@ -227,6 +319,10 @@ class DistributedScanKernel:
             aggs: Sequence[AggSpec] = (),
             group: Optional[GroupSpec] = None,
             read_ht: Optional[int] = None):
+        """(agg results, count or group counts), every one already
+        combined over the shards on the device; a DictGroupSpec adds the
+        spill count (nonzero = slot overflow: the caller must fall
+        back)."""
         aggs = tuple(_expand_avg(aggs))
         mvcc_mode, lanes = mvcc_lanes(batch, read_ht)
         consts: List = []
@@ -235,28 +331,61 @@ class DistributedScanKernel:
         for a in aggs:
             if a.expr is not None:
                 collect_constants(a.expr, consts)
+        dict_group = isinstance(group, DictGroupSpec)
+        domains: tuple = ()
+        if dict_group:
+            # as ScanKernel.run: the pow2 slot bucket is static, the
+            # dictionary sizes are runtime scalars.  The dictionaries
+            # are the batch's, global over the shards
+            group, sizes = resolve_group(group, batch.dicts)
+            domains = tuple(np.int32(d) for d in sizes)
         col_sig = tuple(sorted(
             (cid, str(v.dtype)) for cid, v in batch.cols.items()))
         tm = batch.mesh
+        # runtime scalars go in as host values: the launch replicates
+        # them over the mesh itself, no program of its own for each
         static_sums, scale_args = _static_scales(
             aggs, batch.col_bounds,
-            batch.padded_rows * batch.num_shards, batch.cols)
+            batch.padded_rows * batch.num_shards, batch.cols, host=True)
         strategy = _group_strategy()
         sig = (
             id(tm.mesh), expr_signature(where) if where is not None else None,
             tuple(a.signature() for a in aggs),
-            group.cols if group else None, mvcc_mode,
+            (type(group).__name__, group.cols,
+             getattr(group, "num_slots", None)) if group else None,
+            mvcc_mode,
             batch.padded_rows, col_sig, static_sums, strategy,
         )
+        pre = self.compiles
         fn = self._get(sig, tm, where, aggs, group, mvcc_mode,
                        static_sums, strategy)
-        outs, scales, counts = fn(
-            batch.cols, batch.nulls,
-            [jnp.asarray(c) for c in consts], batch.valid, lanes,
-            jnp.uint64(read_ht if read_ht is not None
-                       else 0xFFFFFFFFFFFFFFFF),
-            scale_args)
-        return _rescale_outs(outs, scales), counts
+        with _trace.device_span("scan", signature=sig,
+                                compiled=self.compiles > pre,
+                                bucket=batch.padded_rows, rows=batch.n_rows,
+                                mvcc=mvcc_mode) as sp:
+            if sp is not None:
+                sp.set_tag("chips", tm.mesh.devices.size)
+                sp.set_tag("shards", batch.num_shards)
+            raw = fn(
+                batch.cols, batch.nulls,
+                [np.asarray(c) for c in consts], batch.valid, lanes,
+                np.uint64(read_ht if read_ht is not None
+                          else 0xFFFFFFFFFFFFFFFF),
+                scale_args, domains)
+        # one read-back of the whole (replicated) result; the rescale
+        # then works on host values.  `device.wait` is the host's wait
+        # for the mesh program, as on the single-device path
+        with _trace.wait_status("Device_BlockUntilReady",
+                                component="device"), \
+                _trace.TRACES.span("device.wait", child_only=True) as sp:
+            if sp.sampled:
+                sp.set_tag("thread", _thread_kind())
+                sp.set_tag("chips", tm.mesh.devices.size)
+            outs, scales, counts, spilled = jax.device_get(raw)
+            outs = _rescale_outs(outs, scales)
+        if dict_group:
+            return outs, counts, int(spilled)
+        return outs, counts
 
 
 def sig_cols(sig) -> Tuple[int, ...]:

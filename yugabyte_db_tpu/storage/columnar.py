@@ -593,24 +593,36 @@ class ColumnarBlock:
                 "parts": [ulens.nbytes, uheap.nbytes, codes_n.nbytes]}
 
     @staticmethod
-    def _decode_dict_varlen(vmeta: dict, fetch):
-        """Inverse of _dict_varlen_parts: rebuild the exact (ends, heap)
-        pair and return the raw dict parts for dict_varlen()."""
-        ulens = np.frombuffer(fetch(vmeta["parts"][0]), np.uint8)
+    def _dict_varlen_stored(vmeta: dict, fetch):
+        """The stored parts of a dictionary-coded text lane: the uniques'
+        lengths and bytes, and a code a row."""
+        ulens = np.frombuffer(fetch(vmeta["parts"][0]), np.uint8).copy()
         uheap = bytes(fetch(vmeta["parts"][1]))
         codes = np.frombuffer(fetch(vmeta["parts"][2]),
                               np.dtype(vmeta["cdt"])).astype(np.int32)
+        return ulens, uheap, codes
+
+    @classmethod
+    def _decode_dict_varlen(cls, vmeta: dict, fetch):
+        """Inverse of _dict_varlen_parts: rebuild the exact (ends, heap)
+        pair and return the raw dict parts for dict_varlen()."""
+        ulens, uheap, codes = cls._dict_varlen_stored(vmeta, fetch)
         u_ends = np.cumsum(ulens.astype(np.int64))
         u_starts = u_ends - ulens
         row_lens = ulens[codes].astype(np.int64)
         ends = np.cumsum(row_lens).astype(np.uint32)
-        total = int(row_lens.sum())
-        if total:
+        if int(row_lens.sum()):
+            # the uniques as rows of one zero-padded byte matrix; a row's
+            # payload is its unique's row up to its length, so the heap
+            # is one gather of whole rows and one masked copy (the values
+            # are at most 255 bytes and few: a dictionary lane)
             hb = np.frombuffer(uheap, np.uint8)
-            starts_out = ends.astype(np.int64) - row_lens
-            off = np.arange(total, dtype=np.int64) - \
-                np.repeat(starts_out, row_lens)
-            heap = hb[np.repeat(u_starts[codes], row_lens) + off].tobytes()
+            width = int(ulens.max())
+            col = np.arange(width)
+            inside = col < ulens[:, None]
+            padded = np.zeros((len(ulens), width), np.uint8)
+            padded[inside] = hb[:int(u_ends[-1])]
+            heap = padded[codes][inside[codes]].tobytes()
         else:
             heap = b""
         return ends, heap, (ulens, uheap, codes)
@@ -657,8 +669,8 @@ class ColumnarBlock:
 
     @classmethod
     def deserialize(cls, data, copy: bool = True,
-                    max_version: int = SUPPORTED_FORMAT_VERSION
-                    ) -> "ColumnarBlock":
+                    max_version: int = SUPPORTED_FORMAT_VERSION,
+                    columns=None) -> "ColumnarBlock":
         """Rebuild a block from its serialized form. With copy=False and
         a buffer-backed `data` (e.g. a memoryview over the SST mmap) the
         arrays are zero-copy READ-ONLY views — the compaction pipeline
@@ -668,7 +680,17 @@ class ColumnarBlock:
         lanes stay views.)
 
         Blocks newer than ``max_version`` raise a clear ValueError — the
-        v2-written/v1-reader rejection path — instead of misparsing."""
+        v2-written/v1-reader rejection path — instead of misparsing.
+
+        ``columns``: a set of value-column ids — a PROJECTED block, for a
+        reader that knows what it will touch (a key probe, a scan's
+        column set).  The MVCC lanes, the pk lanes and the keys are
+        always there; a value column outside the set is skipped in the
+        stream and absent from `fixed`/`varlen`; a dictionary-coded text
+        column inside it keeps its stored parts (`dict_varlen`) and its
+        null mask, and its row heap is not rebuilt (`varlen[cid]` is
+        ``(None, None, null)``); document shreds are left out.  Such a
+        block is its caller's own: it never enters a shared cache."""
         hlen = struct.unpack_from("<I", data)[0]
         meta = msgpack.unpackb(data[4:4 + hlen], strict_map_key=False)
         version = meta.get("v", 1)
@@ -684,6 +706,11 @@ class ColumnarBlock:
             raw = data[pos:pos + n]
             pos += n
             return raw
+
+        def skip(ref) -> None:
+            nonlocal pos
+            pos += ref["len"] if ref.get("enc") is None \
+                else sum(ref["parts"])
 
         if version == 1:
             def take(ref) -> np.ndarray:
@@ -715,12 +742,28 @@ class ColumnarBlock:
         for k, ref_ in meta["pk"].items():
             blk.pk[int(k)] = take(ref_)
         for k, (vref, mref) in meta["fixed"].items():
+            if columns is not None and int(k) not in columns:
+                skip(vref)
+                skip(mref)
+                continue
             v = take(vref)
             m = take(mref)
             blk.fixed[int(k)] = (v, m)
         for k, (eref, heapinfo, nref) in meta["varlen"].items():
+            coded = eref.get("venc") == "dict"
+            if columns is not None and int(k) not in columns:
+                pos += heapinfo["len"]
+                if coded:
+                    pos += sum(eref["parts"])
+                else:
+                    skip(eref)
+                skip(nref)
+                continue
             heap = fetch(heapinfo["len"])
-            if eref.get("venc") == "dict":
+            if coded and columns is not None:
+                ends = heap = None
+                blk._vdicts[int(k)] = cls._dict_varlen_stored(eref, fetch)
+            elif coded:
                 ends, heap, parts = cls._decode_dict_varlen(eref, fetch)
                 blk._vdicts[int(k)] = parts
             else:
@@ -728,7 +771,7 @@ class ColumnarBlock:
             null = take(nref)
             blk.varlen[int(k)] = (ends, heap, null)
         if version >= 2:
-            sh = meta.get("shred")
+            sh = meta.get("shred") if columns is None else None
             if sh:
                 from ..docstore import shred as _doc_shred
                 for cid_s, entries in sh.items():

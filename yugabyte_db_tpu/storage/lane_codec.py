@@ -193,6 +193,46 @@ _VARLEN_DICT_SAMPLE = 2048
 _VARLEN_DICT_SAMPLE_MAX = 384
 
 
+_ROW_HASH_MULT = np.uint64(0x9E3779B97F4A7C15)
+
+
+def _unique_rows(mat: np.ndarray):
+    """``(uniq [k, W] uint8, codes int64 [n])`` of the rows of a byte
+    matrix, uniques in byte order — what ``np.unique`` gives over a void
+    view of the rows, without comparing n rows byte by byte in a sort:
+    the rows are hashed a 64-bit word at a time, the hashes sorted as
+    numbers, every row checked against the first of its hash (a
+    collision falls back to the void sort), and only the k uniques are
+    put in byte order."""
+    n, W = mat.shape
+    v = np.dtype((np.void, W))
+    if n == 0 or W == 0:
+        uniq, codes = np.unique(np.ascontiguousarray(mat).view(v)
+                                .reshape(-1), return_inverse=True)
+        return uniq.view(np.uint8).reshape(len(uniq), W), codes
+    W8 = -(-W // 8)
+    wide = mat
+    if W8 * 8 != W or not mat.flags["C_CONTIGUOUS"]:
+        wide = np.zeros((n, W8 * 8), np.uint8)
+        wide[:, :W] = mat
+    words = wide.view(np.uint64)
+    h = words[:, 0].copy()
+    for j in range(1, W8):
+        h *= _ROW_HASH_MULT
+        h ^= words[:, j]
+    _, first, inv = np.unique(h, return_index=True, return_inverse=True)
+    firsts = words[first]
+    if W8 > 1 and not (firsts[inv] == words).all():
+        uniq, codes = np.unique(np.ascontiguousarray(mat).view(v)
+                                .reshape(-1), return_inverse=True)
+        return uniq.view(np.uint8).reshape(len(uniq), W), codes
+    urows = np.ascontiguousarray(mat[first])
+    order = np.argsort(urows.view(v).reshape(-1), kind="stable")
+    rank = np.empty(len(order), np.int64)
+    rank[order] = np.arange(len(order))
+    return urows[order], rank[inv]
+
+
 def varlen_code_rows(ends: np.ndarray, heap,
                      null: Optional[np.ndarray] = None,
                      max_len: int = _VARLEN_DICT_MAX_LEN,
@@ -222,34 +262,43 @@ def varlen_code_rows(ends: np.ndarray, heap,
     w = int(lens.max()) if n else 0
     if w > max_len:
         return None
-    # padded [n, w+1] matrix: row bytes then the length byte — the
-    # length column disambiguates trailing-NUL payloads and preserves
-    # shorter-is-smaller ordering
-    mat = np.zeros((n, w + 1), np.uint8)
-    if w:
-        idx = starts[:, None] + np.arange(w)[None, :]
-        inb = np.arange(w)[None, :] < lens[:, None]
-        np.clip(idx, 0, max(len(hb) - 1, 0), out=idx)
-        mat[:, :w] = np.where(inb, hb[idx] if len(hb) else 0, 0)
-    mat[:, w] = lens.astype(np.uint8)
-    v = np.dtype((np.void, w + 1))
-    rows = np.ascontiguousarray(mat).view(v).reshape(-1)
+    packed = int(lens.sum()) == int(ends64[-1])
+
+    def padded(lo: int, hi: int) -> np.ndarray:
+        """Rows [lo, hi) as a padded [m, w+1] matrix: row bytes then the
+        length byte — the length column disambiguates trailing-NUL
+        payloads and preserves shorter-is-smaller ordering."""
+        mat = np.zeros((hi - lo, w + 1), np.uint8)
+        ln = lens[lo:hi]
+        if w and packed:
+            # the heap is the rows' bytes end to end (no payload under a
+            # NULL): one masked assignment in row order
+            inb = np.arange(w)[None, :] < ln[:, None]
+            mat[:, :w][inb] = hb[int(starts[lo]):int(ends64[hi - 1])]
+        elif w:
+            idx = starts[lo:hi, None] + np.arange(w)[None, :]
+            inb = np.arange(w)[None, :] < ln[:, None]
+            np.clip(idx, 0, max(len(hb) - 1, 0), out=idx)
+            mat[:, :w] = np.where(inb, hb[idx] if len(hb) else 0, 0)
+        mat[:, w] = ln.astype(np.uint8)
+        return mat
+
     # the prefix sample cheaply skips near-unique lanes where a dict is
-    # a write-time LOSS; scan-time dictionary formation (dict_varlen for
+    # a write-time LOSS (before the whole lane is padded out);
+    # scan-time dictionary formation (dict_varlen for
     # the grouped kernel) passes sample_guard=False — there the dict is
     # REQUIRED up to max_card, the full unique runs once per block and
     # memoizes, and a 4096-group GROUP BY must not be capped by a
     # 384-distinct write heuristic
     if sample_guard and max_card is not None and n > _VARLEN_DICT_SAMPLE:
-        if len(np.unique(rows[:_VARLEN_DICT_SAMPLE])) > \
+        if len(_unique_rows(padded(0, _VARLEN_DICT_SAMPLE))[0]) > \
                 _VARLEN_DICT_SAMPLE_MAX:
             return None
-    uniq, codes = np.unique(rows, return_inverse=True)
-    if max_card is not None and len(uniq) > max_card:
+    umat, codes = _unique_rows(padded(0, n))
+    if max_card is not None and len(umat) > max_card:
         return None
-    umat = uniq.view(np.uint8).reshape(len(uniq), w + 1)
     ulens = umat[:, w]
-    parts = [umat[i, :ulens[i]] for i in range(len(uniq))]
+    parts = [umat[i, :ulens[i]] for i in range(len(umat))]
     uniq_heap = (np.concatenate(parts) if parts
                  else np.zeros(0, np.uint8))
     return (ulens.astype(np.uint8), np.ascontiguousarray(uniq_heap),

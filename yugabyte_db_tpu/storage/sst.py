@@ -283,7 +283,15 @@ class SstWriter:
             self._col_only.append(None)
             self._entries = []
 
-    def add_columnar_block(self, cb: ColumnarBlock) -> None:
+    def serialize_block(self, cb: ColumnarBlock):
+        """`cb` as `add_columnar_block` would write it, for a caller that
+        serializes blocks on threads of its own and hands each result to
+        `add_columnar_block(cb, parts)` in block order.  Keeps no lane
+        accounting (`lane_stats`)."""
+        return cb.serialize_parts(self._fmt, self.key_builder, None,
+                                  self.shred_cols)
+
+    def add_columnar_block(self, cb: ColumnarBlock, parts=None) -> None:
         """Bulk-load fast path: a sorted, keyed ColumnarBlock becomes a
         columnar-ONLY block — no row region is materialized; readers
         reconstruct KV entries on demand via their row_decoder.
@@ -321,9 +329,9 @@ class SstWriter:
             e = BlockIndexEntry(
                 first_key=first, last_key=last, offset=0, length=0,
                 num_rows=cb.n, col_offset=self._sf.tell(), col_length=0)
-            head, bufs = cb.serialize_parts(self._fmt, self.key_builder,
-                                            self.lane_stats,
-                                            self.shred_cols)
+            head, bufs = parts or cb.serialize_parts(
+                self._fmt, self.key_builder, self.lane_stats,
+                self.shred_cols)
             e.col_length = len(head)
             self._sf.write(head)
             for b in bufs:
@@ -542,6 +550,7 @@ class SstReader:
         self.index = [BlockIndexEntry(*row) for row in raw_index]
         self._first_keys = [e.first_key for e in self.index]
         self._col_cache: dict = {}
+        self._key_cache: dict = {}   # block idx -> keys-only projection
         self._row_cache: dict = {}   # block idx -> decoded entries
         self._point_readers: dict = {}   # codec -> native PointReader|None
 
@@ -647,8 +656,20 @@ class SstReader:
         cache[codec] = pr
         return pr
 
+    def _key_block(self, i: int) -> Optional[ColumnarBlock]:
+        """Block `i` for a probe that reads keys and MVCC lanes only: the
+        decoded block if it is at hand, else its projection to no value
+        column, kept in a small cache of its own."""
+        cb = self._col_cache.get(i) or self._key_cache.get(i)
+        if cb is None:
+            cb = self.projected_block(i, ())
+            if cb is not None:
+                self._cache_put(self._key_cache, i, cb, 64)
+        return cb
+
     def point_find(self, prefix: bytes, read_ht: int,
-                   restart_hi: Optional[int] = None):
+                   restart_hi: Optional[int] = None,
+                   keys_only: bool = False):
         """Newest VISIBLE version of the doc key `prefix` in this SST —
         the fused point-read hot path (reference analog:
         BlockBasedTable::Get + DocDB visibility). Returns one of:
@@ -659,7 +680,10 @@ class SstReader:
             window (read_ht, restart_hi] exists: caller restarts
           None — no visible version here
         Reads MVCC metadata straight from the columnar ht/write_id
-        arrays instead of decoding the key's DocHybridTime suffix."""
+        arrays instead of decoding the key's DocHybridTime suffix.
+        `keys_only`: the caller reads no value column of a columnar hit
+        (its `tombstone[pos]` at most), so a block is decoded without
+        them (`_key_block`)."""
         import bisect
         bi = max(bisect.bisect_right(self._first_keys, prefix) - 1, 0)
         plen = len(prefix)
@@ -669,8 +693,15 @@ class SstReader:
                 return None
             if e.last_key < prefix:
                 continue
-            cb = (self.columnar_block(i)
-                  if self.row_decoder is not None else None)
+            cb = None
+            if self.row_decoder is not None:
+                cb = (self._key_block(i) if keys_only
+                      else self.columnar_block(i))
+            if keys_only and cb is not None and cb._keys is None and not (
+                    cb.key_hash == np.uint64(fnv64_bytes(prefix))).any():
+                # no row here has the key's hash (a bloom filter's false
+                # positive, mostly): told without the block's key matrix
+                return None
             if cb is not None and cb.keys is None:
                 cb = None
             if cb is not None:
@@ -737,6 +768,22 @@ class SstReader:
             self._data[e.col_offset:e.col_offset + e.col_length])
         cb.bind_key_builder(self.key_builder)
         return self._cache_put(self._col_cache, i, cb, 32)
+
+    def projected_block(self, i: int, columns) -> Optional[ColumnarBlock]:
+        """Block `i` with the MVCC, pk and key lanes and only the value
+        columns in `columns` (`ColumnarBlock.deserialize`): what a key
+        probe or a scan of a known column set reads, at the cost of those
+        lanes alone — the other columns' pages are not touched.  Owned
+        arrays, never cached here: the caller keeps what it needs."""
+        e = self.index[i]
+        if e.col_offset < 0:
+            return None
+        cb = ColumnarBlock.deserialize(
+            memoryview(self._data)[e.col_offset:e.col_offset
+                                   + e.col_length],
+            columns=frozenset(columns))
+        cb.bind_key_builder(self.key_builder)
+        return cb
 
     def read_columnar(self, i: int) -> Optional[ColumnarBlock]:
         """Streaming (uncached) columnar-block read for the compaction

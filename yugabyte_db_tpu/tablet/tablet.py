@@ -10,6 +10,7 @@ directly.
 """
 from __future__ import annotations
 
+import collections
 import logging
 import os
 import threading
@@ -46,6 +47,10 @@ _DEVICE_CACHE = DeviceBlockCache()
 # to a stalled disk must not park every other tablet's flush behind it.
 _FLUSH_POOL = ThreadPoolExecutor(max_workers=2,
                                  thread_name_prefix="bg-flush")
+
+#: blocks a bulk load makes and serializes at a time, ahead of the file
+#: write
+_BULK_WORKERS = max(2, min(4, (os.cpu_count() or 2) - 1))
 
 #: stage split of the most recent bulk_load (read by profile_ycsb.py
 #: --json; informational only)
@@ -287,6 +292,33 @@ class Tablet:
         self._m_read_lat.increment((_perf_counter() - t0) * 1e6)
         return resp
 
+    def key_is_live(self, table_id: str, pk_row: dict) -> bool:
+        """Whether a live row sits at the key now: what `read` of a
+        `pk_eq` request tells by its rows, without making the row
+        (`DocReadOperation.key_is_live`)."""
+        op = self._read_ops.get(table_id, self._read_op)
+        read_ht = self.clock.now().value
+        for _attempt in range(3):
+            try:
+                live = op.key_is_live(pk_row, read_ht, allow_restart=True)
+                break
+            except ReadRestartError as e:
+                read_ht = e.restart_ht
+        else:
+            live = op.key_is_live(pk_row, read_ht)
+        self._m_reads.increment()
+        return live
+
+    def read_op(self, table_id: str) -> DocReadOperation:
+        """The read operation over this tablet's store for `table_id`:
+        what a read that spans several tablets (docdb/mesh_read.py)
+        takes in place of `read`."""
+        return self._read_ops.get(table_id, self._read_op)
+
+    def note_read(self) -> None:
+        """Count a read served over this tablet from outside `read`."""
+        self._m_reads.increment()
+
     def multi_read(self, table_id: str, pk_rows, read_ht=None,
                    allow_restart=None):
         """Batched point reads: the engine seam where concurrent
@@ -432,32 +464,47 @@ class Tablet:
         partition are dropped, so the same arrays can be fed to every
         tablet of a table).
 
-        Streams through the shared stage pipeline: the codec's fused
-        per-block gather (GIL-released native call) runs on the feeder
-        thread while the previous block's serialize+write (also
-        GIL-released) runs on the writer stage — gather and IO overlap
-        instead of running as two serial phases."""
-        import itertools
+        After the codec's global phase (partition filter, key encode,
+        sort order) every block is its own job: `_BULK_WORKERS` threads
+        make (fused native gather) and serialize blocks ahead, and the
+        shared stage pipeline writes them to the file in block order —
+        gather, encode and IO overlap."""
         import time as _time
         from ..storage.pipeline import StreamPipeline
         ht = ht or self.clock.now()
         t0 = _time.perf_counter()
-        blocks = self.codec.bulk_blocks_iter(
+        makers = self.codec.bulk_block_makers(
             columns, ht, block_rows=block_rows, partition=self.partition)
-        try:
-            first = next(blocks)
-        except StopIteration:
+        if not makers:
             return 0        # everything partition-filtered: no SST
         n = 0
         stats: dict = {}
 
+        def serialized(w, pool):
+            """(block, its serialized parts), in block order, a few
+            blocks made and serialized ahead on the pool's threads
+            (numpy's gathers, sorts and copies release the GIL)."""
+            def job(make):
+                blk = make()
+                return blk, w.serialize_block(blk)
+            ahead = collections.deque()
+            for make in makers:
+                ahead.append(pool.submit(job, make))
+                if len(ahead) > _BULK_WORKERS:
+                    yield ahead.popleft().result()
+            for got in ahead:
+                yield got.result()
+
         def build(w):
             nonlocal n
             pipe = StreamPipeline(
-                [lambda blk: (w.add_columnar_block(blk), blk.n)[1]],
+                [lambda got: (w.add_columnar_block(*got), got[0].n)[1]],
                 depth=2, name="bulk-load")
-            for bn in pipe.run(itertools.chain([first], blocks)):
-                n += bn
+            with ThreadPoolExecutor(
+                    max_workers=_BULK_WORKERS,
+                    thread_name_prefix="bulk-block") as pool:
+                for bn in pipe.run(serialized(w, pool)):
+                    n += bn
             stats.update(pipe.stats(),
                          write_stage_s=round(pipe.stage_s[0], 4))
         self.regular.ingest_sst(build, stream=True)
@@ -466,8 +513,9 @@ class Tablet:
         LAST_BULK_LOAD_STATS.update({
             "rows": n, "blocks": stats.get("items"),
             "wall_s": round(_time.perf_counter() - t0, 4),
-            # feeder thread = global encode/sort + fused per-block
-            # gathers; write stage = serialize + GIL-released file write
+            # calling thread = global encode/sort; the pool's threads
+            # make and serialize blocks ahead; write stage =
+            # GIL-released file write
             "write_stage_s": stats.get("write_stage_s"),
             "gather_wait_s": stats.get("consumer_wait_s")})
         return n

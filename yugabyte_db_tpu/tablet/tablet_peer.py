@@ -208,7 +208,6 @@ class TabletPeer:
         both pass (reference: unique-index conflict through docdb
         intents, yb_access/yb_lsm.c:233-366).  Returns the reserved
         keys (caller releases after the write resolves)."""
-        from ..docdb.operations import ReadRequest
         codec = self.tablet._codec_for(req.table_id)
         reserved = []
         try:
@@ -223,8 +222,7 @@ class TabletPeer:
                         "constraint", "DUPLICATE_KEY")
                 pk_row = {c.name: op.row[c.name]
                           for c in codec.info.schema.key_columns}
-                rr = ReadRequest(req.table_id, pk_eq=pk_row)
-                if self.tablet.read(rr).rows:
+                if self.tablet.key_is_live(req.table_id, pk_row):
                     raise RpcError(
                         "duplicate key value violates unique "
                         "constraint", "DUPLICATE_KEY")
@@ -436,23 +434,35 @@ class TabletPeer:
         (consistent-prefix) reads serve from any replica at its applied
         state — the clock is ratcheted by leader heartbeats, so the
         prefix is consistent though possibly stale."""
+        if req.consistency == "follower":
+            if self.split_done:
+                raise RpcError("tablet has been split", "TABLET_SPLIT")
+            return self.tablet.read(req)
+        self.check_strong_read()
+        if req.read_ht is None:
+            req.read_ht = self.clock.now().value
+            req.server_assigned_read_ht = True
+        await self.wait_safe_time(req.read_ht)
+        return self.tablet.read(req)
+
+    def check_strong_read(self) -> None:
+        """The gates of a strong read: not split away, leader, lease."""
         if self.split_done:
             raise RpcError("tablet has been split", "TABLET_SPLIT")
-        if req.consistency == "follower":
-            return self.tablet.read(req)
         if not self.consensus.is_leader():
             raise RpcError(
                 f"not leader (hint={self.consensus.leader_hint()})",
                 "LEADER_NOT_READY")
         if not self.consensus.has_leader_lease():
             raise RpcError("leader lease expired", "LEADER_HAS_NO_LEASE")
-        if req.read_ht is None:
-            req.read_ht = self.clock.now().value
-            req.server_assigned_read_ht = True
+
+    async def wait_safe_time(self, read_ht: int) -> None:
+        """Wait until the MVCC safe time passes `read_ht`: every write
+        below it has reached the store."""
         import time as _time
         deadline = _time.monotonic() + 10.0
         with wait_status("SafeTime_Wait", component="mvcc"):
-            while self.safe_read_ht(self.clock.now().value) < req.read_ht:
+            while self.safe_read_ht(self.clock.now().value) < read_ht:
                 if _time.monotonic() > deadline:
                     raise RpcError("in-flight writes below the read time "
                                    "did not drain", "TIMED_OUT")
@@ -463,7 +473,6 @@ class TabletPeer:
                     await asyncio.wait_for(ev.wait(), 0.05)
                 except asyncio.TimeoutError:
                     pass
-        return self.tablet.read(req)
 
     async def read_points(self, table_id: str, pk_rows: list) -> list:
         """Batched same-tablet strong point gets (the scheduler's
@@ -475,27 +484,9 @@ class TabletPeer:
         one pass (same per-key result as read() with pk_eq; parity
         pinned by tests/test_scheduler.py).  Returns a row-or-None per
         pk_row."""
-        if self.split_done:
-            raise RpcError("tablet has been split", "TABLET_SPLIT")
-        if not self.consensus.is_leader():
-            raise RpcError(
-                f"not leader (hint={self.consensus.leader_hint()})",
-                "LEADER_NOT_READY")
-        if not self.consensus.has_leader_lease():
-            raise RpcError("leader lease expired", "LEADER_HAS_NO_LEASE")
+        self.check_strong_read()
         read_ht = self.clock.now().value
-        import time as _time
-        deadline = _time.monotonic() + 10.0
-        with wait_status("SafeTime_Wait", component="mvcc"):
-            while self.safe_read_ht(self.clock.now().value) < read_ht:
-                if _time.monotonic() > deadline:
-                    raise RpcError("in-flight writes below the read time "
-                                   "did not drain", "TIMED_OUT")
-                ev = self._progress_event
-                try:
-                    await asyncio.wait_for(ev.wait(), 0.05)
-                except asyncio.TimeoutError:
-                    pass
+        await self.wait_safe_time(read_ht)
         # read EXACTLY at the waited-out read point (a fresh clock.now
         # inside multi_read could run ahead of a write queued during
         # the wait — a write below the read point the wait never

@@ -16,6 +16,7 @@ import os
 from typing import Dict, List, Optional, Tuple
 
 from ..consensus import PeerSpec, RaftConfig
+from ..docdb.mesh_read import MeshIneligible, tablets_in_partition_order
 from ..docdb.table_codec import TableInfo
 from ..docdb.wire import (
     read_request_from_wire, read_response_to_wire, write_request_from_wire,
@@ -176,6 +177,31 @@ class TabletServer:
         # on it, this one included)
         self._m_tick_late = metrics.REGISTRY.entity(
             "server", f"ts-{uuid}").histogram("heartbeat_tick_late_ms")
+        # the chips this server owns (`tserver_device_chips`): with more
+        # than one, a table's tablets are placed on them and an
+        # aggregate read of all of them is one mesh launch
+        # (docdb/mesh_read.py); with one, nothing of that exists
+        self.mesh_reader = None
+        self._cache_capacity_before = None
+        chips = int(flags.get("tserver_device_chips"))
+        if chips > 1:
+            self._own_chips(chips)
+
+    def _own_chips(self, chips: int) -> None:
+        import jax
+        from ..docdb.mesh_read import MeshReader
+        from ..ops.device_batch import chip_capacity
+        from ..tablet.tablet import _DEVICE_CACHE
+        devices = jax.devices()
+        if len(devices) < chips:
+            raise RuntimeError(
+                f"tserver_device_chips={chips}, JAX has {len(devices)}")
+        self.mesh_reader = MeshReader(devices[:chips], _DEVICE_CACHE,
+                                      owner=f"ts-{self.uuid}")
+        # the cache's capacity is a chip's, from the chip's own memory
+        self._cache_capacity_before = _DEVICE_CACHE.capacity
+        _DEVICE_CACHE.capacity = chip_capacity(
+            devices[:chips], default=_DEVICE_CACHE.capacity)
 
     # --- lifecycle --------------------------------------------------------
     async def start(self, host: str = "127.0.0.1", port: int = 0):
@@ -203,6 +229,14 @@ class TabletServer:
             ASH.unregister(p)
         self._ash_providers = []
         await self.scheduler.shutdown()
+        if self._cache_capacity_before is not None:
+            # the chips go back as they were taken: this server's
+            # batches leave them, and the cache its capacity
+            from ..tablet.tablet import _DEVICE_CACHE
+            for p in self.peers.values():
+                _DEVICE_CACHE.invalidate_prefix((id(p.tablet.regular),))
+            _DEVICE_CACHE.capacity = self._cache_capacity_before
+            self._cache_capacity_before = None
         if graceful:
             # lease release first: a pinned compaction-victim SST is
             # physically unlinked on the last release, which must
@@ -485,16 +519,19 @@ class TabletServer:
                 return await self.scheduler.submit(
                     Lane.POINT_WRITE, run, cost_bytes=cost)
 
+    async def _serve_read(self, peer, tablet_id: str, req_wire) -> dict:
+        req = read_request_from_wire(req_wire)
+        with TRACES.span(f"tserver.read:{tablet_id}", child_only=True):
+            with wait_status("OnCpu_Read", component="tserver"):
+                resp = await peer.read(req)
+        return read_response_to_wire(resp)
+
     async def rpc_read(self, payload) -> dict:
         peer = self._peer(payload["tablet_id"])
 
         async def run():
-            req = read_request_from_wire(payload["req"])
-            with TRACES.span(f"tserver.read:{payload['tablet_id']}",
-                             child_only=True):
-                with wait_status("OnCpu_Read", component="tserver"):
-                    resp = await peer.read(req)
-            return read_response_to_wire(resp)
+            return await self._serve_read(peer, payload["tablet_id"],
+                                          payload["req"])
         if not self.scheduler.enabled():
             return await run()
         lane = classify_read(payload["req"])
@@ -531,6 +568,69 @@ class TabletServer:
         sig = (payload["tablet_id"], canon(payload["req"]))
         return await self.scheduler.submit_grouped(
             Lane.SCAN, sig, ScanItem(run), cost_bytes=4096)
+
+    async def rpc_read_tablets(self, payload) -> dict:
+        """One aggregate read over several tablets of a table that this
+        server leads, as the client sends it to a server that owns
+        several chips.  `{"mesh": response}` is the answer over all of
+        them, combined on the chips by one launch at one read time;
+        `{"parts": [response, ...]}` are the tablets' own answers by the
+        one-device path, in the order asked, where the mesh does not
+        take the read — the client combines those as it combines
+        per-tablet RPCs.  Each tablet is in exactly one of the two."""
+        tablet_ids = list(payload["tablet_ids"])
+        peers = [self._peer(t) for t in tablet_ids]
+
+        async def run():
+            if self.mesh_reader is not None:
+                try:
+                    return {"mesh": read_response_to_wire(
+                        await self._mesh_read(peers, payload["req"]))}
+                except MeshIneligible:
+                    pass
+            return {"parts": [await self._serve_read(p, t, payload["req"])
+                              for p, t in zip(peers, tablet_ids)]}
+        if not self.scheduler.enabled():
+            return await run()
+        sig = (tuple(tablet_ids), canon(payload["req"]))
+        return await self.scheduler.submit_grouped(
+            Lane.SCAN, sig, ScanItem(run), cost_bytes=4096)
+
+    async def _mesh_read(self, peers: list, req_wire):
+        """The tablets' reads gathered into one launch: every tablet's
+        gates, ONE read time for all of them, every tablet's safe-time
+        wait (`tserver.mesh_gather`), then the mesh scan.  Restarts as
+        `DocReadOperation.execute`: three bumps of a server-assigned
+        read time, then a read that does not restart."""
+        from ..docdb.operations import ReadRestartError
+        reader = self.mesh_reader
+        with TRACES.span("tserver.read_tablets", child_only=True), \
+                wait_status("OnCpu_Read", component="tserver"):
+            with TRACES.span("tserver.mesh_gather", child_only=True,
+                             tags={"tablets": len(peers),
+                                   "chips": reader.chips,
+                                   "fanin": len(peers)}):
+                req = read_request_from_wire(req_wire)
+                if req.consistency == "follower":
+                    raise MeshIneligible("follower_read")
+                peers = tablets_in_partition_order(peers)
+                for p in peers:
+                    p.check_strong_read()
+                if req.read_ht is None:
+                    req.read_ht = self.clock.now().value
+                    req.server_assigned_read_ht = True
+                for p in peers:
+                    await p.wait_safe_time(req.read_ht)
+                ops = [p.tablet.read_op(req.table_id) for p in peers]
+            for attempt in range(4):
+                try:
+                    resp = reader.read(req, ops, allow_restart=attempt < 3)
+                    break
+                except ReadRestartError as e:
+                    req.read_ht = e.restart_ht
+            for p in peers:
+                p.tablet.note_read()
+            return resp
 
     async def rpc_alter_table(self, payload) -> dict:
         peer = self._peer(payload["tablet_id"])
@@ -1574,6 +1674,8 @@ class TabletServer:
             "ts_uuid": self.uuid,
             "addr": list(self.messenger.addr),
             "zone": self.zone,
+            **({"device_chips": self.mesh_reader.chips}
+               if self.mesh_reader is not None else {}),
             "tablets": [
                 {"tablet_id": tid, "is_leader": p.is_leader(),
                  "size_bytes": p.tablet.approximate_size(),

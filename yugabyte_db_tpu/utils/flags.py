@@ -180,6 +180,15 @@ DEFINE_RUNTIME("device_float_dtype", "auto",
                "exact via the scan kernel's int64 fixed-point "
                "accumulation); 'float32'/'float64' force one (tests use "
                "float32 to exercise the TPU-representative path on CPU).")
+DEFINE_RUNTIME("tserver_device_chips", 1,
+               "Chips a tablet server owns: it takes the first N of "
+               "jax.devices() when it starts.  1 serves every tablet read "
+               "on the default device, one launch a tablet.  With N > 1 "
+               "the tablets of a table are placed on the chips in "
+               "partition order (tablet i of n on chip i*N//n), their "
+               "lanes are cached as one batch sharded over the chips, and "
+               "an aggregate read of all of them is one shard_map launch "
+               "combined by lax.psum (docdb/mesh_read.py).")
 DEFINE_RUNTIME("scan_group_strategy", "auto",
                "Grouped-aggregate reduction strategy: 'segment' "
                "(scatter-add segment_sum — fastest on CPU backends), "
