@@ -28,7 +28,7 @@ from ..ops.scan import AggSpec, GroupSpec, HashGroupSpec, ScanKernel
 from ..ops.stream_scan import chunk_safe_mvcc
 from ..storage.columnar import ColumnarBlock, fnv64_bytes
 from ..storage.lsm import LsmStore, WriteBatch
-from ..utils import flags
+from ..utils import flags, metrics
 from ..utils import trace as _trace
 from ..utils.hybrid_time import ENCODED_SIZE, DocHybridTime, HybridTime
 from .hotpath import load as _hot_mod
@@ -896,18 +896,43 @@ class ReadRestartError(Exception):
         self.restart_ht = restart_ht
 
 
+@dataclass
+class StoreFacts:
+    """What a device read has to know about a store's blocks beyond its
+    cached batch, made in one pass over them and kept with the store
+    (`LsmStore.read_facts`) for as long as its contents stand: `key` is
+    the SST set and the write generation, as `_batch_cache_key` names
+    them, so a write, a flush or a compaction makes the next read take
+    the facts anew and a stale one cannot be reached."""
+    key: tuple
+    #: the newest write time over every collected block (SSTs and the
+    #: memtable overlay): a read at or above it has an empty
+    #: uncertainty window
+    max_ht: int
+    #: `chunk_safe_mvcc` over the whole block list: one version a key,
+    #: every key inside one block
+    chunk_safe: bool
+    #: table id -> the streaming route's kept dictionary plans, by
+    #: dictionary-column set (`ops.stream_scan._kept_plan`)
+    plans: Dict[str, dict] = field(default_factory=dict)
+
+
 class DocReadOperation:
     """Executes a ReadRequest against one tablet's stores."""
 
     def __init__(self, codec: TableCodec, store: LsmStore,
                  scan_kernel: Optional[ScanKernel] = None,
-                 device_cache=None):
+                 device_cache=None, owner: str = ""):
         self.codec = codec
         self.store = store
         self.kernel = scan_kernel or _SHARED_KERNEL
         self.device_cache = device_cache
         # restarts engage only via execute() on server-assigned read points
         self._allow_restart = False
+        # `/metrics` of the server that owns the tablet (`owner`)
+        ent = metrics.REGISTRY.entity("server", owner or "docdb")
+        self._m_facts_hits = ent.counter("store_facts_hits")
+        self._m_facts_misses = ent.counter("store_facts_misses")
 
     # ---- point lookup ----------------------------------------------------
     def _mem_best(self, prefix: bytes, read_ht: int, restart_hi, mems):
@@ -1484,6 +1509,34 @@ class DocReadOperation:
             sp.set_tag("blocks", len(blocks))
             return blocks
 
+    def _collect_with_facts(self):
+        """``(blocks, facts)`` for a device read: the store's whole block
+        list and the :class:`StoreFacts` of its contents — looked up
+        where nothing was written since they were made (`facts` = `hit`
+        on the `docdb.read` span), made in one pass over the blocks
+        otherwise.  ``(None, None)`` without blocks."""
+        store = self.store
+        # named BEFORE the blocks are collected: a write that lands in
+        # between leaves facts of newer contents under the older name,
+        # which no later read asks for — never older facts under a
+        # newer name
+        key = (tuple(r.path for r in store.ssts), store.write_generation())
+        blocks = self._collect_blocks()
+        if not blocks:
+            return None, None
+        facts = store.read_facts
+        hit = facts is not None and facts.key == key
+        if not hit:
+            facts = store.read_facts = StoreFacts(
+                key, max((int(b.ht.max()) for b in blocks if b.n),
+                         default=0),
+                chunk_safe_mvcc(blocks))
+        (self._m_facts_hits if hit else self._m_facts_misses).increment()
+        sp = _trace.current_span()
+        if sp.sampled:
+            sp.set_tag("facts", "hit" if hit else "miss")
+        return blocks, facts
+
     # --- string predicates on device (dictionary rewrite) -----------------
     class _Unrewritable(Exception):
         pass
@@ -1640,27 +1693,25 @@ class DocReadOperation:
                 self.store.write_generation(),
                 flags.get("device_float_dtype"))
 
-    def _cached_batch(self, blocks, needed, extra: tuple = (),
-                      collected=None):
+    def _cached_batch(self, blocks, needed, extra: tuple,
+                      facts: StoreFacts):
         """Build (or fetch from the device cache) the columnar batch for
         `needed` columns. `extra` extends the cache key — the zone-map
         prune signature rides here so a batch built from one predicate's
         pruned block set never serves another predicate.
-        `collected`: the store's whole block list where `blocks` is its
-        zone-pruned part.  Unless it is proved one version a key (it is
-        not with several SSTs or a memtable overlay), the batch links
-        its row versions when it is built (`next_ht`) and is served
-        `linked`; a property of the store's contents, which the key
-        already names (SST paths, write generation), looked at on a miss
-        only."""
+        Unless the store's whole block list is proved one version a key
+        (`facts.chunk_safe`: it is not with several SSTs or a memtable
+        overlay), the batch links its row versions when it is built
+        (`next_ht`) and is served `linked`; a property of the store's
+        contents, which the key already names (SST paths, write
+        generation)."""
         miss = False
 
         def build():
             nonlocal miss
             miss = True
-            return build_batch(
-                blocks, sorted(needed),
-                multi_version=not chunk_safe_mvcc(collected or blocks))
+            return build_batch(blocks, sorted(needed),
+                               multi_version=not facts.chunk_safe)
 
         with _trace.TRACES.span("docdb.batch", child_only=True) as sp:
             if self.device_cache is None:
@@ -1674,14 +1725,15 @@ class DocReadOperation:
                 sp.set_tag("bytes", batch_bytes(batch))
             return batch
 
-    def _zone_prune(self, blocks, where, read_ht):
+    def _zone_prune(self, blocks, where, read_ht, chunk_safe: bool):
         """Zone-map block pruning for the monolithic pushdown paths:
         (kept_blocks, cache_key_extra). MVCC-gated exactly like the
         streaming path — pruning is only sound when every doc key lives
-        wholly inside one block (chunk_safe over the FULL list), since
-        dropping a block may otherwise unmask an older version of a key
-        that survives elsewhere. Tallies LAST_SCAN_PRUNE_STATS either
-        way so the bench counter reads fresh values per scan."""
+        wholly inside one block (`chunk_safe`: the proof over the FULL
+        list, `StoreFacts.chunk_safe`), since dropping a block may
+        otherwise unmask an older version of a key that survives
+        elsewhere. Tallies LAST_SCAN_PRUNE_STATS either way so the
+        bench counter reads fresh values per scan."""
         stats = {"blocks_total": len(blocks), "blocks_pruned": 0}
         LAST_SCAN_PRUNE_STATS.clear()
         LAST_SCAN_PRUNE_STATS.update(stats)
@@ -1697,7 +1749,7 @@ class DocReadOperation:
             # a read point ALWAYS flows into the kernel's MVCC selection
             # in these paths (even _MAX_HT), so the chunk-safety proof is
             # unconditionally required before dropping any block
-            if read_ht is not None and not chunk_safe_mvcc(blocks):
+            if read_ht is not None and not chunk_safe:
                 return blocks, ()
             from ..ops.scan import zone_prune_blocks
             kept, kept_idx = zone_prune_blocks(blocks, where)
@@ -1709,7 +1761,7 @@ class DocReadOperation:
             return kept, ("zp", kept_idx)
 
     def _try_streaming_aggregate(self, req: ReadRequest, blocks, needed,
-                                 read_ht: int):
+                                 read_ht: int, facts: StoreFacts):
         """Chunked pipelined aggregate (ops/stream_scan.py) for scans it
         can serve exactly; None falls through to the monolithic batch.
         Hash grouping and MVCC-unsafe block sequences are rejected
@@ -1738,7 +1790,8 @@ class DocReadOperation:
         got = streaming_scan_aggregate(
             blocks, sorted(needed), req.where, aggs_run, req.group_by,
             read_ht, kernel=self.kernel, cache=cache, cache_key=key,
-            grouped_out=grouped_out, dict_out=dict_out)
+            grouped_out=grouped_out, dict_out=dict_out,
+            chunk_safe=facts.chunk_safe, plans=self._kept_plans(facts))
         if got is None:
             return None
         if dict_group and grouped_out.get("spill"):
@@ -1755,7 +1808,7 @@ class DocReadOperation:
                 # exactly like the normal streamed path and the
                 # interpreted re-scan — a zone-pruned block's
                 # ambiguous-HT rows must keep forcing the restart
-                self._check_restart_window(blocks, read_ht)
+                self._check_restart_window(blocks, read_ht, facts)
                 resp = self._grouped_spill_merge(
                     req, grouped_out, expanded, minmax, aggs_run, got,
                     read_ht)
@@ -1768,7 +1821,7 @@ class DocReadOperation:
         # is actually serving the read — a scan that falls through to
         # the monolithic/CPU paths keeps their own (possibly narrower)
         # restart behavior, exactly as before this path existed
-        self._check_restart_window(blocks, read_ht)
+        self._check_restart_window(blocks, read_ht, facts)
         outs, counts = got
         outs = _nullify_minmax(expanded, minmax, outs)
         outs = dict_minmax_decode(expanded, outs,
@@ -1925,12 +1978,26 @@ class DocReadOperation:
         return self._spill_merge_tail(req, blocks, sel, aggs_run,
                                       expanded, minmax, dev_part)
 
-    def _check_restart_window(self, blocks, read_ht: int) -> None:
+    def _kept_plans(self, facts: StoreFacts) -> dict:
+        return facts.plans.setdefault(self.codec.info.table_id, {})
+
+    def _check_restart_window(self, blocks, read_ht: int,
+                              facts: Optional[StoreFacts] = None) -> None:
         """Raise ReadRestartError when any block holds a record inside
         (read_ht, read_ht + skew] — the coarse whole-block uncertainty
-        check shared by the monolithic and streaming aggregate paths."""
+        check shared by the monolithic and streaming aggregate paths.
+        `facts`: those of the store `blocks` is the whole list of; a
+        read at or above its newest write time walks nothing."""
         if not (self._allow_restart and read_ht != _MAX_HT):
             return
+        if facts is not None and facts.max_ht <= read_ht:
+            return      # no record newer than the read: none in the window
+        self._walk_restart_window(blocks, read_ht)
+
+    @staticmethod
+    def _walk_restart_window(blocks, read_ht: int) -> None:
+        """Every block's `ht` lane against the window; the restart time
+        is the newest record of the first block that holds one."""
         window_hi = read_ht + _skew_window_ht()
         for b in blocks:
             amb = b.ht[(b.ht > np.uint64(read_ht))
@@ -1939,7 +2006,7 @@ class DocReadOperation:
                 raise ReadRestartError(int(amb.max()))
 
     def _execute_tpu_aggregate(self, req: ReadRequest) -> Optional[ReadResponse]:
-        blocks = self._collect_blocks()
+        blocks, facts = self._collect_with_facts()
         if not blocks:
             return None
         req = self._maybe_doc_rewrite(req, blocks)
@@ -1960,7 +2027,8 @@ class DocReadOperation:
                 and not flags.get("grouped_pushdown_enabled"):
             return None     # interpreted GROUP BY (the flag-off path)
         read_ht = req.read_ht if req.read_ht is not None else _MAX_HT
-        resp = self._try_streaming_aggregate(req, blocks, needed, read_ht)
+        resp = self._try_streaming_aggregate(req, blocks, needed, read_ht,
+                                             facts)
         if resp is _SPILLED:
             return None     # over-cardinality: interpreted GROUP BY
         if resp is not None:
@@ -1969,13 +2037,13 @@ class DocReadOperation:
         # restart window below still checks the FULL block list (a
         # pruned block's ambiguous-HT rows keep today's restart
         # behavior)
-        kept, prune_key = self._zone_prune(blocks, req.where, read_ht)
+        kept, prune_key = self._zone_prune(blocks, req.where, read_ht,
+                                           facts.chunk_safe)
         try:
-            batch = self._cached_batch(kept, needed, prune_key,
-                                       collected=blocks)
+            batch = self._cached_batch(kept, needed, prune_key, facts)
         except KeyError:
             return None   # some column lacks columnar form → CPU path
-        self._check_restart_window(blocks, read_ht)
+        self._check_restart_window(blocks, read_ht, facts)
         return self.aggregate_on_batch(
             req, batch,
             lambda where, aggs, group: self.kernel.run(
@@ -2110,7 +2178,7 @@ class DocReadOperation:
         dict_group = isinstance(group, DictGroupSpec)
         if dict_group and not flags.get("grouped_pushdown_enabled"):
             return None
-        blocks = self._collect_blocks()
+        blocks, facts = self._collect_with_facts()
         if not blocks:
             return None
         from ..ops.expr import referenced_columns
@@ -2163,7 +2231,7 @@ class DocReadOperation:
             from ..ops.grouped_scan import GROUPED_STATS
             GROUPED_STATS["spill_fallbacks"] += 1
             return None       # slot overflow: interpreted join
-        self._check_restart_window(blocks, read_ht)
+        self._check_restart_window(blocks, read_ht, facts)
         outs, counts = got
         outs = _nullify_minmax(expanded, minmax, outs)
         if dict_group:
@@ -2286,7 +2354,7 @@ class DocReadOperation:
         matching rows gather host-side with vectorized numpy over the
         columnar blocks (no per-row predicate evaluation). Falls back to
         the CPU row loop when columns aren't columnar-capable."""
-        blocks = self._collect_blocks()
+        blocks, facts = self._collect_with_facts()
         if not blocks:
             return None
         req = self._maybe_doc_rewrite(req, blocks)
@@ -2299,16 +2367,15 @@ class DocReadOperation:
                      if req.columns else list(schema.columns))
         read_ht = req.read_ht if req.read_ht is not None else _MAX_HT
         resp = self._try_streaming_filter(req, blocks, needed,
-                                          proj_cols, read_ht)
+                                          proj_cols, read_ht, facts)
         if resp is not None:
             return self._served("streaming", resp)
-        all_blocks = blocks
-        blocks, prune_key = self._zone_prune(blocks, req.where, read_ht)
+        blocks, prune_key = self._zone_prune(blocks, req.where, read_ht,
+                                             facts.chunk_safe)
         try:
             # same device cache as the aggregate path: repeated string-
             # predicate scans must not rebuild dictionaries per query
-            batch = self._cached_batch(blocks, needed, prune_key,
-                                       collected=all_blocks)
+            batch = self._cached_batch(blocks, needed, prune_key, facts)
         except KeyError:
             return None
         where = req.where
@@ -2327,7 +2394,7 @@ class DocReadOperation:
         return ReadResponse(rows=rows, backend="tpu")
 
     def _try_streaming_filter(self, req: ReadRequest, blocks, needed,
-                              proj_cols, read_ht: int
+                              proj_cols, read_ht: int, facts: StoreFacts
                               ) -> Optional[ReadResponse]:
         """Streamed filter-pushdown ROW path: per-chunk WHERE masks on
         device overlapped with the next chunk's batch formation, rows
@@ -2355,7 +2422,8 @@ class DocReadOperation:
         rows = streaming_scan_filter(
             blocks, sorted(needed), req.where, read_ht, materialize,
             limit=req.limit, kernel=self.kernel, cache=cache,
-            cache_key=key)
+            cache_key=key, chunk_safe=facts.chunk_safe,
+            plans=self._kept_plans(facts))
         if rows is None:
             return None
         return ReadResponse(rows=rows, backend="tpu")

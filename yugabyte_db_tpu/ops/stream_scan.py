@@ -31,6 +31,7 @@ forces it, keeping the honest r05 baseline reproducible.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -130,26 +131,50 @@ def group_domain_ok(group, dicts) -> bool:
     return domain_product(group, dicts) < 2 ** 31
 
 
-def _plan_dict_columns(blocks, columns, where, aggs, group):
+def _kept_plan(plans, key, blocks, make):
+    """The scan-global dictionary plan for `key`, made once per store
+    contents: `plans` is the mapping the caller keeps with those
+    contents (`docdb.operations.StoreFacts.plans`), so the same key
+    means the same blocks in the same order.  A plan is kept by block
+    position and handed out keyed by this read's block objects — an
+    SST's block cache may have decoded a block anew since the plan was
+    made.  `plans` None: made for this scan alone."""
+    if plans is None:
+        return make()
+    if key not in plans:
+        plan = make()
+        plans[key] = (plan, plan and {
+            cid: [by_block[id(b)] for b in blocks]
+            for cid, by_block in plan.codes.items()})
+        return plan
+    plan, by_position = plans[key]
+    if plan is None:
+        return None     # these blocks do not dictionary-encode
+    return dataclasses.replace(
+        plan, merge_s=0.0,
+        codes={cid: {id(b): c for b, c in zip(blocks, lanes)}
+               for cid, lanes in by_position.items()})
+
+
+def _plan_dict_columns(blocks, columns, where, aggs, group, plans=None):
     """Scan-global dictionary planning + string-predicate rewrite for a
     streamed scan.  Returns ``(plan, where, aggs, ok)``: plan is None
     when no column needs dictionary form; ok=False means the scan can't
     stream (no columnar/dictionary form, over-wide group domain, or a
-    string column used outside a rewritable predicate shape)."""
+    string column used outside a rewritable predicate shape).
+    `plans`: see :func:`_kept_plan`."""
     dcids = dict_cols_needed(blocks, columns)
     if dcids is None:
         return None, where, aggs, False
-    dict_group = isinstance(group, DictGroupSpec)
-    if dict_group:
-        if not flags.get("grouped_pushdown_enabled"):
-            return None, where, aggs, False
+    if isinstance(group, DictGroupSpec):
         for cid in group.cols:
             if not all(cid in b.varlen for b in blocks):
                 return None, where, aggs, False
         dcids = sorted(set(dcids) | set(group.cols))
     if not dcids:
         return None, where, aggs, True
-    plan = make_dict_plan(blocks, dcids)
+    plan = _kept_plan(plans, tuple(dcids), blocks,
+                      lambda: make_dict_plan(blocks, dcids))
     if plan is None:
         return None, where, aggs, False
     if not group_domain_ok(group, plan.dicts):
@@ -163,6 +188,21 @@ def _plan_dict_columns(blocks, columns, where, aggs, group):
     return plan, where, aggs, True
 
 
+def _cannot_stream(blocks, group, read_ht, chunk_safe):
+    """The refusals of a streamed scan that need no dictionary plan, so
+    that none is made for a scan the monolithic batch will serve:
+    ``(refused, chunk_safe)``.  `chunk_safe`: the caller's proof over
+    `blocks` (kept with the store's contents), None = prove it here."""
+    if isinstance(group, HashGroupSpec):
+        return True, chunk_safe
+    if isinstance(group, DictGroupSpec) \
+            and not flags.get("grouped_pushdown_enabled"):
+        return True, chunk_safe
+    if chunk_safe is None:
+        chunk_safe = chunk_safe_mvcc(blocks)
+    return read_ht is not None and not chunk_safe, chunk_safe
+
+
 def streaming_scan_aggregate(
         blocks: Sequence[ColumnarBlock], columns: Sequence[int],
         where: Optional[tuple], aggs: Sequence[AggSpec],
@@ -172,7 +212,9 @@ def streaming_scan_aggregate(
         cache=None, cache_key: Optional[tuple] = None,
         min_chunks: int = 3, prefilter=None,
         grouped_out: Optional[dict] = None,
-        dict_out: Optional[dict] = None):
+        dict_out: Optional[dict] = None,
+        chunk_safe: Optional[bool] = None,
+        plans: Optional[dict] = None):
     """Chunked scan-aggregate over `blocks`.
 
     Returns ``(agg_values, counts)`` — the shapes of
@@ -215,12 +257,17 @@ def streaming_scan_aggregate(
     device cache (a one-shot snapshot scan has no warm re-scan to
     serve) and with the dictionary plan (compacted blocks have no
     remap entries).
+
+    `chunk_safe`, `plans`: what the caller keeps with the store's
+    contents (:func:`_cannot_stream`, :func:`_kept_plan`).
     """
-    if isinstance(group, HashGroupSpec):
+    refused, chunk_safe = _cannot_stream(blocks, group, read_ht,
+                                         chunk_safe)
+    if refused:
         return None
     dict_group = isinstance(group, DictGroupSpec)
     plan, where, aggs, ok = _plan_dict_columns(blocks, columns, where,
-                                               aggs, group)
+                                               aggs, group, plans)
     if not ok or (dict_group and plan is None):
         return None
     if plan is not None:
@@ -230,9 +277,6 @@ def streaming_scan_aggregate(
             # coded in — callers decode dict-code MIN/MAX results
             # through them (docdb.operations.dict_minmax_decode)
             dict_out["dicts"] = plan.dicts
-    chunk_safe = chunk_safe_mvcc(blocks)
-    if read_ht is not None and not chunk_safe:
-        return None
     # zone-map pruning: skip whole blocks whose v2 min/max maps prove
     # the WHERE can't match, BEFORE any batch formation. Safe exactly
     # when each doc key lives in one block (chunk_safe over the FULL
@@ -366,7 +410,9 @@ def streaming_scan_filter(
         kernel: Optional[ScanKernel] = None,
         chunk_rows: Optional[int] = None,
         cache=None, cache_key: Optional[tuple] = None,
-        min_chunks: int = 2):
+        min_chunks: int = 2,
+        chunk_safe: Optional[bool] = None,
+        plans: Optional[dict] = None):
     """Streamed filter-pushdown ROW path (ROADMAP operator-frontier
     rung (a)): per-chunk WHERE masks compute on device while the next
     chunk's batch forms on the pipeline thread; matching rows
@@ -380,13 +426,15 @@ def streaming_scan_filter(
     scan-global dictionary plan exactly like the aggregate path.
     ``limit``: stop dispatching once this many rows matched — the
     pipeline closes early, which is the row-path win the monolithic
-    batch can't have."""
-    plan, where, _, ok = _plan_dict_columns(blocks, columns, where,
-                                            (), None)
-    if not ok:
+    batch can't have.  `chunk_safe`, `plans`: as
+    :func:`streaming_scan_aggregate`."""
+    refused, chunk_safe = _cannot_stream(blocks, None, read_ht,
+                                         chunk_safe)
+    if refused:
         return None
-    chunk_safe = chunk_safe_mvcc(blocks)
-    if read_ht is not None and not chunk_safe:
+    plan, where, _, ok = _plan_dict_columns(blocks, columns, where,
+                                            (), None, plans)
+    if not ok:
         return None
     pruned = 0
     kept_idx = None

@@ -144,6 +144,10 @@ class LsmStore:
         self._write_gen = 0
         self._struct_gen = 0           # bumps on flush/compact/replace
         self._snap = None              # cached (gen-key, (mems, ssts))
+        # the device read path's facts about the blocks of the current
+        # contents (docdb/operations.py StoreFacts, which names the
+        # contents it was made for); None = none made yet, or dropped
+        self.read_facts = None
         self._mem_frontier: dict = {}
         # out-of-band reader leases: path -> refcount; paths the store
         # dropped while pinned wait in _deferred until the last lease

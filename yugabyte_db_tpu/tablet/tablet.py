@@ -96,8 +96,11 @@ class _VectorIndexState:
 class Tablet:
     def __init__(self, tablet_id: str, info: TableInfo, directory: str,
                  clock: Optional[HybridClock] = None,
-                 partition=None, colocated: bool = False):
+                 partition=None, colocated: bool = False,
+                 owner: str = ""):
         self.tablet_id = tablet_id
+        # the server whose `/metrics` entity the read path counts on
+        self.owner = owner
         self.info = info
         self.partition = partition
         self.dir = directory
@@ -118,7 +121,8 @@ class Tablet:
         self.intents = LsmStore(
             os.path.join(directory, "intents"), name="intents")
         self._read_op = DocReadOperation(
-            self.codec, self.regular, device_cache=_DEVICE_CACHE)
+            self.codec, self.regular, device_cache=_DEVICE_CACHE,
+            owner=owner)
         self._read_ops: Dict[str, DocReadOperation] = {
             info.table_id: self._read_op}
         # vector ANN indexes: col_id -> _VectorIndexState
@@ -141,7 +145,7 @@ class Tablet:
         codec = TableCodec(info)
         self.codecs[info.table_id] = codec
         self._read_ops[info.table_id] = DocReadOperation(
-            codec, self.regular, device_cache=None)
+            codec, self.regular, device_cache=None, owner=self.owner)
 
     def _codec_for(self, table_id: str) -> TableCodec:
         return self.codecs.get(table_id, self.codec)
@@ -180,12 +184,14 @@ class Tablet:
                     r.key_builder = merged.derive_keys
             from ..docdb.operations import DocReadOperation
             self._read_op = DocReadOperation(
-                merged, self.regular, device_cache=_DEVICE_CACHE)
+                merged, self.regular, device_cache=_DEVICE_CACHE,
+                owner=self.owner)
         from ..docdb.operations import DocReadOperation as _DRO
         self._read_ops[new_info.table_id] = _DRO(
             merged, self.regular,
             device_cache=_DEVICE_CACHE
-            if new_info.table_id == self.info.table_id else None)
+            if new_info.table_id == self.info.table_id else None,
+            owner=self.owner)
 
     def tables(self):
         return list(self.codecs)
@@ -243,8 +249,7 @@ class Tablet:
                                  component="flush"):
                     # analysis-ok(async_blocking): deliberate backpressure
                     if self.regular.flush_frozen() is not None:
-                        _DEVICE_CACHE.invalidate_prefix(
-                            (id(self.regular),))
+                        self.drop_device_state()
                 FLUSH_APPLY_STATS["inline_flushes"] += 1
                 FLUSH_APPLY_STATS["inline_s"] += _perf_counter() - ti
             FLUSH_APPLY_STATS["handoff_s"] += _perf_counter() - t0
@@ -272,14 +277,20 @@ class Tablet:
                         n = 0
                         while self.regular.flush_frozen(wait=False) \
                                 is not None:
-                            _DEVICE_CACHE.invalidate_prefix(
-                                (id(self.regular),))
+                            self.drop_device_state()
                             FLUSH_APPLY_STATS["background_flushes"] += 1
                             n += 1
                     sp.set_tag("flushed", n)
         except Exception:   # noqa: BLE001 — must not kill the pool
             log.exception("%s: background flush failed (frozen "
                           "memtable retained for retry)", self.tablet_id)
+
+    def drop_device_state(self) -> None:
+        """The regular store's SST set changed (flush, compaction), or
+        its chips go back: its cached batches leave the device cache,
+        and the read path's facts about its blocks go with them."""
+        _DEVICE_CACHE.invalidate_prefix((id(self.regular),))
+        self.regular.read_facts = None
 
     # --- reads ------------------------------------------------------------
     def read(self, req: ReadRequest) -> ReadResponse:
@@ -406,7 +417,7 @@ class Tablet:
     def flush(self, wait: bool = True) -> Optional[str]:
         path = self.regular.flush(wait=wait)
         if path:
-            _DEVICE_CACHE.invalidate_prefix((id(self.regular),))
+            self.drop_device_state()
         return path
 
     def history_cutoff(self) -> int:
@@ -454,7 +465,7 @@ class Tablet:
             path = self.regular.compact(
                 inputs=inputs, feed=RepackingCompactionFeed(cutoff,
                                                             self.codec))
-        _DEVICE_CACHE.invalidate_prefix((id(self.regular),))
+        self.drop_device_state()
         return path
 
     def bulk_load(self, columns: Dict[str, np.ndarray],

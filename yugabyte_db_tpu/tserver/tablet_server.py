@@ -234,7 +234,7 @@ class TabletServer:
             # batches leave them, and the cache its capacity
             from ..tablet.tablet import _DEVICE_CACHE
             for p in self.peers.values():
-                _DEVICE_CACHE.invalidate_prefix((id(p.tablet.regular),))
+                p.tablet.drop_device_state()
             _DEVICE_CACHE.capacity = self._cache_capacity_before
             self._cache_capacity_before = None
         if graceful:
@@ -327,7 +327,8 @@ class TabletServer:
                          bytes.fromhex(meta["partition"][1]))
         tablet = Tablet(tablet_id, info, self._tablet_dir(tablet_id),
                         clock=self.clock, partition=part,
-                        colocated=meta.get("colocated", False))
+                        colocated=meta.get("colocated", False),
+                        owner=f"ts-{self.uuid}")
         for tw in meta.get("colocated_tables", []):
             tablet.add_table(TableInfo.from_wire(tw))
         config = RaftConfig([PeerSpec(e[0], tuple(e[1]),
