@@ -20,8 +20,7 @@ jax.config.update("jax_enable_x64", True)
 # the package keeps the persistent XLA compile cache off where the
 # platform was forced to cpu (XLA:CPU AOT entries can fail the loader's
 # machine check); make the choice visible to yugabyte_db_tpu/__init__.py
-# before its import.  bench.py starts pytest children after it has taken
-# the chip: this line is what keeps them off it.
+# before its import.
 os.environ.setdefault("YBTPU_PLATFORM", "cpu")
 
 # state-invariant sanitizer (utils/sanitizer.py — the TSAN/DCHECK-build

@@ -18,7 +18,7 @@ Four layers:
    day the pass shipped.
 4. CONTRACTS — the run.py --json schema (pass ids, counts, findings,
    suppression tally, per-pass wall time), the --changed incremental
-   mode, the suppression-vs-baseline tally bench.py WARNs on, and the
+   mode, the suppression tally held to baseline.json, and the
    wall-time budget that keeps the sweep from bloating tier-1.
 """
 import json

@@ -7,8 +7,7 @@ time.  Nothing runs, so these tests say nothing about results or speed.
 
 `jax.default_backend()` still says `cpu` during such a compile, so the
 TPU arm of each backend branch is steered from here (f32 value lanes,
-`unroll` group strategy, Pallas `interpret=False`), never through an
-option of the program.
+`unroll` group strategy), never through an option of the program.
 
 The topology is described inside a module-scoped fixture — not at
 import, not in conftest.py — so that under several xdist workers only
@@ -184,42 +183,6 @@ def test_chunk_merge_kernel_compiles(one_chip, key_words):
         s((key_words,), u64), s((), u64), s((), u32), s((), b), s((), b),
         s((), u64), num_dk_words=key_words))
     assert "sort" in compiled.as_text()
-
-
-@pytest.mark.parametrize("query_name", ["q6", "q1"])
-def test_pallas_generic_scan_compiles(one_chip, query_name):
-    """The opt-in (`tpu_pallas_scan`) hand-blocked scan through Mosaic,
-    flat and grouped, as `ScanKernel._try_pallas` builds it."""
-    from yugabyte_db_tpu.models import tpch
-    from yugabyte_db_tpu.ops.expr import compile_expr, const_count
-    from yugabyte_db_tpu.ops.pallas_scan import build_generic_scan
-    from yugabyte_db_tpu.ops.scan import _expand_avg
-    query = {"q6": tpch.TPCH_Q6, "q1": tpch.TPCH_Q1}[query_name]
-    aggs = tuple(_expand_avg(query.aggs))
-    consts = _consts(query, aggs)
-    off = const_count(query.where)
-    agg_fns = []
-    for a in aggs:
-        if a.expr is None:
-            agg_fns.append((a.op, None))
-            continue
-        agg_fns.append((a.op, compile_expr(a.expr, offset=off)))
-        off += const_count(a.expr)
-    group = query.group
-    col_order = tuple(sorted(query.columns))
-    run = build_generic_scan(
-        query.where, agg_fns,
-        group.cols if group is not None else None,
-        group.num_groups if group is not None else None,
-        col_order, col_order, len(consts), interpret=False)
-
-    def s(shape):
-        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
-
-    rows = [s((SCAN_ROWS,)) for _ in col_order]
-    compiled = _compile(lambda: run.lower(s((len(consts),)), rows, rows,
-                                          s((SCAN_ROWS,))))
-    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_distributed_scan_compiles_for_four_chips(topo, tpu_arms):

@@ -4,8 +4,7 @@ Unlike the psycopg/cassandra suites (skip-if-absent — those drivers
 cannot be vendored), this one always runs: when no system redis-py is
 installed it falls back to the vendored RESP2 client in
 third_party/redispy (an API-compatible subset; see its docstring), so
-the external-client tier executes in the default tier-1 run and in
-bench.py's driver_conformance accounting."""
+the external-client tier executes in the default tier-1 run."""
 import os
 import sys
 

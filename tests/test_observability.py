@@ -737,6 +737,70 @@ class TestServedScanSpans:
         trace_mod.current_span().count("retries")          # goes nowhere
 
 
+class TestReadRouteTag:
+    """Every exit of `DocReadOperation._execute_once` names itself on
+    the `docdb.read` span: the one way to tell which driver served a
+    read.  (`tpu_aggregate` through SQL, `mesh` and `mesh_fallback`
+    are pinned by TestServedScanSpans and tests/test_mesh_read.py.)"""
+
+    @staticmethod
+    def _requests(route):
+        """(the reads to make in order, flag overrides): the LAST read's
+        span is the one held to `route`."""
+        from yugabyte_db_tpu.docdb.operations import ReadRequest
+        from yugabyte_db_tpu.ops import AggSpec, Expr
+        from yugabyte_db_tpu.ops.join_scan import JoinWire
+        C = Expr.col
+        where = (C(3) < 50).node
+        aggs = (AggSpec("sum", C(2).node), AggSpec("count"))
+        if route == "point":
+            return [ReadRequest("probe", pk_eq={"k": 5})], {}
+        if route == "prefix":
+            return [ReadRequest("probe", pk_prefix={"k": 5})], {}
+        if route == "join":
+            wire = JoinWire(probe_col=1,
+                            keys=np.arange(500, dtype=np.int64),
+                            payload={})
+            return [ReadRequest("probe", where=where, aggregates=aggs,
+                                join=wire)], {}
+        if route == "hash_enumerated":
+            return [ReadRequest("probe",
+                                where=C(0).between(10, 19).node)], {}
+        if route == "streaming":     # 24,000 rows = six chunks
+            return [ReadRequest("probe", where=where, aggregates=aggs)], \
+                {"streaming_chunk_rows": 4096}
+        if route == "tpu_filter":
+            return [ReadRequest("probe", where=where)], {}
+        if route == "cpu":
+            return [ReadRequest("probe")], {}
+        assert route == "tpu_aggregate"   # one chunk: the whole batch
+        return [ReadRequest("probe", where=where, aggregates=aggs)
+                for _ in range(2)], {}
+
+    @pytest.mark.parametrize("route", [
+        "point", "prefix", "join", "hash_enumerated", "streaming",
+        "tpu_filter", "cpu", "tpu_aggregate"])
+    def test_tablet_read_names_the_route_that_served(self, route):
+        from tests.test_join_scan import _probe_tablet
+        t, _ = _probe_tablet("route-")
+        reqs, overrides = self._requests(route)
+        for name, value in overrides.items():
+            flags.set_flag(name, value)
+        try:
+            for req in reqs:
+                with TRACES.trace("route") as root:
+                    t.read(req)
+        finally:
+            for name in overrides:
+                flags.REGISTRY.reset(name)
+        reads = [s for s in TRACES.finished()
+                 if s.trace_id == root.trace_id and s.name == "docdb.read"]
+        assert [s.tags["route"] for s in reads] == [route]
+        if route == "tpu_aggregate":
+            # the second read of unchanged contents looks its facts up
+            assert reads[0].tags["facts"] == "hit"
+
+
 class TestSpanRegistry:
     """ISSUE 27: one clock, kept until read."""
 

@@ -394,20 +394,38 @@ class TestZoneMaps:
                 is True)
             assert got == want, where
 
-    def test_selective_scan_skips_blocks(self, tmp_path, v2_flag):
+    @pytest.mark.parametrize("streamed", [True, False])
+    def test_selective_scan_skips_blocks(self, tmp_path, v2_flag,
+                                         streamed):
+        """Either driver drops the ~18 of 20 blocks provably out of
+        range: the streamed one tallies its prune, the monolithic one
+        tags the `zone_prune` step of `docdb.collect_blocks`."""
         t, data = self._range_tablet(tmp_path, n=20000, block_rows=1000)
         from yugabyte_db_tpu.ops import Expr
         from yugabyte_db_tpu.ops.stream_scan import LAST_STREAM_STATS
-        from yugabyte_db_tpu.docdb.operations import LAST_SCAN_PRUNE_STATS
+        from yugabyte_db_tpu.utils.trace import TRACES
         C = Expr.col
         req = ReadRequest("lineitem_r",
                           where=(C(0) < 2000).node,
                           aggregates=(AggSpec("count"),))
-        resp = t.read(req)
+        LAST_STREAM_STATS.clear()
+        flags.set_flag("streaming_scan_enabled", streamed)
+        try:
+            with TRACES.trace("zone-prune") as root:
+                resp = t.read(req)
+        finally:
+            flags.REGISTRY.reset("streaming_scan_enabled")
         assert int(np.asarray(resp.agg_values[0])) == 2000
-        skipped = (LAST_STREAM_STATS.get("zone_blocks_pruned")
-                   or LAST_SCAN_PRUNE_STATS.get("blocks_pruned", 0))
-        assert skipped >= 15   # ~18 of 20 blocks provably out of range
+        if streamed:
+            assert LAST_STREAM_STATS["zone_blocks_total"] == 20
+            assert LAST_STREAM_STATS["zone_blocks_pruned"] >= 15
+            return
+        assert not LAST_STREAM_STATS
+        prune, = [s.tags for s in TRACES.finished()
+                  if s.trace_id == root.trace_id
+                  and s.name == "docdb.collect_blocks"
+                  and s.tags.get("step") == "zone_prune"]
+        assert prune["blocks"] == 20 and prune["pruned"] >= 15
 
     def test_f32_boundary_rounding_never_prunes_matches(
             self, tmp_path, v2_flag):

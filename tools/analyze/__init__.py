@@ -5,7 +5,7 @@ The hazard classes this repo keeps re-growing are mechanical and
 AST-checkable: a blocking call or lock-held ``await`` on the one event
 loop freezes admission and Raft heartbeats for the whole server; a
 host-sync or shape-dependent branch inside a jitted kernel silently
-destroys the compile-once property the bench numbers depend on; a flag
+destroys the compile-once property the served path depends on; a flag
 that drifts between definition and use lies to operators; an attribute
 mutated from both an executor thread and the event loop is a data race.
 
@@ -18,7 +18,7 @@ Layout:
 - ``passes/``    one module per pass; ``passes.ALL_PASSES`` is the
                  registry.
 - ``run``        CLI: human output or ``--json`` (schema consumed by
-                 tests/test_analysis.py and bench.py's WARN tail).
+                 tests/test_analysis.py).
 
 See ANALYSIS.md at the repo root for the pass catalog, the suppression
 grammar, and how to add a pass.

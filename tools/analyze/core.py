@@ -80,11 +80,10 @@ class ProjectIndex:
     """Parse-once file index over the analysis roots.
 
     ``modules()`` walks the roots; ``module(rel)`` parses any repo file
-    on demand (flag_drift reads bench.py / profile scripts / tests this
-    way without widening every other pass's scope).  ``call_graph()``
-    lazily builds the shared interprocedural graph; with ``cache_dir``
-    set, its per-file extraction facts persist across runs keyed on
-    (path, mtime, size) so a repeat run re-walks only changed files."""
+    on demand.  ``call_graph()`` lazily builds the shared
+    interprocedural graph; with ``cache_dir`` set, its per-file
+    extraction facts persist across runs keyed on (path, mtime, size)
+    so a repeat run re-walks only changed files."""
 
     def __init__(self, base: str, roots: Sequence[str] = DEFAULT_ROOTS,
                  overlay: Optional[Dict[str, str]] = None,
@@ -263,7 +262,7 @@ def run_analysis(index: ProjectIndex,
     {"passes": [{"id", "title", "findings": N, "suppressed": N,
                  "wall_ms": F}],
      "findings": [finding dicts...],          # unsuppressed only
-     "suppressions": {pass_id: N},            # the tally bench.py diffs
+     "suppressions": {pass_id: N},            # held to baseline.json
      "total_findings": N, "total_suppressed": N, "wall_ms": F,
      "parse_errors": [{"path", "error"}]}
     """

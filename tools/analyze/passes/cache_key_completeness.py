@@ -150,9 +150,6 @@ REGISTRY: Tuple[dict, ...] = (
             "scan_group_strategy": "resolved value `strategy` is a "
                                    "signature component (finer: "
                                    "auto's resolution is keyed)",
-            "tpu_pallas_scan": "dispatch gate only; pallas "
-                               "eligibility memo keyed separately "
-                               "under ('pallas', sig)",
         },
         "must_mention": [
             ("strategy", "grouped-path choice bakes into the kernel"),

@@ -3,7 +3,7 @@
 Four mechanical drift shapes:
 
 1. DEFINED, NEVER READ — a ``DEFINE``/``DEFINE_RUNTIME`` whose name no
-   product code or bench/profile script ever ``flags.get``s: dead
+   product code ever ``flags.get``s: dead
    operator surface that lies about being a knob.
 2. READ, NEVER DEFINED — ``flags.get("name")`` of a name no DEFINE
    creates: a KeyError waiting for that code path.
@@ -22,7 +22,6 @@ flag only a test touches is not wired into the product.
 from __future__ import annotations
 
 import ast
-import glob
 import os
 import re
 from typing import Dict, List, Optional, Set, Tuple
@@ -71,10 +70,6 @@ class FlagDriftPass(AnalysisPass):
     hint = ("wire the flag, delete it, or annotate the DEFINE with "
             "`# analysis-ok(flag_drift): <reason>` if it is reserved")
 
-    #: extra read scopes beyond the analysis roots: bench/profile
-    #: scripts at the repo root use flags too.
-    EXTRA_READ_GLOBS = ("*.py",)
-
     def run(self, index: ProjectIndex) -> List[Finding]:
         out: List[Finding] = []
         defs, autos = self._collect_definitions(index, out)
@@ -88,7 +83,7 @@ class FlagDriftPass(AnalysisPass):
         # truly dead flag's name appears nowhere outside its DEFINE.
         unread = {n for n in defs if n not in reads}
         if unread:
-            for mod in self._read_modules(index):
+            for mod in index.modules():
                 if mod.tree is None or mod.rel == FLAGS_MODULE:
                     continue
                 for node in ast.walk(mod.tree):
@@ -104,8 +99,8 @@ class FlagDriftPass(AnalysisPass):
             if name not in reads and name not in autos:
                 out.append(self.finding(
                     mod, line,
-                    f"flag `{name}` is defined but never read by product "
-                    f"code or bench/profile scripts",
+                    f"flag `{name}` is defined but never read by "
+                    f"product code",
                     detail=name))
         self._check_doc_defaults(index, defs, out)
         return out
@@ -160,16 +155,6 @@ class FlagDriftPass(AnalysisPass):
         return defs, autos
 
     # --- reads ------------------------------------------------------------
-    def _read_modules(self, index: ProjectIndex) -> List[ModuleInfo]:
-        mods = list(index.modules())
-        for pat in self.EXTRA_READ_GLOBS:
-            for path in sorted(glob.glob(os.path.join(index.base, pat))):
-                rel = os.path.relpath(path, index.base)
-                mi = index.module(rel)
-                if mi is not None:
-                    mods.append(mi)
-        return mods
-
     @staticmethod
     def _flag_aliases(mod: ModuleInfo) -> Set[str]:
         """Names the flags module is bound to in this module (`flags`,
@@ -191,7 +176,7 @@ class FlagDriftPass(AnalysisPass):
                        defined: Set[str]):
         reads: Set[str] = set()
         regexes: Set[str] = set()
-        for mod in self._read_modules(index):
+        for mod in index.modules():
             if mod.tree is None or mod.rel == FLAGS_MODULE:
                 continue
             aliases = self._flag_aliases(mod)
@@ -230,7 +215,7 @@ class FlagDriftPass(AnalysisPass):
                         regexes.add(rx)
                 # fully dynamic reads (Name arg) prove nothing; skip
         # set_flag("x", v) module-level helper calls
-        for mod in self._read_modules(index):
+        for mod in index.modules():
             if mod.tree is None or mod.rel == FLAGS_MODULE:
                 continue
             for node in ast.walk(mod.tree):

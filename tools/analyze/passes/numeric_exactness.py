@@ -33,9 +33,7 @@ evidence — no finding without a dtype witness):
   second expression reads the first's constant table.
 
 Suppress at the reported line:
-``# analysis-ok(numeric_exactness): <reason>`` (the mosaic/pallas
-kernels legitimately accumulate f32 inside bounded-row eligibility —
-each such site carries its reason).
+``# analysis-ok(numeric_exactness): <reason>``.
 """
 from __future__ import annotations
 

@@ -3,7 +3,7 @@ handler, never a broad ``except`` that swallows them.
 
 The fast paths refuse work they cannot do exactly by RAISING a typed
 refusal (BypassIneligible, DocIneligible, JoinIneligible,
-PallasIneligible, MatviewIneligible, ...).  The contract is that every
+MatviewIneligible, ...).  The contract is that every
 refusal propagates to a dispatcher that catches the TYPE and routes the
 request to the interpreted / CPU fallback.  A broad ``except
 Exception:`` between the raise and that dispatcher launders the refusal

@@ -18,8 +18,7 @@ in the staged/changed files.  Repeat runs stay cheap because the call
 graph's per-file facts persist under ``.analyze_cache/`` keyed on
 (path, mtime, size); ``--no-cache`` opts out.
 
-The ``--json`` schema (consumed by tests/test_analysis.py and the
-bench.py WARN tail):
+The ``--json`` schema (consumed by tests/test_analysis.py):
 
     {"passes": [{"id", "title", "findings": N, "suppressed": N,
                  "wall_ms": F}],

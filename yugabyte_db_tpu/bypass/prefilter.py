@@ -43,7 +43,7 @@ _Interval = Tuple[object, bool, object, bool]
 
 _INF = float("inf")
 
-#: most recent prefilter tally (profile/bench scripts read it)
+#: most recent prefilter tally (bypass/scan.py copies it into its stats)
 LAST_PREFILTER_STATS = {"rows_in": 0, "rows_kept": 0, "blocks": 0,
                         "blocks_compacted": 0}
 

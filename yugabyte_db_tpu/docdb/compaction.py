@@ -51,8 +51,8 @@ import jax.numpy as jnp
 
 _HT_SUFFIX = ENCODED_SIZE + 1
 
-#: stage/shape counters of the most recent chunked compaction (read by
-#: profile_compact.py --json; informational only)
+#: stage/shape counters of the most recent chunked compaction
+#: (informational only)
 LAST_COMPACTION_STATS: dict = {}
 
 
@@ -381,10 +381,8 @@ def _compact_columnar(store, codec, blocks: List[ColumnarBlock],
     pk_cat = {cid: cat_pk(cid) for cid in pk_ids}
     path = store._new_sst_path()
     # format follows the sst_format_version flag like every other
-    # writer (bench pins the flag to 1 around its baseline runs to get
-    # the pre-PR byte yardstick — that is a harness concern, not this
-    # engine's: an operator running baseline compactions must still get
-    # the format they configured)
+    # writer: an operator running baseline compactions must still get
+    # the format they configured
     w = SstWriter(path, stream_columnar=True,
                   key_builder=codec.derive_keys,
                   shred_cols=codec.shred_cols)
@@ -746,7 +744,7 @@ def _native_chunk_merge_segs(seg_views, run_starts: np.ndarray,
     # Fan-in routing: at low k the in-place segment merge wins (no
     # concatenated key matrix at all); at high fan-in the heap's
     # pointer-chasing across many mmap regions loses to one sequential
-    # concat + dense-matrix merge (measured on the 100-SST bench).
+    # concat + dense-matrix merge.
     got = (native_lib.kway_merge_segments(key_segs)
            if len(key_segs) <= 8 else None)
     if got is None:

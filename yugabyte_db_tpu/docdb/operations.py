@@ -36,10 +36,6 @@ from .table_codec import TableCodec
 
 _HT_SUFFIX = ENCODED_SIZE + 1
 
-#: zone-map pruning tally of the most recent pushdown scan (read by
-#: bench.py's cold_scan block; informational only)
-LAST_SCAN_PRUNE_STATS: dict = {}
-
 
 # --------------------------------------------------------------------------
 # Requests (wire format objects)
@@ -1732,11 +1728,7 @@ class DocReadOperation:
         wholly inside one block (`chunk_safe`: the proof over the FULL
         list, `StoreFacts.chunk_safe`), since dropping a block may
         otherwise unmask an older version of a key that survives
-        elsewhere. Tallies LAST_SCAN_PRUNE_STATS either way so the
-        bench counter reads fresh values per scan."""
-        stats = {"blocks_total": len(blocks), "blocks_pruned": 0}
-        LAST_SCAN_PRUNE_STATS.clear()
-        LAST_SCAN_PRUNE_STATS.update(stats)
+        elsewhere."""
         if where is None or not flags.get("zone_map_pruning"):
             return blocks, ()
         # the second step of `docdb.collect_blocks`: which of the
@@ -1755,8 +1747,6 @@ class DocReadOperation:
             kept, kept_idx = zone_prune_blocks(blocks, where)
             if len(kept) == len(blocks):
                 return blocks, ()
-            LAST_SCAN_PRUNE_STATS["blocks_pruned"] = \
-                len(blocks) - len(kept)
             sp.set_tag("pruned", len(blocks) - len(kept))
             return kept, ("zp", kept_idx)
 

@@ -25,10 +25,10 @@ from .pushdown import (DOC_COL_BASE, DOC_STATS, LAST_DOC_STATS,
                        attach_shredded, doc_compatible, exprs_have_doc,
                        has_doc_nodes, prepare_doc_scan, record_fallback,
                        rewrite_doc, vcid_for)
-from .shred import DOC_WRITE_STATS, infer_paths, shred_lanes
+from .shred import infer_paths, shred_lanes
 
 __all__ = [
-    "ALL_REASONS", "DOC_COL_BASE", "DOC_STATS", "DOC_WRITE_STATS",
+    "ALL_REASONS", "DOC_COL_BASE", "DOC_STATS",
     "DocIneligible", "LAST_DOC_STATS", "REASON_DOC_SHAPE",
     "REASON_KIND_MISMATCH", "REASON_NOT_DOC_COLUMN", "REASON_OFF",
     "REASON_UNSHREDDED_BLOCK", "attach_shredded", "doc_compatible",

@@ -53,7 +53,7 @@ _VCIDS: Dict[Tuple[int, tuple], int] = {}
 
 #: cumulative scan-side accounting
 DOC_STATS = {"shredded_scans": 0, "fallbacks": 0, "reasons": {}}
-#: stats of the most recent shredded scan (bench/profile read these)
+#: stats of the most recent shredded scan
 LAST_DOC_STATS: dict = {}
 
 _INT_CASTS = ("cast_bigint", "cast_int", "cast_integer", "cast_int8",
@@ -427,7 +427,7 @@ def attach_shredded(blocks, refs: Dict[Tuple[int, tuple],
     ``varlen[vcid]`` with the stored dict parts pre-seeded into
     ``_vdicts``, so the scan-global dictionary plan forms with zero
     row-string decodes.  Returns ``(clones, stats)`` with the coverage
-    stats the bench's shred_coverage counter reads."""
+    stats (rows, present rows, paths)."""
     rows = 0
     present_rows = 0
     out = []

@@ -52,10 +52,6 @@ _MIN_COVERAGE = 0.05
 _MAX_DICT_CARD = 0xFFFF
 _I64_MIN, _I64_MAX = -(2 ** 63), 2 ** 63 - 1
 
-#: cumulative write-side accounting (profile_doc / bench read it)
-DOC_WRITE_STATS = {"blocks": 0, "blocks_shredded": 0, "docs": 0,
-                   "paths_shredded": 0, "present_rows": 0}
-
 
 def _classify(v) -> Tuple[str, object]:
     """(tag, normalized value) of one extracted JSON value.  Tags:
@@ -175,13 +171,6 @@ def shred_lanes(ends: np.ndarray, heap, null,
         lane = _build_lane(paths[p], kind, n)
         if lane is not None:
             out[p] = lane
-    DOC_WRITE_STATS["blocks"] += 1
-    DOC_WRITE_STATS["docs"] += docs
-    if out:
-        DOC_WRITE_STATS["blocks_shredded"] += 1
-        DOC_WRITE_STATS["paths_shredded"] += len(out)
-        DOC_WRITE_STATS["present_rows"] += int(
-            sum(int(lane[2].sum()) for lane in out.values()))
     return out
 
 

@@ -92,10 +92,6 @@ class ViewMaintainer:
         self.watermark_ht = 0
         self.applied_lsn: Optional[list] = None
         self.counters = _fresh_counters()
-        # wall-clock split across the maintainer's stages; read by
-        # profile_matview.py — never reset, only accumulated
-        self.stage_s = {"seed": 0.0, "stream": 0.0, "fold": 0.0,
-                        "rescan": 0.0, "persist": 0.0}
         self.vw: Optional[VirtualWal] = None
         self._task: Optional[asyncio.Task] = None
         self._round_lock = asyncio.Lock()
@@ -109,7 +105,6 @@ class ViewMaintainer:
         await self._seed_current_slot(first=True)
 
     async def _seed_current_slot(self, first: bool) -> None:
-        t0 = time.perf_counter()
         pre_lsn = None
         wm = 0
         for _ in range(600):
@@ -128,13 +123,10 @@ class ViewMaintainer:
         self.watermark_ht = wm
         self.applied_lsn = pre_lsn
         await self._seed_scan(wm)
-        self.stage_s["seed"] += time.perf_counter() - t0
         self.counters["seeds"] += 1
         if not first:
             self.counters["full_rescans"] += 1
-        t0 = time.perf_counter()
         await self._persist(create=first)
-        self.stage_s["persist"] += time.perf_counter() - t0
         if pre_lsn is not None:
             await self.vw.confirm_flush(pre_lsn)
 
@@ -314,9 +306,7 @@ class ViewMaintainer:
     async def _round_inner(self) -> int:
         if self._stream_dirty:
             await self._recover_stream()
-        t0 = time.perf_counter()
         recs = await self.vw.get_consistent_changes()
-        self.stage_s["stream"] += time.perf_counter() - t0
         wm = self.vw._watermark()
         if not recs:
             if wm > 0:
@@ -347,7 +337,6 @@ class ViewMaintainer:
         last_lsn = None
         try:
             dirty_keys: set = set()
-            t0 = time.perf_counter()
             for t in txns:
                 last_lsn = t["lsn"]
                 if t["ht"] <= self.seed_ht:
@@ -357,12 +346,9 @@ class ViewMaintainer:
                     continue           # replay of an applied txn
                 dirty_keys |= await self._apply_txn(t)
                 self.counters["txns_applied"] += 1
-            self.stage_s["fold"] += time.perf_counter() - t0
             if dirty_keys:
-                t0 = time.perf_counter()
                 await self._rescan_groups(dirty_keys,
                                           max(wm, self.seed_ht))
-                self.stage_s["rescan"] += time.perf_counter() - t0
         except BaseException:
             self.state = snap_state
             self.counters = snap_counters
@@ -375,10 +361,8 @@ class ViewMaintainer:
             self.watermark_ht = max(self.watermark_ht, wm)
         if last_lsn is not None:
             self.applied_lsn = last_lsn
-            t0 = time.perf_counter()
             await self._persist()
             await self.vw.confirm_flush(last_lsn)
-            self.stage_s["persist"] += time.perf_counter() - t0
         return len(recs)
 
     async def _apply_txn(self, txn: dict) -> set:

@@ -220,11 +220,10 @@ def compact_runs(runs: Sequence[Tuple[np.ndarray, np.ndarray]],
 _U64_MAX = jnp.uint64(0xFFFFFFFFFFFFFFFF)
 _U32_MAX = jnp.uint32(0xFFFFFFFF)
 
-#: process-lifetime kernel-compile accounting, mirrored by
-#: profile_compact.py --json.  A signature is one (frontier_rows,
-#: num_dk_words) pair — jax.jit compiles exactly once per signature, so
-#: "compiles" counts cache misses and a repeat compaction of the same
-#: shape reports zero new compiles.
+#: process-lifetime kernel-compile accounting.  A signature is one
+#: (frontier_rows, num_dk_words) pair — jax.jit compiles exactly once
+#: per signature, so "compiles" counts cache misses and a repeat
+#: compaction of the same shape reports zero new compiles.
 _KERNEL_SIGS: set = set()
 KERNEL_STATS = {"compiles": 0, "calls": 0, "cache_hits": 0}
 

@@ -2,8 +2,8 @@
 
 A fair stand-in for the reference's C++ scan loop
 (reference: src/yb/docdb/pgsql_operation.cc:2790): whole-column numpy
-evaluation over the same columnar blocks the TPU path reads, so
-`bench.py`'s vs-baseline ratio measures TPU-vs-CPU execution, not
+evaluation over the same columnar blocks the TPU path reads, so a
+comparison against it measures TPU-vs-CPU execution, not
 Python-vs-compiled overhead. (The row-at-a-time interpreter in
 docdb/operations.py is the semantics reference, not the baseline.)
 """

@@ -60,9 +60,6 @@ class DeviceBatch:
     # code space (order-preserving) or LUT gathers before compilation
     # (SURVEY §7 hard-part 3: varlen data in fixed-shape kernels)
     dicts: Dict[int, np.ndarray] = field(default_factory=dict)
-    # per-column (min, max) memo for int32 columns — the pallas route
-    # checks f32-exactness once per batch, not once per query
-    int32_ranges: Dict[int, tuple] = field(default_factory=dict)
     # host-side per-column value bounds (min, max) in f64, computed once
     # at batch build — ops/expr.expr_bound turns these into STATIC
     # fixed-point SUM scales so the scan kernel needs no device

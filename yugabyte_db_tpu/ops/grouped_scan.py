@@ -46,8 +46,8 @@ import numpy as np
 from ..storage import lane_codec
 from ..storage.columnar import ColumnarBlock
 
-#: accounting + stage split of the most recent grouped scan (read by
-#: bench/profile scripts; informational only)
+#: accounting + stage split of the most recent grouped scan
+#: (informational only)
 LAST_GROUPED_STATS: dict = {}
 
 #: process-wide grouped-kernel accounting (compiles tallied by

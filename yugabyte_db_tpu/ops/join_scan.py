@@ -36,7 +36,6 @@ numpy twin of the probe, used by the plan twin for bitwise parity.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Dict, Optional, Tuple
 
@@ -50,9 +49,6 @@ BUILD_COL_BASE = 1 << 20
 #: process-wide join accounting (probes tallied by the plan kernel;
 #: builds/fallbacks tallied here)
 JOIN_STATS = {"builds": 0, "fallbacks": 0}
-
-#: stats of the most recent build-table construction (bench/profile)
-LAST_JOIN_STATS: dict = {}
 
 _MIN_TABLE_SLOTS = 8
 _MAX_TABLE_SLOTS_HARD = 1 << 24
@@ -211,7 +207,6 @@ class JoinRuntime:
     payload_dicts: Dict[int, np.ndarray] = field(default_factory=dict)
     payload_bounds: Dict[int, Tuple[float, float]] = \
         field(default_factory=dict)
-    build_s: float = 0.0
 
     def shape_signature(self) -> tuple:
         return (self.probe_col, self.num_slots, self.build_rows_pad,
@@ -255,7 +250,6 @@ def _make_join_runtime(wire: JoinWire,
     table stays collision-correct and the payload gather indexes stay
     aligned with the wire's build rows.  Raises JoinIneligible with a
     typed reason for every shape the device join cannot serve."""
-    t0 = time.perf_counter()
     if max_slots is None:
         from ..utils import flags
         max_slots = flags.get("join_max_build_slots")
@@ -320,13 +314,7 @@ def _make_join_runtime(wire: JoinWire,
                                               float(nz.max()))
         rt.payload_vals[bid] = _pad_to(vals, rows_pad)
         rt.payload_nulls[bid] = _pad_to(nulls, rows_pad)
-    rt.build_s = time.perf_counter() - t0
     JOIN_STATS["builds"] += 1
-    LAST_JOIN_STATS.clear()
-    LAST_JOIN_STATS.update({
-        "n_build": n, "num_slots": num_slots,
-        "build_s": round(rt.build_s, 5),
-        "payload_cols": len(rt.build_cols)})
     return rt
 
 
@@ -386,16 +374,6 @@ def make_join_runtimes(wires, probe_dicts: Dict[int, np.ndarray],
         rts.append(rt)
         seen_bids |= set(wire.payload)
         dicts.update(rt.payload_dicts)
-    if len(rts) > 1:
-        # chain-level build accounting (make_join_runtime wrote the
-        # last stage's alone)
-        LAST_JOIN_STATS.clear()
-        LAST_JOIN_STATS.update({
-            "stages": len(rts),
-            "n_build": sum(rt.n_build for rt in rts),
-            "num_slots": [rt.num_slots for rt in rts],
-            "build_s": round(sum(rt.build_s for rt in rts), 5),
-            "payload_cols": sum(len(rt.build_cols) for rt in rts)})
     return tuple(rts)
 
 

@@ -62,7 +62,7 @@ from .scan import (AggSpec, HashGroupSpec, _expand_avg, _group_strategy,
 PLAN_STATS = {"compiles": 0, "launches": 0, "cache_hits": 0,
               "fallbacks": 0}
 
-#: stage split of the most recent fused-plan scan (bench/profile)
+#: stage split of the most recent fused-plan scan
 LAST_PLAN_STATS: dict = {}
 
 
@@ -70,7 +70,7 @@ class FusedPlanKernel:
     """Signature-keyed cache of jitted fused-plan programs.
 
     ``sig_compiles`` maps each canonical plan signature (stringified,
-    order of first compile) to its compile count — the bench asserts
+    order of first compile) to its compile count — tests assert
     this stays 1 per signature across data growth and repeated runs."""
 
     def __init__(self):

@@ -544,9 +544,6 @@ class ScanKernel:
     def __init__(self):
         self._cache: Dict[tuple, object] = {}
         self.compiles = 0
-        #: typed-refusal tally: PallasIneligible reason -> count (why
-        #: the pallas route declined; reads like bypass REASON_* stats)
-        self.pallas_refusals: Dict[str, int] = {}
 
     def _get(self, sig, where_node, aggs, group, mvcc_mode, static_sums,
              strategy):
@@ -564,145 +561,6 @@ class ScanKernel:
             self._cache[sig] = fn
             self.compiles += 1
         return fn
-
-    # dtypes the f32 pallas compute admits. int64 HTs/keys/timestamps
-    # never route; int32 columns additionally get a runtime |max| <
-    # 2^24 guard (below) so integer predicates stay exact. float64
-    # columns DO round to f32 in this path — sums carry ~1e-7 relative
-    # drift and f64 predicate boundaries can flip within that noise;
-    # that is the documented contract of the opt-in flag.
-    _PALLAS_DTYPES = ("float32", "float64", "int32", "int16", "int8",
-                      "bool")
-
-    def _pallas_eligible(self, batch, where, aggs, group, mvcc_mode,
-                         consts):
-        """Typed eligibility gate for the pallas route: returns the
-        referenced-column set, or raises PallasIneligible with the
-        refusal reason.  The refusal-flow contract: fast paths refuse
-        BY TYPE so dispatchers can route (and count) the decline —
-        a silent None return is indistinguishable from a bug."""
-        from .pallas_scan import PallasIneligible
-        if mvcc_mode != "none" or not aggs:
-            raise PallasIneligible("mvcc_or_no_aggs")
-        if group is not None and (not isinstance(group, GroupSpec)
-                                  or group.num_groups > 64):
-            raise PallasIneligible("group_shape")
-        if any(a.op not in ("sum", "count", "min", "max") for a in aggs):
-            raise PallasIneligible("agg_op")
-        if batch.padded_rows % 4096 != 0:
-            raise PallasIneligible("bucket_rows")
-        from .expr import referenced_columns
-        needed = set(referenced_columns(where)) if where is not None \
-            else set()
-        for a in aggs:
-            if a.expr is not None:
-                # dict-code MIN/MAX (aggregate-over-string-payload):
-                # the f32 pallas pipeline would round code indices —
-                # those shapes stay on the exact XLA path
-                if any(cid in batch.dicts
-                       for cid in referenced_columns(a.expr)):
-                    raise PallasIneligible("dict_code_agg")
-                needed |= set(referenced_columns(a.expr))
-        if group is not None:
-            needed |= {cid for cid, _, _ in group.cols}
-        for cid in needed:
-            col = batch.cols.get(cid)
-            if col is None or str(col.dtype) not in self._PALLAS_DTYPES:
-                raise PallasIneligible("column_dtype")
-            if str(col.dtype) == "int32":
-                rng = batch.col_bounds.get(cid) or \
-                    batch.int32_ranges.setdefault(
-                        cid, (int(jnp.min(col)), int(jnp.max(col))))
-                if max(abs(rng[0]), abs(rng[1])) >= 2 ** 24:
-                    raise PallasIneligible("int32_range")  # not f32-exact
-        for c in consts:
-            if np.ndim(c) != 0:
-                raise PallasIneligible("const_shape")
-            if abs(float(c)) >= 2 ** 24:
-                raise PallasIneligible("const_range")  # not f32-exact
-        return needed
-
-    def _try_pallas(self, sig, batch, where, aggs, group, mvcc_mode,
-                    consts):
-        """Route eligible aggregate scans through the hand-fused pallas
-        kernel (ops/pallas_scan.py). Returns the XLA-shaped result
-        tuple, or None on a typed PallasIneligible refusal — the
-        caller falls back to the XLA kernel and the reason is tallied
-        in ``pallas_refusals``."""
-        from .pallas_scan import PallasIneligible
-        try:
-            needed = self._pallas_eligible(batch, where, aggs, group,
-                                           mvcc_mode, consts)
-        except PallasIneligible as e:
-            r = str(e)
-            self.pallas_refusals[r] = self.pallas_refusals.get(r, 0) + 1
-            return None
-        key = ("pallas", sig)
-        entry = self._cache.get(key)
-        col_order = tuple(sorted(needed))
-        null_order = tuple(cid for cid in col_order
-                           if cid in batch.nulls)
-        entry_was_compiled = entry is None
-        # only typed PallasIneligible refusals route to the XLA kernel;
-        # a build or Mosaic compile error propagates — swallowing it
-        # would hide a kernel the chip's compiler refuses
-        if entry is None:
-            from .expr import const_count
-            from .pallas_scan import build_generic_scan
-            off = const_count(where) if where is not None else 0
-            agg_fns = []
-            for a in aggs:
-                if a.expr is None:
-                    agg_fns.append((a.op, None))
-                    continue
-                agg_fns.append(
-                    (a.op, compile_expr(a.expr, offset=off)))
-                off += const_count(a.expr)
-            interpret = jax.default_backend() == "cpu"
-            entry = build_generic_scan(
-                where, agg_fns,
-                group.cols if group is not None else None,
-                group.num_groups if group is not None else None,
-                col_order, null_order, len(consts),
-                interpret=interpret)
-            self._cache[key] = entry
-            self.compiles += 1
-        carr = jnp.asarray(
-            np.asarray([float(c) for c in consts] or [0.0],
-                       np.float32))
-        col_arrs = [batch.cols[cid].astype(jnp.float32)
-                    for cid in col_order]
-        null_arrs = [batch.nulls[cid].astype(jnp.float32)
-                     for cid in null_order]
-        from ..utils import trace as _trace
-        with _trace.device_span("pallas_scan", signature=key,
-                                compiled=entry_was_compiled,
-                                bucket=batch.padded_rows,
-                                rows=batch.n_rows):
-            outs = entry(carr, col_arrs, null_arrs,
-                         batch.valid.astype(jnp.float32))
-        agg_parts, cnt_parts = outs[:-1], outs[-1]
-        results = []
-        for a, p in zip(aggs, agg_parts):
-            if a.op in ("count",):
-                # per-block partials are exact ints (block <= 4096);
-                # sum them in int64 ON THE HOST so totals past 2^24
-                # stay exact, unlike an f32 device accumulation
-                r = np.asarray(p, np.float64).sum(axis=0).astype(np.int64)
-            elif a.op == "sum":
-                # combine per-block f32 partials in f64 on the host —
-                # residual error is the block-local (<=4096-row) f32
-                # accumulation, the documented contract of this opt-in
-                # flag; the default XLA path is exact (int64 fixed point)
-                r = np.asarray(p, np.float64).sum(axis=0)
-            elif a.op == "min":
-                r = jnp.min(p, axis=0)
-            else:
-                r = jnp.max(p, axis=0)
-            results.append(r)
-        counts = np.asarray(cnt_parts, np.float64).sum(axis=0).astype(
-            np.int64)
-        return tuple(results), counts, None
 
     def run(self, batch: DeviceBatch,
             where: Optional[tuple] = None,
@@ -744,13 +602,7 @@ class ScanKernel:
             else None,
             mvcc_mode, batch.padded_rows, col_sig, static_sums, strategy,
         )
-        from ..utils import flags as _flags
         from ..utils import trace as _trace
-        if _flags.get("tpu_pallas_scan"):
-            got = self._try_pallas(sig, batch, where, aggs, group,
-                                   mvcc_mode, consts)
-            if got is not None:
-                return got
         pre = self.compiles
         fn = self._get(sig, where, aggs, group, mvcc_mode, static_sums,
                        strategy)

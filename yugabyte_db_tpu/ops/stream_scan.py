@@ -48,8 +48,7 @@ from .scan import AggSpec, HashGroupSpec, ScanKernel, _expand_avg
 
 _HT_SUFFIX = ENCODED_SIZE + 1   # DocHybridTime suffix + kHybridTime marker
 
-#: stats of the most recent streaming scan (read by bench/profile
-#: scripts; informational only)
+#: stats of the most recent streaming scan (informational only)
 LAST_STREAM_STATS: dict = {}
 
 

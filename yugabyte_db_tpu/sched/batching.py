@@ -19,7 +19,6 @@ three batch shapes and their correctness arguments:
 """
 from __future__ import annotations
 
-from time import perf_counter as _perf_counter
 from typing import List
 
 from ..utils import trace as _trace
@@ -73,8 +72,6 @@ async def dispatch_write_group(items: List[tuple], fanin_hist) -> None:
     txn ops) further fuse into one WAL append + one replicate round
     (the ReplicateBatch shape)."""
     from ..docdb.operations import WriteRequest
-    from ..tablet.tablet_peer import WRITE_PATH_STATS
-    t0 = _perf_counter()
     first = items[0][0]
     ops = []
     for wb, _, _, _ in items:
@@ -82,7 +79,6 @@ async def dispatch_write_group(items: List[tuple], fanin_hist) -> None:
     merged = WriteRequest(first.req.table_id, ops,
                           schema_version=first.req.schema_version)
     fanin_hist.increment(len(items))
-    WRITE_PATH_STATS["group_merge_s"] += _perf_counter() - t0
     # dispatch span parents under the FIRST member's request (the
     # worker task has no ambient context of its own); fanin tags how
     # many requests shared this one WAL append + apply

@@ -533,8 +533,7 @@ class RequestScheduler:
 
     # --- introspection ----------------------------------------------------
     def stats(self) -> dict:
-        """Per-lane live stats for /scheduler, profile_ycsb --json and
-        the dashboard."""
+        """Per-lane live stats for /scheduler and the dashboard."""
         out = {}
         for lane, st in self.lanes.items():
             out[lane.value] = {

@@ -356,7 +356,7 @@ def dict_identity(uniq: np.ndarray) -> tuple:
 
 def tally(stats: Optional[dict], lane: str, pre: int, post: int,
           enc: str) -> None:
-    """Accumulate per-lane encode accounting (profile_compact --json's
+    """Accumulate per-lane encode accounting (the compaction stats'
     per-lane breakdown); no-op when the caller passed no stats dict."""
     if stats is None:
         return

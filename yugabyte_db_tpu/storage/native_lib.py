@@ -304,10 +304,6 @@ def gather_scatter_rows(src: np.ndarray, src_idx: np.ndarray,
     return True
 
 
-#: counters for the profile scripts: fused-call vs fallback tallies
-GATHER_STATS = {"fused_calls": 0, "fused_jobs": 0, "fallback_calls": 0}
-
-
 def gather_multi(jobs: Sequence[tuple]) -> bool:
     """THE fused multi-column gather/scatter: one GIL-released native
     call executes every (src, dst, src_idx, dst_idx) job — all value
@@ -369,14 +365,11 @@ def gather_multi(jobs: Sequence[tuple]) -> bool:
         cnt[j] = n
     lib.gather_multi(src_p, dst_p, _ptr(rb, _i64p), sidx_p, didx_p,
                      _ptr(cnt, _i64p), n_jobs)
-    GATHER_STATS["fused_calls"] += 1
-    GATHER_STATS["fused_jobs"] += n_jobs
     return True
 
 
 def gather_multi_fallback(jobs: Sequence[tuple]) -> None:
     """Numpy twin of gather_multi (also the parity oracle in tests)."""
-    GATHER_STATS["fallback_calls"] += 1
     for src, dst, src_idx, dst_idx in jobs:
         if src_idx is None and dst_idx is None:
             dst[:len(src)] = src
@@ -415,8 +408,6 @@ def copy_multi(jobs: Sequence[Tuple[np.ndarray, np.ndarray]]) -> bool:
         dst_p[j] = dst.ctypes.data
         nb[j] = src.nbytes
     lib.copy_multi(src_p, dst_p, _ptr(nb, _i64p), n_jobs)
-    GATHER_STATS["fused_calls"] += 1
-    GATHER_STATS["fused_jobs"] += n_jobs
     return True
 
 
@@ -464,9 +455,6 @@ _PREFILTER_DTYPES = {
     np.dtype(np.float32): 3, np.dtype(np.float64): 4,
     np.dtype(np.uint32): 5,
 }
-
-#: counters for the profile scripts: native vs fallback prefilter calls
-PREFILTER_STATS = {"native_calls": 0, "fallback_calls": 0}
 
 _I64_MIN, _I64_MAX = -(1 << 63), (1 << 63) - 1
 
@@ -519,7 +507,6 @@ def prefilter_ranges(preds: Sequence[tuple], n: int
         lo_f.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
         hi_f.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
         _ptr(lo_i, _i64p), _ptr(hi_i, _i64p), np_, n, _ptr(keep, _u8p))
-    PREFILTER_STATS["native_calls"] += 1
     return keep
 
 
@@ -527,7 +514,6 @@ def prefilter_ranges_fallback(preds: Sequence[tuple],
                               n: int) -> np.ndarray:
     """Numpy twin of prefilter_ranges (also the parity oracle in
     tests): identical keep-mask semantics, pure numpy."""
-    PREFILTER_STATS["fallback_calls"] += 1
     keep = np.ones(n, bool)
     for vals, nulls, lo, hi in preds:
         if vals.dtype.kind == "f":

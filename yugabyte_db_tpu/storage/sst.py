@@ -241,7 +241,7 @@ class SstWriter:
             if enabled:
                 self.shred_cols = tuple(shred_cols)
         #: per-lane encode accounting accumulated across this file's
-        #: blocks (profile_compact --json reads it off the compaction
+        #: blocks (the chunked compaction copies it into its
         #: stats; {"lanes": {lane: {pre_bytes, post_bytes, encodings}}})
         self.lane_stats: dict = {}
         if stream_columnar:

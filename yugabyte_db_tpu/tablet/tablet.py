@@ -52,17 +52,6 @@ _FLUSH_POOL = ThreadPoolExecutor(max_workers=2,
 #: write
 _BULK_WORKERS = max(2, min(4, (os.cpu_count() or 2) - 1))
 
-#: stage split of the most recent bulk_load (read by profile_ycsb.py
-#: --json; informational only)
-LAST_BULK_LOAD_STATS: dict = {}
-
-#: process-wide flush-on-apply accounting: what the apply thread paid
-#: (``handoff_s`` = freeze + submit, ``inline_s`` = backpressure or
-#: flag-off inline drains) vs what moved to the flush executor
-#: (``background_flushes``).  Read by profile_ycsb.py --json.
-FLUSH_APPLY_STATS = {"handoff_s": 0.0, "inline_s": 0.0, "handoffs": 0,
-                     "inline_flushes": 0, "background_flushes": 0}
-
 
 class _VectorIndexState:
     """One ANN index: a frozen chunk (any registry method) plus a
@@ -134,9 +123,8 @@ class Tablet:
         self._m_rows_written = ent.counter("rows_inserted")
         self._m_reads = ent.counter("read_ops")
         self._m_read_lat = ent.histogram("read_latency_us")
-        # what the APPLY THREAD paid for flush work per trigger — the
-        # histogram whose collapse (inline SST write -> pointer swap)
-        # the cluster bench's p99-round-spread gate rides on
+        # what the APPLY THREAD paid for flush work per trigger (an
+        # inline SST write before async flush, a pointer swap since)
         self._m_flush_pause = ent.histogram("flush_pause_ms")
         self._m_stalls_avoided = ent.counter("flush_stalls_avoided")
 
@@ -228,12 +216,9 @@ class Tablet:
                 # (the default) hands the SST write to the executor
                 # analysis-ok(async_blocking): deliberate inline flush
                 self.flush()
-                FLUSH_APPLY_STATS["inline_flushes"] += 1
-                FLUSH_APPLY_STATS["inline_s"] += _perf_counter() - t0
                 return
             if self.regular.freeze_active():
                 self._m_stalls_avoided.increment()
-                FLUSH_APPLY_STATS["handoffs"] += 1
                 _trace.TRACE("flush.handoff")
                 # explicit context capture: the flush-executor thread
                 # has no contextvars from this task, so the handoff
@@ -244,15 +229,11 @@ class Tablet:
                    > flags.get("max_frozen_memtables")):
                 # the executor fell behind; the apply thread helps
                 # drain one frozen memtable, bounding frozen memory
-                ti = _perf_counter()
                 with wait_status("Flush_MemtableBackpressure",
                                  component="flush"):
                     # analysis-ok(async_blocking): deliberate backpressure
                     if self.regular.flush_frozen() is not None:
                         self.drop_device_state()
-                FLUSH_APPLY_STATS["inline_flushes"] += 1
-                FLUSH_APPLY_STATS["inline_s"] += _perf_counter() - ti
-            FLUSH_APPLY_STATS["handoff_s"] += _perf_counter() - t0
         finally:
             self._m_flush_pause.increment((_perf_counter() - t0) * 1e3)
 
@@ -278,7 +259,6 @@ class Tablet:
                         while self.regular.flush_frozen(wait=False) \
                                 is not None:
                             self.drop_device_state()
-                            FLUSH_APPLY_STATS["background_flushes"] += 1
                             n += 1
                     sp.set_tag("flushed", n)
         except Exception:   # noqa: BLE001 — must not kill the pool
@@ -480,16 +460,13 @@ class Tablet:
         make (fused native gather) and serialize blocks ahead, and the
         shared stage pipeline writes them to the file in block order —
         gather, encode and IO overlap."""
-        import time as _time
         from ..storage.pipeline import StreamPipeline
         ht = ht or self.clock.now()
-        t0 = _time.perf_counter()
         makers = self.codec.bulk_block_makers(
             columns, ht, block_rows=block_rows, partition=self.partition)
         if not makers:
             return 0        # everything partition-filtered: no SST
         n = 0
-        stats: dict = {}
 
         def serialized(w, pool):
             """(block, its serialized parts), in block order, a few
@@ -516,19 +493,8 @@ class Tablet:
                     thread_name_prefix="bulk-block") as pool:
                 for bn in pipe.run(serialized(w, pool)):
                     n += bn
-            stats.update(pipe.stats(),
-                         write_stage_s=round(pipe.stage_s[0], 4))
         self.regular.ingest_sst(build, stream=True)
         self._m_rows_written.increment(n)
-        LAST_BULK_LOAD_STATS.clear()
-        LAST_BULK_LOAD_STATS.update({
-            "rows": n, "blocks": stats.get("items"),
-            "wall_s": round(_time.perf_counter() - t0, 4),
-            # calling thread = global encode/sort; the pool's threads
-            # make and serialize blocks ahead; write stage =
-            # GIL-released file write
-            "write_stage_s": stats.get("write_stage_s"),
-            "gather_wait_s": stats.get("consumer_wait_s")})
         return n
 
     # --- vector indexes (reference: vector_index/vector_lsm.cc,

@@ -12,7 +12,6 @@ from __future__ import annotations
 
 import asyncio
 import os
-from time import perf_counter as _perf_counter
 from typing import Optional
 
 import msgpack
@@ -26,21 +25,6 @@ from ..utils import trace as _trace
 from ..utils.hybrid_time import HybridClock, HybridTime
 from ..utils.trace import wait_status
 from .tablet import Tablet
-
-#: process-wide write-path stage accounting (read by profile_ycsb.py
-#: --json next to the scheduler's admission-wait histograms, and by
-#: tests asserting the fused-append shape; informational only).
-#: ``replicate_s`` covers append+fsync+commit wait, ``apply_s`` the
-#: state-machine apply, ``entries``/``batches`` the group-commit fanin
-#: (batches == WAL entries of type 'write'; entries == member writes).
-WRITE_PATH_STATS = {"replicate_s": 0.0, "apply_s": 0.0,
-                    "group_merge_s": 0.0, "entries": 0, "batches": 0}
-
-
-def reset_write_path_stats() -> None:
-    WRITE_PATH_STATS.update(replicate_s=0.0, apply_s=0.0,
-                            group_merge_s=0.0, entries=0, batches=0)
-
 
 class TabletPeer:
     def __init__(self, tablet: Tablet, uuid: str, config: RaftConfig,
@@ -322,7 +306,6 @@ class TabletPeer:
                 continue
             payload = msgpack.packb({
                 "batch": [p for p, _ in batch]})
-            t0 = _perf_counter()
             try:
                 await self.consensus.replicate(
                     "write", payload, precheck=self.split_fence_check)
@@ -332,9 +315,6 @@ class TabletPeer:
                         fut.set_exception(e)
                 self._notify_progress()
                 continue
-            WRITE_PATH_STATS["replicate_s"] += _perf_counter() - t0
-            WRITE_PATH_STATS["batches"] += 1
-            WRITE_PATH_STATS["entries"] += len(batch)
             for _, fut in batch:
                 if not fut.done():
                     fut.set_result(None)
@@ -413,7 +393,6 @@ class TabletPeer:
             return
         d = msgpack.unpackb(entry.payload, raw=False)
         items = d["batch"] if "batch" in d else [d]
-        t0 = _perf_counter()
         with _trace.TRACES.span("tablet.apply", child_only=True,
                                 tags={"tablet": self.tablet.tablet_id,
                                       "entries": len(items)}):
@@ -421,7 +400,6 @@ class TabletPeer:
                 req = write_request_from_wire(item["req"])
                 self.tablet.apply_write(req, ht=HybridTime(item["ht"]),
                                         op_id=(entry.term, entry.index))
-        WRITE_PATH_STATS["apply_s"] += _perf_counter() - t0
 
     # --- read path --------------------------------------------------------
     async def read(self, req: ReadRequest) -> ReadResponse:

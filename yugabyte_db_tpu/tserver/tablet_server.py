@@ -1427,8 +1427,8 @@ class TabletServer:
 
     async def rpc_scheduler_stats(self, payload) -> dict:
         """Live scheduler lane stats (depths, sheds, wait/batch/fanin
-        histograms) — the webserver /scheduler endpoint and
-        profile_ycsb --json read these."""
+        histograms) — the webserver /scheduler endpoint reads
+        these."""
         return {"enabled": self.scheduler.enabled(),
                 "lanes": self.scheduler.stats()}
 

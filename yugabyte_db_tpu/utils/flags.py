@@ -170,10 +170,6 @@ DEFINE_RUNTIME("streaming_chunk_rows", 1 << 20,
                "Target rows per streamed scan chunk; the chunk bucket "
                "is the pow2 ceiling, so every chunk of a scan shares "
                "one kernel-cache signature.")
-DEFINE_RUNTIME("tpu_pallas_scan", False,
-               "Route eligible aggregate scans through the hand-fused "
-               "pallas kernel (ops/pallas_scan.py) instead of the XLA "
-               "scan; f32 compute, so int64 columns stay on XLA.")
 DEFINE_RUNTIME("device_float_dtype", "auto",
                "Device representation of fractional f64 columns: 'auto' "
                "keeps f64 on CPU backends and ships f32 on TPU (SUMs stay "
@@ -265,12 +261,6 @@ DEFINE_RUNTIME("window_server_pushdown_enabled", True,
                "sorted rows with a typed reason and the client tier "
                "recomputes bit-identically; off disables the request "
                "shape entirely.")
-DEFINE_RUNTIME("tpch_sf", 10.0,
-               "Scale factor for the full-suite TPC-H device gauntlet "
-               "(bench.py tpch_full / profile_plan.py): rows = "
-               "6,000,000 x sf per lineitem clone. The BENCH_TPCH_SF "
-               "env knob overrides per run (smoke runs use 0.1; the "
-               "acceptance gauntlet runs 10).")
 DEFINE_RUNTIME("grouped_spill_merge_enabled", True,
                "Partial-spill merge for over-cardinality device GROUP "
                "BYs: slots below the spill slot keep their (exact) "
