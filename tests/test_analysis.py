@@ -2078,7 +2078,7 @@ class TestCacheKeyCompleteness:
         # registered, and the PR-9 regression input stays pinned
         from analyze.passes.cache_key_completeness import REGISTRY
         quals = {e["key_builder"][1] for e in REGISTRY}
-        assert {"DocReadOperation._batch_cache_key", "ScanKernel.run",
+        assert {"DocReadOperation._batch_cache_key", "prepare_launch",
                 "FusedPlanKernel.run"} <= quals
         batch = next(e for e in REGISTRY if e["key_builder"][1]
                      == "DocReadOperation._batch_cache_key")

@@ -75,61 +75,40 @@ def _compile(lower):
     return compiled
 
 
-def _consts(query, aggs):
-    from yugabyte_db_tpu.ops.expr import collect_constants
-    consts = []
-    if query.where is not None:
-        collect_constants(query.where, consts)
-    for a in aggs:
-        if a.expr is not None:
-            collect_constants(a.expr, consts)
-    return consts
-
-
 def _scan_args(query, n_total, mvcc_mode="visible"):
     """(avg-expanded aggs, static_sums, example args, example rows): the
-    argument list `ScanKernel.run` and `__graft_entry__.entry()` build,
-    on a small batch with the TPU arm's dtypes; the MVCC lanes
-    (`args[4]`) are those `mvcc_lanes` hands out for `mvcc_mode`.  A
-    described device can hold no array, so callers turn these into
-    shapes."""
+    argument list of a served launch — `ops.scan.prepare_launch`, which
+    `ScanKernel.run`, `DistributedScanKernel.run` and
+    `__graft_entry__.entry()` call — on a small batch with the TPU arm's
+    dtypes; the MVCC lanes (`args[4:7]`) are those `mvcc_lanes` hands
+    out for `mvcc_mode`.  A described device can hold no array, so
+    callers turn these into shapes."""
     from __graft_entry__ import _example_batch
     from yugabyte_db_tpu.ops.device_batch import build_batch
-    from yugabyte_db_tpu.ops.scan import (_expand_avg, _group_strategy,
-                                          _static_scales, mvcc_lanes)
-    aggs = tuple(_expand_avg(query.aggs))
+    from yugabyte_db_tpu.ops.scan import prepare_launch
     batch = build_batch(_example_batch(), sorted(query.columns),
                         multi_version=mvcc_mode == "linked")
     assert batch.cols[2].dtype == jnp.float32       # l_extendedprice
     assert batch.ht.dtype == jnp.uint64
-    assert _group_strategy() == "unroll"
-    static_sums, scale_args = _static_scales(
-        aggs, batch.col_bounds, n_total, batch.cols)
-    mode, lanes = mvcc_lanes(batch, 1 << 63)
+    _, (_, aggs, _, mode, static_sums, strategy), args = prepare_launch(
+        batch, query.where, query.aggs, query.group, 1 << 63, n_total=n_total)
+    assert strategy == "unroll"
     assert mode == mvcc_mode
-    assert (lanes[1] is not None) == (mode == "linked")
-    args = (batch.cols, batch.nulls,
-            [jnp.asarray(c) for c in _consts(query, aggs)],
-            batch.valid, lanes, jnp.uint64(1 << 63), scale_args)
+    assert (args[5] is not None) == (mode == "linked")
     return aggs, static_sums, args, batch.padded_rows
-
-
-def _flat(args):
-    """`args` as the single-chip kernel takes them: the lanes spliced
-    in place."""
-    return args[:4] + tuple(args[4]) + args[5:]
 
 
 def _shapes(tree, small: int, rows_shape, row_sharding, scalar_sharding):
     """`tree` as ShapeDtypeStructs: every `small`-row vector becomes
     `rows_shape` on `row_sharding`, anything else keeps its shape."""
     def one(x):
-        x = jnp.asarray(x)
+        x = jnp.asarray(x)      # a Python literal stays weakly typed
         if x.shape == (small,):
             return jax.ShapeDtypeStruct(rows_shape, x.dtype,
                                         sharding=row_sharding)
         return jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                    sharding=scalar_sharding)
+                                    sharding=scalar_sharding,
+                                    weak_type=x.weak_type)
     return jax.tree_util.tree_map(one, tree)
 
 
@@ -147,7 +126,7 @@ def test_scan_kernel_compiles(one_chip, tpu_arms, query_name, mvcc_mode):
                                                 mvcc_mode)
     fn = _build_kernel(query.where, aggs, query.group, mvcc_mode,
                        static_sums=static_sums, strategy="unroll")
-    shapes = _shapes(_flat(args), small, (SCAN_ROWS,), one_chip, one_chip)
+    shapes = _shapes(args, small, (SCAN_ROWS,), one_chip, one_chip)
     compiled = _compile(lambda: jax.jit(fn).lower(*shapes))
     text = compiled.as_text()
     assert "sort" not in text and "while" not in text
@@ -163,7 +142,7 @@ def test_sort_grouped_kernel_compiles(one_chip, tpu_arms):
     fn = _build_kernel(
         q.where, aggs, HashGroupSpec((tpch.RETFLAG, tpch.LINESTATUS)),
         "visible", static_sums=static_sums, strategy="unroll")
-    shapes = _shapes(_flat(args), small, (SCAN_ROWS,), one_chip, one_chip)
+    shapes = _shapes(args, small, (SCAN_ROWS,), one_chip, one_chip)
     assert "sort" in _compile(lambda: jax.jit(fn).lower(*shapes)).as_text()
 
 
@@ -265,7 +244,8 @@ def test_served_mesh_scan_compiles_for_four_chips(topo, tmp_path):
         if x.ndim == 3:
             return jax.ShapeDtypeStruct((4, 1, MESH_SHARD_ROWS), x.dtype,
                                         sharding=rows)
-        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=everywhere)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=everywhere,
+                                    weak_type=x.weak_type)
 
     for (sig, _, where, aggs, group, mode, static_sums, strategy), args \
             in seen:

@@ -227,8 +227,11 @@ def test_a_statement_is_one_rpc_one_gather_one_launch(answers, query):
         (scan,) = _named(spans, "device.scan")
         assert (scan.tags["mvcc"], scan.tags["chips"],
                 scan.tags["shards"]) == ("linked", 4, 4)
+        # the statement's literals, read_ht, one vector of scales and
+        # (Q1) one of dictionary sizes: 7 host values either way
+        assert scan.tags["host_args"] == 7
         (wait,) = _named(spans, "device.wait")
-        assert wait.tags["chips"] == 4
+        assert wait.tags["chips"] == 4 and wait.tags["reads"] == 1
         (combine,) = _named(spans, "client.combine")
         assert combine.tags["parts"] == 1
     # the miss builds, links per shard and ships; the hit does none of it
@@ -256,6 +259,9 @@ def test_with_one_chip_no_mesh_code_serves(answers, query):
     assert {s.tags["route"] for s in _named(spans, "docdb.read")} \
         == {"tpu_aggregate"}
     assert all("chips" not in s.tags for s in _named(spans, "device.scan"))
+    # the one-device launch goes through the same code: the same tags
+    assert {s.tags["host_args"] for s in _named(spans, "device.scan")} == {7}
+    assert {s.tags["reads"] for s in _named(spans, "device.wait")} == {1}
 
 
 def test_an_insert_after_the_batch_was_cached_is_in_the_next_answer():

@@ -411,6 +411,14 @@ class TestDeviceTelemetry:
         assert spans[0].tags["signature"] == spans[1].tags["signature"]
         assert spans[0].tags["bucket"] == batch.padded_rows
         assert spans[0].tags["rows"] == batch.n_rows
+        # what crosses the host-device boundary a launch: the host values
+        # the jitted call placed (one literal, read_ht, the scales) ...
+        assert [s.tags["host_args"] for s in spans] == [3, 3]
+        waits = [s for s in TRACES.recent
+                 if s.trace_id == t.trace_id and s.name == "device.wait"]
+        # ... and the transfers that brought the result back
+        assert [s.tags["reads"] for s in waits] == [1, 1]
+        assert {s.tags["thread"] for s in waits} == {"executor"}
 
     def test_no_spans_without_sampled_trace(self):
         from yugabyte_db_tpu.ops import AggSpec, scan_aggregate

@@ -194,5 +194,153 @@ class TestKernelCache:
         assert int(count) == np_mask.sum()
 
 
+class TestLaunchProtocol:
+    """One launch = one program and one read-back (`prepare_launch`,
+    `launch`): runtime scalars go into the jitted call as host values,
+    the result comes back in one transfer, the row mask stays on the
+    device."""
+
+    AGGS = (AggSpec("sum", (C(2) * (Expr.const(1) - C(3))).node),
+            AggSpec("avg", C(1).node), AggSpec("count"))
+
+    @staticmethod
+    def _batch(seed=0, n=1000):
+        import dataclasses
+        blk, d = make_block(n=n, seed=seed)
+        batch = build_batch([blk], [1, 2, 3, 4])
+        # column 4 doubles as codes of a three-word dictionary
+        return dataclasses.replace(
+            batch, dicts={4: np.array(["a", "b", "c"], object)}), d
+
+    @staticmethod
+    def _group(kind):
+        from yugabyte_db_tpu.ops.grouped_scan import DictGroupSpec
+        from yugabyte_db_tpu.ops.scan import HashGroupSpec
+        return {"none": None, "dense": GroupSpec(cols=((4, 3, 0),)),
+                "dict": DictGroupSpec(cols=(4,)),
+                "hash": HashGroupSpec((4,), max_groups=8)}[kind]
+
+    @staticmethod
+    def _recording(seen):
+        """A ScanKernel whose `_get` hands out programs that note the
+        argument list they were called with."""
+        class Recording(ScanKernel):
+            def _get(self, *key):
+                fn = super()._get(*key)
+
+                def call(*args):
+                    seen.append(args)
+                    return fn(*args)
+                return call
+        return Recording()
+
+    @pytest.mark.parametrize("kind", ["none", "dense", "dict", "hash"])
+    def test_runtime_scalars_are_host_values(self, kind):
+        import jax
+        seen = []
+        kern = self._recording(seen)
+        batch, _ = self._batch()
+        where = ((C(1) < 24.0) & (C(4) >= 1)).node
+        for _ in range(2):          # the second launch is warm
+            kern.run(batch, where, self.AGGS, self._group(kind), 20)
+        assert kern.compiles == 1
+        cols, nulls, consts, valid, ht, next_ht, tomb, read_ht, scales, \
+            domains = seen[-1]
+        # what the batch keeps on the device goes in as it is ...
+        assert all(isinstance(x, jax.Array)
+                   for x in (*cols.values(), valid, ht, tomb))
+        # ... and nothing else is put there ahead of the call
+        runtime = jax.tree_util.tree_leaves(
+            (consts, read_ht, scales, domains))
+        assert runtime and not any(isinstance(x, jax.Array)
+                                   for x in runtime)
+        assert consts == [24.0, 1, 1] and all(
+            type(c) in (int, float) for c in consts)   # weak: as written
+        assert type(read_ht) is np.uint64 and read_ht == 20
+        # one vector of scales (AVG expanded: sum, sum, count, count) and
+        # one of dictionary sizes
+        assert isinstance(scales, np.ndarray) and \
+            (scales.dtype, scales.shape) == (np.float32, (4,))
+        assert scales[0] > 0 and scales[1] > 0 and not scales[2:].any()
+        if kind == "dict":
+            assert isinstance(domains, np.ndarray) and \
+                (domains.dtype, domains.tolist()) == (np.int32, [3])
+        else:
+            assert domains == ()
+
+    def test_latest_read_point_fits(self):
+        seen = []
+        kern = self._recording(seen)
+        batch, d = self._batch()
+        (cnt,), _, _ = kern.run(batch, None, (AggSpec("count"),))
+        assert seen[-1][7] == np.uint64(0xFFFFFFFFFFFFFFFF)
+        assert int(cnt) == len(d["qty"])
+
+    @pytest.mark.parametrize("kind", ["none", "dense", "dict", "hash"])
+    def test_one_read_back_host_result_device_mask(self, kind, monkeypatch):
+        import jax
+        from yugabyte_db_tpu.utils.trace import TRACES
+        batch, d = self._batch()
+        kern = ScanKernel()
+        kern.run(batch, None, self.AGGS, self._group(kind), 20)   # warm
+        reads = []
+        real = jax.device_get
+        monkeypatch.setattr(jax, "device_get",
+                            lambda x: reads.append(x) or real(x))
+        with TRACES.trace("launch") as t:
+            got = kern.run(batch, None, self.AGGS, self._group(kind), 20)
+        monkeypatch.undo()
+        assert len(reads) == 1
+        # the transfer carried everything but the mask
+        assert not any(getattr(x, "shape", None) == batch.valid.shape
+                       for x in jax.tree_util.tree_leaves(reads[0]))
+        outs, counts, mask, *rest = got
+        assert isinstance(mask, jax.Array) and mask.shape == batch.valid.shape
+        host = jax.tree_util.tree_leaves((outs, counts, rest))
+        assert host and all(isinstance(x, (np.ndarray, np.generic))
+                            for x in host)
+        assert len(rest) == {"none": 0, "dense": 0, "dict": 1,
+                             "hash": 2}[kind]
+        assert int(np.sum(counts)) == len(d["qty"]) == int(np.sum(outs[-1]))
+        spans = {s.name: s for s in TRACES.recent
+                 if s.trace_id == t.trace_id}
+        assert spans["device.wait"].tags["reads"] == 1
+        assert spans["device.scan"].tags["host_args"] == \
+            3 + (kind == "dict")       # one literal, read_ht, scales[, sizes]
+
+    @pytest.mark.parametrize("kind", ["none", "dict"])
+    def test_other_literals_read_ht_and_bounds_do_not_compile(self, kind):
+        import dataclasses
+        kern = ScanKernel()
+        batch, d = self._batch(seed=0)
+        words = np.array(list("abcdefg"), object)
+        batch = dataclasses.replace(batch, dicts={4: words[:5]})
+        group = self._group(kind)
+
+        def run(batch, d, threshold, read_ht):
+            outs, counts, *_ = kern.run(
+                batch, (C(1) < threshold).node,
+                (AggSpec("sum", C(2).node), AggSpec("count")), group,
+                read_ht)
+            m = (d["qty"] < threshold) & (d["ht"] <= read_ht)
+            assert int(np.sum(counts)) == m.sum()
+            np.testing.assert_allclose(np.sum(outs[0]), d["price"][m].sum(),
+                                       rtol=1e-9)
+        run(batch, d, 10.0, 20)
+        assert kern.compiles == 1
+        run(batch, d, 33.5, 5)                    # literals, read point
+        # other rows: other column bounds, so other SUM scales
+        blk, d2 = make_block(n=900, seed=9)
+        blk.fixed[2] = (blk.fixed[2][0] * 1000.0, blk.fixed[2][1])
+        d2["price"] = d2["price"] * 1000.0
+        other = dataclasses.replace(
+            build_batch([blk], [1, 2, 3, 4]),
+            dicts={4: words})
+        assert other.col_bounds[2] != batch.col_bounds[2]
+        # ... and a dictionary that grew inside its slot bucket
+        run(other, d2, 12.0, 1 << 40)
+        assert kern.compiles == 1
+
+
 def col_expr(cid):
     return C(cid).node

@@ -96,6 +96,117 @@ class TestDistributedScan:
         assert kern.compiles == 1
 
 
+class TestMeshLaunchProtocol:
+    """The mesh kernel launches through `ops.scan.prepare_launch` /
+    `launch`, as the one-device kernel does: same argument list, same
+    weakly typed literals, one read-back — so the two give the same
+    bits."""
+
+    @staticmethod
+    def _lineitem(rows_a_shard, shards):
+        from yugabyte_db_tpu.docdb.table_codec import TableCodec
+        from yugabyte_db_tpu.models.tpch import (generate_lineitem,
+                                                 lineitem_info)
+        from yugabyte_db_tpu.utils.hybrid_time import HybridTime
+        data = generate_lineitem(0.004, seed=5)
+        assert len(data["rowid"]) >= rows_a_shard * shards
+        codec = TableCodec(lineitem_info())
+        return [codec.bulk_blocks(
+            {k: v[i * rows_a_shard:(i + 1) * rows_a_shard]
+             for k, v in data.items()}, HybridTime.from_micros(100))
+            for i in range(shards)]
+
+    @pytest.mark.parametrize("float_dtype", ["float32", "float64"])
+    @pytest.mark.parametrize("query_name", ["q6", "q1"])
+    def test_mesh_result_is_the_one_device_result(self, query_name,
+                                                  float_dtype):
+        from yugabyte_db_tpu.models import tpch
+        from yugabyte_db_tpu.ops.device_batch import build_batch
+        from yugabyte_db_tpu.ops.scan import ScanKernel, prepare_launch
+        from yugabyte_db_tpu.utils import flags
+        from yugabyte_db_tpu.utils.hybrid_time import HybridTime
+        q = {"q6": tpch.TPCH_Q6, "q1": tpch.TPCH_Q1}[query_name]
+        read_ht = HybridTime.from_micros(10_000).value
+        flags.set_flag("device_float_dtype", float_dtype)
+        try:
+            # 4 shards of one 4,096-row bucket = one batch of 16,384: both
+            # kernels quantize their SUMs over the same row count
+            per_shard = self._lineitem(3000, 4)
+            tm = tablet_mesh(num_tablet_shards=4)
+            mesh = build_sharded_batch(tm, per_shard, sorted(q.columns))
+            one = build_batch([b for blocks in per_shard for b in blocks],
+                              sorted(q.columns))
+        finally:
+            flags.REGISTRY.reset("device_float_dtype")
+        assert mesh.padded_rows * mesh.num_shards == one.padded_rows
+        assert str(one.cols[tpch.EXTPRICE].dtype) == float_dtype
+        a = prepare_launch(one, q.where, q.aggs, q.group, read_ht)
+        b = prepare_launch(mesh, q.where, q.aggs, q.group, read_ht,
+                           n_total=one.padded_rows)
+        # the same program (over other row counts) and runtime scalars
+        assert a[1] == b[1]
+        assert a[2][2] == b[2][2] and a[2][7] == b[2][7]
+        np.testing.assert_array_equal(a[2][8], b[2][8])
+        outs1, counts1, _ = ScanKernel().run(one, q.where, q.aggs, q.group,
+                                             read_ht)
+        outs4, counts4 = DistributedScanKernel().run(
+            mesh, q.where, q.aggs, q.group, read_ht)
+        assert int(np.sum(counts1)) > 0
+        np.testing.assert_array_equal(counts1, counts4)
+        assert len(outs1) == len(outs4) > 0
+        for x, y in zip(outs1, outs4):
+            assert x.dtype == y.dtype
+            np.testing.assert_array_equal(x, y)
+
+    def test_host_values_in_one_read_back_out(self, monkeypatch):
+        from yugabyte_db_tpu.utils.trace import TRACES
+        seen = []
+
+        class Recording(DistributedScanKernel):
+            def _get(self, *key):
+                fn = super()._get(*key)
+
+                def call(*args):
+                    seen.append(args)
+                    return fn(*args)
+                return call
+        tm = tablet_mesh(num_tablet_shards=8)
+        batch = build_sharded_batch(
+            tm, [[shard_block(200, seed=400 + s)[0]] for s in range(8)],
+            [1, 4])
+        kern = Recording()
+        where = ((C(1) < 25.0) & (C(4) >= 1)).node
+        aggs = (AggSpec("sum", C(1).node), AggSpec("count"))
+        kern.run(batch, where, aggs, GroupSpec(cols=((4, 4, 0),)), 20)
+        reads = []
+        real = jax.device_get
+        monkeypatch.setattr(jax, "device_get",
+                            lambda x: reads.append(x) or real(x))
+        with TRACES.trace("mesh-launch") as t:
+            outs, counts = kern.run(batch, where, aggs,
+                                    GroupSpec(cols=((4, 4, 0),)), 1 << 40)
+        monkeypatch.undo()
+        assert kern.compiles == 1 and len(reads) == 1
+        assert all(isinstance(x, np.ndarray) for x in (*outs, counts))
+        cols, nulls, consts, valid, ht, next_ht, tomb, read_ht, scales, \
+            domains = seen[-1]
+        assert consts == [25.0, 1] and \
+            [type(c) for c in consts] == [float, int]
+        assert type(read_ht) is np.uint64 and read_ht == 1 << 40
+        assert isinstance(scales, np.ndarray) and \
+            (scales.dtype, scales.shape) == (np.float32, (2,))
+        assert domains == () and next_ht is None and ht is not None
+        assert not any(isinstance(x, jax.Array) for x in
+                       jax.tree_util.tree_leaves((consts, read_ht, scales)))
+        spans = {s.name: s for s in TRACES.recent
+                 if s.trace_id == t.trace_id}
+        assert spans["device.scan"].tags["host_args"] == 4
+        assert (spans["device.scan"].tags["chips"],
+                spans["device.scan"].tags["shards"]) == (8, 8)
+        assert spans["device.wait"].tags["reads"] == 1
+        assert spans["device.wait"].tags["chips"] == 8
+
+
 class TestShardedVector:
     def test_global_topk_matches_local(self):
         tm = tablet_mesh(num_tablet_shards=4, num_block_shards=2)

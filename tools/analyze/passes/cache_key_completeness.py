@@ -143,8 +143,12 @@ REGISTRY: Tuple[dict, ...] = (
         ],
     },
     {
-        "key_builder": (f"{_OPS}/scan.py", "ScanKernel.run"),
-        "roots": [(f"{_OPS}/scan.py", "ScanKernel.run")],
+        # one signature for both scan kernels (the mesh kernel prefixes
+        # its mesh's identity)
+        "key_builder": (f"{_OPS}/scan.py", "prepare_launch"),
+        "roots": [(f"{_OPS}/scan.py", "ScanKernel.run"),
+                  ("yugabyte_db_tpu/parallel/distributed_scan.py",
+                   "DistributedScanKernel.run")],
         "key_helpers": [],
         "allow": {
             "scan_group_strategy": "resolved value `strategy` is a "
@@ -155,6 +159,8 @@ REGISTRY: Tuple[dict, ...] = (
             ("strategy", "grouped-path choice bakes into the kernel"),
             ("col_sig", "column dtype/shape identity"),
             ("mvcc_mode", "visibility mode changes the kernel body"),
+            ("static_sums", "const-folded sum lanes change the body"),
+            ("padded_rows", "pow2 pad bucket is a compile-time shape"),
         ],
     },
     {

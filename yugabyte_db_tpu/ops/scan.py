@@ -570,72 +570,107 @@ class ScanKernel:
         """Returns (agg_results tuple, count_or_group_counts, mask).
         HashGroupSpec adds (group_values, n_groups); DictGroupSpec adds
         a trailing spill count (nonzero = slot overflow, the caller
-        must revert to the interpreted GROUP BY)."""
-        aggs = tuple(_expand_avg(aggs))
-        mvcc_mode, lanes = mvcc_lanes(batch, read_ht)
-        consts: List = []
-        if where is not None:
-            collect_constants(where, consts)
-        for a in aggs:
-            if a.expr is not None:
-                collect_constants(a.expr, consts)
-        domain_args: tuple = ()
-        if isinstance(group, DictGroupSpec):
-            # resolve against the batch's scan-global dictionaries: the
-            # pow2 slot bucket is static (kernel signature), dictionary
-            # sizes are runtime scalars (growth inside one bucket never
-            # recompiles).  KeyError = a group column with no dictionary
-            # (caller falls back).
-            group, domains = resolve_group(group, batch.dicts)
-            domain_args = tuple(jnp.int32(d) for d in domains)
-        col_sig = tuple(sorted(
-            (cid, str(v.dtype)) for cid, v in batch.cols.items()))
-        static_sums, scale_args = _static_scales(
-            aggs, batch.col_bounds, batch.padded_rows, batch.cols)
-        strategy = _group_strategy()
-        sig = (
-            expr_signature(where) if where is not None else None,
-            tuple(a.signature() for a in aggs),
-            (type(group).__name__, group.cols,
-             getattr(group, "max_groups",
-                     getattr(group, "num_slots", None))) if group
-            else None,
-            mvcc_mode, batch.padded_rows, col_sig, static_sums, strategy,
-        )
-        from ..utils import trace as _trace
+        must revert to the interpreted GROUP BY).  Everything but the
+        mask is a host value (`launch`)."""
+        sig, key, args = prepare_launch(batch, where, aggs, group, read_ht)
+        _, _, resolved, mvcc_mode, _, _ = key
         pre = self.compiles
-        fn = self._get(sig, where, aggs, group, mvcc_mode, static_sums,
-                       strategy)
-        compiled = self.compiles > pre
-        if isinstance(group, ResolvedDictGroup):
+        fn = self._get(sig, *key)
+        if isinstance(resolved, ResolvedDictGroup):
             from .grouped_scan import GROUPED_STATS
             GROUPED_STATS["launches"] += 1
-        with _trace.device_span("scan", signature=sig, compiled=compiled,
-                                bucket=batch.padded_rows,
-                                rows=batch.n_rows, mvcc=mvcc_mode):
-            raw = fn(
-                batch.cols, batch.nulls,
-                [jnp.asarray(c) for c in consts], batch.valid, *lanes,
-                jnp.uint64(read_ht if read_ht is not None
-                           else 0xFFFFFFFFFFFFFFFF),
-                scale_args, domain_args,
-            )
-        # (outs, scales, counts, mask[, gvals, n_groups | spill]) ->
-        # rescale the fixed-point sums host-side; callers keep the
-        # historical shape (outs, counts, mask[, ...]).  The rescale is
-        # the first host read of the result, so this is where the host
-        # waits for the device: the `device.wait` span, and
-        # `Device_BlockUntilReady` for ASH.  A sampled span waits for
-        # every output first, so it holds the whole wait whatever the
-        # rescale reads.
-        with _trace.wait_status("Device_BlockUntilReady",
-                                component="device"), \
-                _trace.TRACES.span("device.wait", child_only=True) as sp:
-            if sp.sampled:
-                sp.set_tag("thread", _thread_kind())
-                jax.block_until_ready(raw)
-            outs = _rescale_outs(raw[0], raw[1])
-        return (outs,) + tuple(raw[2:])
+        return launch(fn, sig, args, batch, mvcc_mode, self.compiles > pre,
+                      mask=True)
+
+
+def prepare_launch(batch, where, aggs, group, read_ht, n_total=None):
+    """What one launch is made of, for either kernel — a `DeviceBatch`
+    here, a `ShardedBatch` in parallel/distributed_scan.py, whose SUMs
+    run over `n_total` = rows x shards.  Returns (sig, key, args):
+
+    - key = (where, aggs, group, mvcc_mode, static_sums, strategy), what
+      a kernel's `_get` builds the program from: AVG expanded, a
+      DictGroupSpec resolved against the batch's scan-global
+      dictionaries (the pow2 slot bucket is static; KeyError = a group
+      column with no dictionary, the caller falls back);
+    - sig, the structural signature that names the program in a cache;
+    - args, the jitted call's argument list.  Every runtime scalar in it
+      is a HOST value, placed by the one jitted call and by no program
+      of its own: the literals as the Python scalars they are (weakly
+      typed, so a compare runs in the lane's own type), array constants
+      (`dictlut` tables) as numpy arrays, `read_ht` as np.uint64 (all
+      ones does not fit a weak int64), the SUM scales as ONE float32
+      vector and the dictionary sizes as ONE int32 vector — so other
+      literals, bounds or dictionary sizes never recompile."""
+    aggs = tuple(_expand_avg(aggs))
+    mvcc_mode, lanes = mvcc_lanes(batch, read_ht)
+    consts: List = []
+    if where is not None:
+        collect_constants(where, consts)
+    for a in aggs:
+        if a.expr is not None:
+            collect_constants(a.expr, consts)
+    domains = ()
+    if isinstance(group, DictGroupSpec):
+        group, sizes = resolve_group(group, batch.dicts)
+        domains = np.asarray(sizes, np.int32)
+    col_sig = tuple(sorted(
+        (cid, str(v.dtype)) for cid, v in batch.cols.items()))
+    static_sums, scales = _static_scales(
+        aggs, batch.col_bounds, n_total or batch.padded_rows, batch.cols)
+    strategy = _group_strategy()
+    sig = (
+        expr_signature(where) if where is not None else None,
+        tuple(a.signature() for a in aggs),
+        (type(group).__name__, group.cols,
+         getattr(group, "max_groups", getattr(group, "num_slots", None)))
+        if group else None,
+        mvcc_mode, batch.padded_rows, col_sig, static_sums, strategy,
+    )
+    args = (batch.cols, batch.nulls, consts, batch.valid, *lanes,
+            np.uint64(0xFFFFFFFFFFFFFFFF if read_ht is None else read_ht),
+            scales, domains)
+    return sig, (where, aggs, group, mvcc_mode, static_sums, strategy), args
+
+
+def launch(fn, sig, args, batch, mvcc_mode: str, compiled: bool,
+           mask: bool, tags=()):
+    """Dispatch `fn(*args)` (the `device.scan` span: tag `host_args` =
+    how many host values the call placed) and read its result back in
+    ONE transfer (`device.wait`: tag `reads`), `tags` on both spans.
+    `fn` returns (outs, scales, counts[, mask], ...): the fixed-point
+    sums are rescaled on the host values and the caller gets (outs,
+    counts[, mask], ...) — all host values but the row mask, which stays
+    a device array (only the filter route and the spill merge read it).
+    The read-back is the first host read of the result, so this is
+    where the host waits for the device (`Device_BlockUntilReady` for
+    ASH); a sampled span waits for every output first, so it holds the
+    whole wait whatever the transfer covers."""
+    from ..utils import trace as _trace
+    with _trace.device_span("scan", signature=sig, compiled=compiled,
+                            bucket=batch.padded_rows, rows=batch.n_rows,
+                            mvcc=mvcc_mode) as sp:
+        if sp is not None:
+            sp.set_tag("host_args", sum(
+                not isinstance(x, jax.Array)
+                for x in jax.tree_util.tree_leaves(args)))
+            for k, v in tags:
+                sp.set_tag(k, v)
+        raw = fn(*args)
+    with _trace.wait_status("Device_BlockUntilReady",
+                            component="device"), \
+            _trace.TRACES.span("device.wait", child_only=True) as sp:
+        if sp.sampled:
+            sp.set_tag("thread", _thread_kind())
+            sp.set_tag("reads", 1)
+            for k, v in tags:
+                sp.set_tag(k, v)
+            jax.block_until_ready(raw)
+        on_device = raw[3:4] if mask else ()
+        outs, scales, counts, *rest = jax.device_get(
+            raw[:3] + raw[3 + len(on_device):])
+        outs = _rescale_outs(outs, scales)
+    return (outs, counts, *on_device, *rest)
 
 
 def _thread_kind() -> str:
@@ -650,20 +685,18 @@ def _thread_kind() -> str:
 
 def _static_scales(aggs: Sequence[AggSpec],
                    col_bounds: Dict[int, Tuple[float, float]],
-                   n_total: int, cols=None, host: bool = False):
+                   n_total: int, cols=None):
     """Per-agg static fixed-point scales from host column stats.
-    Returns (static_flags, scale_args) — scale_args are runtime jnp
-    scalars (0.0 placeholders for non-static entries) so changing data
-    bounds never recompiles the kernel (`host`: numpy scalars instead,
-    for a launch that places its arguments itself). `cols` (col_id ->
-    device array)
-    supplies dtypes: expressions touching f32 columns cap every
-    intermediate interval at the f32 finite range, since an f32 product
-    can overflow to Inf on device even when the final bound is small
-    and the static path has no Inf fallback lane."""
+    Returns (static_flags, scales) — scales is ONE float32 vector on the
+    host, an entry an aggregate (0.0 for a non-static one) and a runtime
+    argument, so changing data bounds never recompiles the kernel.
+    `cols` (col_id -> device array) supplies dtypes: expressions
+    touching f32 columns cap every intermediate interval at the f32
+    finite range, since an f32 product can overflow to Inf on device
+    even when the final bound is small and the static path has no Inf
+    fallback lane."""
     from .expr import expr_bound, referenced_columns
     flags_, scales = [], []
-    f32 = np.float32 if host else jnp.float32
     for a in aggs:
         s = None
         if a.op == "sum" and a.expr is not None and col_bounds:
@@ -681,8 +714,8 @@ def _static_scales(aggs: Sequence[AggSpec],
             if b is not None:
                 s = _scale_for(max(abs(b[0]), abs(b[1])), n_total)
         flags_.append(s is not None)
-        scales.append(f32(s if s is not None else 0.0))
-    return tuple(flags_), tuple(scales)
+        scales.append(0.0 if s is None else s)
+    return tuple(flags_), np.asarray(scales, np.float32)
 
 
 def _expand_avg(aggs: Sequence[AggSpec]) -> List[AggSpec]:

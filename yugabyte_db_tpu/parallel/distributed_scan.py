@@ -20,12 +20,9 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.device_batch import (HT_NONE, batch_bytes, bucket_rows,
                                 f64_conversion, link_versions)
-from ..ops.expr import collect_constants, expr_signature
-from ..ops.grouped_scan import DictGroupSpec, resolve_group
-from ..ops.scan import (
-    AggSpec, GroupSpec, _build_kernel, _expand_avg, _group_strategy,
-    _rescale_outs, _static_scales, _thread_kind, mvcc_lanes,
-)
+from ..ops.grouped_scan import DictGroupSpec
+from ..ops.scan import (AggSpec, GroupSpec, _build_kernel, launch,
+                        prepare_launch)
 from ..storage.columnar import ColumnarBlock
 from ..utils import trace as _trace
 from .mesh import BLOCKS_AXIS, TABLETS_AXIS, TabletMesh
@@ -254,15 +251,16 @@ class DistributedScanKernel:
                               axis_names=axes, row_multiplier=S,
                               static_sums=static_sums, strategy=strategy)
 
-        def shard_fn(cols, nulls, consts, valid, lanes, read_ht,
-                     sum_scales, domains):
+        def shard_fn(cols, nulls, consts, valid, ht, next_ht, tombstone,
+                     read_ht, sum_scales, domains):
             # local shard view: [1, 1, N] -> [N]; a lane the mode does
             # not read is None
             sq = lambda a: None if a is None else a.reshape(a.shape[-1])
             lcols = {k: sq(v) for k, v in cols.items()}
             lnulls = {k: sq(v) for k, v in nulls.items()}
-            got = local(lcols, lnulls, consts, sq(valid), *map(sq, lanes),
-                        read_ht, sum_scales, domains)
+            got = local(lcols, lnulls, consts, sq(valid), sq(ht),
+                        sq(next_ht), sq(tombstone), read_ht, sum_scales,
+                        domains)
             outs, scales, counts = got[:3]
             # a dictionary-grouped kernel also counts the rows whose
             # group fell past its slot budget: they add up like a count
@@ -294,16 +292,15 @@ class DistributedScanKernel:
         spec3 = P(TABLETS_AXIS, BLOCKS_AXIS, None)
         in_specs = (
             {k: spec3 for k in sig_cols(sig)}, {k: spec3 for k in sig_cols(sig)},
-            P(), spec3, spec3, P(), P(), P())
+            P(), spec3, spec3, spec3, spec3, P(), P(), P())
         smapped = jax.shard_map(
             shard_fn, mesh=tm.mesh, in_specs=in_specs,
             out_specs=(tuple(P() for _ in aggs), tuple(P() for _ in aggs),
                        P(), P()), check_vma=False)
 
-        def mesh_scan(cols, nulls, consts, valid, lanes, read_ht,
-                      sum_scales, domains=()):
-            return smapped(cols, nulls, consts, valid, lanes, read_ht,
-                           sum_scales, domains)
+        def mesh_scan(*args):
+            """The argument list `ops.scan.prepare_launch` makes."""
+            return smapped(*args)
         # a stable program name, as `ScanKernel._get` gives the
         # single-device program: jit_mesh_scan_linked_resolveddictgroup
         mesh_scan.__name__ = mesh_scan.__qualname__ = "_".join(
@@ -323,67 +320,18 @@ class DistributedScanKernel:
         combined over the shards on the device; a DictGroupSpec adds the
         spill count (nonzero = slot overflow: the caller must fall
         back)."""
-        aggs = tuple(_expand_avg(aggs))
-        mvcc_mode, lanes = mvcc_lanes(batch, read_ht)
-        consts: List = []
-        if where is not None:
-            collect_constants(where, consts)
-        for a in aggs:
-            if a.expr is not None:
-                collect_constants(a.expr, consts)
-        dict_group = isinstance(group, DictGroupSpec)
-        domains: tuple = ()
-        if dict_group:
-            # as ScanKernel.run: the pow2 slot bucket is static, the
-            # dictionary sizes are runtime scalars.  The dictionaries
-            # are the batch's, global over the shards
-            group, sizes = resolve_group(group, batch.dicts)
-            domains = tuple(np.int32(d) for d in sizes)
-        col_sig = tuple(sorted(
-            (cid, str(v.dtype)) for cid, v in batch.cols.items()))
         tm = batch.mesh
-        # runtime scalars go in as host values: the launch replicates
-        # them over the mesh itself, no program of its own for each
-        static_sums, scale_args = _static_scales(
-            aggs, batch.col_bounds,
-            batch.padded_rows * batch.num_shards, batch.cols, host=True)
-        strategy = _group_strategy()
-        sig = (
-            id(tm.mesh), expr_signature(where) if where is not None else None,
-            tuple(a.signature() for a in aggs),
-            (type(group).__name__, group.cols,
-             getattr(group, "num_slots", None)) if group else None,
-            mvcc_mode,
-            batch.padded_rows, col_sig, static_sums, strategy,
-        )
+        sig, key, args = prepare_launch(
+            batch, where, aggs, group, read_ht,
+            n_total=batch.padded_rows * batch.num_shards)
+        sig, mvcc_mode = (id(tm.mesh),) + sig, key[3]
         pre = self.compiles
-        fn = self._get(sig, tm, where, aggs, group, mvcc_mode,
-                       static_sums, strategy)
-        with _trace.device_span("scan", signature=sig,
-                                compiled=self.compiles > pre,
-                                bucket=batch.padded_rows, rows=batch.n_rows,
-                                mvcc=mvcc_mode) as sp:
-            if sp is not None:
-                sp.set_tag("chips", tm.mesh.devices.size)
-                sp.set_tag("shards", batch.num_shards)
-            raw = fn(
-                batch.cols, batch.nulls,
-                [np.asarray(c) for c in consts], batch.valid, lanes,
-                np.uint64(read_ht if read_ht is not None
-                          else 0xFFFFFFFFFFFFFFFF),
-                scale_args, domains)
-        # one read-back of the whole (replicated) result; the rescale
-        # then works on host values.  `device.wait` is the host's wait
-        # for the mesh program, as on the single-device path
-        with _trace.wait_status("Device_BlockUntilReady",
-                                component="device"), \
-                _trace.TRACES.span("device.wait", child_only=True) as sp:
-            if sp.sampled:
-                sp.set_tag("thread", _thread_kind())
-                sp.set_tag("chips", tm.mesh.devices.size)
-            outs, scales, counts, spilled = jax.device_get(raw)
-            outs = _rescale_outs(outs, scales)
-        if dict_group:
+        fn = self._get(sig, tm, *key)
+        outs, counts, spilled = launch(
+            fn, sig, args, batch, mvcc_mode, self.compiles > pre, mask=False,
+            tags=(("chips", tm.mesh.devices.size),
+                  ("shards", batch.num_shards)))
+        if isinstance(group, DictGroupSpec):
             return outs, counts, int(spilled)
         return outs, counts
 
