@@ -21,8 +21,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
-    SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
 
 from yugabyte_db_tpu.utils import flags
 
@@ -181,10 +180,8 @@ def test_distributed_scan_compiles_for_four_chips(topo, tpu_arms):
            static_sums, "unroll")
     fn = DistributedScanKernel()._get(sig, tm, q.where, aggs, q.group,
                                       "visible", static_sums, "unroll")
-    shapes = _shapes(
-        args, small, (4, 1, SCAN_ROWS),
-        NamedSharding(tm.mesh, P(TABLETS_AXIS, BLOCKS_AXIS, None)),
-        NamedSharding(tm.mesh, P()))
+    shapes = _shapes(args, small, (4 * SCAN_ROWS,), tm.row_sharding(),
+                     tm.replicated())
     compiled = _compile(lambda: fn.lower(*shapes))
     assert "all-reduce" in compiled.as_text()
 
@@ -197,8 +194,9 @@ def test_served_mesh_scan_compiles_for_four_chips(topo, tmp_path):
     """The programs one tserver that owns four chips launches for Q6 and
     Q1 through SQL (`docdb/mesh_read.py`): `linked` mask with `next_ht`
     per shard, float64 lanes, Q1 grouped by two text columns as codes of
-    a global dictionary, every additive partial in one all-reduce — at
-    the shard size of `mesh4_q1_psum`, on a mesh of the described chips.
+    a global dictionary (its body a loop over row tiles), every additive
+    partial in one all-reduce — at the shard size of `mesh4_q1_psum`, on
+    a mesh of the described chips.
     The statements run here first, on four of the CPU's devices, to take
     each program's own arguments; what is compiled is the TPU's arm
     (`unroll`)."""
@@ -236,13 +234,13 @@ def test_served_mesh_scan_compiles_for_four_chips(topo, tmp_path):
     assert [key[5] for key, _ in seen] == ["linked", "linked"]
     tm = TabletMesh(Mesh(np.array(topo.devices).reshape(4, 1),
                          (TABLETS_AXIS, BLOCKS_AXIS)))
-    rows = NamedSharding(tm.mesh, P(TABLETS_AXIS, BLOCKS_AXIS, None))
-    everywhere = NamedSharding(tm.mesh, P())
+    rows, everywhere = tm.row_sharding(), tm.replicated()
+    small = seen[0][1][3].shape[0]      # `valid`: the four shards' rows
 
     def shape(x):
         x = jnp.asarray(x)
-        if x.ndim == 3:
-            return jax.ShapeDtypeStruct((4, 1, MESH_SHARD_ROWS), x.dtype,
+        if x.shape == (small,):
+            return jax.ShapeDtypeStruct((4 * MESH_SHARD_ROWS,), x.dtype,
                                         sharding=rows)
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=everywhere,
                                     weak_type=x.weak_type)
@@ -260,7 +258,14 @@ def test_served_mesh_scan_compiles_for_four_chips(topo, tmp_path):
         text = compiled.as_text()
         assert text.count("all-reduce(") + text.count("all-reduce-start(") \
             == 1, "every additive partial rides one all-reduce"
-        assert "sort" not in text and "while" not in text
+        # Q6 runs whole, Q1's grouped body in one loop over four row
+        # tiles (`ops.scan.tile_count`)
+        assert "sort" not in text
+        assert text.count(" while(") == (group is not None)
+        # a shard's bool lane is the one-device kernel's packed [N]
+        # lane, one byte a row, not a [1, 1, N] array padded to four
+        assert f"pred[{MESH_SHARD_ROWS}]" in text
+        assert f"pred[1,1,{MESH_SHARD_ROWS}]" not in text
         mem = compiled.memory_analysis()
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
             < 8 << 30

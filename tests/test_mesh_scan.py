@@ -230,6 +230,8 @@ def test_a_statement_is_one_rpc_one_gather_one_launch(answers, query):
         # the statement's literals, read_ht, one vector of scales and
         # (Q1) one of dictionary sizes: 7 host values either way
         assert scan.tags["host_args"] == 7
+        # a shard of two 1,500-row tablets is one tile of the kernel
+        assert scan.tags["tiles"] == 1
         (wait,) = _named(spans, "device.wait")
         assert wait.tags["chips"] == 4 and wait.tags["reads"] == 1
         (combine,) = _named(spans, "client.combine")
@@ -261,6 +263,7 @@ def test_with_one_chip_no_mesh_code_serves(answers, query):
     assert all("chips" not in s.tags for s in _named(spans, "device.scan"))
     # the one-device launch goes through the same code: the same tags
     assert {s.tags["host_args"] for s in _named(spans, "device.scan")} == {7}
+    assert {s.tags["tiles"] for s in _named(spans, "device.scan")} == {1}
     assert {s.tags["reads"] for s in _named(spans, "device.wait")} == {1}
 
 
@@ -430,3 +433,80 @@ def test_cache_accounts_and_evicts_per_chip():
     cache.invalidate_prefix((id(tablets[2].regular),))
     assert all(not isinstance(k[0], tuple) for k in cache._map)
     assert sum(cache.bytes_by_chip().values()) == cache._bytes
+
+
+# --- a shard longer than the kernel's row tile --------------------------------
+@pytest.mark.parametrize("shape", ["q6", "q1_dense", "q1_dict"])
+def test_a_long_shard_runs_in_row_tiles(shape, monkeypatch):
+    """Four shards of 4,096 padded rows under a tile of 1,024: for a
+    grouped scan each chip loops over four tiles and adds their
+    partials, then ONE all-reduce adds the chips' — the bits of the
+    whole program, and of the one-device kernel over the same rows
+    (sixteen tiles).  Q6 has no group: whole on either kernel."""
+    import dataclasses
+    from yugabyte_db_tpu.docdb.table_codec import TableCodec
+    from yugabyte_db_tpu.models import tpch as model
+    from yugabyte_db_tpu.ops import scan as scan_mod
+    from yugabyte_db_tpu.ops.scan import ScanKernel
+    from yugabyte_db_tpu.parallel import tablet_mesh
+    from yugabyte_db_tpu.parallel.distributed_scan import (
+        DistributedScanKernel, build_sharded_batch)
+    from yugabyte_db_tpu.utils.hybrid_time import HybridTime
+    q = model.TPCH_Q6 if shape == "q6" else model.TPCH_Q1
+    group = q.group
+    words = {model.RETFLAG: np.array(list("ANR"), object),
+             model.LINESTATUS: np.array(list("FO"), object)}
+    if shape == "q1_dict":
+        group = DictGroupSpec(cols=(model.RETFLAG, model.LINESTATUS))
+    data = model.generate_lineitem(0.004, seed=5)
+    codec = TableCodec(model.lineitem_info())
+    per_shard = [codec.bulk_blocks(
+        {k: v[i * 3000:(i + 1) * 3000] for k, v in data.items()},
+        HybridTime.from_micros(100)) for i in range(4)]
+    flags.set_flag("device_float_dtype", "float64")
+    mesh = dataclasses.replace(build_sharded_batch(
+        tablet_mesh(4, devices=jax.devices()[:4]), per_shard,
+        sorted(q.columns), multi_version=True), dicts=words)
+    one = dataclasses.replace(build_batch(
+        [b for blocks in per_shard for b in blocks], sorted(q.columns),
+        multi_version=True), dicts=words)
+    assert (mesh.padded_rows, mesh.num_shards, one.padded_rows) \
+        == (4096, 4, 16384)
+    read_ht = HybridTime.from_micros(10_000).value
+    programs = []
+
+    class Recording(DistributedScanKernel):
+        def _get(self, *key):
+            fn = super()._get(*key)
+
+            def call(*args):
+                programs.append(fn.lower(*args).compile().as_text())
+                return fn(*args)
+            return call
+
+    def launches():
+        with TRACES.trace("tiles") as t:
+            four = Recording().run(mesh, q.where, q.aggs, group, read_ht)
+            alone = ScanKernel().run(one, q.where, q.aggs, group, read_ht)
+        tiles = [s.tags["tiles"] for s in TRACES.recent
+                 if s.trace_id == t.trace_id and s.name == "device.scan"]
+        return four, alone, tiles
+    whole4, whole1, tiles = launches()
+    assert tiles == [1, 1]
+    monkeypatch.setattr(scan_mod, "_TILE_ROWS", 1024)
+    tiled4, tiled1, tiles = launches()
+    assert tiles == ([1, 1] if shape == "q6" else [4, 16])
+    for text, looped in zip(programs, (False, shape != "q6")):
+        assert text.count("all-reduce(") + text.count("all-reduce-start(") \
+            == 1
+        assert (" while(" in text) == looped
+    assert int(np.sum(tiled4[1])) == int(np.asarray(tiled1[2]).sum()) > 0
+    for got in (tiled4, whole4, tiled1[:2] + tiled1[3:]):
+        got, want = (jax.tree_util.tree_leaves(x)
+                     for x in (got, whole1[:2] + whole1[3:]))
+        assert len(got) == len(want)
+        for x, y in zip(got, want):
+            assert np.asarray(x).dtype == np.asarray(y).dtype
+            np.testing.assert_array_equal(x, y)
+    np.testing.assert_array_equal(np.asarray(tiled1[2]),
+                                  np.asarray(whole1[2]))

@@ -414,6 +414,9 @@ class TestDeviceTelemetry:
         # what crosses the host-device boundary a launch: the host values
         # the jitted call placed (one literal, read_ht, the scales) ...
         assert [s.tags["host_args"] for s in spans] == [3, 3]
+        # ... in how many row tiles the program ran the lane (512 rows:
+        # one; `ops/scan.py tile_count`) ...
+        assert [s.tags["tiles"] for s in spans] == [1, 1]
         waits = [s for s in TRACES.recent
                  if s.trace_id == t.trace_id and s.name == "device.wait"]
         # ... and the transfers that brought the result back

@@ -342,5 +342,172 @@ class TestLaunchProtocol:
         assert kern.compiles == 1
 
 
+class TestRowTiles:
+    """A grouped scan of a lane longer than `_TILE_ROWS` runs as one
+    device loop over row tiles whose partials add (int64 sums and
+    counts, order-free extremes): the answer is the whole program's bit
+    for bit, the row mask element for element.  8,192 padded rows by
+    tiles of 1,024: 5,000 rows, so tiles 5..7 hold padding only, and
+    tile 2 holds only deleted rows."""
+
+    N, TILE, READ_HT = 8192, 1024, 600
+
+    @classmethod
+    def _batch(cls, bounds=True):
+        import dataclasses
+        rng = np.random.default_rng(11)
+        n = 5000
+        blk = ColumnarBlock.from_arrays(
+            schema_version=1,
+            key_hash=rng.integers(0, n // 2, n).astype(np.uint64),
+            ht=rng.integers(1, 1000, n).astype(np.uint64),
+            fixed={1: (rng.uniform(0, 50, n), np.zeros(n, bool)),
+                   2: (rng.uniform(1, 100, n), rng.random(n) < 0.01),
+                   3: (rng.uniform(0, 0.1, n), np.zeros(n, bool)),
+                   4: (rng.integers(0, 5, n).astype(np.int32),
+                       np.zeros(n, bool))},
+            tombstone=np.zeros(n, bool), unique_keys=False)
+        batch = build_batch([blk], [1, 2, 3, 4])
+        assert batch.padded_rows == cls.N and batch.next_ht is not None
+        return dataclasses.replace(
+            batch, tombstone=batch.tombstone.at[2048:3072].set(True),
+            dicts={4: np.array(list("abcde"), object)},
+            col_bounds=batch.col_bounds if bounds else {})
+
+    @staticmethod
+    def _shape(kind):
+        """(where, aggs, group) of a scan shape."""
+        from yugabyte_db_tpu.ops.grouped_scan import DictGroupSpec
+        from yugabyte_db_tpu.ops.scan import HashGroupSpec
+        money = (C(2) * (Expr.const(1) - C(3))).node
+        every = (AggSpec("sum", money), AggSpec("sum", C(4).node),
+                 AggSpec("count"), AggSpec("count", C(2).node),
+                 AggSpec("min", C(2).node), AggSpec("max", C(2).node),
+                 AggSpec("min", C(4).node), AggSpec("max", C(4).node))
+        where = (C(1) < 40.0).node
+        return {
+            "sum_static": (where, (AggSpec("sum", money),), None),
+            "sum_integer": (where, (AggSpec("sum", C(4).node),), None),
+            "count_min_max": (None, every[2:], None),
+            "dense": (where, every, GroupSpec(cols=((4, 5, 0),))),
+            "dense_count": (None, (AggSpec("count"),),
+                            GroupSpec(cols=((4, 5, 0),))),
+            # five words in four slots: codes 3 and 4 spill, in every tile
+            "dict_spilling": (where, every, DictGroupSpec((4,), max_slots=4)),
+            "hash": (where, every[:4], HashGroupSpec((4,), max_groups=8)),
+        }[kind]
+
+    @classmethod
+    def _programs(cls, kind, strategy="segment", bounds=True):
+        """(tiled, whole, args, key): the kernel of a launch
+        (`prepare_launch`) built with tiles of 1,024 rows and with one
+        of the lane's length."""
+        import jax
+        from yugabyte_db_tpu.ops.scan import _build_kernel, prepare_launch
+        where, aggs, group = cls._shape(kind)
+        _, key, args = prepare_launch(cls._batch(bounds), where, aggs, group,
+                                      cls.READ_HT)
+        where, aggs, group, mode, static_sums, _ = key
+        assert mode == "linked"
+        tiled, whole = (jax.jit(_build_kernel(
+            where, aggs, group, mode, static_sums=static_sums,
+            strategy=strategy, tile_rows=t)) for t in (cls.TILE, cls.N))
+        return tiled, whole, args, key
+
+    @staticmethod
+    def _same_bits(a, b):
+        import jax
+        a, b = (jax.tree_util.tree_leaves(x) for x in (a, b))
+        assert len(a) == len(b) > 0
+        for x, y in zip(a, b):
+            assert (x.dtype, x.shape) == (y.dtype, y.shape)
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+    @pytest.mark.parametrize("strategy", ["segment", "unroll"])
+    @pytest.mark.parametrize("kind", ["dense", "dense_count",
+                                      "dict_spilling"])
+    def test_tiled_is_the_whole_program_bit_for_bit(self, kind, strategy):
+        from yugabyte_db_tpu.ops.scan import tile_count
+        tiled, whole, args, (_, aggs, group, _, static_sums, _) = \
+            self._programs(kind, strategy)
+        assert tile_count(self.N, group, aggs, static_sums, self.TILE) == 8
+        assert "while" in tiled.lower(*args).as_text()
+        assert "while" not in whole.lower(*args).as_text()
+        got, want = tiled(*args), whole(*args)
+        self._same_bits(got, want)
+        # (outs, scales, counts, mask[, spilled]): the mask covers the
+        # lane, rows answered, and the deleted and the padded tiles none
+        mask = np.asarray(got[3])
+        assert mask.shape == (self.N,) and mask[:2048].any()
+        assert not mask[2048:3072].any() and not mask[5000:].any()
+        assert int(np.sum(got[2])) == int(mask.sum()) > 0
+        if kind == "dict_spilling":
+            assert int(got[4]) > 0
+            spilled = np.asarray(args[0][4])[mask] >= 3
+            assert int(got[4]) == spilled.sum()
+            # ... from rows of several tiles
+            assert len(set(np.nonzero(mask)[0][spilled] // self.TILE)) > 1
+
+    @pytest.mark.parametrize("kind,bounds", [
+        ("sum_static", True), ("sum_integer", True), ("count_min_max", True),
+        ("hash", True), ("dense", False), ("dict_spilling", False)])
+    def test_a_shape_that_tiles_do_not_serve_runs_whole(self, kind, bounds):
+        """An ungrouped body reads each lane once and has nothing to keep
+        near; HashGroupSpec sorts the whole lane; a SUM with no
+        host-derived scale takes a max over all rows first: one tile,
+        the program that it was, and the answer with it."""
+        from yugabyte_db_tpu.ops.scan import tile_count
+        tiled, whole, args, (_, aggs, group, _, static_sums, _) = \
+            self._programs(kind, bounds=bounds)
+        assert bounds or not any(static_sums)
+        assert tile_count(self.N, group, aggs, static_sums, self.TILE) == 1
+        assert tiled.lower(*args).as_text() == whole.lower(*args).as_text()
+        got = tiled(*args)
+        self._same_bits(got, whole(*args))
+        assert int(np.sum(got[2])) == int(np.asarray(got[3]).sum()) > 0
+
+    @pytest.mark.parametrize("kind", ["dense", "dense_count",
+                                      "dict_spilling"])
+    def test_a_lane_of_one_tile_is_the_program_it_was(self, kind):
+        """`n` <= the tile: no loop, and nothing else of the tiled form
+        in the program either."""
+        import jax
+        from yugabyte_db_tpu.ops.scan import _build_kernel
+        _, whole, args, (where, aggs, group, mode, static_sums, strategy) = \
+            self._programs(kind)
+        texts = [jax.jit(_build_kernel(
+            where, aggs, group, mode, static_sums=static_sums,
+            strategy=strategy, **kw)).lower(*args).as_text()
+            for kw in ({}, {"tile_rows": self.N}, {"tile_rows": 4 * self.N})]
+        assert texts[0] == texts[1] == texts[2] == \
+            whole.lower(*args).as_text()
+        assert "while" not in texts[0]
+
+    @pytest.mark.parametrize("kind", ["sum_static", "dense",
+                                      "dict_spilling", "hash"])
+    def test_launch_tags_the_tiles_it_ran(self, kind, monkeypatch):
+        """`ScanKernel.run` with the module's tile at 1,024: the same
+        answer and mask as with the served tile, and `device.scan` says
+        how many tiles the program ran the lane in."""
+        from yugabyte_db_tpu.ops import scan as scan_mod
+        from yugabyte_db_tpu.utils.trace import TRACES
+        batch = self._batch()
+        where, aggs, group = self._shape(kind)
+
+        def run():
+            with TRACES.trace("tiles") as t:
+                got = ScanKernel().run(batch, where, aggs, group,
+                                       self.READ_HT)
+            (span,) = [s for s in TRACES.recent if s.trace_id == t.trace_id
+                       and s.name == "device.scan"]
+            return got, span.tags["tiles"]
+        want, tiles = run()
+        assert tiles == 1                   # 8,192 rows: under the tile
+        monkeypatch.setattr(scan_mod, "_TILE_ROWS", self.TILE)
+        got, tiles = run()
+        assert tiles == (8 if kind in ("dense", "dict_spilling") else 1)
+        self._same_bits(got, want)
+
+
 def col_expr(cid):
     return C(cid).node

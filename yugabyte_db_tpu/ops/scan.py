@@ -127,6 +127,15 @@ _UNROLL_G = 16
 # scale sentinel meaning "integer-exact result, do not rescale"
 _NOSCALE = jnp.float32(0.0)
 
+# a grouped scan of a longer lane runs its body over row tiles of this
+# length and adds the partials.  Chosen on the chip (PERF.md §5, PR 37:
+# a 16,777,216-row shard, Q1 by a 16-slot dictionary group, ms a
+# statement on the host): whole 20.6, tiles of 1,048,576 18.0,
+# 2,097,152 17.3, 4,194,304 16.9, 8,388,608 18.2 — a tile's mask and
+# per-group intermediates stay in the chip's vector memory between the
+# body's fusions, and every loop step pays the fusions' fixed costs.
+_TILE_ROWS = 4_194_304
+
 
 def _group_strategy() -> str:
     """Reduction strategy for small-G grouped aggregates. CPU XLA does
@@ -360,12 +369,34 @@ def masked_aggregate(group, agg_fns, prep, cols, nulls, consts, mask,
     return tuple(out), tuple(scales), group_counts, mask
 
 
+def tile_count(n: int, group, aggs, static_sums, tile_rows=None) -> int:
+    """How many row tiles the kernel runs a lane of `n` rows in — what
+    `_build_kernel` traces and what `launch` tags, from what both see.
+    More than one only for a grouped scan whose tile partials add
+    exactly: a GroupSpec or ResolvedDictGroup (an ungrouped body reads
+    each lane once in a few fusions and has nothing to keep near: the
+    loop only costs it; HashGroupSpec sorts the whole lane) with every
+    SUM static-scale (int64 fixed point at a host-derived scale, or an
+    integer lane; a dynamic-scale SUM takes a max over all rows
+    first).  Buckets are powers of two, so a longer lane is a
+    multiple of the tile."""
+    tile_rows = tile_rows or _TILE_ROWS
+    if n <= tile_rows or n % tile_rows or group is None \
+            or isinstance(group, HashGroupSpec):
+        return 1
+    if not all(s for a, s in zip(aggs, static_sums)
+               if a.op == "sum" and a.expr is not None):
+        return 1
+    return n // tile_rows
+
+
 def _build_kernel(where_node, agg_specs: Tuple[AggSpec, ...],
                   group: Optional[GroupSpec], mvcc_mode: str,
                   axis_names: Tuple[str, ...] = (),
                   row_multiplier: int = 1,
                   static_sums: Tuple[bool, ...] = (),
-                  strategy: str = "unroll"):
+                  strategy: str = "unroll",
+                  tile_rows: Optional[int] = None):
     """mvcc_mode: 'none' | 'visible' | 'linked' (see visibility_mask);
     the kernel takes the lanes `mvcc_lanes` hands out for that mode.
 
@@ -381,7 +412,11 @@ def _build_kernel(where_node, agg_specs: Tuple[AggSpec, ...],
     runtime arg `sum_scales[i]` — the fast path: quantization fuses
     into the predicate pass with no device max-reduce and no float
     fallback lane. Non-static SUMs keep the dynamic in-kernel scale
-    with its degenerate-magnitude fallbacks."""
+    with its degenerate-magnitude fallbacks.
+
+    A lane of more than `tile_rows` rows (default `_TILE_ROWS`) runs in
+    `tile_count` row tiles inside one device loop, the tiles' partials
+    added; one tile is the program without a loop."""
     # the kernel's consts list concatenates WHERE constants first, then
     # each aggregate expression's, in AggSpec order — every compile
     # lands at its cumulative offset so the slots can never collide
@@ -406,8 +441,10 @@ def _build_kernel(where_node, agg_specs: Tuple[AggSpec, ...],
             return q, s, None
         return _sum_prep(v, m, n_total, axis_names)
 
-    def fn(cols, nulls, consts, valid, ht, next_ht, tombstone, read_ht,
-           sum_scales, group_domains=()):
+    def body(cols, nulls, consts, valid, ht, next_ht, tombstone, read_ht,
+             sum_scales, group_domains, n_total):
+        """The scan of one run of rows — a whole lane or one tile of
+        it; `n_total` is what the SUM scales were sized for."""
         mask = visibility_mask(mvcc_mode, valid, ht, next_ht, tombstone,
                                read_ht)
         if where_fn is not None:
@@ -422,7 +459,6 @@ def _build_kernel(where_node, agg_specs: Tuple[AggSpec, ...],
                 gn = nulls.get(cid)
                 if gn is not None:
                     mask = mask & jnp.logical_not(gn)
-            n = mask.shape[0]
             G = group.max_groups
             inv = jnp.logical_not(mask).astype(jnp.uint8)
             gcols = [cols[cid] for cid in group.cols]
@@ -436,7 +472,6 @@ def _build_kernel(where_node, agg_specs: Tuple[AggSpec, ...],
                 [jnp.array([True]), changed])
             n_groups = jnp.sum(first, dtype=jnp.int32)
             seg = jnp.clip(jnp.cumsum(first) - 1, 0, G - 1)
-            n_total = n * row_multiplier
             out, scales = [], []
             for i, (op, f) in enumerate(agg_fns):
                 if f is None:
@@ -482,7 +517,62 @@ def _build_kernel(where_node, agg_specs: Tuple[AggSpec, ...],
 
         return masked_aggregate(group, agg_fns, _prep, cols, nulls,
                                 consts, mask, group_domains, sum_scales,
-                                mask.shape[0] * row_multiplier, strategy)
+                                n_total, strategy)
+
+    # how a tile's partial joins the ones before it: sums and counts add
+    # (int64, exactly), an extreme is order-free
+    joins = [jnp.minimum if op == "min" and f is not None
+             else jnp.maximum if op == "max" and f is not None
+             else jnp.add for op, f in agg_fns]
+
+    def fn(cols, nulls, consts, valid, ht, next_ht, tombstone, read_ht,
+           sum_scales, group_domains=()):
+        n = valid.shape[0]
+        n_total = n * row_multiplier
+        tiles = tile_count(n, group, agg_specs, static_sums, tile_rows)
+        lanes = (cols, nulls, valid, ht, next_ht, tombstone)
+
+        def run(lanes):
+            c, nl, *rest = lanes
+            return body(c, nl, consts, *rest, read_ht, sum_scales,
+                        group_domains, n_total)
+        if tiles == 1:
+            return run(lanes)
+
+        # one device loop over the lane's tiles (`dynamic_slice` of the
+        # lanes as they are: a [tiles, rows] view is a copy under the
+        # chip's layouts; a lane the mode does not read is None, no
+        # leaf).  The carry starts from each partial's identity — 0, or
+        # the type's sentinel for an extreme: what `body` answers on no
+        # rows — and holds the lane's row mask, which a caller that
+        # does not read it (the mesh) leaves for the compiler to drop.
+        rows = n // tiles
+        tile = lambda i: jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_slice_in_dim(a, i * rows, rows), lanes)
+        like = jax.eval_shape(lambda: run(tile(0)))
+        zero = lambda x: jnp.zeros(x.shape, x.dtype)
+
+        def identity(join, x):
+            if join is jnp.add:
+                return zero(x)
+            sentinel = _type_max if join is jnp.minimum else _type_min
+            return jnp.full(x.shape, sentinel(x), x.dtype)
+        start = (tuple(map(identity, joins, like[0])),
+                 jax.tree_util.tree_map(zero, like[1]), zero(like[2]),
+                 tuple(map(zero, like[4:])), jnp.zeros(n, jnp.bool_))
+
+        def step(i, carry):
+            acc, _, acc_counts, acc_spilled, mask = carry
+            outs, scales, counts, tile_mask, *spilled = run(tile(i))
+            # a static scale is the same every tile: the last one stays
+            return (tuple(j(a, o) for j, a, o in zip(joins, acc, outs)),
+                    scales, acc_counts + counts,
+                    tuple(a + x for a, x in zip(acc_spilled, spilled)),
+                    jax.lax.dynamic_update_slice_in_dim(
+                        mask, tile_mask, i * rows, 0))
+        outs, scales, counts, spilled, mask = jax.lax.fori_loop(
+            0, tiles, step, start)
+        return (outs, scales, counts, mask, *spilled)
 
     return fn
 
@@ -573,13 +663,12 @@ class ScanKernel:
         must revert to the interpreted GROUP BY).  Everything but the
         mask is a host value (`launch`)."""
         sig, key, args = prepare_launch(batch, where, aggs, group, read_ht)
-        _, _, resolved, mvcc_mode, _, _ = key
         pre = self.compiles
         fn = self._get(sig, *key)
-        if isinstance(resolved, ResolvedDictGroup):
+        if isinstance(key[2], ResolvedDictGroup):
             from .grouped_scan import GROUPED_STATS
             GROUPED_STATS["launches"] += 1
-        return launch(fn, sig, args, batch, mvcc_mode, self.compiles > pre,
+        return launch(fn, sig, key, args, batch, self.compiles > pre,
                       mask=True)
 
 
@@ -633,11 +722,12 @@ def prepare_launch(batch, where, aggs, group, read_ht, n_total=None):
     return sig, (where, aggs, group, mvcc_mode, static_sums, strategy), args
 
 
-def launch(fn, sig, args, batch, mvcc_mode: str, compiled: bool,
-           mask: bool, tags=()):
-    """Dispatch `fn(*args)` (the `device.scan` span: tag `host_args` =
-    how many host values the call placed) and read its result back in
-    ONE transfer (`device.wait`: tag `reads`), `tags` on both spans.
+def launch(fn, sig, key, args, batch, compiled: bool, mask: bool, tags=()):
+    """Dispatch `fn(*args)`, the program `prepare_launch`'s `key` names
+    (the `device.scan` span: tag `host_args` = how many host values the
+    call placed, `tiles` = how many row tiles the program runs a lane
+    in, 1 = whole) and read its result back in ONE transfer
+    (`device.wait`: tag `reads`), `tags` on both spans.
     `fn` returns (outs, scales, counts[, mask], ...): the fixed-point
     sums are rescaled on the host values and the caller gets (outs,
     counts[, mask], ...) — all host values but the row mask, which stays
@@ -647,6 +737,7 @@ def launch(fn, sig, args, batch, mvcc_mode: str, compiled: bool,
     ASH); a sampled span waits for every output first, so it holds the
     whole wait whatever the transfer covers."""
     from ..utils import trace as _trace
+    _, aggs, group, mvcc_mode, static_sums, _ = key
     with _trace.device_span("scan", signature=sig, compiled=compiled,
                             bucket=batch.padded_rows, rows=batch.n_rows,
                             mvcc=mvcc_mode) as sp:
@@ -654,6 +745,8 @@ def launch(fn, sig, args, batch, mvcc_mode: str, compiled: bool,
             sp.set_tag("host_args", sum(
                 not isinstance(x, jax.Array)
                 for x in jax.tree_util.tree_leaves(args)))
+            sp.set_tag("tiles", tile_count(batch.padded_rows, group, aggs,
+                                           static_sums))
             for k, v in tags:
                 sp.set_tag(k, v)
         raw = fn(*args)

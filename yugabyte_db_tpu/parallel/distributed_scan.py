@@ -25,13 +25,15 @@ from ..ops.scan import (AggSpec, GroupSpec, _build_kernel, launch,
                         prepare_launch)
 from ..storage.columnar import ColumnarBlock
 from ..utils import trace as _trace
-from .mesh import BLOCKS_AXIS, TABLETS_AXIS, TabletMesh
+from .mesh import ROW_AXES, TabletMesh
 
 
 @dataclass
 class ShardedBatch:
-    """[S, N] columnar arrays sharded over the mesh (S = total shards =
-    tablets * blocks, N = per-shard padded rows)."""
+    """[S * N] columnar row lanes cut over the mesh (S = total shards =
+    tablets * blocks, N = per-shard padded rows): shard i, tablet-major,
+    holds rows [i * N, (i + 1) * N) as a one-dimensional [N] lane
+    (`TabletMesh.row_sharding`)."""
     n_rows_per_shard: List[int]
     cols: Dict[int, jnp.ndarray]
     nulls: Dict[int, jnp.ndarray]
@@ -73,12 +75,11 @@ class ShardedBatch:
 
     @property
     def padded_rows(self) -> int:
-        # valid is [tablet_shards, block_shards, N] after device_put
-        return int(self.valid.shape[-1])
+        return int(self.valid.shape[0]) // self.num_shards
 
     @property
     def num_shards(self) -> int:
-        return int(np.prod(self.valid.shape[:-1]))
+        return self.mesh.num_tablet_shards * self.mesh.num_block_shards
 
 
 def _column_part(b: ColumnarBlock, cid: int) -> np.ndarray:
@@ -93,9 +94,9 @@ def build_sharded_batch(tm: TabletMesh,
                         per_shard_blocks: Sequence[Sequence[ColumnarBlock]],
                         columns: Sequence[int], dict_plan=None,
                         multi_version: bool = False) -> ShardedBatch:
-    """Per-shard block lists -> mesh-sharded [T, B, N] lanes, one shard a
-    device.  The number of shard slots must equal the mesh size; short
-    shards pad.  Each shard's lanes are filled, linked and put on its own
+    """Per-shard block lists -> mesh-sharded [S * N] lanes, one shard of
+    N rows a device.  The number of shard slots must equal the mesh size;
+    short shards pad.  Each shard's lanes are filled, linked and put on its own
     device by a thread of its own, so the host never holds a stacked copy
     of the table.
 
@@ -106,8 +107,7 @@ def build_sharded_batch(tm: TabletMesh,
     unique-keyed by itself (several SSTs a tablet); such a batch gets
     ``next_ht``, linked per shard.  Spans as `build_batch`:
     ``batch.build`` (host fill), ``batch.version_link``, ``batch.h2d``."""
-    T, B = tm.num_tablet_shards, tm.num_block_shards
-    S = T * B
+    S = tm.num_tablet_shards * tm.num_block_shards
     if len(per_shard_blocks) != S:
         raise ValueError(f"need {S} shard block-lists, got "
                          f"{len(per_shard_blocks)}")
@@ -195,16 +195,15 @@ def build_sharded_batch(tm: TabletMesh,
         sp.set_tag("shards", S)
         sp.set_tag("rows", sum(ns))
 
-    sharding = tm.tablet_block_sharding(extra_dims=1)
+    sharding = tm.row_sharding()
 
     def put(lane: str, cid=None):
         """One lane of every shard, each on its shard's device, as one
         array sharded over the mesh."""
-        parts = [jax.device_put(
-            (h[lane] if cid is None else h[lane][cid]).reshape(1, 1, pad),
-            d) for h, d in zip(host, devices)]
+        parts = [jax.device_put(h[lane] if cid is None else h[lane][cid], d)
+                 for h, d in zip(host, devices)]
         return jax.make_array_from_single_device_arrays(
-            (T, B, pad), sharding, parts)
+            (S * pad,), sharding, parts)
 
     with _trace.TRACES.span("batch.h2d", child_only=True) as sp:
         batch = ShardedBatch(
@@ -240,7 +239,7 @@ class DistributedScanKernel:
         fn = self._cache.get(sig)
         if fn is not None:
             return fn
-        axes = (TABLETS_AXIS, BLOCKS_AXIS)
+        axes = ROW_AXES
         S = tm.num_tablet_shards * tm.num_block_shards
         # static SUM scales derive from GLOBAL host-side column bounds,
         # so every shard quantizes identically and the int64 partials
@@ -253,14 +252,11 @@ class DistributedScanKernel:
 
         def shard_fn(cols, nulls, consts, valid, ht, next_ht, tombstone,
                      read_ht, sum_scales, domains):
-            # local shard view: [1, 1, N] -> [N]; a lane the mode does
-            # not read is None
-            sq = lambda a: None if a is None else a.reshape(a.shape[-1])
-            lcols = {k: sq(v) for k, v in cols.items()}
-            lnulls = {k: sq(v) for k, v in nulls.items()}
-            got = local(lcols, lnulls, consts, sq(valid), sq(ht),
-                        sq(next_ht), sq(tombstone), read_ht, sum_scales,
-                        domains)
+            # the local shard view of a row lane is the [N] lane the
+            # one-device kernel reads; a lane the mode does not read is
+            # None
+            got = local(cols, nulls, consts, valid, ht, next_ht, tombstone,
+                        read_ht, sum_scales, domains)
             outs, scales, counts = got[:3]
             # a dictionary-grouped kernel also counts the rows whose
             # group fell past its slot budget: they add up like a count
@@ -289,10 +285,10 @@ class DistributedScanKernel:
                        for s in scales]
             return tuple(combined), tuple(cscales), added[1], added[2]
 
-        spec3 = P(TABLETS_AXIS, BLOCKS_AXIS, None)
+        rows = P(ROW_AXES)
         in_specs = (
-            {k: spec3 for k in sig_cols(sig)}, {k: spec3 for k in sig_cols(sig)},
-            P(), spec3, spec3, spec3, spec3, P(), P(), P())
+            {k: rows for k in sig_cols(sig)}, {k: rows for k in sig_cols(sig)},
+            P(), rows, rows, rows, rows, P(), P(), P())
         smapped = jax.shard_map(
             shard_fn, mesh=tm.mesh, in_specs=in_specs,
             out_specs=(tuple(P() for _ in aggs), tuple(P() for _ in aggs),
@@ -324,11 +320,11 @@ class DistributedScanKernel:
         sig, key, args = prepare_launch(
             batch, where, aggs, group, read_ht,
             n_total=batch.padded_rows * batch.num_shards)
-        sig, mvcc_mode = (id(tm.mesh),) + sig, key[3]
+        sig = (id(tm.mesh),) + sig
         pre = self.compiles
         fn = self._get(sig, tm, *key)
         outs, counts, spilled = launch(
-            fn, sig, args, batch, mvcc_mode, self.compiles > pre, mask=False,
+            fn, sig, key, args, batch, self.compiles > pre, mask=False,
             tags=(("chips", tm.mesh.devices.size),
                   ("shards", batch.num_shards)))
         if isinstance(group, DictGroupSpec):

@@ -21,6 +21,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 TABLETS_AXIS = "tablets"
 BLOCKS_AXIS = "blocks"
+ROW_AXES = (TABLETS_AXIS, BLOCKS_AXIS)
 
 
 @dataclass(frozen=True)
@@ -35,15 +36,14 @@ class TabletMesh:
     def num_block_shards(self) -> int:
         return self.mesh.shape.get(BLOCKS_AXIS, 1)
 
-    def tablet_sharding(self, extra_dims: int = 1) -> NamedSharding:
-        """[T, ...] arrays sharded over the tablets axis."""
-        return NamedSharding(self.mesh,
-                             P(TABLETS_AXIS, *([None] * extra_dims)))
-
-    def tablet_block_sharding(self, extra_dims: int = 1) -> NamedSharding:
-        """[T, B, ...] arrays sharded over both axes."""
-        return NamedSharding(
-            self.mesh, P(TABLETS_AXIS, BLOCKS_AXIS, *([None] * extra_dims)))
+    def row_sharding(self) -> NamedSharding:
+        """[S * N] row lanes cut over both axes, tablet-major: shard i
+        holds rows [i * N, (i + 1) * N) as ONE-dimensional [N] — the
+        shape a one-device lane has, so the chip lays a shard's lane
+        out as it lays that one out (a [1, 1, N] shard pads each bool
+        to four bytes and has to be relaid before the kernel reads it:
+        PERF.md, PR 37)."""
+        return NamedSharding(self.mesh, P(ROW_AXES))
 
     def replicated(self) -> NamedSharding:
         return NamedSharding(self.mesh, P())
