@@ -539,6 +539,9 @@ class TestServedScanSpans:
         "docdb.read": "tserver.read",
         "docdb.collect_blocks": "docdb.read", "docdb.batch": "docdb.read",
         "device.scan": "docdb.read", "device.wait": "docdb.read",
+        # the launch hops beside the event loop (tablet.serve_read): the
+        # hop hangs under the tablet read, the launch under docdb.read
+        "tserver.read_offload": "tserver.read",
     }
     QUERIES = {
         "sum_where": ("SELECT sum(v), count(*) FROM t WHERE v < 100",
@@ -616,7 +619,10 @@ class TestServedScanSpans:
                         assert s.tags["wait_ms"] >= 0.0
                         assert "cut_through" in s.tags
                     if s.name == "device.wait":
-                        assert s.tags["thread"] == "loop"
+                        assert s.tags["thread"] == "executor"
+                    if s.name == "tserver.read_offload":
+                        assert s.tags["queue_ms"] >= 0.0
+                        assert s.tags["in_flight"] >= 1
             caches = [[s.tags["cache"] for s in spans
                        if s.name == "docdb.batch"] for spans, _ in runs]
             assert caches == [["miss", "miss"], ["hit", "hit"]]
@@ -749,7 +755,7 @@ class TestServedScanSpans:
 
 
 class TestReadRouteTag:
-    """Every exit of `DocReadOperation._execute_once` names itself on
+    """Every exit of `DocReadOperation._execute_once_steps` names itself on
     the `docdb.read` span: the one way to tell which driver served a
     read.  (`tpu_aggregate` through SQL, `mesh` and `mesh_fallback`
     are pinned by TestServedScanSpans and tests/test_mesh_read.py.)"""

@@ -12,7 +12,7 @@ import pytest
 from yugabyte_db_tpu.docdb import RowOp, WriteRequest
 from yugabyte_db_tpu.docdb.operations import (DocReadOperation, ReadRequest,
                                               ReadRestartError,
-                                              _skew_window_ht)
+                                              _skew_window_ht, run_steps)
 from yugabyte_db_tpu.docdb.table_codec import TableInfo
 from yugabyte_db_tpu.dockv.packed_row import (ColumnSchema, ColumnType,
                                               TableSchema)
@@ -140,8 +140,7 @@ def test_read_above_every_write_walks_nothing_and_then_hits(walks):
     assert facts.max_ht == OLD.value and facts.chunk_safe is False
     # the gate without facts is the walk a read made before: every row
     op = t.read_op("li")
-    op._allow_restart = True
-    op._check_restart_window(op._collect_blocks(), t.clock.now().value)
+    op._check_restart_window(op._collect_blocks(), t.clock.now().value, True)
     assert walks == [2 * N]
 
 
@@ -167,7 +166,7 @@ def test_record_in_the_window_restarts_at_the_walks_time(shape, walks):
     req.server_assigned_read_ht = True
     before = len(walks)
     with pytest.raises(ReadRestartError) as e:
-        op._execute_once(req)
+        run_steps(op._execute_once_steps(req))
     assert e.value.restart_ht == want
     assert len(walks) == before + 1      # the slow path is the walk
     assert t.regular.read_facts.max_ht == AHEAD.value
@@ -187,8 +186,8 @@ def test_a_change_of_contents_makes_the_next_read_a_miss(event, walks):
     old_ht = HybridTime.from_micros(NOW_US).value
     quiet = count_req(read_ht=old_ht)
     quiet.server_assigned_read_ht = True
-    op._execute_once(quiet)              # miss: facts made
-    op._execute_once(quiet)              # hit
+    run_steps(op._execute_once_steps(quiet))              # miss: facts made
+    run_steps(op._execute_once_steps(quiet))              # hit
     assert counters(t) == (1, 1) and walks == []
     made = t.regular.read_facts
     assert made.max_ht == OLD.value
@@ -206,7 +205,7 @@ def test_a_change_of_contents_makes_the_next_read_a_miss(event, walks):
     again = count_req(read_ht=old_ht)
     again.server_assigned_read_ht = True
     with pytest.raises(ReadRestartError) as e:
-        op._execute_once(again)
+        run_steps(op._execute_once_steps(again))
     assert e.value.restart_ht == AHEAD.value
     assert counters(t) == (1, 2)
     facts = t.regular.read_facts

@@ -32,7 +32,8 @@ from ..parallel.mesh import TabletMesh, tablet_mesh
 from ..utils import flags, metrics
 from ..utils import trace as _trace
 from .operations import (_MAX_HT, DocReadOperation, ReadRequest,
-                         ReadResponse, ReadRestartError, _skew_window_ht)
+                         ReadResponse, ReadRestartError, _skew_window_ht,
+                         run_steps)
 
 #: one kernel cache for every mesh of the process, as `_SHARED_KERNEL`
 _MESH_KERNEL = DistributedScanKernel()
@@ -165,14 +166,21 @@ class MeshReader:
     # -- the read ------------------------------------------------------------
     def read(self, req: ReadRequest, ops: List[DocReadOperation],
              allow_restart: bool = True) -> ReadResponse:
+        """`read_steps`, the launch made on the calling thread."""
+        return run_steps(self.read_steps(req, ops, allow_restart))
+
+    def read_steps(self, req: ReadRequest, ops: List[DocReadOperation],
+                   allow_restart: bool = True):
         """`req` (its `read_ht` set by the caller, one for all tablets)
         over the tablets of `ops`, which are a table's tablets on this
-        server in partition order.  Raises `MeshIneligible` where the
+        server in partition order, as the steps of
+        `DocReadOperation.execute_steps`: the one launch is yielded as a
+        call and its result taken back.  Raises `MeshIneligible` where the
         caller has to serve tablet by tablet, `ReadRestartError` as the
         one-device route does."""
         with _trace.TRACES.span("docdb.read", child_only=True) as sp:
             try:
-                resp = self._read(req, ops, allow_restart)
+                resp = yield from self._read_steps(req, ops, allow_restart)
             except MeshIneligible as e:
                 self._m_fallbacks.increment()
                 sp.set_tag("route", "mesh_fallback")
@@ -182,7 +190,7 @@ class MeshReader:
             sp.set_tag("tablets", len(ops))
             return resp
 
-    def _read(self, req, ops, allow_restart) -> ReadResponse:
+    def _read_steps(self, req, ops, allow_restart):
         self.check(req, ops)
         needed: set = set()
         from ..ops.expr import referenced_columns
@@ -213,7 +221,8 @@ class MeshReader:
             # ScanKernel.run's shape: (outs, counts, mask[, spill])
             return got[:2] + (None,) + got[2:]
 
-        resp = DocReadOperation.aggregate_on_batch(req, batch, run)
+        resp = yield from DocReadOperation.aggregate_on_batch_steps(
+            req, batch, run)
         if resp is None:
             raise MeshIneligible("shape_or_spill")
         return resp
@@ -224,8 +233,7 @@ class MeshReader:
         tablet's blocks: the slow path of a read whose batch holds rows
         newer than its read time plus the skew window."""
         for op in ops:
-            op._allow_restart = True
-            op._check_restart_window(op._collect_blocks(()) or [], read_ht)
+            op._walk_restart_window(op._collect_blocks(()) or [], read_ht)
 
 
 def tablets_in_partition_order(peers: Sequence) -> list:

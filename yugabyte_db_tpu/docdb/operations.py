@@ -16,6 +16,7 @@ exactly the two-backend structure the reference's
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -913,6 +914,22 @@ class StoreFacts:
     plans: Dict[str, dict] = field(default_factory=dict)
 
 
+def run_steps(steps):
+    """Drive a read written as steps (`DocReadOperation.execute_steps`)
+    on the calling thread: every launch it yields is called here."""
+    try:
+        call = next(steps)
+        while True:
+            try:
+                got = call()
+            except Exception as e:   # noqa: BLE001 — the read's to see
+                call = steps.throw(e)
+            else:
+                call = steps.send(got)
+    except StopIteration as done:
+        return done.value
+
+
 class DocReadOperation:
     """Executes a ReadRequest against one tablet's stores."""
 
@@ -923,8 +940,6 @@ class DocReadOperation:
         self.store = store
         self.kernel = scan_kernel or _SHARED_KERNEL
         self.device_cache = device_cache
-        # restarts engage only via execute() on server-assigned read points
-        self._allow_restart = False
         # `/metrics` of the server that owns the tablet (`owner`)
         ent = metrics.REGISTRY.entity("server", owner or "docdb")
         self._m_facts_hits = ent.counter("store_facts_hits")
@@ -1021,7 +1036,8 @@ class DocReadOperation:
                     best[i] = got
         return best, slow
 
-    def get_row(self, pk_row: Dict[str, object], read_ht: int
+    def get_row(self, pk_row: Dict[str, object], read_ht: int,
+                allow_restart: bool = False
                 ) -> Optional[Dict[str, object]]:
         """Newest visible version across memtable + SSTs, using per-SST
         bloom filters and the native fused whole-SST lookup (reference:
@@ -1031,7 +1047,7 @@ class DocReadOperation:
         workloads keep the C path for the expensive part."""
         prefix = self.codec.doc_key_prefix(pk_row)
         restart_hi = (read_ht + _skew_window_ht()
-                      if self._allow_restart else None)
+                      if allow_restart else None)
         mems, ssts = self.store.read_snapshot()
         got = self._native_best([prefix], ssts, read_ht, restart_hi)
         if got is not None:
@@ -1140,19 +1156,21 @@ class DocReadOperation:
         return out
 
     def _enumerated_multi_get(self, hot, spec, keys, read_ht: int,
-                              want) -> List[Optional[Dict[str, object]]]:
+                              want, allow_restart: bool
+                              ) -> List[Optional[Dict[str, object]]]:
         """Per-key path for enumerated scans: inline single-int key
         encoding (one native call per key, no per-key dict/genexpr
         wrapping) feeding the batched prefix MultiGet."""
         restart_hi = (read_ht + _skew_window_ht()
-                      if self._allow_restart else None)
+                      if allow_restart else None)
         enc = hot.encode_doc_key
         prefixes = [enc(spec, (int(k),)) for k in keys]
         return self._multi_get_prefixes(prefixes, read_ht, restart_hi,
                                         want)
 
     def _range_read_fused(self, hot, spec, keys: range, read_ht: int,
-                          want) -> List[Optional[Dict[str, object]]]:
+                          want, allow_restart: bool
+                          ) -> List[Optional[Dict[str, object]]]:
         """Contiguous-int-key MultiGet through ONE C call
         (ybtpu_hot.range_read): key encode + per-SST bloom/bisect/MVCC
         walk + cross-SST merge + memtable-guard probe all happen below
@@ -1163,12 +1181,12 @@ class DocReadOperation:
         fused path (reader-less SST, multiple or foreign-layout
         memtables)."""
         restart_hi = (read_ht + _skew_window_ht()
-                      if self._allow_restart else None)
+                      if allow_restart else None)
         mems, ssts = self.store.read_snapshot()
 
         def fallback():
             return self._enumerated_multi_get(hot, spec, keys, read_ht,
-                                              want)
+                                              want, allow_restart)
 
         readers = []
         for r in ssts:
@@ -1208,6 +1226,21 @@ class DocReadOperation:
 
     # ---- scans -----------------------------------------------------------
     def execute(self, req: ReadRequest) -> ReadResponse:
+        """`execute_steps`, every launch made on the calling thread."""
+        return run_steps(self.execute_steps(req))
+
+    def execute_steps(self, req: ReadRequest):
+        """The read as a generator of steps.  Each launch it makes it
+        yields as a call of no arguments — `ScanKernel.run` over the
+        batch it holds: dispatch, wait for the device, read-back, which
+        touch nothing of a store — and takes that call's result back;
+        its value is the response.  Whoever drives it chooses the thread
+        the launch runs and waits on: `run_steps` the calling one,
+        `tablet/tablet.py serve_read` one beside the event loop.  Every
+        structure of the store is read between the yields, on the
+        driver's thread.  Other reads of the tablet may run while one is
+        suspended; what it resumes with (batch, blocks, read time) it
+        holds itself."""
         # one tablet's share of a read, as a span: block collection,
         # batch formation, the kernel's dispatch and the wait for its
         # result are its children; `route` is the path that served
@@ -1215,13 +1248,14 @@ class DocReadOperation:
             if req.server_assigned_read_ht:
                 for _attempt in range(3):
                     try:
-                        return self._execute_once(req)
+                        return (yield from self._execute_once_steps(req))
                     except ReadRestartError as e:
                         req.read_ht = e.restart_ht
                         sp.count("restarts")
             # explicit read points never restart; after 3 bumps serve at
             # the last restart point without further bumps
-            return self._execute_once(req, allow_restart=False)
+            return (yield from self._execute_once_steps(
+                req, allow_restart=False))
 
     @staticmethod
     def _served(route: str, resp: ReadResponse) -> ReadResponse:
@@ -1232,37 +1266,42 @@ class DocReadOperation:
             sp.set_tag("route", route)
         return resp
 
-    def _execute_once(self, req: ReadRequest,
-                      allow_restart: bool = True) -> ReadResponse:
-        self._allow_restart = allow_restart and req.server_assigned_read_ht
+    def _execute_once_steps(self, req: ReadRequest,
+                            allow_restart: bool = True):
+        # restarts engage only on server-assigned read points; the
+        # answer is this request's, handed down as an argument: other
+        # reads of the tablet run while this one is suspended in a yield
+        allow_restart = bool(allow_restart and req.server_assigned_read_ht)
         if req.pk_eq is not None:
             read_ht = req.read_ht if req.read_ht is not None else _MAX_HT
-            row = self.get_row(req.pk_eq, read_ht)
+            row = self.get_row(req.pk_eq, read_ht, allow_restart)
             rows = [self._project(row, req.columns)] if row is not None else []
             return self._served("point",
                                 ReadResponse(rows=rows, backend="cpu"))
         if req.pk_prefix is not None:
             return self._served("prefix", self._prefix_scan(req))
         if req.join is not None and req.aggregates:
-            return self._served("join", self._execute_join_aggregate(req))
+            return self._served(
+                "join", self._execute_join_aggregate(req, allow_restart))
         if (not req.aggregates and req.where is not None
                 and req.paging_state is None):
-            got = self._hash_enumerated_read(req)
+            got = self._hash_enumerated_read(req, allow_restart)
             if got is not None:
                 return self._served("hash_enumerated",
                                     self._serve_window(req, got))
         if req.aggregates and self._tpu_eligible(req):
-            resp = self._execute_tpu_aggregate(req)
+            resp = yield from self._execute_tpu_aggregate_steps(
+                req, allow_restart)
             if resp is not None:
                 return self._served("tpu_aggregate", resp)
         if (not req.aggregates and req.where is not None
                 and req.paging_state is None and self._tpu_eligible(req)):
-            resp = self._execute_tpu_filter(req)
+            resp = yield from self._execute_tpu_filter_steps(req)
             if resp is not None:
                 return self._served("tpu_filter",
                                     self._serve_window(req, resp))
-        return self._served("cpu",
-                            self._serve_window(req, self._execute_cpu(req)))
+        return self._served("cpu", self._serve_window(
+            req, self._execute_cpu(req, allow_restart)))
 
     def _serve_window(self, req: ReadRequest,
                       resp: ReadResponse) -> ReadResponse:
@@ -1333,7 +1372,8 @@ class DocReadOperation:
                     break
         return ReadResponse(rows=rows_out, backend="cpu")
 
-    def _hash_enumerated_read(self, req: ReadRequest):
+    def _hash_enumerated_read(self, req: ReadRequest,
+                              allow_restart: bool):
         """Short-range scans on a single-INTEGER-hash-PK table become
         batched point gets: hash sharding cannot seek key ranges, but a
         small enumerable target set (BETWEEN span, IN list, =) IS a
@@ -1387,14 +1427,14 @@ class DocReadOperation:
                 and isinstance(keys, range) and keys
                 and len(keys) < 1_000_000
                 and hasattr(hot, "range_read")):
-            rows = self._range_read_fused(hot, spec, keys, read_ht, want)
+            rows = self._range_read_fused(hot, spec, keys, read_ht, want,
+                                          allow_restart)
         elif hot is not None and spec is not None:
             rows = self._enumerated_multi_get(hot, spec, keys, read_ht,
-                                              want)
+                                              want, allow_restart)
         else:
             rows = self.multi_get([{name: int(k)} for k in keys],
-                                  read_ht,
-                                  allow_restart=self._allow_restart,
+                                  read_ht, allow_restart=allow_restart,
                                   columns=want)
         by_id = {c.name: c.id for c in schema.columns}
         out = []
@@ -1751,7 +1791,8 @@ class DocReadOperation:
             return kept, ("zp", kept_idx)
 
     def _try_streaming_aggregate(self, req: ReadRequest, blocks, needed,
-                                 read_ht: int, facts: StoreFacts):
+                                 read_ht: int, facts: StoreFacts,
+                                 allow_restart: bool):
         """Chunked pipelined aggregate (ops/stream_scan.py) for scans it
         can serve exactly; None falls through to the monolithic batch.
         Hash grouping and MVCC-unsafe block sequences are rejected
@@ -1798,7 +1839,8 @@ class DocReadOperation:
                 # exactly like the normal streamed path and the
                 # interpreted re-scan — a zone-pruned block's
                 # ambiguous-HT rows must keep forcing the restart
-                self._check_restart_window(blocks, read_ht, facts)
+                self._check_restart_window(blocks, read_ht,
+                                           allow_restart, facts)
                 resp = self._grouped_spill_merge(
                     req, grouped_out, expanded, minmax, aggs_run, got,
                     read_ht)
@@ -1811,7 +1853,7 @@ class DocReadOperation:
         # is actually serving the read — a scan that falls through to
         # the monolithic/CPU paths keeps their own (possibly narrower)
         # restart behavior, exactly as before this path existed
-        self._check_restart_window(blocks, read_ht, facts)
+        self._check_restart_window(blocks, read_ht, allow_restart, facts)
         outs, counts = got
         outs = _nullify_minmax(expanded, minmax, outs)
         outs = dict_minmax_decode(expanded, outs,
@@ -1972,13 +2014,14 @@ class DocReadOperation:
         return facts.plans.setdefault(self.codec.info.table_id, {})
 
     def _check_restart_window(self, blocks, read_ht: int,
+                              allow_restart: bool,
                               facts: Optional[StoreFacts] = None) -> None:
         """Raise ReadRestartError when any block holds a record inside
         (read_ht, read_ht + skew] — the coarse whole-block uncertainty
         check shared by the monolithic and streaming aggregate paths.
         `facts`: those of the store `blocks` is the whole list of; a
         read at or above its newest write time walks nothing."""
-        if not (self._allow_restart and read_ht != _MAX_HT):
+        if not (allow_restart and read_ht != _MAX_HT):
             return
         if facts is not None and facts.max_ht <= read_ht:
             return      # no record newer than the read: none in the window
@@ -1995,7 +2038,10 @@ class DocReadOperation:
             if len(amb):
                 raise ReadRestartError(int(amb.max()))
 
-    def _execute_tpu_aggregate(self, req: ReadRequest) -> Optional[ReadResponse]:
+    def _execute_tpu_aggregate_steps(self, req: ReadRequest,
+                                     allow_restart: bool):
+        """Steps (`execute_steps`) to the response, or None where the
+        device cannot serve the aggregate."""
         blocks, facts = self._collect_with_facts()
         if not blocks:
             return None
@@ -2018,7 +2064,7 @@ class DocReadOperation:
             return None     # interpreted GROUP BY (the flag-off path)
         read_ht = req.read_ht if req.read_ht is not None else _MAX_HT
         resp = self._try_streaming_aggregate(req, blocks, needed, read_ht,
-                                             facts)
+                                             facts, allow_restart)
         if resp is _SPILLED:
             return None     # over-cardinality: interpreted GROUP BY
         if resp is not None:
@@ -2033,22 +2079,24 @@ class DocReadOperation:
             batch = self._cached_batch(kept, needed, prune_key, facts)
         except KeyError:
             return None   # some column lacks columnar form → CPU path
-        self._check_restart_window(blocks, read_ht, facts)
-        return self.aggregate_on_batch(
+        self._check_restart_window(blocks, read_ht, allow_restart, facts)
+        return (yield from self.aggregate_on_batch_steps(
             req, batch,
             lambda where, aggs, group: self.kernel.run(
                 batch, where, aggs, group, read_ht),
             lambda *partials: self._monolithic_spill_merge(
-                req, req.group_by, batch, kept, *partials))
+                req, req.group_by, batch, kept, *partials)))
 
     @classmethod
-    def aggregate_on_batch(cls, req: ReadRequest, batch, run,
-                           on_spill=None) -> Optional[ReadResponse]:
+    def aggregate_on_batch_steps(cls, req: ReadRequest, batch, run,
+                                 on_spill=None):
         """The request's aggregates over one cached batch — a
         `DeviceBatch`, or a `ShardedBatch` that covers several tablets
-        (docdb/mesh_read.py): string shapes rewritten into the batch's
-        code space, the kernel launched through `run(where, aggs,
-        group)` (what `ScanKernel.run` returns), the result decoded.
+        (docdb/mesh_read.py) — as steps (`execute_steps`): string shapes
+        rewritten into the batch's code space, the kernel launched
+        through `run(where, aggs, group)` (what `ScanKernel.run`
+        returns; the call is yielded, and its result taken back), the
+        result decoded.
         None = a shape the device cannot serve exactly; the caller
         falls back.  `on_spill(expanded, minmax, aggs_run, outs, counts,
         mask)` may serve a dictionary-grouped scan that overflowed its
@@ -2081,8 +2129,8 @@ class DocReadOperation:
                 batch.dicts)
 
         if isinstance(req.group_by, HashGroupSpec):
-            outs, counts, _, gvals, n_groups = run(
-                where, aggs_run, req.group_by)
+            outs, counts, _, gvals, n_groups = yield partial(
+                run, where, aggs_run, req.group_by)
             if int(n_groups) > req.group_by.max_groups:
                 return None     # distinct-group overflow: CPU fallback
             return ReadResponse(
@@ -2098,7 +2146,8 @@ class DocReadOperation:
             if any(c not in batch.dicts for c in gspec.cols) or \
                     domain_product(gspec, batch.dicts) >= 2 ** 31:
                 return None     # no dictionary / gid would wrap: CPU
-            outs, counts, mask, spill = run(where, aggs_run, gspec)
+            outs, counts, mask, spill = yield partial(
+                run, where, aggs_run, gspec)
             if int(spill) > 0:
                 # slot overflow on the MONOLITHIC dict-group route:
                 # same partial-spill merge as the streamed path — keep
@@ -2120,7 +2169,8 @@ class DocReadOperation:
             return ReadResponse(agg_values=outs_c,
                                 group_counts=counts_c,
                                 group_values=gvals, backend="tpu")
-        outs, counts, _ = run(where, aggs_run, req.group_by)
+        outs, counts, _ = yield partial(run, where, aggs_run,
+                                        req.group_by)
         return ReadResponse(agg_values=_nullify(outs),
                             group_counts=np.asarray(counts),
                             backend="tpu")
@@ -2138,7 +2188,8 @@ class DocReadOperation:
         approx_rows = sum(r.num_entries for r in self.store.ssts)
         return approx_rows >= flags.get("tpu_min_rows_for_pushdown")
 
-    def _execute_join_aggregate(self, req: ReadRequest) -> ReadResponse:
+    def _execute_join_aggregate(self, req: ReadRequest,
+                                allow_restart: bool) -> ReadResponse:
         """Aggregate request with a shipped build side: the fused-plan
         device path (filter -> probe -> gather -> group -> aggregate in
         ONE program, ops/plan_fusion.py) when eligible, the interpreted
@@ -2149,14 +2200,14 @@ class DocReadOperation:
         if flags.get("join_pushdown_enabled") and \
                 self._join_eligible(req):
             try:
-                resp = self._execute_fused_join(req)
+                resp = self._execute_fused_join(req, allow_restart)
                 if resp is not None:
                     return resp
             except JoinIneligible:
                 JOIN_STATS["fallbacks"] += 1
-        return self._execute_join_cpu(req)
+        return self._execute_join_cpu(req, allow_restart)
 
-    def _execute_fused_join(self, req: ReadRequest
+    def _execute_fused_join(self, req: ReadRequest, allow_restart: bool
                             ) -> Optional[ReadResponse]:
         from ..ops.join_scan import BUILD_COL_BASE
         from ..ops.plan_fusion import (default_plan_kernel,
@@ -2221,7 +2272,7 @@ class DocReadOperation:
             from ..ops.grouped_scan import GROUPED_STATS
             GROUPED_STATS["spill_fallbacks"] += 1
             return None       # slot overflow: interpreted join
-        self._check_restart_window(blocks, read_ht, facts)
+        self._check_restart_window(blocks, read_ht, allow_restart, facts)
         outs, counts = got
         outs = _nullify_minmax(expanded, minmax, outs)
         if dict_group:
@@ -2235,7 +2286,7 @@ class DocReadOperation:
                             group_counts=np.asarray(counts),
                             backend="tpu")
 
-    def _iter_visible_idrows(self, read_ht: int):
+    def _iter_visible_idrows(self, read_ht: int, allow_restart: bool):
         """Newest visible version of every row as a {col_id: value}
         dict — the interpreted scan loop the CPU join path feeds on
         (same MVCC walk as _execute_cpu, minus segments/paging, which
@@ -2257,7 +2308,7 @@ class DocReadOperation:
                 continue
             dht = DocHybridTime.decode_desc(k[-ENCODED_SIZE:])
             if dht.ht.value > read_ht:
-                if self._allow_restart and \
+                if allow_restart and \
                         dht.ht.value <= read_ht + _skew_window_ht():
                     raise ReadRestartError(dht.ht.value)
                 continue
@@ -2272,7 +2323,8 @@ class DocReadOperation:
                 continue
             yield {name_to_id[n]: val for n, val in row.items()}
 
-    def _execute_join_cpu(self, req: ReadRequest) -> ReadResponse:
+    def _execute_join_cpu(self, req: ReadRequest,
+                          allow_restart: bool) -> ReadResponse:
         """Interpreted FK-equijoin aggregate: row-at-a-time probe scan,
         a Python dict over each stage's shipped build keys, payload
         values merged into the row under their build-column ids, stages
@@ -2327,7 +2379,7 @@ class DocReadOperation:
                         bv.item() if isinstance(bv, np.generic) else bv)
                 fold(r2, si + 1)
 
-        for idrow in self._iter_visible_idrows(read_ht):
+        for idrow in self._iter_visible_idrows(read_ht, allow_restart):
             if req.where is not None and \
                     eval_expr_py(req.where, idrow) is not True:
                 continue
@@ -2339,11 +2391,12 @@ class DocReadOperation:
         return ReadResponse(agg_values=vals, backend="cpu",
                             group_counts=None)
 
-    def _execute_tpu_filter(self, req: ReadRequest) -> Optional[ReadResponse]:
-        """Filter-pushdown row scan: the WHERE mask computes on device,
-        matching rows gather host-side with vectorized numpy over the
-        columnar blocks (no per-row predicate evaluation). Falls back to
-        the CPU row loop when columns aren't columnar-capable."""
+    def _execute_tpu_filter_steps(self, req: ReadRequest):
+        """Steps (`execute_steps`) of the filter-pushdown row scan: the
+        WHERE mask computes on device, matching rows gather host-side
+        with vectorized numpy over the columnar blocks (no per-row
+        predicate evaluation). Falls back to the CPU row loop when
+        columns aren't columnar-capable."""
         blocks, facts = self._collect_with_facts()
         if not blocks:
             return None
@@ -2374,8 +2427,11 @@ class DocReadOperation:
                 where = self._rewrite_strings(where, batch.dicts)
             except self._Unrewritable:
                 return None
-        _, _, mask = self.kernel.run(batch, where, (), None, read_ht)
-        sel = np.nonzero(np.asarray(mask))[0]
+        # the mask's read-back belongs to the launch: it waits for the
+        # device as `device.wait` does
+        mask = yield lambda: np.asarray(self.kernel.run(
+            batch, where, (), None, read_ht)[2])
+        sel = np.nonzero(mask)[0]
         if req.limit is not None and len(sel) > req.limit:
             sel = sel[:req.limit]
         rows = self._gather_rows(blocks, sel, proj_cols)
@@ -2502,7 +2558,8 @@ class DocReadOperation:
         segments.sort(key=lambda s: s[0])
         return segments, residual
 
-    def _execute_cpu(self, req: ReadRequest) -> ReadResponse:
+    def _execute_cpu(self, req: ReadRequest,
+                     allow_restart: bool) -> ReadResponse:
         read_ht = req.read_ht if req.read_ht is not None else _MAX_HT
         table_prefix = self.codec.scan_prefix()
         segments, scan_where = self._scan_segments(req)
@@ -2540,7 +2597,7 @@ class DocReadOperation:
                     continue
                 dht = DocHybridTime.decode_desc(k[-ENCODED_SIZE:])
                 if dht.ht.value > read_ht:
-                    if self._allow_restart and \
+                    if allow_restart and \
                             dht.ht.value <= read_ht + _skew_window_ht():
                         raise ReadRestartError(dht.ht.value)
                     continue

@@ -659,6 +659,71 @@ def numpy_reference(query: QuerySpec, data: Dict[str, np.ndarray]):
     raise ValueError(query.name)
 
 
+# --- Q1 and Q6 at other substitution parameters (clause 2.4) ---------------
+# The throughput test's query streams send the same statements with the
+# parameters qgen draws for each (clauses 2.4.1.3 and 2.4.6.3): the plain
+# answer at any of them, for the tests.  The benchmark keeps its own copy
+# (benchmark/tpch_qgen.py); this one shares no code with it.  Q1: `delta`
+# days before 1998-12-01, 60 to 120.  Q6: the ship date's `year`, 1993 to
+# 1997; `discount` in hundredths, 2 to 9, and 0.01 around it; `quantity`
+# 24 or 25.
+_D1998_12_01 = 10561
+
+
+def _jan1(year: int) -> int:
+    import datetime
+    return (datetime.date(year, 1, 1) - datetime.date(1970, 1, 1)).days
+
+
+def sql_at(name: str, table: str = "lineitem", *, delta: int = 90,
+           year: int = 1994, discount: int = 6, quantity: int = 24) -> str:
+    """Q1 (`delta`) or Q6 (`year`, `discount` in hundredths, `quantity`)
+    as SQL text over the 16-column LINEITEM."""
+    if name == "q1":
+        return ("SELECT l_returnflag, l_linestatus, sum(l_quantity) AS "
+                "sum_qty, sum(l_extendedprice) AS sum_base_price, "
+                "sum(l_extendedprice * (1 - l_discount)) AS sum_disc_price, "
+                "sum(l_extendedprice * (1 - l_discount) * (1 + l_tax)) "
+                f"AS sum_charge, count(*) AS count_order FROM {table} "
+                f"WHERE l_shipdate <= {_D1998_12_01 - delta} "
+                "GROUP BY l_returnflag, l_linestatus")
+    return ("SELECT sum(l_extendedprice * l_discount) AS revenue "
+            f"FROM {table} WHERE l_shipdate >= {_jan1(year)} "
+            f"AND l_shipdate < {_jan1(year + 1)} AND l_discount BETWEEN "
+            f"{(discount - 1) / 100:.2f} AND {(discount + 1) / 100:.2f} "
+            f"AND l_quantity < {quantity}")
+
+
+def numpy_reference_at(name: str, data: Dict[str, np.ndarray], *,
+                       delta: int = 90, year: int = 1994,
+                       discount: int = 6, quantity: int = 24):
+    """The float64 numpy answer to `sql_at(name, ...)` over `data`, whose
+    flag columns are text (bytes or str): Q6's revenue, or Q1's
+    {returnflag + linestatus: {column: value}}."""
+    qty, price = data["l_quantity"], data["l_extendedprice"]
+    disc, ship = data["l_discount"], data["l_shipdate"]
+    if name == "q6":
+        lo = float(f"{(discount - 1) / 100:.2f}")
+        hi = float(f"{(discount + 1) / 100:.2f}")
+        m = ((ship >= _jan1(year)) & (ship < _jan1(year + 1))
+             & (disc >= lo) & (disc <= hi) & (qty < quantity))
+        return float((price[m] * disc[m]).sum())
+    m = ship <= _D1998_12_01 - delta
+    flags_ = np.char.add(data["l_returnflag"].astype(str),
+                         data["l_linestatus"].astype(str))
+    disc_price = price * (1.0 - disc)
+    charge = disc_price * (1.0 + data["l_tax"])
+    out = {}
+    for g in np.unique(flags_[m]).tolist():
+        mg = m & (flags_ == g)
+        out[g] = {"sum_qty": float(qty[mg].sum()),
+                  "sum_base_price": float(price[mg].sum()),
+                  "sum_disc_price": float(disc_price[mg].sum()),
+                  "sum_charge": float(charge[mg].sum()),
+                  "count_order": int(mg.sum())}
+    return out
+
+
 class LineitemTable:
     """Helper owning a set of tablets covering the lineitem table."""
 

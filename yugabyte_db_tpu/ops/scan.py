@@ -66,6 +66,7 @@ src/yb/docdb/pgsql_operation.cc:3153):
 from __future__ import annotations
 
 import asyncio
+import threading
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -634,23 +635,28 @@ class ScanKernel:
     def __init__(self):
         self._cache: Dict[tuple, object] = {}
         self.compiles = 0
+        # `run` is called from the threads that serve reads' launches
+        # beside the event loop (tablet/tablet.py serve_read) as well as
+        # from the loop: one program a signature whoever asks first
+        self._lock = threading.Lock()
 
     def _get(self, sig, where_node, aggs, group, mvcc_mode, static_sums,
              strategy):
-        fn = self._cache.get(sig)
-        if fn is None:
-            raw = _build_kernel(where_node, aggs, group, mvcc_mode,
-                                static_sums=static_sums,
-                                strategy=strategy)
-            # a stable program name: a kept trace's "XLA Modules" line
-            # reads jit_scan_linked..., not jit_fn
-            raw.__name__ = raw.__qualname__ = "_".join(
-                ["scan", mvcc_mode] + ([type(group).__name__.lower()]
-                                       if group is not None else []))
-            fn = jax.jit(raw)
-            self._cache[sig] = fn
-            self.compiles += 1
-        return fn
+        with self._lock:
+            fn = self._cache.get(sig)
+            if fn is None:
+                raw = _build_kernel(where_node, aggs, group, mvcc_mode,
+                                    static_sums=static_sums,
+                                    strategy=strategy)
+                # a stable program name: a kept trace's "XLA Modules"
+                # line reads jit_scan_linked..., not jit_fn
+                raw.__name__ = raw.__qualname__ = "_".join(
+                    ["scan", mvcc_mode] + ([type(group).__name__.lower()]
+                                           if group is not None else []))
+                fn = jax.jit(raw)
+                self._cache[sig] = fn
+                self.compiles += 1
+            return fn
 
     def run(self, batch: DeviceBatch,
             where: Optional[tuple] = None,
@@ -661,15 +667,20 @@ class ScanKernel:
         HashGroupSpec adds (group_values, n_groups); DictGroupSpec adds
         a trailing spill count (nonzero = slot overflow, the caller
         must revert to the interpreted GROUP BY).  Everything but the
-        mask is a host value (`launch`)."""
+        mask is a host value (`launch`).  It reads the batch, which
+        nothing changes once it is built, and this kernel's own program
+        cache under its lock — nothing of a store — so a served read
+        calls it on a thread beside the event loop."""
         sig, key, args = prepare_launch(batch, where, aggs, group, read_ht)
-        pre = self.compiles
+        # (two threads that both find no program both wait for the one
+        # compile, and both say so)
+        compiled = sig not in self._cache
         fn = self._get(sig, *key)
         if isinstance(key[2], ResolvedDictGroup):
             from .grouped_scan import GROUPED_STATS
-            GROUPED_STATS["launches"] += 1
-        return launch(fn, sig, key, args, batch, self.compiles > pre,
-                      mask=True)
+            with self._lock:
+                GROUPED_STATS["launches"] += 1
+        return launch(fn, sig, key, args, batch, compiled, mask=True)
 
 
 def prepare_launch(batch, where, aggs, group, read_ht, n_total=None):

@@ -9,6 +9,7 @@ src/postgres yb_scan paths).
 """
 from __future__ import annotations
 
+import threading
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field, replace
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -233,12 +234,30 @@ class DistributedScanKernel:
     def __init__(self):
         self._cache: Dict[tuple, object] = {}
         self.compiles = 0
+        self._lock = threading.Lock()
 
     def _get(self, sig, tm: TabletMesh, where, aggs, group, mvcc_mode,
              static_sums, strategy):
-        fn = self._cache.get(sig)
-        if fn is not None:
+        # under the lock, as `ScanKernel._get`: `run` is called beside
+        # the event loop too
+        with self._lock:
+            fn = self._cache.get(sig)
+            if fn is None:
+                fn = self._cache[sig] = self._build(
+                    sig, tm, where, aggs, group, mvcc_mode, static_sums,
+                    strategy)
+                self.compiles += 1
             return fn
+
+    def forget(self, tm: TabletMesh) -> None:
+        """Drop the programs compiled for `tm` (their signature begins
+        with `id(tm.mesh)`): its owner gives its chips back."""
+        with self._lock:
+            for sig in [s for s in self._cache if s[0] == id(tm.mesh)]:
+                del self._cache[sig]
+
+    def _build(self, sig, tm: TabletMesh, where, aggs, group, mvcc_mode,
+               static_sums, strategy):
         axes = ROW_AXES
         S = tm.num_tablet_shards * tm.num_block_shards
         # static SUM scales derive from GLOBAL host-side column bounds,
@@ -302,10 +321,7 @@ class DistributedScanKernel:
         mesh_scan.__name__ = mesh_scan.__qualname__ = "_".join(
             ["mesh_scan", mvcc_mode] + ([type(group).__name__.lower()]
                                         if group is not None else []))
-        fn = jax.jit(mesh_scan)
-        self._cache[sig] = fn
-        self.compiles += 1
-        return fn
+        return jax.jit(mesh_scan)
 
     def run(self, batch: ShardedBatch,
             where: Optional[tuple] = None,
@@ -321,10 +337,10 @@ class DistributedScanKernel:
             batch, where, aggs, group, read_ht,
             n_total=batch.padded_rows * batch.num_shards)
         sig = (id(tm.mesh),) + sig
-        pre = self.compiles
-        fn = self._get(sig, tm, *key)
+        compiled = sig not in self._cache
         outs, counts, spilled = launch(
-            fn, sig, key, args, batch, self.compiles > pre, mask=False,
+            self._get(sig, tm, *key), sig, key, args, batch, compiled,
+            mask=False,
             tags=(("chips", tm.mesh.devices.size),
                   ("shards", batch.num_shards)))
         if isinstance(group, DictGroupSpec):

@@ -10,12 +10,14 @@ directly.
 """
 from __future__ import annotations
 
+import asyncio
 import collections
 import logging
 import os
 import threading
 from concurrent.futures import ThreadPoolExecutor
 from time import perf_counter as _perf_counter
+from time import perf_counter_ns as _perf_counter_ns
 from typing import Dict, Optional
 
 import numpy as np
@@ -27,7 +29,7 @@ from ..docdb.compaction import (
 )
 from ..docdb.operations import (
     DocReadOperation, DocWriteOperation, ReadRequest, ReadResponse,
-    ReadRestartError, WriteRequest, WriteResponse,
+    ReadRestartError, WriteRequest, WriteResponse, run_steps,
 )
 from ..docdb.table_codec import TableCodec, TableInfo
 from ..ops.device_batch import DeviceBlockCache
@@ -48,9 +50,84 @@ _DEVICE_CACHE = DeviceBlockCache()
 _FLUSH_POOL = ThreadPoolExecutor(max_workers=2,
                                  thread_name_prefix="bg-flush")
 
+# threads that make a served read's launches — `ScanKernel.run`:
+# dispatch, the wait for the device, the read-back — while the event
+# loop goes on with the other reads and with everything else
+# (`serve_read`).  Most of a launch is a wait that holds no lock and no
+# core; eight is two statements' four tablet reads each, more than a
+# chip runs at a time.
+_READ_LAUNCH_POOL = ThreadPoolExecutor(max_workers=8,
+                                       thread_name_prefix="read-launch")
+
 #: blocks a bulk load makes and serializes at a time, ahead of the file
 #: write
 _BULK_WORKERS = max(2, min(4, (os.cpu_count() or 2) - 1))
+
+
+class ServedReads:
+    """One server's reads whose launch is with `_READ_LAUNCH_POOL`: how
+    many are open (`serve_read` counts on the event loop), and on the
+    server's `/metrics` the most that were (`reads_in_flight_max`) and
+    the time a launch stood in the pool's queue
+    (`read_offload_queue_us`).  A `TabletServer` makes one and hands it
+    to its tablets; a tablet with no server has its own."""
+
+    def __init__(self, owner: str):
+        ent = metrics.REGISTRY.entity("server", owner or "docdb")
+        self.open = 0
+        self.m_max = ent.gauge("reads_in_flight_max")
+        self.m_queue = ent.histogram("read_offload_queue_us")
+
+
+def _launch_in_pool(call, tctx):
+    """Pool side of `serve_read`: when a thread took the launch, and its
+    result; `tctx` is the read's trace context on the loop (executor
+    threads see no contextvars), so `device.scan` and `device.wait` stay
+    in the statement's span tree."""
+    began = _perf_counter_ns()
+    with _trace.use_context(tctx):
+        return began, call()
+
+
+async def serve_read(steps, served: ServedReads):
+    """Drive a read written as steps (`DocReadOperation.execute_steps`,
+    `MeshReader.read_steps`) on the event loop: every step runs here, on
+    the loop's thread, and each launch it yields runs on a thread of
+    `_READ_LAUNCH_POOL`, so the loop serves the other reads — the other
+    stream's, and the other tablets of the same statement — meanwhile.
+    The hop is the `tserver.read_offload` span, child of the span that
+    was ambient when the read began (`tserver.read:<tablet>`): `queue_ms`
+    from handing the launch over to a thread taking it, `in_flight` the
+    server's reads in the pool's hands with this one."""
+    parent = _trace.current_context()
+    loop = asyncio.get_running_loop()
+    try:
+        call = next(steps)
+        while True:
+            served.open += 1
+            if served.open > served.m_max.value():
+                served.m_max.set(served.open)
+            try:
+                tctx = _trace.current_context()     # the read's own span
+                with _trace.TRACES.span(
+                        "tserver.read_offload", parent=parent,
+                        child_only=True,
+                        tags={"in_flight": served.open}) as sp:
+                    t0 = _perf_counter_ns()
+                    began, got = await loop.run_in_executor(
+                        _READ_LAUNCH_POOL, _launch_in_pool, call, tctx)
+                    served.m_queue.increment((began - t0) / 1e3)
+                    sp.set_tag("queue_ms", (began - t0) / 1e6)
+            except Exception as e:   # noqa: BLE001 — the read's to see
+                call = steps.throw(e)
+            else:
+                call = steps.send(got)
+            finally:
+                served.open -= 1
+    except StopIteration as done:
+        return done.value
+    finally:
+        steps.close()
 
 
 class _VectorIndexState:
@@ -86,10 +163,12 @@ class Tablet:
     def __init__(self, tablet_id: str, info: TableInfo, directory: str,
                  clock: Optional[HybridClock] = None,
                  partition=None, colocated: bool = False,
-                 owner: str = ""):
+                 owner: str = "", served: Optional[ServedReads] = None):
         self.tablet_id = tablet_id
-        # the server whose `/metrics` entity the read path counts on
+        # the server whose `/metrics` entity the read path counts on,
+        # and its count of reads in flight
         self.owner = owner
+        self.served = served or ServedReads(owner)
         self.info = info
         self.partition = partition
         self.dir = directory
@@ -273,15 +352,27 @@ class Tablet:
         self.regular.read_facts = None
 
     # --- reads ------------------------------------------------------------
-    def read(self, req: ReadRequest) -> ReadResponse:
+    def read_steps(self, req: ReadRequest):
+        """The read as the steps of `DocReadOperation.execute_steps`."""
         t0 = _perf_counter()
         if req.read_ht is None:
             req.read_ht = self.clock.now().value
             req.server_assigned_read_ht = True
-        resp = self._read_ops.get(req.table_id, self._read_op).execute(req)
+        resp = yield from self._read_ops.get(
+            req.table_id, self._read_op).execute_steps(req)
         self._m_reads.increment()
         self._m_read_lat.increment((_perf_counter() - t0) * 1e6)
         return resp
+
+    def read(self, req: ReadRequest) -> ReadResponse:
+        """For a caller that is no event loop: it makes the read's
+        launches, and stands in their waits, itself."""
+        return run_steps(self.read_steps(req))
+
+    async def read_served(self, req: ReadRequest) -> ReadResponse:
+        """For the event loop (`TabletPeer.read`): the steps run on it,
+        the launches beside it (`serve_read`)."""
+        return await serve_read(self.read_steps(req), self.served)
 
     def key_is_live(self, table_id: str, pk_row: dict) -> bool:
         """Whether a live row sits at the key now: what `read` of a

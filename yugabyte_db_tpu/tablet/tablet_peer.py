@@ -415,13 +415,13 @@ class TabletPeer:
         if req.consistency == "follower":
             if self.split_done:
                 raise RpcError("tablet has been split", "TABLET_SPLIT")
-            return self.tablet.read(req)
+            return await self.tablet.read_served(req)
         self.check_strong_read()
         if req.read_ht is None:
             req.read_ht = self.clock.now().value
             req.server_assigned_read_ht = True
         await self.wait_safe_time(req.read_ht)
-        return self.tablet.read(req)
+        return await self.tablet.read_served(req)
 
     def check_strong_read(self) -> None:
         """The gates of a strong read: not split away, leader, lease."""

@@ -26,7 +26,7 @@ from ..rpc.messenger import (Messenger, RpcError, Sidecars,
                              sidecar_ref)
 from ..sched import (Lane, PointReadItem, RequestScheduler, ScanItem,
                      WriteItem, canon, classify_read)
-from ..tablet.tablet import Tablet
+from ..tablet.tablet import ServedReads, Tablet, serve_read
 from ..tablet.tablet_peer import TabletPeer
 import logging
 
@@ -163,6 +163,8 @@ class TabletServer:
         # tablet execution (sched/): data-path RPCs route through it
         # when `scheduler_enabled` is on; flag off = direct dispatch
         self.scheduler = RequestScheduler(f"ts-{uuid}")
+        # this server's reads whose launch is beside the event loop
+        self.served_reads = ServedReads(f"ts-{uuid}")
         # edge gate: saturated-lane requests shed at the frame edge,
         # before a dispatch task is even spawned
         self.messenger.overload_probe = self.scheduler.overload_probe
@@ -237,6 +239,9 @@ class TabletServer:
                 p.tablet.drop_device_state()
             _DEVICE_CACHE.capacity = self._cache_capacity_before
             self._cache_capacity_before = None
+            # and the programs compiled for this server's mesh with them:
+            # they are named by `id(mesh)`, which a later mesh may reuse
+            self.mesh_reader.kernel.forget(self.mesh_reader.mesh)
         if graceful:
             # lease release first: a pinned compaction-victim SST is
             # physically unlinked on the last release, which must
@@ -328,7 +333,8 @@ class TabletServer:
         tablet = Tablet(tablet_id, info, self._tablet_dir(tablet_id),
                         clock=self.clock, partition=part,
                         colocated=meta.get("colocated", False),
-                        owner=f"ts-{self.uuid}")
+                        owner=f"ts-{self.uuid}",
+                        served=self.served_reads)
         for tw in meta.get("colocated_tables", []):
             tablet.add_table(TableInfo.from_wire(tw))
         config = RaftConfig([PeerSpec(e[0], tuple(e[1]),
@@ -625,7 +631,10 @@ class TabletServer:
                 ops = [p.tablet.read_op(req.table_id) for p in peers]
             for attempt in range(4):
                 try:
-                    resp = reader.read(req, ops, allow_restart=attempt < 3)
+                    resp = await serve_read(
+                        reader.read_steps(req, ops,
+                                          allow_restart=attempt < 3),
+                        self.served_reads)
                     break
                 except ReadRestartError as e:
                     req.read_ht = e.restart_ht

@@ -444,8 +444,11 @@ def device_span(kind: str, signature=None, compiled: bool = False,
     path."""
     with (wait_status("Device_Compile", component="device")
           if compiled else _NO_WAIT):
-        cur = _current_trace.get()
-        if not isinstance(cur, Trace):
+        # a sampled trace is ambient as the span itself, or — on the
+        # far side of an executor hop (`use_context`: a served read's
+        # launch runs beside the event loop) — as its context
+        ctx = current_context()
+        if ctx is None or not ctx.sampled:
             yield None
             return
         sig = (f"{hash(signature) & 0xFFFFFFFFFFFFFFFF:016x}"
