@@ -14,6 +14,7 @@ import, not in conftest.py — so that under several xdist workers only
 the worker that is given this file loads the TPU library.
 """
 import os
+import re
 import time
 
 import numpy as np
@@ -74,6 +75,35 @@ def _compile(lower):
     return compiled
 
 
+#: an `X64SplitHigh` / `X64SplitLow` custom call whose result is an array:
+#: the chip splitting a whole 64-bit lane into 32-bit halves at the
+#: program's entry (one on a scalar, such as `read_ht`, costs nothing)
+_LANE_SPLIT = re.compile(
+    r"= \w+\[\d[\d,]*\]\S* custom-call\(.*custom_call_target=\"X64Split")
+
+
+def _lane_splits(text: str) -> int:
+    return sum(bool(_LANE_SPLIT.search(line)) for line in text.splitlines())
+
+
+def _lanes_as(lanes: str, shape):
+    """`shape` (a leaf -> ShapeDtypeStruct function) over an argument
+    tree whose `Pair`s are kept as their two words (`pairs`) or made the
+    one 64-bit lane each holds (`whole`: what a launch was handed before
+    the batches held pairs)."""
+    from yugabyte_db_tpu.ops.device_batch import Pair
+
+    def one(x):
+        if not isinstance(x, Pair):
+            return shape(x)
+        if lanes == "pairs":
+            return Pair(shape(x.hi), shape(x.lo))
+        hi = shape(x.hi)
+        return jax.ShapeDtypeStruct(hi.shape, x.dtype, sharding=hi.sharding)
+    return lambda tree: jax.tree_util.tree_map(
+        one, tree, is_leaf=lambda x: isinstance(x, Pair))
+
+
 def _scan_args(query, n_total, mvcc_mode="visible"):
     """(avg-expanded aggs, static_sums, example args, example rows): the
     argument list of a served launch — `ops.scan.prepare_launch`, which
@@ -88,7 +118,7 @@ def _scan_args(query, n_total, mvcc_mode="visible"):
     batch = build_batch(_example_batch(), sorted(query.columns),
                         multi_version=mvcc_mode == "linked")
     assert batch.cols[2].dtype == jnp.float32       # l_extendedprice
-    assert batch.ht.dtype == jnp.uint64
+    assert batch.ht.dtype == jnp.uint64             # as its two words
     _, (_, aggs, _, mode, static_sums, strategy), args = prepare_launch(
         batch, query.where, query.aggs, query.group, 1 << 63, n_total=n_total)
     assert strategy == "unroll"
@@ -129,6 +159,55 @@ def test_scan_kernel_compiles(one_chip, tpu_arms, query_name, mvcc_mode):
     compiled = _compile(lambda: jax.jit(fn).lower(*shapes))
     text = compiled.as_text()
     assert "sort" not in text and "while" not in text
+
+
+#: `scan_power` / `scan_streams2`: a tablet of 1.5M rows, padded
+SERVED_ROWS = 1 << 21
+#: `X64Split` calls on whole lanes of a `linked` launch before the lanes
+#: were pairs: `ht`, `next_ht` and the float64 values, each split high
+#: and low (Q6 price and discount, Q1 also tax)
+WHOLE_LANE_SPLITS = {"q6": 8, "q1": 10}
+
+
+@pytest.mark.parametrize("lanes", ["pairs", "whole"])
+@pytest.mark.parametrize("query_name", ["q6", "q1"])
+def test_served_scan_splits_no_whole_lane(one_chip, monkeypatch,
+                                          query_name, lanes):
+    """The one-device programs of the one-chip cells — `linked`, the
+    configuration's float64 on the TPU's arm, 2,097,152 rows — take
+    every 64-bit lane of the batch as two 32-bit lanes, so the chip
+    splits none at the program's entry; the same program handed whole
+    lanes splits each (the count before the pairs)."""
+    from __graft_entry__ import _example_batch
+    from yugabyte_db_tpu.models import tpch
+    from yugabyte_db_tpu.ops import device_batch
+    from yugabyte_db_tpu.ops.scan import _build_kernel, prepare_launch
+    query = {"q6": tpch.TPCH_Q6, "q1": tpch.TPCH_Q1}[query_name]
+    monkeypatch.setattr(device_batch, "_backend_float64_is_pair",
+                        lambda: True)
+    flags.set_flag("device_float_dtype", "float64")
+    flags.set_flag("scan_group_strategy", "unroll")
+    try:
+        batch = device_batch.build_batch(
+            _example_batch(), sorted(query.columns), multi_version=True)
+        _, (where, aggs, group, mode, static_sums, strategy), args = \
+            prepare_launch(batch, query.where, query.aggs, query.group,
+                           1 << 63, n_total=SERVED_ROWS)
+    finally:
+        for f in ("device_float_dtype", "scan_group_strategy"):
+            flags.REGISTRY.reset(f)
+    assert (mode, strategy) == ("linked", "unroll")
+    assert isinstance(batch.cols[tpch.EXTPRICE], device_batch.Pair)
+    fn = _build_kernel(where, aggs, group, mode, static_sums=static_sums,
+                       strategy=strategy)
+    small = batch.padded_rows
+    shapes = _lanes_as(lanes, lambda x: _shapes(
+        x, small, (SERVED_ROWS,), one_chip, one_chip))(args)
+    text = _compile(lambda: jax.jit(fn).lower(*shapes)).as_text()
+    assert _lane_splits(text) == (
+        0 if lanes == "pairs" else WHOLE_LANE_SPLITS[query_name])
+    # what is left: the scalars' splits (`read_ht`, the literals)
+    assert "X64Split" in text
 
 
 def test_sort_grouped_kernel_compiles(one_chip, tpu_arms):
@@ -190,7 +269,9 @@ def test_distributed_scan_compiles_for_four_chips(topo, tpu_arms):
 MESH_SHARD_ROWS = 1 << 24
 
 
-def test_served_mesh_scan_compiles_for_four_chips(topo, tmp_path):
+@pytest.mark.parametrize("lanes", ["pairs", "whole"])
+def test_served_mesh_scan_compiles_for_four_chips(topo, tmp_path,
+                                                  monkeypatch, lanes):
     """The programs one tserver that owns four chips launches for Q6 and
     Q1 through SQL (`docdb/mesh_read.py`): `linked` mask with `next_ht`
     per shard, float64 lanes, Q1 grouped by two text columns as codes of
@@ -199,10 +280,13 @@ def test_served_mesh_scan_compiles_for_four_chips(topo, tmp_path):
     a mesh of the described chips.
     The statements run here first, on four of the CPU's devices, to take
     each program's own arguments; what is compiled is the TPU's arm
-    (`unroll`)."""
+    (`unroll`, float64 lanes as pairs).  With `pairs` the chip splits no
+    whole lane at a program's entry; handed `whole` lanes, as before the
+    batches held pairs, it splits each, high and low."""
     import asyncio
     from test_mesh_scan import TABLE, Served
     from benchmark import tpch
+    from yugabyte_db_tpu.ops import device_batch
     from yugabyte_db_tpu.parallel.distributed_scan import \
         DistributedScanKernel
     from yugabyte_db_tpu.parallel.mesh import (BLOCKS_AXIS, TABLETS_AXIS,
@@ -225,6 +309,8 @@ def test_served_mesh_scan_compiles_for_four_chips(topo, tmp_path):
                 await t.sql.execute(tpch.SQL[q].format(name=TABLE))
 
     flags.set_flag("scan_group_strategy", "unroll")
+    monkeypatch.setattr(device_batch, "_backend_float64_is_pair",
+                        lambda: True)
     try:
         asyncio.run(statements())
     finally:
@@ -253,9 +339,11 @@ def test_served_mesh_scan_compiles_for_four_chips(topo, tmp_path):
         fn = DistributedScanKernel()._get(
             (id(tm.mesh),) + sig[1:], tm, where, aggs, group, mode,
             static_sums, strategy)
-        compiled = _compile(lambda: fn.lower(
-            *jax.tree_util.tree_map(shape, args)))
+        compiled = _compile(lambda: fn.lower(*_lanes_as(lanes, shape)(args)))
         text = compiled.as_text()
+        assert _lane_splits(text) == (0 if lanes == "pairs" else
+                                      WHOLE_LANE_SPLITS[
+                                          "q6" if group is None else "q1"])
         assert text.count("all-reduce(") + text.count("all-reduce-start(") \
             == 1, "every additive partial rides one all-reduce"
         # Q6 runs whole, Q1's grouped body in one loop over four row

@@ -436,16 +436,20 @@ def test_cache_accounts_and_evicts_per_chip():
 
 
 # --- a shard longer than the kernel's row tile --------------------------------
+@pytest.mark.parametrize("lanes", ["whole", "pairs"])
 @pytest.mark.parametrize("shape", ["q6", "q1_dense", "q1_dict"])
-def test_a_long_shard_runs_in_row_tiles(shape, monkeypatch):
+def test_a_long_shard_runs_in_row_tiles(shape, lanes, monkeypatch):
     """Four shards of 4,096 padded rows under a tile of 1,024: for a
     grouped scan each chip loops over four tiles and adds their
     partials, then ONE all-reduce adds the chips' — the bits of the
     whole program, and of the one-device kernel over the same rows
-    (sixteen tiles).  Q6 has no group: whole on either kernel."""
+    (sixteen tiles).  Q6 has no group: whole on either kernel.  With
+    `pairs` (the TPU's arm) the float64 lanes are `Pair`s, joined a tile
+    at a time."""
     import dataclasses
     from yugabyte_db_tpu.docdb.table_codec import TableCodec
     from yugabyte_db_tpu.models import tpch as model
+    from yugabyte_db_tpu.ops import device_batch
     from yugabyte_db_tpu.ops import scan as scan_mod
     from yugabyte_db_tpu.ops.scan import ScanKernel
     from yugabyte_db_tpu.parallel import tablet_mesh
@@ -464,6 +468,9 @@ def test_a_long_shard_runs_in_row_tiles(shape, monkeypatch):
         {k: v[i * 3000:(i + 1) * 3000] for k, v in data.items()},
         HybridTime.from_micros(100)) for i in range(4)]
     flags.set_flag("device_float_dtype", "float64")
+    if lanes == "pairs":
+        monkeypatch.setattr(device_batch, "_backend_float64_is_pair",
+                            lambda: True)
     mesh = dataclasses.replace(build_sharded_batch(
         tablet_mesh(4, devices=jax.devices()[:4]), per_shard,
         sorted(q.columns), multi_version=True), dicts=words)
@@ -472,6 +479,8 @@ def test_a_long_shard_runs_in_row_tiles(shape, monkeypatch):
         multi_version=True), dicts=words)
     assert (mesh.padded_rows, mesh.num_shards, one.padded_rows) \
         == (4096, 4, 16384)
+    assert all(isinstance(b.cols[model.EXTPRICE], device_batch.Pair)
+               == (lanes == "pairs") for b in (mesh, one))
     read_ht = HybridTime.from_micros(10_000).value
     programs = []
 

@@ -8,7 +8,7 @@ import pytest
 from yugabyte_db_tpu.ops import (
     AggSpec, DeviceBatch, Expr, ScanKernel, scan_aggregate, scan_filter,
 )
-from yugabyte_db_tpu.ops.device_batch import build_batch, bucket_rows
+from yugabyte_db_tpu.ops.device_batch import Pair, build_batch, bucket_rows
 from yugabyte_db_tpu.ops.scan import GroupSpec
 from yugabyte_db_tpu.storage.columnar import ColumnarBlock
 
@@ -246,9 +246,11 @@ class TestLaunchProtocol:
         assert kern.compiles == 1
         cols, nulls, consts, valid, ht, next_ht, tomb, read_ht, scales, \
             domains = seen[-1]
-        # what the batch keeps on the device goes in as it is ...
-        assert all(isinstance(x, jax.Array)
-                   for x in (*cols.values(), valid, ht, tomb))
+        # what the batch keeps on the device goes in as it is, the
+        # write times as their two 32-bit words ...
+        assert isinstance(ht, Pair)
+        assert all(isinstance(x, jax.Array) for x in
+                   jax.tree_util.tree_leaves((cols, valid, ht, tomb)))
         # ... and nothing else is put there ahead of the call
         runtime = jax.tree_util.tree_leaves(
             (consts, read_ht, scales, domains))

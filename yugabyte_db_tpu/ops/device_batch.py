@@ -12,7 +12,7 @@ from __future__ import annotations
 import threading
 from collections import OrderedDict
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,26 +34,114 @@ def bucket_rows(n: int) -> int:
     return b
 
 
+class Pair(NamedTuple):
+    """A 64-bit lane as the two 32-bit lanes the chip computes with.
+    The TPU has no 64-bit lanes: XLA splits every 64-bit parameter into
+    two 32-bit arrays at a program's entry, over the whole lane, before
+    any fusion or loop (a custom call that reads the lane from HBM and
+    writes each half back).  A cached batch holds its 64-bit lanes split
+    once, at build time, and the kernel joins a run of rows where it
+    reads them (`join`).  Two forms, told apart by the words' dtype:
+    uint32 words of a uint64 (`u64_pair`: high, low; exact), and the
+    float32 high part and remainder of a float64 (`f64_pair`: what the
+    chip's own split makes of it, so the chip computes on the same
+    value).  A pytree: it rides a jitted call, a `shard_map` and the
+    kernel's row tiles as its two leaves."""
+    hi: Any
+    lo: Any
+
+    @property
+    def dtype(self) -> np.dtype:
+        """The dtype of the value the pair holds."""
+        return np.dtype(np.uint64 if self.hi.dtype == np.uint32
+                        else np.float64)
+
+    def devices(self) -> set:
+        return self.hi.devices()
+
+
+#: a 32-bit word that is all ones: both words of `HT_NONE`
+WORD_MAX = 0xFFFFFFFF
+
+
+def u64_pair(a: np.ndarray) -> Pair:
+    """A host uint64 lane as its (high, low) uint32 words."""
+    return Pair((a >> np.uint64(32)).astype(np.uint32),
+                a.astype(np.uint32))
+
+
+def f64_pair(a: np.ndarray) -> Pair:
+    """A host float64 lane as (hi, lo) float32: `hi` the value rounded
+    to float32, `lo` the remainder rounded to float32 — the pair XLA's
+    own split of a float64 on the TPU gives.  Where `hi` is not finite
+    the pair is (hi, 0)."""
+    with np.errstate(over="ignore", invalid="ignore"):
+        hi = a.astype(np.float32)
+        lo = (a - hi).astype(np.float32)
+    lo[~np.isfinite(hi)] = 0
+    return Pair(hi, lo)
+
+
+def join(x):
+    """Inside a program: the value a lane holds — a `Pair` joined into
+    the 64-bit value (uint64 words exactly; a float64 pair as the sum
+    of its parts, which on the TPU is the pair itself), anything else
+    as it is.  The kernels join a run of rows where they read it, never
+    a whole lane at a program's entry."""
+    if not isinstance(x, Pair):
+        return x
+    if x.hi.dtype == jnp.uint32:
+        return (x.hi.astype(jnp.uint64) << 32) | x.lo.astype(jnp.uint64)
+    return x.hi.astype(jnp.float64) + x.lo.astype(jnp.float64)
+
+
+def words(x) -> Pair:
+    """Inside a program: a uint64 value (a lane or a scalar such as
+    `read_ht`) as its (high, low) uint32 words; a `Pair` as it is."""
+    if isinstance(x, Pair):
+        return x
+    return Pair((x >> 32).astype(jnp.uint32), x.astype(jnp.uint32))
+
+
+def lane_sig(v) -> str:
+    """A lane's dtype as a program's signature names it: a pair is not
+    the 64-bit lane it holds."""
+    if isinstance(v, Pair):
+        return f"{v.hi.dtype}x2"
+    return str(v.dtype)
+
+
+def wide_lanes(tree) -> int:
+    """How many 64-bit arrays (not scalars) `tree` holds: the parameters
+    the chip would split over the whole lane at a launch's entry."""
+    return sum(np.ndim(x) >= 1 and np.dtype(x.dtype).itemsize == 8
+               for x in jax.tree_util.tree_leaves(tree)
+               if hasattr(x, "dtype"))
+
+
 @dataclass
 class DeviceBatch:
     """Padded columnar batch on device.
 
-    cols / nulls: col_id -> [N] arrays (nulls True where SQL NULL).
+    cols / nulls: col_id -> [N] arrays (nulls True where SQL NULL); a
+    float64 column on a backend without 64-bit lanes is a `Pair`.
     valid: [N] bool — False on padding rows and MVCC-invisible rows.
-    ht / tombstone: the MVCC lanes (absent when built without them).
+    ht / tombstone: the MVCC lanes (absent when built without them); `ht`
+    is a `Pair` of uint32 words on every backend.
     next_ht: present exactly when the batch may hold several versions of
     a key — per row the `ht` of the next newer version of its key among
     the batch's rows (`link_versions`), which makes the kernel's
-    newest-visible-version mask one elementwise pass.  The doc-key hash
-    and the write id stay on the host: only the link reads them.
+    newest-visible-version mask one elementwise pass; a `Pair` too.  The
+    doc-key hash and the write id stay on the host: only the link reads
+    them.
     """
 
     n_rows: int                      # true (unpadded) row count
-    cols: Dict[int, jnp.ndarray]
+    cols: Dict[int, Any]
     nulls: Dict[int, jnp.ndarray]
     valid: jnp.ndarray
-    ht: Optional[jnp.ndarray] = None
-    next_ht: Optional[jnp.ndarray] = None
+    ht: Optional[Pair] = None
+    next_ht: Optional[Pair] = None
     tombstone: Optional[jnp.ndarray] = None
     # string columns ride as int32 dictionary CODES in `cols`; the
     # sorted dictionaries stay host-side here — predicates translate to
@@ -93,6 +181,24 @@ def _float64_device_dtype() -> np.dtype:
     import jax
     return np.dtype(np.float64 if jax.default_backend() == "cpu"
                     else np.float32)
+
+
+def _backend_float64_is_pair() -> bool:
+    """True where the backend has no 64-bit lanes and computes a float64
+    as two float32 (the TPU).  Tests steer the TPU arm by patching this,
+    as `jax.default_backend()` says `cpu` in a compile for a described
+    chip."""
+    return jax.default_backend() == "tpu"
+
+
+def f64_pairs() -> bool:
+    """Whether a float64 value lane that `f64_conversion` keeps float64
+    ships as a `Pair` (`f64_pair`): where the backend's float64 is itself
+    a pair, the chip then computes on the same value without splitting
+    the whole lane at each launch.  A CPU keeps whole float64 lanes.
+    Asked by both batch builders; the lane builders of the join and
+    grouped routes keep whole lanes."""
+    return _backend_float64_is_pair()
 
 
 def _integral_int32(arr: np.ndarray) -> bool:
@@ -209,6 +315,7 @@ def build_batch(blocks: Sequence[ColumnarBlock],
         col_bounds: Dict[int, Tuple[float, float]] = {}
         copy_jobs: List[Tuple[np.ndarray, np.ndarray]] = []
         host_cols: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
+        pair_cols: List[int] = []      # float64 lanes shipped as pairs
 
         def fill(parts: List[np.ndarray],
                  out_dtype: Optional[np.dtype] = None) -> np.ndarray:
@@ -302,6 +409,8 @@ def build_batch(blocks: Sequence[ColumnarBlock],
                     if stat_parts and stat_parts[0].dtype == np.float64
                     else None)
             arr = fill(parts, conv)
+            if arr.dtype == np.float64 and f64_pairs():
+                pair_cols.append(cid)
             stat_n = sum(len(p) for p in stat_parts)
             if stat_n and arr.dtype.kind in "fiu":
                 # bounds from the parts (the padded tail is zeros and must
@@ -331,18 +440,27 @@ def build_batch(blocks: Sequence[ColumnarBlock],
                     np.concatenate([b.write_id for b in blocks]))
                 sp.set_tag("rows", n)
                 sp.set_tag("superseded", superseded)
+        # every 64-bit lane as the two 32-bit lanes the chip computes
+        # with, split once here and not at each launch (`Pair`)
+        for cid in pair_cols:
+            host_cols[cid] = (f64_pair(host_cols[cid][0]),
+                              host_cols[cid][1])
+        if with_mvcc:
+            ht_host = u64_pair(ht_host)
+        if next_host is not None:
+            next_host = u64_pair(next_host)
     with TRACES.span("batch.h2d", child_only=True) as sp:
         for cid, (arr, null) in host_cols.items():
-            cols[cid] = jnp.asarray(arr)
+            cols[cid] = _to_device(arr)
             nulls[cid] = jnp.asarray(null)
         batch = DeviceBatch(
             n_rows=n, cols=cols, nulls=nulls, valid=jnp.asarray(valid),
             dicts=dicts, col_bounds=col_bounds)
         if with_mvcc:
-            batch.ht = jnp.asarray(ht_host)
+            batch.ht = _to_device(ht_host)
             batch.tombstone = jnp.asarray(tomb_host)
         if next_host is not None:
-            batch.next_ht = jnp.asarray(next_host)
+            batch.next_ht = _to_device(next_host)
         if sp.sampled:
             # transfers are asynchronous: wait, so that the span times
             # them and not their enqueue
@@ -351,6 +469,11 @@ def build_batch(blocks: Sequence[ColumnarBlock],
                  batch.tombstone))
             sp.set_tag("bytes", batch_bytes(batch))
     return batch
+
+
+def _to_device(x):
+    """A host lane, or each word of a host `Pair`, on the device."""
+    return jax.tree_util.tree_map(jnp.asarray, x)
 
 
 def _dict_merge_column(blocks: Sequence[ColumnarBlock], cid: int):
@@ -525,8 +648,9 @@ class DeviceBlockCache:
 
 
 def _lanes(b) -> list:
-    return [a for a in list(b.cols.values()) + list(b.nulls.values())
-            + [b.valid, b.ht, b.next_ht, b.tombstone] if a is not None]
+    """Every array of a batch, a `Pair` as its two."""
+    return jax.tree_util.tree_leaves(
+        [b.cols, b.nulls, b.valid, b.ht, b.next_ht, b.tombstone])
 
 
 def batch_bytes(b) -> int:

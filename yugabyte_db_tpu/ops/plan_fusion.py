@@ -42,7 +42,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..utils import flags
-from .device_batch import DeviceBatch, bucket_rows, build_batch
+from .device_batch import (DeviceBatch, bucket_rows, build_batch, join,
+                           lane_sig, wide_lanes)
 from .expr import collect_constants, compile_expr, expr_signature
 from .grouped_scan import (DictGroupSpec, ResolvedDictGroup,
                            dict_cols_needed, domain_product,
@@ -109,6 +110,7 @@ class FusedPlanKernel:
         def fn(cols, nulls, consts, valid, ht, next_ht, tombstone,
                read_ht, sum_scales, group_domains, joins):
             import jax.numpy as jnp
+            cols = {cid: join(v) for cid, v in cols.items()}
             mask = visibility_mask(mvcc_mode, valid, ht, next_ht,
                                    tombstone, read_ht)
             if where_fn is not None:
@@ -164,7 +166,7 @@ class FusedPlanKernel:
                     else tuple(join_rt))
         # per-stage probe-lane eligibility: stage 0 probes a real batch
         # lane, stage k may also probe an earlier stage's payload lane
-        avail = {cid: str(v.dtype) for cid, v in batch.cols.items()}
+        avail = {cid: lane_sig(v) for cid, v in batch.cols.items()}
         for si, rt in enumerate(join_rts):
             dt = avail.get(rt.probe_col)
             if dt is None or dt[:3] not in ("int", "uin"):
@@ -197,7 +199,7 @@ class FusedPlanKernel:
             aggs, bounds, batch.padded_rows, dtype_cols)
         strategy = _group_strategy()
         col_sig = tuple(sorted(
-            (cid, str(v.dtype)) for cid, v in batch.cols.items()))
+            (cid, lane_sig(v)) for cid, v in batch.cols.items()))
         join_shape = tuple(
             (rt.probe_col, rt.num_slots, rt.build_rows_pad,
              tuple((bid, str(rt.payload_vals[bid].dtype))
@@ -240,8 +242,8 @@ class FusedPlanKernel:
         with _trace.device_span("fused_plan", signature=sig,
                                 compiled=compiled,
                                 bucket=batch.padded_rows,
-                                rows=batch.n_rows, mvcc=mvcc_mode):
-            raw = fn(
+                                rows=batch.n_rows, mvcc=mvcc_mode) as sp:
+            args = (
                 batch.cols, batch.nulls,
                 [jnp.asarray(c) for c in consts], batch.valid, *lanes,
                 jnp.uint64(read_ht if read_ht is not None
@@ -256,6 +258,10 @@ class FusedPlanKernel:
                            for bid in rt.build_cols))
                     for rt in join_rts),
             )
+            if sp is not None:
+                # as `ops/scan.py launch`: 64-bit arrays the chip splits
+                sp.set_tag("wide_lanes", wide_lanes(args))
+            raw = fn(*args)
         return (_rescale_outs(raw[0], raw[1]),) + tuple(raw[2:])
 
 

@@ -35,7 +35,9 @@ src/yb/docdb/pgsql_operation.cc:3153):
   n_rows * bound * s < 2^62 cannot overflow — then summed exactly and
   rescaled on the host in f64. The only error is per-row: the f32
   device representation of the value itself (<= 2^-24 relative; f64 on
-  CPU backends) plus quantization <= 0.5 granule/row. For a FIXED
+  CPU backends; a float64 lane on the TPU is the float32 pair the chip
+  computes with, `device_batch.Pair`, <= 2^-48) plus quantization
+  <= 0.5 granule/row. For a FIXED
   device dtype and quantization scale the result is order-independent —
   accumulation order (MXU vs VPU vs psum tree) can never change it;
   error bounds do not grow with row count. Results may still differ at
@@ -74,7 +76,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .device_batch import HT_NONE, DeviceBatch
+from .device_batch import (WORD_MAX, DeviceBatch, join, lane_sig,
+                           wide_lanes, words)
 from .expr import collect_constants, compile_expr, expr_signature
 from .grouped_scan import (DictGroupSpec, ResolvedDictGroup,
                            grouped_reduce, resolve_group)
@@ -266,13 +269,23 @@ def visibility_mask(mvcc_mode: str, valid, ht, next_ht, tombstone,
     single-version), 'linked' (newest visible version of each key: the
     row is visible and its next newer version, `next_ht`, is not — the
     all-ones sentinel says it has none, and keeps `read_ht` = MAX,
-    "latest", selecting the newest)."""
+    "latest", selecting the newest).
+    Times compare as their 32-bit words, high word first (`words`): a
+    batch holds `ht` and `next_ht` as `Pair`s, and `read_ht`, one uint64
+    host value, splits as a scalar — no 64-bit lane in the program."""
     if mvcc_mode == "none":
         return valid
-    mask = valid & (ht <= read_ht) & jnp.logical_not(tombstone)
+    read = words(read_ht)
+
+    def le(a):          # a <= read_ht
+        return (a.hi < read.hi) | ((a.hi == read.hi) & (a.lo <= read.lo))
+    ht = words(ht)
+    mask = valid & le(ht) & jnp.logical_not(tombstone)
     if mvcc_mode == "visible":
         return mask
-    return mask & ((next_ht == HT_NONE) | (next_ht > read_ht))
+    nxt = words(next_ht)
+    newest = (nxt.hi == WORD_MAX) & (nxt.lo == WORD_MAX)    # HT_NONE
+    return mask & (newest | jnp.logical_not(le(nxt)))
 
 
 def masked_aggregate(group, agg_fns, prep, cols, nulls, consts, mask,
@@ -445,7 +458,9 @@ def _build_kernel(where_node, agg_specs: Tuple[AggSpec, ...],
     def body(cols, nulls, consts, valid, ht, next_ht, tombstone, read_ht,
              sum_scales, group_domains, n_total):
         """The scan of one run of rows — a whole lane or one tile of
-        it; `n_total` is what the SUM scales were sized for."""
+        it; `n_total` is what the SUM scales were sized for.  A column
+        that arrives as a `Pair` is joined here, on the run's rows."""
+        cols = {cid: join(v) for cid, v in cols.items()}
         mask = visibility_mask(mvcc_mode, valid, ht, next_ht, tombstone,
                                read_ht)
         if where_fn is not None:
@@ -715,7 +730,7 @@ def prepare_launch(batch, where, aggs, group, read_ht, n_total=None):
         group, sizes = resolve_group(group, batch.dicts)
         domains = np.asarray(sizes, np.int32)
     col_sig = tuple(sorted(
-        (cid, str(v.dtype)) for cid, v in batch.cols.items()))
+        (cid, lane_sig(v)) for cid, v in batch.cols.items()))
     static_sums, scales = _static_scales(
         aggs, batch.col_bounds, n_total or batch.padded_rows, batch.cols)
     strategy = _group_strategy()
@@ -736,8 +751,10 @@ def prepare_launch(batch, where, aggs, group, read_ht, n_total=None):
 def launch(fn, sig, key, args, batch, compiled: bool, mask: bool, tags=()):
     """Dispatch `fn(*args)`, the program `prepare_launch`'s `key` names
     (the `device.scan` span: tag `host_args` = how many host values the
-    call placed, `tiles` = how many row tiles the program runs a lane
-    in, 1 = whole) and read its result back in ONE transfer
+    call placed, `wide_lanes` = how many 64-bit arrays it was given —
+    each one the chip splits over the whole lane first, 0 with a
+    batch's `Pair`s — `tiles` = how many row tiles the program runs a
+    lane in, 1 = whole) and read its result back in ONE transfer
     (`device.wait`: tag `reads`), `tags` on both spans.
     `fn` returns (outs, scales, counts[, mask], ...): the fixed-point
     sums are rescaled on the host values and the caller gets (outs,
@@ -756,6 +773,7 @@ def launch(fn, sig, key, args, batch, compiled: bool, mask: bool, tags=()):
             sp.set_tag("host_args", sum(
                 not isinstance(x, jax.Array)
                 for x in jax.tree_util.tree_leaves(args)))
+            sp.set_tag("wide_lanes", wide_lanes(args))
             sp.set_tag("tiles", tile_count(batch.padded_rows, group, aggs,
                                            static_sums))
             for k, v in tags:

@@ -19,8 +19,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..ops.device_batch import (HT_NONE, batch_bytes, bucket_rows,
-                                f64_conversion, link_versions)
+from ..ops.device_batch import (HT_NONE, Pair, batch_bytes, bucket_rows,
+                                f64_conversion, f64_pair, f64_pairs,
+                                link_versions, u64_pair)
 from ..ops.grouped_scan import DictGroupSpec
 from ..ops.scan import (AggSpec, GroupSpec, _build_kernel, launch,
                         prepare_launch)
@@ -34,20 +35,22 @@ class ShardedBatch:
     """[S * N] columnar row lanes cut over the mesh (S = total shards =
     tablets * blocks, N = per-shard padded rows): shard i, tablet-major,
     holds rows [i * N, (i + 1) * N) as a one-dimensional [N] lane
-    (`TabletMesh.row_sharding`)."""
+    (`TabletMesh.row_sharding`).  The 64-bit lanes are `Pair`s of such
+    lanes, as in a `DeviceBatch`: `ht`, `next_ht`, and float64 columns
+    where the backend's float64 is a pair."""
     n_rows_per_shard: List[int]
-    cols: Dict[int, jnp.ndarray]
+    cols: Dict[int, object]
     nulls: Dict[int, jnp.ndarray]
     # GLOBAL per-column (min, max) across all shards — static SUM scales
     # derived from these are identical on every shard, so int64 partials
     # psum exactly over ICI with no in-kernel pmax round
     col_bounds: Dict[int, Tuple[float, float]]
     valid: jnp.ndarray
-    ht: jnp.ndarray
+    ht: Pair
     # per shard, as DeviceBatch.next_ht: present when some block is not
     # unique-keyed.  Linking per shard is exact because one doc key
     # lives in exactly one tablet shard and one block shard.
-    next_ht: Optional[jnp.ndarray]
+    next_ht: Optional[Pair]
     tombstone: jnp.ndarray
     mesh: TabletMesh
     # text columns ride as int32 codes of these dictionaries, which are
@@ -136,6 +139,7 @@ def build_sharded_batch(tm: TabletMesh,
         # scales come from, so every shard quantizes identically and the
         # int64 partials psum exactly
         plain = [cid for cid in columns if cid not in dict_cols]
+        pairs = f64_pairs()
         dtypes: Dict[int, np.dtype] = {}
         col_bounds: Dict[int, Tuple[float, float]] = {}
         for cid in plain:
@@ -154,6 +158,8 @@ def build_sharded_batch(tm: TabletMesh,
             for cid in plain:
                 lanes["cols"][cid] = fill(
                     blocks, lambda b: _column_part(b, cid), dtypes[cid])
+                if pairs and dtypes[cid] == np.float64:
+                    lanes["cols"][cid] = f64_pair(lanes["cols"][cid])
                 lanes["nulls"][cid] = fill(
                     blocks, lambda b: (b.fixed[cid][1] if cid in b.fixed
                                        else np.zeros(b.n, bool)), bool)
@@ -193,6 +199,13 @@ def build_sharded_batch(tm: TabletMesh,
                 lsp.set_tag("rows", sum(ns))
                 lsp.set_tag("superseded", superseded)
                 lsp.set_tag("shards", S)
+
+        def split(shard: int) -> None:
+            """The time lanes as their 32-bit words (`Pair`), once."""
+            for lane in ("ht", "next_ht"):
+                if lane in host[shard]:
+                    host[shard][lane] = u64_pair(host[shard][lane])
+        list(pool.map(split, range(S)))
         sp.set_tag("shards", S)
         sp.set_tag("rows", sum(ns))
 
@@ -200,11 +213,16 @@ def build_sharded_batch(tm: TabletMesh,
 
     def put(lane: str, cid=None):
         """One lane of every shard, each on its shard's device, as one
-        array sharded over the mesh."""
-        parts = [jax.device_put(h[lane] if cid is None else h[lane][cid], d)
-                 for h, d in zip(host, devices)]
-        return jax.make_array_from_single_device_arrays(
-            (S * pad,), sharding, parts)
+        array sharded over the mesh — a `Pair` as a pair of them."""
+        shards = [h[lane] if cid is None else h[lane][cid] for h in host]
+
+        def one(parts):
+            return jax.make_array_from_single_device_arrays(
+                (S * pad,), sharding,
+                [jax.device_put(p, d) for p, d in zip(parts, devices)])
+        if isinstance(shards[0], Pair):
+            return Pair(*map(one, zip(*shards)))
+        return one(shards)
 
     with _trace.TRACES.span("batch.h2d", child_only=True) as sp:
         batch = ShardedBatch(
