@@ -3,9 +3,8 @@ one tserver, client, SQL session) and the calls every driver and loader
 shares.  What a table holds, and how it is filled, belongs to the
 configuration's loader (`benchmark/loaders/<loader>.py`).
 
-Cut from `chip_smoke.py` (`Smoke.start/_flush/compact`).  One process
-holds the chip, so master, tserver, client and load generator all live
-here.
+One process holds the chip, so master, tserver, client and load
+generator all live here.
 """
 from __future__ import annotations
 

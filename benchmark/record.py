@@ -113,8 +113,7 @@ class GcWatch:
 
     def summary(self) -> dict:
         return {"collections": self.collections, "full": self.full,
-                "seconds": round(self.seconds, 3),
-                "longest_s": round(self.longest_s, 3)}
+                "seconds": self.seconds, "longest_s": self.longest_s}
 
 
 class Checks:

@@ -32,7 +32,7 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _ROOT not in sys.path:
     sys.path.insert(0, _ROOT)
 
-from benchmark import manifest, peaks, trace_reduce       # noqa: E402
+from benchmark import manifest, peaks, span_reduce, trace_reduce  # noqa: E402
 from benchmark.cluster import Cluster                     # noqa: E402
 from benchmark.record import Checks, GcWatch, Recorder             # noqa: E402
 
@@ -93,6 +93,16 @@ def _program_counters(cluster) -> dict:
             "scan_kernel.compiles": _SHARED_KERNEL.compiles,
             "merge_kernel.calls": merge["calls"],
             "merge_kernel.compiles": merge["compiles"]}
+
+
+def breakdown(ctx) -> dict:
+    """The traced line's top device operations, and its idle gaps named by
+    the program span the host was in (`span_reduce.idle_gaps`); where the
+    spans cannot be read on the trace's clock, by the benchmark span alone
+    (`trace_reduce`)."""
+    gaps = span_reduce.idle_gaps(ctx)
+    return {"device_ops": ctx.trace["device_ops"],
+            "idle_gaps": ctx.trace["idle_gaps"] if gaps is None else gaps}
 
 
 async def _measure(cell, cluster, rec, seconds, traced, keep_trace):
@@ -190,8 +200,7 @@ async def _run(cell, args, devices, info) -> dict:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
         dev.update(busy_s=trace["busy_s"], window_s=trace["window_s"],
                    devices_busy=trace["devices_busy"])
-        result["breakdown"] = {"device_ops": trace["device_ops"],
-                               "idle_gaps": trace["idle_gaps"]}
+        result["breakdown"] = breakdown(ctx)
     compared = checks.table()
     for name, e in compared.items():
         print(f"compared {name}: {e['value']} limit {e['limit']} "

@@ -18,11 +18,14 @@ out.
 """
 from __future__ import annotations
 
+import bisect
+
 from benchmark import trace_reduce
 
 ROOT = "sql.execute"
 STMT_SPAN = "bench:stmt."          # the recorder's statement annotations
 MAX_CLOCK_DISAGREEMENT_NS = 1e6    # 1 ms
+TOP_GAPS = 10                      # entries of `breakdown.idle_gaps`
 
 
 # -- the window's spans -----------------------------------------------------
@@ -166,10 +169,28 @@ def innermost_cover(spans) -> dict:
     return {k: trace_reduce.merge(v) for k, v in out.items()}
 
 
+def bench_cover(bench, free) -> dict:
+    """label -> merged intervals of `free` (merged) cut where a benchmark
+    span begins or ends and named as `trace_reduce.reduce` names an idle
+    gap: the shortest `bench:` span that holds it, or `between spans`."""
+    cuts = sorted({t for _, a, b in bench for t in (a, b)})
+    out: dict = {}
+    for a, b in free:
+        edges = [a] + cuts[bisect.bisect_right(cuts, a):
+                            bisect.bisect_left(cuts, b)] + [b]
+        for x, y in zip(edges, edges[1:]):
+            out.setdefault(trace_reduce.label(bench, (x + y) / 2),
+                           []).append([x, y])
+    return {k: trace_reduce.merge(v) for k, v in out.items()}
+
+
 def idle_by_span(ctx):
-    """(idle seconds of the traced window, {span name: idle seconds with
-    that span innermost}, the names below a statement's root) — or None
-    without a trace, spans, or a common clock."""
+    """(idle seconds of the traced window, {name: idle seconds a chip},
+    the span names below a statement's root) — or None without a trace,
+    spans, or a common clock.  A program span's name holds the idle time
+    in which it was the innermost span open; idle time under no program
+    span goes to the benchmark span it fell in (`bench:stmt.q1`, ...,
+    `between spans`), so the values add up to the window's idle seconds."""
     if ctx.trace is None or not any(ctx.trace["busy"]):
         return None
     spans = window_spans(ctx)
@@ -181,15 +202,43 @@ def idle_by_span(ctx):
         return None
     lo, hi = (window[0][k] * 1e9 + off for k in ("t0", "t1"))
     n = len(ctx.trace["busy"])
-    idle = {}
+
+    def idle_in(held):
+        busy = sum(trace_reduce.total(trace_reduce.intersect(b, held))
+                   for b in ctx.trace["busy"]) / n
+        return (trace_reduce.total(held) - busy) / 1e9
+    idle, program = {}, []
     for name, held in innermost_cover(spans).items():
         held = trace_reduce.clip([[a + off, b + off] for a, b in held],
                                  lo, hi)
-        busy = sum(trace_reduce.total(trace_reduce.intersect(b, held))
-                   for b in ctx.trace["busy"]) / n
-        idle[name] = (trace_reduce.total(held) - busy) / 1e9
+        program += held
+        idle[name] = idle_in(held)
+    free, edge = [], lo
+    for a, b in trace_reduce.merge(program) + [[hi, hi]]:
+        if a > edge:
+            free.append([edge, a])
+        edge = max(edge, b)
+    for name, held in bench_cover(ctx.trace["spans"], free).items():
+        idle[name] = idle.get(name, 0.0) + idle_in(held)
     below = {short(s.name) for t in trees for s in t if s.name != ROOT}
     return ctx.trace["window_s"] - ctx.trace["busy_s"], idle, below
+
+
+def idle_gaps(ctx):
+    """The result line's `breakdown.idle_gaps`: [name, idle seconds a
+    chip] by `idle_by_span`, longest first, names cut to 160 characters;
+    where there are more than `TOP_GAPS` names the last entry holds the
+    rest (`other names`), so the entries add up to the window's idle time.
+    None where `idle_by_span` has nothing to read."""
+    got = idle_by_span(ctx)
+    if got is None:
+        return None
+    ranked = sorted(([k[:160], v] for k, v in got[1].items() if v > 0),
+                    key=lambda kv: -kv[1])
+    if len(ranked) > TOP_GAPS:
+        ranked[TOP_GAPS - 1:] = [["other names",
+                                  sum(v for _, v in ranked[TOP_GAPS - 1:])]]
+    return ranked
 
 
 def idle_attributed_pct(ctx):
@@ -225,6 +274,7 @@ def table(ctx) -> str:
         lines.append(f"{name:28s} {n:5d} {tot:11.1f} {self_:11.1f} "
                      f"{idle_ms}")
     if got:
+        inside = sum(v for k, v in idle.items() if k in rows)
         lines.append(f"device idle {got[0] * 1e3:.1f} ms of the window, "
-                     f"{sum(idle.values()) * 1e3:.1f} ms inside a span")
+                     f"{inside * 1e3:.1f} ms inside a span")
     return "\n".join(lines)

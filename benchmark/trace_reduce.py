@@ -109,7 +109,7 @@ def bench_spans(planes: list) -> list:
          if name.startswith(SPAN_PREFIX)), key=lambda x: x[1])
 
 
-def _label(spans: list, t: float) -> str:
+def label(spans: list, t: float) -> str:
     """The shortest benchmark span that holds time `t`."""
     best = None
     for name, a, b in spans:
@@ -182,8 +182,8 @@ def reduce(planes: list, chips: int = 1, top: int = 10) -> dict:
             i = bisect.bisect_right(cuts, edge)
             while a > edge:
                 end = cuts[i] if i < len(cuts) and cuts[i] < a else a
-                label = _label(inner, (edge + end) / 2)
-                gaps[label] = gaps.get(label, 0.0) + \
+                name = label(inner, (edge + end) / 2)
+                gaps[name] = gaps.get(name, 0.0) + \
                     (end - edge) / 1e9 / chips
                 edge, i = end, i + 1
             edge = max(edge, b)

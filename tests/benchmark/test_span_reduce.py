@@ -184,6 +184,84 @@ def test_idle_is_attributed_to_the_innermost_span(monkeypatch):
     assert read("idle_attributed_pct", ctx) is None
 
 
+def gaps_case(monkeypatch):
+    """The statement of the test above on the same clocks, its benchmark
+    span held 5 ms past the root (the client's side of the answer), one
+    more device operation in that stretch (147..148 ms), and the
+    reduction's own idle gaps by the benchmark's spans."""
+    off = 7_000 * MS
+    spans = statement(1, 100)
+    busy = [[(100 + a) * MS + off, (100 + b) * MS + off]
+            for a, b in ((6, 16), (26, 36), (47, 48))]
+    lo, hi = 90 * MS + off, 155 * MS + off
+    trace = {"busy": [busy], "busy_s": 0.021, "window_s": 0.065,
+             "spans": [("bench:stmt.q6", 100 * MS + off, 150 * MS + off)],
+             "device_ops": [["fusion.2", 0.021]],
+             "idle_gaps": [["between spans", 0.025],
+                           ["bench:stmt.q6", 0.019]]}
+    ctx, _ = ctx_of(spans, [(0.1, 0.150)], trace=trace,
+                    window=(lo / 1e9 - 7, hi / 1e9 - 7))
+    monkeypatch.setattr(span_reduce, "window_spans", lambda c: spans)
+    return ctx
+
+
+def test_idle_gaps_name_the_program_span_and_the_bench_remainder(
+        monkeypatch):
+    """44 ms idle: 25 under the program's spans (as above), 19 under none
+    of them — 4 in the benchmark's statement after the root closed (5 ms
+    less the operation), 15 before and after the statement."""
+    ctx = gaps_case(monkeypatch)
+    idle_s, idle, below = span_reduce.idle_by_span(ctx)
+    assert idle_s == pytest.approx(0.044)
+    assert sum(idle.values()) == pytest.approx(idle_s, rel=1e-9)
+    assert idle["device.wait"] == pytest.approx(0.010)
+    assert idle["device.scan"] == pytest.approx(0.004)
+    assert idle["sql.execute"] == pytest.approx(0.001)
+    assert idle["bench:stmt.q6"] == pytest.approx(0.004)
+    assert idle["between spans"] == pytest.approx(0.015)
+    # the share below a statement's root reads only the program's names
+    assert read("idle_attributed_pct", ctx) == pytest.approx(24 / 44 * 100)
+    assert len(idle) == 13 and min(idle.values()) > 0
+
+
+def test_idle_gaps_keep_ten_entries_that_add_up(monkeypatch):
+    """Thirteen names: the nine longest, and the rest in a tenth
+    entry."""
+    ctx = gaps_case(monkeypatch)
+    idle = span_reduce.idle_by_span(ctx)[1]
+    gaps = span_reduce.idle_gaps(ctx)
+    assert len(gaps) == 10 and gaps[-1][0] == "other names"
+    assert [v for _, v in gaps[:9]] == sorted(idle.values(),
+                                              reverse=True)[:9]
+    assert [k for k, _ in gaps[:4]] == ["between spans", "device.wait",
+                                        "device.scan", "bench:stmt.q6"]
+    # client.combine 1, sql.execute 1, sql.parse 0.5, sql.plan 0.5 ms
+    assert gaps[-1][1] == pytest.approx(0.003)
+    assert sum(v for _, v in gaps) == pytest.approx(0.044, rel=1e-9)
+    assert all(len(k) <= 160 for k, _ in gaps)
+    assert run.breakdown(ctx) == {"device_ops": [["fusion.2", 0.021]],
+                                  "idle_gaps": gaps}
+
+
+@pytest.mark.parametrize("break_", ["clocks", "no_spans", "no_trace_busy"])
+def test_idle_gaps_fall_back_to_the_bench_spans(monkeypatch, break_):
+    """Where the program's spans cannot be put on the trace's clock (no
+    statement mark to pair), where the program keeps none (a parent
+    commit from before them), or where no device worked, the line keeps
+    the reduction's gaps by the benchmark's spans."""
+    ctx = gaps_case(monkeypatch)
+    if break_ == "clocks":
+        ctx.trace["spans"] = [("bench:other", 0.0, 1.0)]
+    elif break_ == "no_spans":
+        monkeypatch.setattr(span_reduce, "window_spans", lambda c: None)
+    else:
+        ctx.trace["busy"] = [[]]
+    assert span_reduce.idle_gaps(ctx) is None
+    assert run.breakdown(ctx) == {
+        "device_ops": [["fusion.2", 0.021]],
+        "idle_gaps": [["between spans", 0.025], ["bench:stmt.q6", 0.019]]}
+
+
 def test_manifest_holds_the_seven_entries_and_validates():
     m = manifest.load()
     manifest.validate(m)

@@ -1,7 +1,8 @@
-"""The three per-layer readers `scan_streams2` brings (`stmts_in_flight`,
-`loop_wait_ms`, `read_offload_ms`) on hand-made spans: statements that
-follow each other, statements that overlap, waits stood on the loop and
-beside it, and a program that has no hop."""
+"""The per-layer readers `scan_streams2` brought (`stmts_in_flight`,
+`read_offload_ms`) on hand-made spans: statements that follow each other,
+statements that overlap, waits stood on the loop and beside it, and a
+program that has no hop; and the cell's traced rehearsal, which lists
+them."""
 import pytest
 
 from benchmark import span_reduce
@@ -90,11 +91,11 @@ def test_stmts_in_flight_needs_a_tablet_read(monkeypatch):
     assert read("stmts_in_flight", ctx) is None
 
 
-def test_loop_wait_counts_only_waits_stood_on_the_loop(monkeypatch):
+def test_waits_and_hops_beside_the_loop_are_counted(monkeypatch):
     spans = (statement(1, 100, 110, ["loop", "executor"], 0.25)
              + statement(2, 120, 130, ["executor", "executor"], 0.75))
     ctx = ctx_with(monkeypatch, spans, [(0.0999, 0.111), (0.1199, 0.131)])
-    assert read("loop_wait_ms", ctx) == pytest.approx(5.0 / 2)
+    # every wait, on whichever thread it was stood
     assert read("device_wait_ms", ctx) == pytest.approx(20.0 / 2)
     # four hops, 0.25 ms twice and 0.75 ms twice, over two statements
     assert read("read_offload_ms", ctx) == pytest.approx(1.0)
@@ -105,13 +106,13 @@ def test_a_program_without_the_hop_reports_no_read_offload(monkeypatch):
                                                          ["loop"])
     ctx = ctx_with(monkeypatch, spans, [(0.0999, 0.111), (0.1199, 0.131)])
     assert read("read_offload_ms", ctx) is None
-    assert read("loop_wait_ms", ctx) == pytest.approx(5.0)
+    assert read("device_wait_ms", ctx) == pytest.approx(5.0)
     assert read("stmts_in_flight", ctx) == pytest.approx(1.0)
 
 
 def test_without_spans_the_three_report_nothing(monkeypatch):
     ctx = ctx_with(monkeypatch, None, [(0.1, 0.2)])
-    for name in ("stmts_in_flight", "loop_wait_ms", "read_offload_ms"):
+    for name in ("stmts_in_flight", "read_offload_ms", "read_prep_ms"):
         assert read(name, ctx) is None
 
 
@@ -130,10 +131,9 @@ def test_two_interleaved_clients_still_pair_with_their_roots(monkeypatch):
 
 def test_a_traced_rehearsal_of_the_cell_reads_the_three(monkeypatch, capsys):
     """The real spans of a traced window of `scan_streams2` on the CPU:
-    the cell finds a reader for every per-layer entry that names it, and
-    the three readers (which `BENCHMARK.json` cannot list yet, PERF.md
-    section 7) give numbers: no wait is stood on the loop, every
-    statement's tree holds its hops."""
+    the cell finds a reader for every per-layer entry that names it, the
+    three span readers it lists give numbers, and no wait is stood on the
+    loop: every statement's tree holds its hops."""
     import json
     import types
 
@@ -156,8 +156,8 @@ def test_a_traced_rehearsal_of_the_cell_reads_the_three(monkeypatch, capsys):
     listed = [x["name"] for x in m["per_layer"]
               if cell in x.get("workloads", [cell])]
     assert list(c.readers) == listed
-    new = ["stmts_in_flight", "loop_wait_ms", "read_offload_ms"]
-    assert listed and not set(new) & set(listed)
+    new = ["stmts_in_flight", "read_offload_ms", "read_prep_ms"]
+    assert set(new) <= set(listed)
     ctx = types.SimpleNamespace(trace=None, rec=recs[-1], cell=c,
                                 peak=None, data=None)
     values = {n: read(n, ctx) for n in new}
@@ -174,6 +174,6 @@ def test_a_traced_rehearsal_of_the_cell_reads_the_three(monkeypatch, capsys):
     # four tablets a statement; fewer only where the scan lane joined a
     # tablet's read to the other stream's identical one
     assert max(launches) == 4 and launches.count(4) > len(trees) // 2
-    assert values["loop_wait_ms"] == 0.0
     assert values["read_offload_ms"] > 0.0
+    assert values["read_prep_ms"] >= 0.0
     assert 1.0 <= values["stmts_in_flight"] <= 2.0
