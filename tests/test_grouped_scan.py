@@ -2,7 +2,8 @@
 parity across dictionary remaps, NaN payloads, empty groups, slot
 overflow -> interpreter fallback, chunk-straddling groups, flag revert,
 mixed v1+v2 SST inputs — plus the dict-identity device-cache key
-regression and the shared group-keyed partial combine."""
+regression and the shared group-keyed partial combine.  Launches are
+counted as the `device.scan` spans of a forced trace."""
 import tempfile
 
 import numpy as np
@@ -26,6 +27,7 @@ from yugabyte_db_tpu.ops.scan import ScanKernel, combine_grouped_partials
 from yugabyte_db_tpu.storage import lane_codec
 from yugabyte_db_tpu.tablet import Tablet
 from yugabyte_db_tpu.utils import flags
+from yugabyte_db_tpu.utils.trace import TRACES
 
 C = Expr.col
 RF = np.array(["A", "N", "R"], object)
@@ -251,6 +253,12 @@ class TestGroupedParity:
 
 # --- fallbacks ------------------------------------------------------------
 
+def _launches(root):
+    """The kernel launches of the trace `root` began."""
+    return sum(s.name == "device.scan" for s in TRACES.finished()
+               if s.trace_id == root.trace_id)
+
+
 class TestFallbacks:
     def test_slot_overflow_merges_on_monolithic_route(self, strtab):
         # DEFAULT behavior since the monolithic partial-spill merge:
@@ -297,14 +305,14 @@ class TestFallbacks:
         flags.set_flag("grouped_spill_merge_enabled", False)
         _grouped_read(t)                     # warm the chunk plan/cache
         fb0 = GROUPED_STATS["spill_fallbacks"]
-        l0 = GROUPED_STATS["launches"]
-        resp = _grouped_read(t, spec=DictGroupSpec(cols=(1, 2),
-                                                   max_slots=4))
+        with TRACES.trace("spill") as root:
+            resp = _grouped_read(t, spec=DictGroupSpec(cols=(1, 2),
+                                                       max_slots=4))
         chunks = stream_scan.LAST_STREAM_STATS.get("chunks", 0)
         assert resp.backend == "cpu"
         assert GROUPED_STATS["spill_fallbacks"] == fb0 + 1
         assert chunks >= 3
-        assert GROUPED_STATS["launches"] - l0 == chunks
+        assert _launches(root) == chunks
 
     def test_streamed_spill_merges_partials(self, strtab):
         # DEFAULT spill behavior since the partial-spill merge: device
@@ -328,10 +336,10 @@ class TestFallbacks:
     def test_flag_off_reverts(self, strtab):
         t, _ = strtab
         flags.set_flag("grouped_pushdown_enabled", False)
-        l0 = GROUPED_STATS["launches"]
-        resp = _grouped_read(t)
+        with TRACES.trace("off") as root:
+            resp = _grouped_read(t)
         assert resp.backend == "cpu"
-        assert GROUPED_STATS["launches"] == l0
+        assert _launches(root) == 0
         assert sum(c for c, *_ in _by_key(resp).values()) == N
 
     def test_overlong_strings_stay_correct(self):

@@ -111,12 +111,18 @@ def lane_sig(v) -> str:
     return str(v.dtype)
 
 
-def wide_lanes(tree) -> int:
-    """How many 64-bit arrays (not scalars) `tree` holds: the parameters
-    the chip would split over the whole lane at a launch's entry."""
-    return sum(np.ndim(x) >= 1 and np.dtype(x.dtype).itemsize == 8
-               for x in jax.tree_util.tree_leaves(tree)
-               if hasattr(x, "dtype"))
+def launch_leaves(tree) -> Tuple[int, int]:
+    """Of a jitted call's arguments `tree`, in one pass over its leaves:
+    (`host_args`, the host values the call places itself — every leaf
+    that is no `jax.Array` —, `wide_lanes`, the 64-bit arrays (not
+    scalars) it holds: the parameters the chip would split over the
+    whole lane at a launch's entry)."""
+    host = wide = 0
+    for x in jax.tree_util.tree_leaves(tree):
+        host += not isinstance(x, jax.Array)
+        wide += (hasattr(x, "dtype") and np.ndim(x) >= 1
+                 and np.dtype(x.dtype).itemsize == 8)
+    return host, wide
 
 
 @dataclass

@@ -32,8 +32,8 @@ module closes the gap (ROADMAP operator-frontier rungs (b)+(d)):
 
 Compile accounting matches ops/compaction.py: pow2 row chunks (the
 streaming pipeline's shared bucket) x pow2 slot buckets mean one
-compile serves a whole scan, and GROUPED_STATS counts every compile
-and launch so benches can assert the cache holds.
+compile serves a whole scan (`ScanKernel.compiles`; each launch is a
+`device.scan` span).
 """
 from __future__ import annotations
 
@@ -50,12 +50,10 @@ from ..storage.columnar import ColumnarBlock
 #: (informational only)
 LAST_GROUPED_STATS: dict = {}
 
-#: process-wide grouped-kernel accounting (compiles tallied by
-#: ScanKernel; launches/spills tallied here; spill_merges counts
-#: slot overflows served by the partial-spill merge instead of a full
-#: interpreted re-scan)
-GROUPED_STATS = {"launches": 0, "spill_fallbacks": 0,
-                 "spill_merges": 0}
+#: process-wide grouped-kernel fallbacks: spill_fallbacks counts slot
+#: overflows served by a full interpreted re-scan, spill_merges those
+#: served by the partial-spill merge instead
+GROUPED_STATS = {"spill_fallbacks": 0, "spill_merges": 0}
 
 #: slot budgets are powers of two in this band — small enough that a
 #: Q1-shaped 8-slot kernel stays pure VPU code, large enough for a

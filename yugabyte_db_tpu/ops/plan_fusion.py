@@ -43,7 +43,7 @@ import numpy as np
 
 from ..utils import flags
 from .device_batch import (DeviceBatch, bucket_rows, build_batch, join,
-                           lane_sig, wide_lanes)
+                           lane_sig, launch_leaves)
 from .expr import collect_constants, compile_expr, expr_signature
 from .grouped_scan import (DictGroupSpec, ResolvedDictGroup,
                            dict_cols_needed, domain_product,
@@ -239,28 +239,30 @@ class FusedPlanKernel:
         self.launches += 1
         PLAN_STATS["launches"] += 1
         from ..utils import trace as _trace
+        args = (
+            batch.cols, batch.nulls,
+            [jnp.asarray(c) for c in consts], batch.valid, *lanes,
+            jnp.uint64(read_ht if read_ht is not None
+                       else 0xFFFFFFFFFFFFFFFF),
+            scale_args, domain_args,
+            tuple(
+                (jnp.asarray(rt.used), jnp.asarray(rt.table_key),
+                 jnp.asarray(rt.table_val),
+                 tuple(jnp.asarray(rt.payload_vals[bid])
+                       for bid in rt.build_cols),
+                 tuple(jnp.asarray(rt.payload_nulls[bid])
+                       for bid in rt.build_cols))
+                for rt in join_rts),
+        )
+        # as `ops/scan.py launch`: 64-bit arrays the chip splits, counted
+        # before the span opens
+        tags = ((("wide_lanes", launch_leaves(args)[1]),)
+                if _trace.sampled() else ())
         with _trace.device_span("fused_plan", signature=sig,
                                 compiled=compiled,
                                 bucket=batch.padded_rows,
-                                rows=batch.n_rows, mvcc=mvcc_mode) as sp:
-            args = (
-                batch.cols, batch.nulls,
-                [jnp.asarray(c) for c in consts], batch.valid, *lanes,
-                jnp.uint64(read_ht if read_ht is not None
-                           else 0xFFFFFFFFFFFFFFFF),
-                scale_args, domain_args,
-                tuple(
-                    (jnp.asarray(rt.used), jnp.asarray(rt.table_key),
-                     jnp.asarray(rt.table_val),
-                     tuple(jnp.asarray(rt.payload_vals[bid])
-                           for bid in rt.build_cols),
-                     tuple(jnp.asarray(rt.payload_nulls[bid])
-                           for bid in rt.build_cols))
-                    for rt in join_rts),
-            )
-            if sp is not None:
-                # as `ops/scan.py launch`: 64-bit arrays the chip splits
-                sp.set_tag("wide_lanes", wide_lanes(args))
+                                rows=batch.n_rows, mvcc=mvcc_mode,
+                                tags=tags):
             raw = fn(*args)
         return (_rescale_outs(raw[0], raw[1]),) + tuple(raw[2:])
 

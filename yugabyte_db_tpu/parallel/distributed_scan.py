@@ -349,15 +349,19 @@ class DistributedScanKernel:
         """(agg results, count or group counts), every one already
         combined over the shards on the device; a DictGroupSpec adds the
         spill count (nonzero = slot overflow: the caller must fall
-        back)."""
+        back).  What it does before the dispatch is the `launch.prepare`
+        span, as in `ScanKernel.run`."""
         tm = batch.mesh
-        sig, key, args = prepare_launch(
-            batch, where, aggs, group, read_ht,
-            n_total=batch.padded_rows * batch.num_shards)
-        sig = (id(tm.mesh),) + sig
-        compiled = sig not in self._cache
+        with _trace.TRACES.span("launch.prepare", child_only=True,
+                                cpu=True):
+            sig, key, args = prepare_launch(
+                batch, where, aggs, group, read_ht,
+                n_total=batch.padded_rows * batch.num_shards)
+            sig = (id(tm.mesh),) + sig
+            compiled = sig not in self._cache
+            fn = self._get(sig, tm, *key)
         outs, counts, spilled = launch(
-            self._get(sig, tm, *key), sig, key, args, batch, compiled,
+            fn, sig, key, args, batch, compiled,
             mask=False,
             tags=(("chips", tm.mesh.devices.size),
                   ("shards", batch.num_shards)))

@@ -80,13 +80,15 @@ class ServedReads:
 
 
 def _launch_in_pool(call, tctx):
-    """Pool side of `serve_read`: when a thread took the launch, and its
-    result; `tctx` is the read's trace context on the loop (executor
-    threads see no contextvars), so `device.scan` and `device.wait` stay
-    in the statement's span tree."""
+    """Pool side of `serve_read`: when a thread took the launch, its
+    result, and when the launch returned; `tctx` is the read's trace
+    context on the loop (executor threads see no contextvars), so
+    `launch.prepare`, `device.scan` and `device.wait` stay in the
+    statement's span tree."""
     began = _perf_counter_ns()
     with _trace.use_context(tctx):
-        return began, call()
+        got = call()
+    return began, got, _perf_counter_ns()
 
 
 async def serve_read(steps, served: ServedReads):
@@ -98,12 +100,20 @@ async def serve_read(steps, served: ServedReads):
     The hop is the `tserver.read_offload` span, child of the span that
     was ambient when the read began (`tserver.read:<tablet>`): `queue_ms`
     from handing the launch over to a thread taking it, `in_flight` the
-    server's reads in the pool's hands with this one."""
+    server's reads in the pool's hands with this one.  From the launch's
+    return to the read's resumption on the loop is `tserver.read_resume`
+    (a child of the read's own span, `in_flight` as the hop's); the
+    loop's time inside the read's steps is tag `steps_ms` of the span
+    that was ambient when it began — a tag, since the steps open and
+    close spans of their own across the yields."""
     parent = _trace.current_context()
+    read_span = _trace.current_span()
     loop = asyncio.get_running_loop()
+    steps_ns, step_began = 0, _perf_counter_ns()
     try:
         call = next(steps)
         while True:
+            steps_ns += _perf_counter_ns() - step_began
             served.open += 1
             if served.open > served.m_max.value():
                 served.m_max.set(served.open)
@@ -113,20 +123,29 @@ async def serve_read(steps, served: ServedReads):
                         "tserver.read_offload", parent=parent,
                         child_only=True,
                         tags={"in_flight": served.open}) as sp:
-                    t0 = _perf_counter_ns()
-                    began, got = await loop.run_in_executor(
+                    handed = _perf_counter_ns()
+                    began, got, returned = await loop.run_in_executor(
                         _READ_LAUNCH_POOL, _launch_in_pool, call, tctx)
-                    served.m_queue.increment((began - t0) / 1e3)
-                    sp.set_tag("queue_ms", (began - t0) / 1e6)
+                    served.m_queue.increment((began - handed) / 1e3)
+                    sp.set_tag("queue_ms", (began - handed) / 1e6)
+                _trace.TRACES.record("tserver.read_resume", returned,
+                                     _perf_counter_ns(), tctx,
+                                     {"in_flight": served.open})
             except Exception as e:   # noqa: BLE001 — the read's to see
+                step_began = _perf_counter_ns()
                 call = steps.throw(e)
             else:
+                step_began = _perf_counter_ns()
                 call = steps.send(got)
             finally:
                 served.open -= 1
     except StopIteration as done:
+        steps_ns += _perf_counter_ns() - step_began
         return done.value
     finally:
+        if read_span.sampled:
+            read_span.set_tag("steps_ms", read_span.tags.get("steps_ms", 0.0)
+                              + steps_ns / 1e6)
         steps.close()
 
 

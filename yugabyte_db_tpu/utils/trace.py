@@ -252,9 +252,16 @@ class TraceRegistry:
                 self.evicted += max(0, len(self.recent) - keep)
                 self.recent = deque(self.recent, maxlen=keep)
 
+    def _append(self, t: Trace) -> None:
+        """Put a finished span in the ring (the caller holds the lock)."""
+        if len(self.recent) == self.recent.maxlen:
+            self.evicted += 1
+        self.recent.append(t)
+
     @contextmanager
     def span(self, name: str, parent="inherit", tags: Optional[dict] = None,
-             child_only: bool = False, force: bool = False):
+             child_only: bool = False, force: bool = False,
+             cpu: bool = False):
         """Open a span.
 
         - parent="inherit" (default): child of the ambient context;
@@ -267,6 +274,15 @@ class TraceRegistry:
           ``child_only=True`` refuses to root at all — for seams like
           raft broadcasts that are only meaningful inside a request).
         - Unsampled context: yields a shared no-op span.
+        - ``cpu=True``: a sampled span also reads the opening thread's
+          CPU clock (``time.thread_time_ns``) at open and close and
+          sets tag ``cpu_ms``; its wall time minus ``cpu_ms`` is the
+          time that thread stood off the CPU — waiting for the
+          interpreter's lock, or blocked in native code.  Meaningful
+          only for a span that neither yields to an event loop nor is
+          closed on another thread: the clock is one thread's.  Where
+          that clock advances by scheduler ticks, one span reads 0 or a
+          tick, and only a sum over many spans estimates the CPU time.
 
         A sampled span is mirrored into the profiler's trace while a
         session collects (``ybtpu:<name>``, same start and end).
@@ -294,6 +310,7 @@ class TraceRegistry:
                   trace_id=pctx.trace_id if inherit else _new_id(),
                   parent_id=pctx.span_id if inherit else 0,
                   span_id=_new_id())
+        cpu0 = time.thread_time_ns() if cpu else 0
         if tags:
             t.tags.update(tags)
         with self._lock:
@@ -309,16 +326,41 @@ class TraceRegistry:
         try:
             yield t
         finally:
-            t.finish()
             if mirror is not None:
                 mirror.__exit__(None, None, None)
             _current_trace.reset(token)
             self._ensure_keep()
             with self._lock:
+                # stamped last, under the lock: a span holds its own
+                # bookkeeping, which on a thread that contends for the
+                # interpreter's lock can hold a wait for it
+                if cpu:
+                    t.tags["cpu_ms"] = (time.thread_time_ns() - cpu0) / 1e6
+                t.finish()
                 self.active.pop(tid, None)
-                if len(self.recent) == self.recent.maxlen:
-                    self.evicted += 1
-                self.recent.append(t)
+                self._append(t)
+
+    def record(self, name: str, start_ns: int, end_ns: int, parent,
+               tags: Optional[dict] = None) -> None:
+        """Keep a finished span with the given ``perf_counter_ns``
+        stamps, a child of ``parent`` (a span or a ``SpanContext``): for
+        a stretch whose start is only known once it is over, such as a
+        finished launch waiting for its event loop.  Nothing where
+        ``parent`` is None or unsampled.  Not mirrored into the
+        profiler's trace, which takes only spans opened as they run."""
+        pctx = parent.context if isinstance(parent, Trace) else parent
+        if pctx is None or not pctx.sampled:
+            return
+        t = Trace(name, trace_id=pctx.trace_id, parent_id=pctx.span_id,
+                  span_id=_new_id(), start_ns=start_ns,
+                  start_unix=time.time() - (time.perf_counter_ns()
+                                            - start_ns) / 1e9,
+                  end_ns=end_ns)
+        if tags:
+            t.tags.update(tags)
+        self._ensure_keep()
+        with self._lock:
+            self._append(t)
 
     def finished(self, since_ns: int = 0,
                  until_ns: Optional[int] = None) -> List[SpanRecord]:
@@ -428,38 +470,48 @@ def use_context(ctx: Optional[SpanContext]):
         _current_trace.reset(token)
 
 
+def sampled() -> bool:
+    """Whether a span opened here would record: work done only for a
+    span's tags is skipped where it would not."""
+    ctx = current_context()
+    return ctx is not None and ctx.sampled
+
+
 @contextmanager
 def device_span(kind: str, signature=None, compiled: bool = False,
-                bucket=None, rows=None, mvcc=None):
+                bucket=None, rows=None, mvcc=None, tags=()):
     """Per-kernel-launch telemetry: a span tagged {signature,
-    compile|cache_hit, bucket, rows} and, on a scan, the MVCC mode it
-    was served with (`mvcc`), so a compile landing inside a
+    compile|cache_hit, bucket, rows}, on a scan the MVCC mode it was
+    served with (`mvcc`), and the (key, value) pairs of `tags` — which
+    the caller works out before the span opens, so that counting them
+    is not timed as the launch — so a compile landing inside a
     measured round is VISIBLE in the trace instead of inferred from
     compile counters.  It times the DISPATCH — jit cache lookup,
-    argument flattening, enqueue — and the compile when
-    ``codepath=compile`` (published to ASH as ``Device_Compile``); JAX
-    returns before the device finishes, so the device's own time is the
-    ``device.wait`` span around the first host read of the result.  One
-    contextvar read when no sampled trace is ambient — safe on the hot
-    path."""
+    argument flattening, host-value placement, enqueue — and the
+    compile when ``codepath=compile`` (published to ASH as
+    ``Device_Compile``); JAX returns before the device finishes, so the
+    device's own time is the ``device.wait`` span around the first host
+    read of the result.  ``cpu_ms`` is the dispatching thread's CPU
+    time inside it (`TraceRegistry.span`).  One contextvar read when no
+    sampled trace is ambient — safe on the hot path."""
     with (wait_status("Device_Compile", component="device")
           if compiled else _NO_WAIT):
         # a sampled trace is ambient as the span itself, or — on the
         # far side of an executor hop (`use_context`: a served read's
         # launch runs beside the event loop) — as its context
-        ctx = current_context()
-        if ctx is None or not ctx.sampled:
+        if not sampled():
             yield None
             return
         sig = (f"{hash(signature) & 0xFFFFFFFFFFFFFFFF:016x}"
                if signature is not None else None)
-        tags = {"signature": sig,
-                "codepath": "compile" if compiled else "cache_hit",
-                "bucket": bucket, "rows": rows}
+        span_tags = {"signature": sig,
+                     "codepath": "compile" if compiled else "cache_hit",
+                     "bucket": bucket, "rows": rows}
         if mvcc is not None:
-            tags["mvcc"] = mvcc
+            span_tags["mvcc"] = mvcc
+        span_tags.update(tags)
         with TRACES.span(f"device.{kind}", child_only=True,
-                         tags=tags) as sp:
+                         tags=span_tags, cpu=True) as sp:
             yield sp
 
 
