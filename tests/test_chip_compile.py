@@ -75,11 +75,12 @@ def _compile(lower):
     return compiled
 
 
-#: an `X64SplitHigh` / `X64SplitLow` custom call whose result is an array:
-#: the chip splitting a whole 64-bit lane into 32-bit halves at the
-#: program's entry (one on a scalar, such as `read_ht`, costs nothing)
+#: an `X64SplitHigh` / `X64SplitLow` custom call whose result is an array
+#: of a lane's length (10,000 rows or more): the chip splitting a whole
+#: 64-bit lane into 32-bit halves at the program's entry (one on a
+#: launch's few runtime scalars, the packed host vectors, costs nothing)
 _LANE_SPLIT = re.compile(
-    r"= \w+\[\d[\d,]*\]\S* custom-call\(.*custom_call_target=\"X64Split")
+    r"= \w+\[\d{5,}[\d,]*\]\S* custom-call\(.*custom_call_target=\"X64Split")
 
 
 def _lane_splits(text: str) -> int:
@@ -105,13 +106,12 @@ def _lanes_as(lanes: str, shape):
 
 
 def _scan_args(query, n_total, mvcc_mode="visible"):
-    """(avg-expanded aggs, static_sums, example args, example rows): the
-    argument list of a served launch — `ops.scan.prepare_launch`, which
-    `ScanKernel.run`, `DistributedScanKernel.run` and
-    `__graft_entry__.entry()` call — on a small batch with the TPU arm's
-    dtypes; the MVCC lanes (`args[4:7]`) are those `mvcc_lanes` hands
-    out for `mvcc_mode`.  A described device can hold no array, so
-    callers turn these into shapes."""
+    """(the launch, example rows): a served launch — `ops.scan.
+    prepare_launch`, which `ScanKernel.run`, `DistributedScanKernel.run`
+    and `__graft_entry__.entry()` call — on a small batch with the TPU
+    arm's dtypes; the MVCC lanes (`args[4:7]`) are those `mvcc_lanes`
+    hands out for `mvcc_mode`.  A described device can hold no array,
+    so callers turn its argument list into shapes."""
     from __graft_entry__ import _example_batch
     from yugabyte_db_tpu.ops.device_batch import build_batch
     from yugabyte_db_tpu.ops.scan import prepare_launch
@@ -119,12 +119,13 @@ def _scan_args(query, n_total, mvcc_mode="visible"):
                         multi_version=mvcc_mode == "linked")
     assert batch.cols[2].dtype == jnp.float32       # l_extendedprice
     assert batch.ht.dtype == jnp.uint64             # as its two words
-    _, (_, aggs, _, mode, static_sums, strategy), args = prepare_launch(
-        batch, query.where, query.aggs, query.group, 1 << 63, n_total=n_total)
+    job = prepare_launch(batch, query.where, query.aggs, query.group,
+                         1 << 63, n_total=n_total)
+    _, _, _, mode, _, strategy, _ = job.key
     assert strategy == "unroll"
     assert mode == mvcc_mode
-    assert (args[5] is not None) == (mode == "linked")
-    return aggs, static_sums, args, batch.padded_rows
+    assert (job.args[5] is not None) == (mode == "linked")
+    return job, batch.padded_rows
 
 
 def _shapes(tree, small: int, rows_shape, row_sharding, scalar_sharding):
@@ -149,13 +150,11 @@ def test_scan_kernel_compiles(one_chip, tpu_arms, query_name, mvcc_mode):
     and u64 time lanes, at the streaming bucket.  No served scan
     program sorts: the mask is elementwise in either mode."""
     from yugabyte_db_tpu.models import tpch
-    from yugabyte_db_tpu.ops.scan import _build_kernel
+    from yugabyte_db_tpu.ops.scan import scan_program
     query = {"q6": tpch.TPCH_Q6, "q1": tpch.TPCH_Q1}[query_name]
-    aggs, static_sums, args, small = _scan_args(query, SCAN_ROWS,
-                                                mvcc_mode)
-    fn = _build_kernel(query.where, aggs, query.group, mvcc_mode,
-                       static_sums=static_sums, strategy="unroll")
-    shapes = _shapes(args, small, (SCAN_ROWS,), one_chip, one_chip)
+    job, small = _scan_args(query, SCAN_ROWS, mvcc_mode)
+    fn, _ = scan_program(*job.key)
+    shapes = _shapes(job.args, small, (SCAN_ROWS,), one_chip, one_chip)
     compiled = _compile(lambda: jax.jit(fn).lower(*shapes))
     text = compiled.as_text()
     assert "sort" not in text and "while" not in text
@@ -181,7 +180,7 @@ def test_served_scan_splits_no_whole_lane(one_chip, monkeypatch,
     from __graft_entry__ import _example_batch
     from yugabyte_db_tpu.models import tpch
     from yugabyte_db_tpu.ops import device_batch
-    from yugabyte_db_tpu.ops.scan import _build_kernel, prepare_launch
+    from yugabyte_db_tpu.ops.scan import prepare_launch, scan_program
     query = {"q6": tpch.TPCH_Q6, "q1": tpch.TPCH_Q1}[query_name]
     monkeypatch.setattr(device_batch, "_backend_float64_is_pair",
                         lambda: True)
@@ -190,23 +189,23 @@ def test_served_scan_splits_no_whole_lane(one_chip, monkeypatch,
     try:
         batch = device_batch.build_batch(
             _example_batch(), sorted(query.columns), multi_version=True)
-        _, (where, aggs, group, mode, static_sums, strategy), args = \
-            prepare_launch(batch, query.where, query.aggs, query.group,
-                           1 << 63, n_total=SERVED_ROWS)
+        job = prepare_launch(batch, query.where, query.aggs, query.group,
+                             1 << 63, n_total=SERVED_ROWS)
     finally:
         for f in ("device_float_dtype", "scan_group_strategy"):
             flags.REGISTRY.reset(f)
+    _, _, _, mode, _, strategy, _ = job.key
     assert (mode, strategy) == ("linked", "unroll")
     assert isinstance(batch.cols[tpch.EXTPRICE], device_batch.Pair)
-    fn = _build_kernel(where, aggs, group, mode, static_sums=static_sums,
-                       strategy=strategy)
+    fn, _ = scan_program(*job.key)
     small = batch.padded_rows
     shapes = _lanes_as(lanes, lambda x: _shapes(
-        x, small, (SERVED_ROWS,), one_chip, one_chip))(args)
+        x, small, (SERVED_ROWS,), one_chip, one_chip))(job.args)
     text = _compile(lambda: jax.jit(fn).lower(*shapes)).as_text()
     assert _lane_splits(text) == (
         0 if lanes == "pairs" else WHOLE_LANE_SPLITS[query_name])
-    # what is left: the scalars' splits (`read_ht`, the literals)
+    # what is left: the runtime scalars' splits (the packed int64 and
+    # float64 host vectors)
     assert "X64Split" in text
 
 
@@ -214,13 +213,14 @@ def test_sort_grouped_kernel_compiles(one_chip, tpu_arms):
     """Q1 grouped by sort + segments (HashGroupSpec): the GROUP BY route
     of a table that was never ANALYZEd."""
     from yugabyte_db_tpu.models import tpch
-    from yugabyte_db_tpu.ops.scan import HashGroupSpec, _build_kernel
+    from yugabyte_db_tpu.ops.scan import HashGroupSpec, scan_program
     q = tpch.TPCH_Q1
-    aggs, static_sums, args, small = _scan_args(q, SCAN_ROWS)
-    fn = _build_kernel(
-        q.where, aggs, HashGroupSpec((tpch.RETFLAG, tpch.LINESTATUS)),
-        "visible", static_sums=static_sums, strategy="unroll")
-    shapes = _shapes(args, small, (SCAN_ROWS,), one_chip, one_chip)
+    job, small = _scan_args(q, SCAN_ROWS)
+    where, aggs, _, mode, static_sums, strategy, lits = job.key
+    fn, _ = scan_program(
+        where, aggs, HashGroupSpec((tpch.RETFLAG, tpch.LINESTATUS)),
+        mode, static_sums, strategy, lits)
+    shapes = _shapes(job.args, small, (SCAN_ROWS,), one_chip, one_chip)
     assert "sort" in _compile(lambda: jax.jit(fn).lower(*shapes)).as_text()
 
 
@@ -252,14 +252,10 @@ def test_distributed_scan_compiles_for_four_chips(topo, tpu_arms):
                                                TabletMesh)
     tm = TabletMesh(Mesh(np.array(topo.devices).reshape(4, 1),
                          (TABLETS_AXIS, BLOCKS_AXIS)))
-    aggs, static_sums, args, small = _scan_args(q, SCAN_ROWS * 4)
-    col_sig = tuple(sorted((cid, str(v.dtype))
-                           for cid, v in args[0].items()))
-    sig = (id(tm.mesh), None, None, None, "visible", SCAN_ROWS, col_sig,
-           static_sums, "unroll")
-    fn = DistributedScanKernel()._get(sig, tm, q.where, aggs, q.group,
-                                      "visible", static_sums, "unroll")
-    shapes = _shapes(args, small, (4 * SCAN_ROWS,), tm.row_sharding(),
+    job, small = _scan_args(q, SCAN_ROWS * 4)
+    fn = DistributedScanKernel()._get((id(tm.mesh),) + job.sig, tm,
+                                      *job.key)
+    shapes = _shapes(job.args, small, (4 * SCAN_ROWS,), tm.row_sharding(),
                      tm.replicated())
     compiled = _compile(lambda: fn.lower(*shapes))
     assert "all-reduce" in compiled.as_text()
@@ -331,14 +327,13 @@ def test_served_mesh_scan_compiles_for_four_chips(topo, tmp_path,
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=everywhere,
                                     weak_type=x.weak_type)
 
-    for (sig, _, where, aggs, group, mode, static_sums, strategy), args \
-            in seen:
+    for (sig, _, *key), args in seen:
+        _, _, group, _, _, strategy, _ = key
         assert strategy == "unroll"
         assert {str(v.dtype) for v in args[0].values()} \
             == {"float64", "int32"}
         fn = DistributedScanKernel()._get(
-            (id(tm.mesh),) + sig[1:], tm, where, aggs, group, mode,
-            static_sums, strategy)
+            (id(tm.mesh),) + sig[1:], tm, *key)
         compiled = _compile(lambda: fn.lower(*_lanes_as(lanes, shape)(args)))
         text = compiled.as_text()
         assert _lane_splits(text) == (0 if lanes == "pairs" else

@@ -279,6 +279,22 @@ class TestFallbacks:
         off = _grouped_read(t)
         assert _by_key(resp) == _by_key(off)
 
+    def test_monolithic_spill_mask_comes_from_a_filter_launch(self, strtab):
+        # an aggregate launch returns no row mask: the monolithic merge
+        # takes the spilled rows' mask from a filter launch at the same
+        # read point, which folds the WHERE — one launch more, on this
+        # path alone, and the interpreted GROUP BY's answer
+        t, _ = strtab
+        where = (C(3) < 40.0).node
+        with TRACES.trace("spill") as root:
+            resp = _grouped_read(t, where=where,
+                                 spec=DictGroupSpec(cols=(1, 2), max_slots=4))
+        assert resp.backend == "tpu"
+        assert _launches(root) == 2
+        flags.set_flag("grouped_pushdown_enabled", False)
+        off = _grouped_read(t, where=where)
+        assert _by_key(resp) == _by_key(off)
+
     def test_slot_overflow_reverts_when_merge_disabled(self, strtab):
         t, _ = strtab
         flags.set_flag("grouped_spill_merge_enabled", False)

@@ -227,13 +227,16 @@ def test_a_statement_is_one_rpc_one_gather_one_launch(answers, query):
         (scan,) = _named(spans, "device.scan")
         assert (scan.tags["mvcc"], scan.tags["chips"],
                 scan.tags["shards"]) == ("linked", 4, 4)
-        # the statement's literals, read_ht, one vector of scales and
-        # (Q1) one of dictionary sizes: 7 host values either way
-        assert scan.tags["host_args"] == 7
+        # the statement's runtime scalars as one int64 vector (read_ht,
+        # Q1's dictionary sizes, the integer literals) and one float64
+        # vector (the static scales, the float literals) ...
+        assert scan.tags["host_args"] == 2
         # a shard of two 1,500-row tablets is one tile of the kernel
         assert scan.tags["tiles"] == 1
         (wait,) = _named(spans, "device.wait")
         assert wait.tags["chips"] == 4 and wait.tags["reads"] == 1
+        # ... and its result as one int64 array
+        assert wait.tags["result_leaves"] == 1
         (combine,) = _named(spans, "client.combine")
         assert combine.tags["parts"] == 1
     # the miss builds, links per shard and ships; the hit does none of it
@@ -262,7 +265,9 @@ def test_with_one_chip_no_mesh_code_serves(answers, query):
         == {"tpu_aggregate"}
     assert all("chips" not in s.tags for s in _named(spans, "device.scan"))
     # the one-device launch goes through the same code: the same tags
-    assert {s.tags["host_args"] for s in _named(spans, "device.scan")} == {7}
+    assert {s.tags["host_args"] for s in _named(spans, "device.scan")} == {2}
+    assert {s.tags["result_leaves"]
+            for s in _named(spans, "device.wait")} == {1}
     assert {s.tags["tiles"] for s in _named(spans, "device.scan")} == {1}
     assert {s.tags["reads"] for s in _named(spans, "device.wait")} == {1}
 
@@ -509,7 +514,7 @@ def test_a_long_shard_runs_in_row_tiles(shape, lanes, monkeypatch):
         assert text.count("all-reduce(") + text.count("all-reduce-start(") \
             == 1
         assert (" while(" in text) == looped
-    assert int(np.sum(tiled4[1])) == int(np.asarray(tiled1[2]).sum()) > 0
+    assert int(np.sum(tiled4[1])) == int(np.sum(tiled1[1])) > 0
     for got in (tiled4, whole4, tiled1[:2] + tiled1[3:]):
         got, want = (jax.tree_util.tree_leaves(x)
                      for x in (got, whole1[:2] + whole1[3:]))
@@ -517,5 +522,6 @@ def test_a_long_shard_runs_in_row_tiles(shape, lanes, monkeypatch):
         for x, y in zip(got, want):
             assert np.asarray(x).dtype == np.asarray(y).dtype
             np.testing.assert_array_equal(x, y)
-    np.testing.assert_array_equal(np.asarray(tiled1[2]),
-                                  np.asarray(whole1[2]))
+    # an aggregate launch keeps no row mask, tiled or whole (the
+    # kernel's tiled mask is the whole one's: tests/test_ops_scan.py)
+    assert tiled1[2] is None and whole1[2] is None

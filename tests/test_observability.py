@@ -412,15 +412,18 @@ class TestDeviceTelemetry:
         assert spans[0].tags["bucket"] == batch.padded_rows
         assert spans[0].tags["rows"] == batch.n_rows
         # what crosses the host-device boundary a launch: the host values
-        # the jitted call placed (one literal, read_ht, the scales) ...
-        assert [s.tags["host_args"] for s in spans] == [3, 3]
+        # the jitted call placed (one int64 vector: read_ht; one float64
+        # vector: the SUM's scale and the literal) ...
+        assert [s.tags["host_args"] for s in spans] == [2, 2]
         # ... in how many row tiles the program ran the lane (512 rows:
         # one; `ops/scan.py tile_count`) ...
         assert [s.tags["tiles"] for s in spans] == [1, 1]
         waits = [s for s in TRACES.recent
                  if s.trace_id == t.trace_id and s.name == "device.wait"]
-        # ... and the transfers that brought the result back
+        # ... and the transfers that brought the result back: one, of
+        # one array (the SUM, both counts: int64)
         assert [s.tags["reads"] for s in waits] == [1, 1]
+        assert [s.tags["result_leaves"] for s in waits] == [1, 1]
         assert {s.tags["thread"] for s in waits} == {"executor"}
 
     def test_no_spans_without_sampled_trace(self):

@@ -196,9 +196,10 @@ class TestKernelCache:
 
 class TestLaunchProtocol:
     """One launch = one program and one read-back (`prepare_launch`,
-    `launch`): runtime scalars go into the jitted call as host values,
-    the result comes back in one transfer, the row mask stays on the
-    device."""
+    `launch`): the runtime scalars go into the jitted call as one host
+    vector an element kind, the result comes back as one array an
+    element kind in one transfer, and only a filter launch returns the
+    row mask (on the device)."""
 
     AGGS = (AggSpec("sum", (C(2) * (Expr.const(1) - C(3))).node),
             AggSpec("avg", C(1).node), AggSpec("count"))
@@ -223,13 +224,14 @@ class TestLaunchProtocol:
     @staticmethod
     def _recording(seen):
         """A ScanKernel whose `_get` hands out programs that note the
-        argument list they were called with."""
+        key they were built from and the argument list they were called
+        with."""
         class Recording(ScanKernel):
             def _get(self, *key):
                 fn = super()._get(*key)
 
                 def call(*args):
-                    seen.append(args)
+                    seen.append((key, args))
                     return fn(*args)
                 return call
         return Recording()
@@ -237,6 +239,7 @@ class TestLaunchProtocol:
     @pytest.mark.parametrize("kind", ["none", "dense", "dict", "hash"])
     def test_runtime_scalars_are_host_values(self, kind):
         import jax
+        from yugabyte_db_tpu.ops.scan import unpack_scalars
         seen = []
         kern = self._recording(seen)
         batch, _ = self._batch()
@@ -244,29 +247,39 @@ class TestLaunchProtocol:
         for _ in range(2):          # the second launch is warm
             kern.run(batch, where, self.AGGS, self._group(kind), 20)
         assert kern.compiles == 1
-        cols, nulls, consts, valid, ht, next_ht, tomb, read_ht, scales, \
-            domains = seen[-1]
+        key, (cols, nulls, alone, valid, ht, next_ht, tomb, scalars) = \
+            seen[-1]
         # what the batch keeps on the device goes in as it is, the
         # write times as their two 32-bit words ...
         assert isinstance(ht, Pair)
         assert all(isinstance(x, jax.Array) for x in
                    jax.tree_util.tree_leaves((cols, valid, ht, tomb)))
-        # ... and nothing else is put there ahead of the call
-        runtime = jax.tree_util.tree_leaves(
-            (consts, read_ht, scales, domains))
-        assert runtime and not any(isinstance(x, jax.Array)
-                                   for x in runtime)
-        assert consts == [24.0, 1, 1] and all(
-            type(c) in (int, float) for c in consts)   # weak: as written
-        assert type(read_ht) is np.uint64 and read_ht == 20
-        # one vector of scales (AVG expanded: sum, sum, count, count) and
-        # one of dictionary sizes
-        assert isinstance(scales, np.ndarray) and \
-            (scales.dtype, scales.shape) == (np.float32, (4,))
-        assert scales[0] > 0 and scales[1] > 0 and not scales[2:].any()
+        # ... and nothing else is put there ahead of the call: the
+        # runtime scalars are one int64 and one float64 host vector
+        assert alone == []
+        ints, floats = scalars
+        assert not any(isinstance(x, jax.Array) for x in scalars)
+        assert (ints.dtype, floats.dtype) == (np.int64, np.float64)
+        # read_ht's two words, the dictionary sizes, the integer literals
+        assert ints.tolist() == [0, 20] + [3] * (kind == "dict") + [1, 1]
+        # the static scales (AVG expanded: sum, sum, count, count), then
+        # the float literal
+        assert floats.shape == (3,) and (floats[:2] > 0).all() \
+            and floats[2] == 24.0
+        # where each literal rides is the program's: Python scalars, so
+        # the program makes them weakly typed again
+        lits = key[-1]
+        assert lits == ("f", "i", "i")
+        sig, where_, aggs, group, mode, static_sums, strategy, _ = key
+        consts, read_ht, sum_scales, domains = unpack_scalars(
+            alone, scalars, lits, group, static_sums)
+        assert [(c.dtype, c.weak_type) for c in consts] == [
+            (np.float64, True), (np.int64, True), (np.int64, True)]
+        assert (int(read_ht.hi), int(read_ht.lo)) == (0, 20)
+        assert [s is not None for s in sum_scales] == [True, True,
+                                                       False, False]
         if kind == "dict":
-            assert isinstance(domains, np.ndarray) and \
-                (domains.dtype, domains.tolist()) == (np.int32, [3])
+            assert (domains.dtype, domains.tolist()) == (np.int32, [3])
         else:
             assert domains == ()
 
@@ -275,7 +288,9 @@ class TestLaunchProtocol:
         kern = self._recording(seen)
         batch, d = self._batch()
         (cnt,), _, _ = kern.run(batch, None, (AggSpec("count"),))
-        assert seen[-1][7] == np.uint64(0xFFFFFFFFFFFFFFFF)
+        # all ones, "latest", as its two words; no float in the launch
+        (ints,) = seen[-1][1][-1]
+        assert ints.tolist() == [0xFFFFFFFF, 0xFFFFFFFF]
         assert int(cnt) == len(d["qty"])
 
     @pytest.mark.parametrize("kind", ["none", "dense", "dict", "hash"])
@@ -293,11 +308,13 @@ class TestLaunchProtocol:
             got = kern.run(batch, None, self.AGGS, self._group(kind), 20)
         monkeypatch.undo()
         assert len(reads) == 1
-        # the transfer carried everything but the mask
-        assert not any(getattr(x, "shape", None) == batch.valid.shape
-                       for x in jax.tree_util.tree_leaves(reads[0]))
+        # the transfer carried one int64 array: every result of the
+        # launch is an integer (no float SUM needs its scale back)
+        (packed,) = reads[0]
+        assert packed.dtype == np.int64 and packed.ndim == 1
         outs, counts, mask, *rest = got
-        assert isinstance(mask, jax.Array) and mask.shape == batch.valid.shape
+        # an aggregate launch keeps no row mask
+        assert mask is None
         host = jax.tree_util.tree_leaves((outs, counts, rest))
         assert host and all(isinstance(x, (np.ndarray, np.generic))
                             for x in host)
@@ -307,8 +324,14 @@ class TestLaunchProtocol:
         spans = {s.name: s for s in TRACES.recent
                  if s.trace_id == t.trace_id}
         assert spans["device.wait"].tags["reads"] == 1
-        assert spans["device.scan"].tags["host_args"] == \
-            3 + (kind == "dict")       # one literal, read_ht, scales[, sizes]
+        assert spans["device.wait"].tags["result_leaves"] == 1
+        # the int64 vector (read_ht, sizes, the literal) and the float64
+        # one (the scales)
+        assert spans["device.scan"].tags["host_args"] == 2
+        # a filter launch returns its mask, on the device
+        _, count, mask = kern.run(batch, None, (), None, 20)
+        assert isinstance(mask, jax.Array) and mask.shape == batch.valid.shape
+        assert int(count) == int(np.asarray(mask).sum()) == len(d["qty"])
 
     @pytest.mark.parametrize("kind", ["none", "dict"])
     def test_other_literals_read_ht_and_bounds_do_not_compile(self, kind):
@@ -342,6 +365,240 @@ class TestLaunchProtocol:
         # ... and a dictionary that grew inside its slot bucket
         run(other, d2, 12.0, 1 << 40)
         assert kern.compiles == 1
+
+
+def packed_block(n=1000, seed=3, first_key=0):
+    """A block for the packed launch's tests: float value lanes (1, 2), an
+    int32 code lane (4, six words), an int64 lane of values near 2^52
+    (5: its SUM over a batch comes near 2^62) and a float lane that holds
+    an inf (6: its SUM has no scale, dynamic or static)."""
+    rng = np.random.default_rng(seed)
+    ht = rng.integers(1, 30, n).astype(np.uint64)
+    qty = rng.uniform(0, 50, n)
+    flag = rng.integers(0, 6, n).astype(np.int32)
+    inf = rng.uniform(0, 5, n)
+    # the inf is in a row every read and WHERE of the tests keep
+    inf[7], ht[7], qty[7], flag[7] = np.inf, 1, 1.0, 0
+    zeros = np.zeros(n, bool)
+    return ColumnarBlock.from_arrays(
+        schema_version=1,
+        key_hash=np.arange(first_key, first_key + n, dtype=np.uint64),
+        ht=ht,
+        fixed={1: (qty, zeros),
+               2: (rng.uniform(1, 100, n), zeros),
+               4: (flag, zeros),
+               5: (rng.integers(2 ** 51, 2 ** 52, n).astype(np.int64),
+                   zeros),
+               6: (inf, zeros)},
+        tombstone=zeros, unique_keys=True)
+
+
+PACKED_COLUMNS = [1, 2, 4, 5, 6]
+PACKED_WORDS = {4: np.array(list("abcdef"), object)}
+
+
+def packed_batch():
+    import dataclasses
+    batch = build_batch([packed_block()], PACKED_COLUMNS)
+    return dataclasses.replace(batch, dicts=PACKED_WORDS)
+
+
+def unpacked_args(job, read_ht):
+    """The argument list `_build_kernel`'s program took for the launch
+    `job` (`prepare_launch`) before its scalars were packed: the literals
+    as the Python scalars they are (`collect_constants`), `read_ht` as
+    one uint64, the scales as a float32 vector and the dictionary sizes
+    as an int32 one."""
+    from yugabyte_db_tpu.ops.expr import collect_constants
+    where, aggs, group = job.key[:3]
+    cols, nulls, _, valid, ht, next_ht, tomb, (ints, *_) = job.args
+    consts = []
+    for node in [where] + [a.expr for a in aggs]:
+        if node is not None:
+            collect_constants(node, consts)
+    n_domains = len(group.cols) if hasattr(group, "num_slots") else 0
+    domains = np.asarray(ints[2:2 + n_domains], np.int32) \
+        if n_domains else ()
+    return (cols, nulls, consts, valid, ht, next_ht, tomb,
+            np.uint64(read_ht), job.scales, domains)
+
+
+def unpacked_launch(job, read_ht):
+    """What a launch answered before its scalars and result were packed:
+    `_build_kernel`'s program called with `unpacked_args`, every output
+    read back, the fixed-point sums rescaled by the scales the program
+    returned.  (outs, counts, *rest), host values."""
+    import jax
+    from yugabyte_db_tpu.ops.scan import _build_kernel, _rescale_outs
+    where, aggs, group, mode, static_sums, strategy, _ = job.key
+    raw = jax.jit(_build_kernel(where, aggs, group, mode,
+                                static_sums=static_sums, strategy=strategy))
+    outs, scales, counts, _, *rest = jax.device_get(
+        raw(*unpacked_args(job, read_ht)))
+    return (_rescale_outs(outs, scales), counts, *rest)
+
+
+class TestPackedLaunch:
+    """The packed launch (`prepare_launch`, `unpack_scalars`,
+    `ResultLayout`) answers what the program answered with its scalars
+    one host value each and its result read back leaf by leaf — bit for
+    bit, dtypes and shapes included."""
+
+    READ_HT = 20
+    MONEY = (C(2) * (Expr.const(1) - C(1) * 0.01)).node
+
+    @classmethod
+    def _shape(cls, kind, scales, rows):
+        from yugabyte_db_tpu.ops.grouped_scan import DictGroupSpec
+        from yugabyte_db_tpu.ops.scan import HashGroupSpec
+        aggs = (AggSpec("sum", cls.MONEY), AggSpec("sum", C(5).node),
+                AggSpec("avg", C(2).node), AggSpec("count"),
+                AggSpec("count", C(2).node),
+                AggSpec("min", C(2).node), AggSpec("max", C(2).node),
+                AggSpec("min", C(4).node), AggSpec("max", C(4).node),
+                AggSpec("min", C(5).node), AggSpec("max", C(5).node))
+        if scales == "nan":
+            aggs += (AggSpec("sum", C(6).node),)
+        # (six words in four slots: codes 3 to 5 spill)
+        group = {"none": None, "dense": GroupSpec(cols=((4, 6, 0),)),
+                 "dict": DictGroupSpec(cols=(4,)),
+                 "dict_spill": DictGroupSpec(cols=(4,), max_slots=4),
+                 "hash": HashGroupSpec((4,), max_groups=8)}[kind]
+        # "some": group 1 answers no row, so its extremes are sentinels;
+        # "none": no row at all
+        where = {"some": ((C(1) < 40.0) & C(4).ne(1)).node,
+                 "none": (C(1) < -1.0).node}[rows]
+        return where, aggs, group
+
+    @pytest.mark.parametrize("rows", ["some", "none"])
+    @pytest.mark.parametrize("scales", ["static", "dynamic", "nan"])
+    @pytest.mark.parametrize("kind", ["none", "dense", "dict", "dict_spill",
+                                      "hash"])
+    def test_packed_is_the_unpacked_program_bit_for_bit(self, kind, scales,
+                                                        rows):
+        import dataclasses
+        import jax
+        from yugabyte_db_tpu.ops.scan import prepare_launch
+        batch = packed_batch()
+        if scales != "static":
+            batch = dataclasses.replace(batch, col_bounds={})
+        where, aggs, group = self._shape(kind, scales, rows)
+        job = prepare_launch(batch, where, aggs, group, self.READ_HT)
+        assert any(job.key[4]) == (scales == "static")
+        got = ScanKernel().run(batch, where, aggs, group, self.READ_HT)
+        outs, counts, mask, *rest = got
+        assert mask is None
+        want = unpacked_launch(job, self.READ_HT)
+        got, want = (jax.tree_util.tree_leaves(x)
+                     for x in ((outs, counts, *rest), want))
+        assert len(got) == len(want) > 0
+        for x, y in zip(got, want):
+            x, y = np.asarray(x), np.asarray(y)
+            assert (x.dtype, x.shape) == (y.dtype, y.shape)
+            assert x.tobytes() == y.tobytes()
+        sums = np.asarray(outs[1])
+        if rows == "some":
+            # the int64 SUM comes near 2^62 and stays exact ...
+            assert sums.dtype == np.int64 \
+                and sum(map(int, sums.ravel())) > 2 ** 60
+        else:
+            # ... and every extreme of no row is its type's sentinel
+            assert np.isposinf(np.asarray(outs[6])).all()
+            assert (np.asarray(outs[8]) == np.iinfo(np.int32).max).all()
+            assert (np.asarray(outs[11]) == np.iinfo(np.int64).min).all()
+        if scales == "nan":
+            # no scale fits an inf: the float fallback answers
+            assert np.isinf(np.asarray(outs[-1])).any() or rows == "none"
+        if kind == "dict_spill" and rows == "some":
+            assert int(rest[0]) > 0
+
+    def test_fifty_literal_sets_and_read_points_one_program(self):
+        from yugabyte_db_tpu.utils.trace import TRACES
+        kern = ScanKernel()
+        rng = np.random.default_rng(17)
+        batch = packed_batch()
+        blk_qty = np.asarray(batch.cols[1])[:1000]
+        price = np.asarray(batch.cols[2])[:1000]
+        flag = np.asarray(batch.cols[4])[:1000]
+        ht = (np.asarray(batch.ht.hi, np.uint64)[:1000] << np.uint64(32)) \
+            | np.asarray(batch.ht.lo, np.uint64)[:1000]
+        for i in range(50):
+            lo = float(rng.uniform(0, 25))
+            hi = lo + float(rng.uniform(1, 25))
+            code = int(rng.integers(0, 3))
+            read_ht = int(rng.integers(1, 32))
+            where = (C(1).between(lo, hi) & C(4).ne(code)).node
+            with TRACES.trace("literals") as t:
+                (s, c), _, mask = kern.run(
+                    batch, where, (AggSpec("sum", C(2).node),
+                                   AggSpec("count")), None, read_ht)
+            m = (blk_qty >= lo) & (blk_qty <= hi) & (flag != code) \
+                & (ht <= read_ht)
+            assert int(c) == m.sum() and mask is None
+            np.testing.assert_allclose(float(s), price[m].sum(), rtol=1e-9)
+            (scan,) = [x for x in TRACES.recent if x.trace_id == t.trace_id
+                       and x.name == "device.scan"]
+            assert scan.tags["host_args"] == 2
+        assert kern.compiles == 1
+
+    @staticmethod
+    def _compares(text, rows, dtype):
+        """Lines of a lowered program that compare `rows`-long lanes of
+        `dtype` (StableHLO)."""
+        return sum("stablehlo.compare" in line
+                   and f"tensor<{rows}x{dtype}>" in line
+                   for line in text.splitlines())
+
+    @pytest.mark.parametrize("query_name", ["q6", "q1", "q1_dict"])
+    def test_literals_compare_in_the_lane_type(self, query_name):
+        """`l_shipdate` and the int32 code lanes compare with the
+        literals in 32 bits, as with the Python scalars the launch took
+        before the packing; literals of a strong int64 would make them
+        compare in 64."""
+        import dataclasses
+        import jax
+        from __graft_entry__ import _example_batch
+        from yugabyte_db_tpu.models import tpch
+        from yugabyte_db_tpu.ops.grouped_scan import DictGroupSpec
+        from yugabyte_db_tpu.ops.scan import (_build_kernel, prepare_launch,
+                                              scan_program)
+        q = {"q6": tpch.TPCH_Q6, "q1": tpch.TPCH_Q1,
+             "q1_dict": tpch.TPCH_Q1}[query_name]
+        group = DictGroupSpec((tpch.RETFLAG, tpch.LINESTATUS)) \
+            if query_name == "q1_dict" else q.group
+        batch = build_batch(_example_batch(), sorted(
+            set(q.columns) | {tpch.RETFLAG, tpch.LINESTATUS}),
+            multi_version=True)
+        batch = dataclasses.replace(batch, dicts={
+            tpch.RETFLAG: np.array(list("ANR"), object),
+            tpch.LINESTATUS: np.array(list("FO"), object)})
+        # a code literal besides: RETFLAG <> 'R' as its code
+        where = (Expr(q.where) & C(tpch.RETFLAG).ne(2)).node
+        assert batch.cols[tpch.SHIPDATE].dtype == np.int32
+        assert batch.cols[tpch.RETFLAG].dtype == np.int32
+        job = prepare_launch(batch, where, q.aggs, group, 1 << 40)
+        rows = batch.padded_rows
+        program, _ = scan_program(*job.key)
+        packed = jax.jit(program).lower(*job.args).as_text()
+        where_, aggs, group_, mode, static_sums, strategy, _ = job.key
+        raw = _build_kernel(where_, aggs, group_, mode,
+                            static_sums=static_sums, strategy=strategy)
+        args = unpacked_args(job, 1 << 40)
+        before = jax.jit(raw).lower(*args).as_text()
+        # l_shipdate against one or two dates, the code against its
+        # literal
+        assert self._compares(packed, rows, "i32") \
+            == self._compares(before, rows, "i32") \
+            == (3 if query_name == "q6" else 2)
+        assert self._compares(packed, rows, "i64") \
+            == self._compares(before, rows, "i64")
+        # (only the dictionary group's slot test is an int64 compare)
+        assert self._compares(packed, rows, "i64") == (
+            query_name == "q1_dict")
+        strong = [np.int64(c) if type(c) is int else c for c in args[2]]
+        control = jax.jit(raw).lower(*args[:2], strong, *args[3:]).as_text()
+        assert self._compares(control, rows, "i64") \
+            > self._compares(packed, rows, "i64")
 
 
 class TestRowTiles:
@@ -407,14 +664,15 @@ class TestRowTiles:
         import jax
         from yugabyte_db_tpu.ops.scan import _build_kernel, prepare_launch
         where, aggs, group = cls._shape(kind)
-        _, key, args = prepare_launch(cls._batch(bounds), where, aggs, group,
-                                      cls.READ_HT)
+        job = prepare_launch(cls._batch(bounds), where, aggs, group,
+                             cls.READ_HT)
+        key = job.key[:6]
         where, aggs, group, mode, static_sums, _ = key
         assert mode == "linked"
         tiled, whole = (jax.jit(_build_kernel(
             where, aggs, group, mode, static_sums=static_sums,
             strategy=strategy, tile_rows=t)) for t in (cls.TILE, cls.N))
-        return tiled, whole, args, key
+        return tiled, whole, kernel_args(job), key
 
     @staticmethod
     def _same_bits(a, b):
@@ -513,3 +771,16 @@ class TestRowTiles:
 
 def col_expr(cid):
     return C(cid).node
+
+
+def kernel_args(job):
+    """The argument list of `_build_kernel`'s program for the launch
+    `job` (`prepare_launch`): its packed runtime scalars taken apart as
+    the launched program takes them (`unpack_scalars`)."""
+    from yugabyte_db_tpu.ops.scan import unpack_scalars
+    cols, nulls, alone, valid, ht, next_ht, tomb, scalars = job.args
+    _, _, group, _, static_sums, _, lits = job.key
+    consts, read_ht, sum_scales, domains = unpack_scalars(
+        alone, scalars, lits, group, static_sums)
+    return (cols, nulls, consts, valid, ht, next_ht, tomb, read_ht,
+            sum_scales, domains)

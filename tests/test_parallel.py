@@ -144,9 +144,11 @@ class TestMeshLaunchProtocol:
         b = prepare_launch(mesh, q.where, q.aggs, q.group, read_ht,
                            n_total=one.padded_rows)
         # the same program (over other row counts) and runtime scalars
-        assert a[1] == b[1]
-        assert a[2][2] == b[2][2] and a[2][7] == b[2][7]
-        np.testing.assert_array_equal(a[2][8], b[2][8])
+        assert a.key == b.key
+        assert a.args[2] == b.args[2] == []
+        for x, y in zip(a.args[-1], b.args[-1], strict=True):
+            np.testing.assert_array_equal(x, y)
+        np.testing.assert_array_equal(a.scales, b.scales)
         outs1, counts1, _ = ScanKernel().run(one, q.where, q.aggs, q.group,
                                              read_ht)
         outs4, counts4 = DistributedScanKernel().run(
@@ -187,24 +189,136 @@ class TestMeshLaunchProtocol:
                                     GroupSpec(cols=((4, 4, 0),)), 1 << 40)
         monkeypatch.undo()
         assert kern.compiles == 1 and len(reads) == 1
+        # one int64 array back: the SUM, the count, the group counts
+        assert len(reads[0]) == 1 and reads[0][0].dtype == np.int64
         assert all(isinstance(x, np.ndarray) for x in (*outs, counts))
-        cols, nulls, consts, valid, ht, next_ht, tomb, read_ht, scales, \
-            domains = seen[-1]
-        assert consts == [25.0, 1] and \
-            [type(c) for c in consts] == [float, int]
-        assert type(read_ht) is np.uint64 and read_ht == 1 << 40
-        assert isinstance(scales, np.ndarray) and \
-            (scales.dtype, scales.shape) == (np.float32, (2,))
-        assert domains == () and next_ht is None and ht is not None
-        assert not any(isinstance(x, jax.Array) for x in
-                       jax.tree_util.tree_leaves((consts, read_ht, scales)))
+        cols, nulls, alone, valid, ht, next_ht, tomb, (ints, floats) = \
+            seen[-1]
+        # read_ht's two words and the integer literal; the SUM's static
+        # scale and the float literal — each vector a host value
+        assert ints.tolist() == [1 << 8, 0, 1]
+        assert floats.shape == (2,) and floats[0] > 0 and floats[1] == 25.0
+        assert alone == [] and next_ht is None and ht is not None
+        assert not any(isinstance(x, jax.Array) for x in (ints, floats))
         spans = {s.name: s for s in TRACES.recent
                  if s.trace_id == t.trace_id}
-        assert spans["device.scan"].tags["host_args"] == 4
+        assert spans["device.scan"].tags["host_args"] == 2
+        assert spans["device.wait"].tags["result_leaves"] == 1
         assert (spans["device.scan"].tags["chips"],
                 spans["device.scan"].tags["shards"]) == (8, 8)
         assert spans["device.wait"].tags["reads"] == 1
         assert spans["device.wait"].tags["chips"] == 8
+
+    @pytest.mark.parametrize("query_name", ["q6", "q1", "q1_dict"])
+    def test_mesh_literals_compare_in_the_lane_type(self, query_name):
+        """The mesh program takes its literals from the replicated packed
+        vectors weakly typed, as the one-device program does: a shard
+        compares `l_shipdate` and the int32 code lanes in 32 bits, and
+        only a dictionary group's slot test in 64."""
+        import dataclasses
+        from tests.test_ops_scan import TestPackedLaunch
+        from yugabyte_db_tpu.models import tpch
+        from yugabyte_db_tpu.ops.grouped_scan import DictGroupSpec
+        from yugabyte_db_tpu.ops.scan import prepare_launch
+        q = {"q6": tpch.TPCH_Q6, "q1": tpch.TPCH_Q1,
+             "q1_dict": tpch.TPCH_Q1}[query_name]
+        group = DictGroupSpec((tpch.RETFLAG, tpch.LINESTATUS)) \
+            if query_name == "q1_dict" else q.group
+        tm = tablet_mesh(num_tablet_shards=4)
+        mesh = build_sharded_batch(
+            tm, self._lineitem(3000, 4),
+            sorted(set(q.columns) | {tpch.RETFLAG, tpch.LINESTATUS}))
+        mesh = dataclasses.replace(mesh, dicts={
+            tpch.RETFLAG: np.array(list("ANR"), object),
+            tpch.LINESTATUS: np.array(list("FO"), object)})
+        where = (Expr(q.where) & C(tpch.RETFLAG).ne(2)).node
+        job = prepare_launch(mesh, where, q.aggs, group, 1 << 40,
+                             n_total=mesh.padded_rows * mesh.num_shards)
+        kern = DistributedScanKernel()
+        fn = kern._get((id(tm.mesh),) + job.sig, tm, *job.key)
+        text = fn.lower(*job.args).as_text()
+        rows = mesh.padded_rows
+        compares = TestPackedLaunch._compares
+        assert compares(text, rows, "i32") == (
+            3 if query_name == "q6" else 2)
+        assert compares(text, rows, "i64") == (query_name == "q1_dict")
+
+    @staticmethod
+    def _unpacked_mesh_launch(batch, job, read_ht):
+        """What the mesh launch answered before its scalars and result
+        were packed: `_build_kernel`'s program a shard under
+        `shard_map`, its additive partials psummed and its extremes
+        pmin/pmax-ed over the shards, called with `unpacked_args`; every
+        output read back, the sums rescaled by the scales the program
+        returned."""
+        from jax.sharding import PartitionSpec as P
+        from tests.test_ops_scan import unpacked_args
+        from yugabyte_db_tpu.ops.scan import _build_kernel, _rescale_outs
+        from yugabyte_db_tpu.parallel.mesh import ROW_AXES
+        where, aggs, group, mode, static_sums, strategy, _ = job.key
+        local = _build_kernel(where, aggs, group, mode, axis_names=ROW_AXES,
+                              row_multiplier=batch.num_shards,
+                              static_sums=static_sums, strategy=strategy)
+
+        def shard_fn(*args):
+            outs, scales, counts, _, *spilled = local(*args)
+            outs = [jax.lax.pmin(o, ROW_AXES) if a.op == "min"
+                    and a.expr is not None else jax.lax.pmax(o, ROW_AXES)
+                    if a.op == "max" and a.expr is not None
+                    else jax.lax.psum(o, ROW_AXES)
+                    for a, o in zip(aggs, outs)]
+            scales = [(s[0], jax.lax.psum(s[1], ROW_AXES))
+                      if isinstance(s, tuple) else s for s in scales]
+            return (outs, scales, jax.lax.psum(counts, ROW_AXES),
+                    [jax.lax.psum(x, ROW_AXES) for x in spilled])
+        rows = P(ROW_AXES)
+        program = jax.jit(jax.shard_map(
+            shard_fn, mesh=batch.mesh.mesh,
+            in_specs=(rows, rows, P(), rows, rows, rows, rows, P(), P(), P()),
+            out_specs=P(), check_vma=False))
+        outs, scales, counts, spilled = jax.device_get(program(
+            *unpacked_args(job, read_ht)))
+        return (_rescale_outs(outs, scales), counts, *spilled)
+
+    @pytest.mark.parametrize("rows", ["some", "none"])
+    @pytest.mark.parametrize("scales", ["static", "dynamic", "nan"])
+    @pytest.mark.parametrize("kind", ["none", "dense", "dict", "dict_spill"])
+    def test_packed_mesh_result_is_the_unpacked_program(self, kind, scales,
+                                                        rows):
+        """The mesh's packed launch (its scalars one replicated vector an
+        element kind, its result packed after the one all-reduce) answers
+        what its program answered with its scalars one host value each
+        and its result read back leaf by leaf — bit for bit: the static
+        and dynamic scales, a NaN scale's float fallback, the extremes'
+        sentinels, int64 sums near 2^62 and the spill count."""
+        import dataclasses
+        from tests.test_ops_scan import (PACKED_COLUMNS, PACKED_WORDS,
+                                         TestPackedLaunch, packed_block)
+        from yugabyte_db_tpu.ops.scan import prepare_launch
+        blocks = [packed_block(3000, seed=60 + s, first_key=3000 * s)
+                  for s in range(4)]
+        mesh = dataclasses.replace(build_sharded_batch(
+            tablet_mesh(num_tablet_shards=4), [[b] for b in blocks],
+            PACKED_COLUMNS), dicts=PACKED_WORDS)
+        if scales != "static":
+            mesh = dataclasses.replace(mesh, col_bounds={})
+        where, aggs, group = TestPackedLaunch._shape(kind, scales, rows)
+        read_ht = TestPackedLaunch.READ_HT
+        got = DistributedScanKernel().run(mesh, where, aggs, group, read_ht)
+        job = prepare_launch(mesh, where, aggs, group, read_ht,
+                             n_total=mesh.padded_rows * mesh.num_shards)
+        assert any(job.key[4]) == (scales == "static")
+        want = self._unpacked_mesh_launch(mesh, job, read_ht)
+        assert len(got) == len(want) == 2 + kind.startswith("dict")
+        if kind.startswith("dict"):
+            assert got[2] == int(want[2])
+            assert (got[2] > 0) == (kind == "dict_spill" and rows == "some")
+        got, want = (jax.tree_util.tree_leaves(x[:2]) for x in (got, want))
+        assert len(got) == len(want) > 0
+        for x, y in zip(got, want):
+            x, y = np.asarray(x), np.asarray(y)
+            assert (x.dtype, x.shape) == (y.dtype, y.shape)
+            assert x.tobytes() == y.tobytes()
 
 
 class TestShardedVector:

@@ -156,7 +156,13 @@ def expected(cat, read_ht):
 def run_scan_kernel(blocks, read_ht):
     batch = build_batch(blocks, [1, 4], multi_version=True)
     assert batch.next_ht is not None
-    (s, c), _, mask = ScanKernel().run(batch, WHERE, AGGS, None, read_ht)
+    kern = ScanKernel()
+    (s, c), _, mask = kern.run(batch, WHERE, AGGS, None, read_ht)
+    # an aggregate launch keeps no row mask: a filter launch at the same
+    # read point gives it
+    assert mask is None
+    _, count, mask = kern.run(batch, WHERE, (), None, read_ht)
+    assert int(count) == int(c)
     return float(s), int(c), np.asarray(mask)[:batch.n_rows]
 
 

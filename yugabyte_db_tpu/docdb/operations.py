@@ -1977,10 +1977,11 @@ class DocReadOperation:
                                 ) -> Optional[ReadResponse]:
         """Monolithic twin of the partial-spill merge (ROADMAP TPC-H
         item (c)): the dict-group host codes are ALREADY device lanes
-        in ``batch.cols``, and the kernel's returned row mask already
-        folds visibility, WHERE, and group-key nulls — so the spilled
-        row set is just mask & (gid >= spill_slot) replayed host-side,
-        no second device pass.  Slots below the spill slot keep their
+        in ``batch.cols``, and ``mask`` — a filter launch's, at the
+        aggregate's read point — folds visibility and WHERE, so the
+        spilled row set is mask & (gid >= spill_slot) less the rows
+        with a NULL group value (which the grouped kernel leaves out),
+        replayed host-side.  Slots below the spill slot keep their
         exact partials; the spilled rows re-aggregate on the shared
         interpreted tail."""
         from ..ops.grouped_scan import decode_slot_groups, resolve_group
@@ -2005,8 +2006,11 @@ class DocReadOperation:
             batch.dicts)
         dev_part = decode_slot_groups(gspec, batch.dicts, dev_outs,
                                       counts_hot)
-        sel = np.flatnonzero(np.asarray(mask)[:n]
-                             & (gid >= spill_slot))
+        spilled = np.asarray(mask)[:n] & (gid >= spill_slot)
+        for cid in gspec.cols:
+            if batch.nulls.get(cid) is not None:
+                spilled &= ~np.asarray(batch.nulls[cid])[:n]
+        sel = np.flatnonzero(spilled)
         return self._spill_merge_tail(req, blocks, sel, aggs_run,
                                       expanded, minmax, dev_part)
 
@@ -2100,7 +2104,8 @@ class DocReadOperation:
         None = a shape the device cannot serve exactly; the caller
         falls back.  `on_spill(expanded, minmax, aggs_run, outs, counts,
         mask)` may serve a dictionary-grouped scan that overflowed its
-        slot budget."""
+        slot budget; `mask` is the row mask of a filter launch through
+        `run` (the aggregate launch returns none)."""
         where = req.where
         aggregates = req.aggregates
         if where is not None or any(a.expr is not None
@@ -2146,17 +2151,20 @@ class DocReadOperation:
             if any(c not in batch.dicts for c in gspec.cols) or \
                     domain_product(gspec, batch.dicts) >= 2 ** 31:
                 return None     # no dictionary / gid would wrap: CPU
-            outs, counts, mask, spill = yield partial(
+            outs, counts, _, spill = yield partial(
                 run, where, aggs_run, gspec)
             if int(spill) > 0:
                 # slot overflow on the MONOLITHIC dict-group route:
                 # same partial-spill merge as the streamed path — keep
                 # the exact in-range device partials, re-aggregate only
-                # the spilled rows on the interpreted fold.  The kernel
-                # mask already folds visibility/WHERE/group-null, so
-                # the spilled row set replays host-side for free.
+                # the spilled rows on the interpreted fold.  An
+                # aggregate launch returns no row mask: a filter launch
+                # at the same read point gives the one that folds
+                # visibility and WHERE, read back inside its launch.
                 if on_spill is not None \
                         and flags.get("grouped_spill_merge_enabled"):
+                    mask = yield lambda: np.asarray(
+                        run(where, (), None)[2])
                     resp = on_spill(expanded, minmax, aggs_run, outs,
                                     counts, mask)
                     if resp is not None:

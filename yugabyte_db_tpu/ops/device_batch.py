@@ -114,13 +114,15 @@ def lane_sig(v) -> str:
 def launch_leaves(tree) -> Tuple[int, int]:
     """Of a jitted call's arguments `tree`, in one pass over its leaves:
     (`host_args`, the host values the call places itself — every leaf
-    that is no `jax.Array` —, `wide_lanes`, the 64-bit arrays (not
-    scalars) it holds: the parameters the chip would split over the
-    whole lane at a launch's entry)."""
+    that is no `jax.Array` —, `wide_lanes`, the 64-bit device arrays
+    (not scalars) it holds: the lanes the chip would split over their
+    whole length at a launch's entry; a host vector of runtime scalars
+    is no lane)."""
     host = wide = 0
     for x in jax.tree_util.tree_leaves(tree):
-        host += not isinstance(x, jax.Array)
-        wide += (hasattr(x, "dtype") and np.ndim(x) >= 1
+        on_device = isinstance(x, jax.Array)
+        host += not on_device
+        wide += (on_device and x.ndim >= 1
                  and np.dtype(x.dtype).itemsize == 8)
     return host, wide
 

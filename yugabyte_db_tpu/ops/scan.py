@@ -68,15 +68,16 @@ src/yb/docdb/pgsql_operation.cc:3153):
 from __future__ import annotations
 
 import asyncio
+import math
 import threading
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .device_batch import (WORD_MAX, DeviceBatch, join, lane_sig,
+from .device_batch import (WORD_MAX, DeviceBatch, Pair, join, lane_sig,
                            launch_leaves, words)
 from .expr import collect_constants, compile_expr, expr_signature
 from .grouped_scan import (DictGroupSpec, ResolvedDictGroup,
@@ -272,8 +273,10 @@ def visibility_mask(mvcc_mode: str, valid, ht, next_ht, tombstone,
     all-ones sentinel says it has none, and keeps `read_ht` = MAX,
     "latest", selecting the newest).
     Times compare as their 32-bit words, high word first (`words`): a
-    batch holds `ht` and `next_ht` as `Pair`s, and `read_ht`, one uint64
-    host value, splits as a scalar — no 64-bit lane in the program."""
+    batch holds `ht` and `next_ht` as `Pair`s, and `read_ht` arrives as
+    its two words (`unpack_scalars`) or, in the fused plan kernel, as one
+    uint64 host value split as a scalar — no 64-bit lane in the
+    program."""
     if mvcc_mode == "none":
         return valid
     read = words(read_ht)
@@ -431,7 +434,11 @@ def _build_kernel(where_node, agg_specs: Tuple[AggSpec, ...],
 
     A lane of more than `tile_rows` rows (default `_TILE_ROWS`) runs in
     `tile_count` row tiles inside one device loop, the tiles' partials
-    added; one tile is the program without a loop."""
+    added; one tile is the program without a loop.
+
+    Once `fn` is traced, `fn.scaled_by_host` holds the indices of the
+    SUMs whose scale is the host's `sum_scales[i]` (the others' non-tuple
+    scale is `_NOSCALE`)."""
     # the kernel's consts list concatenates WHERE constants first, then
     # each aggregate expression's, in AggSpec order — every compile
     # lands at its cumulative offset so the slots can never collide
@@ -449,10 +456,17 @@ def _build_kernel(where_node, agg_specs: Tuple[AggSpec, ...],
             agg_fns.append((a.op, compile_expr(a.expr, offset=off)))
             off += const_count(a.expr)
     static_sums = static_sums or (False,) * len(agg_fns)
+    # the SUMs whose int64 result is divided by the scale the host gave
+    # (a static scale over a float value; over an integer lane the scale
+    # is `_NOSCALE`): noted as the program is traced, so that a launch
+    # need not read back a scale the host already holds
+    scaled_by_host = set()
 
     def _prep(i, v, m, n_total, sum_scales):
         if static_sums[i]:
             q, s = _sum_prep_static(v, m, sum_scales[i])
+            if s is not _NOSCALE:
+                scaled_by_host.add(i)
             return q, s, None
         return _sum_prep(v, m, n_total, axis_names)
 
@@ -591,6 +605,7 @@ def _build_kernel(where_node, agg_specs: Tuple[AggSpec, ...],
             0, tiles, step, start)
         return (outs, scales, counts, mask, *spilled)
 
+    fn.scaled_by_host = scaled_by_host
     return fn
 
 
@@ -645,6 +660,154 @@ def _type_min(v):
     return -jnp.inf
 
 
+# ---------------------------------------------------------------------------
+# What crosses between host and device a launch: the runtime scalars as
+# one host vector per element kind in, what the host reads as one array
+# per element kind out.  Every host value a jitted call places, and every
+# array a read-back brings and releases, costs the launch's thread time
+# (PERF.md §5).
+# ---------------------------------------------------------------------------
+
+def _literal_slot(c) -> str:
+    """Where a literal rides into the program: 'i' in the int64 vector or
+    'f' in the float64 vector — a Python int or float, which the program
+    makes weakly typed again, so that a compare runs in the lane's own
+    type as it would with the scalar itself — or 'a' as an argument of
+    its own, in the type it has: a `dictlut` table, a numpy scalar, a
+    bool."""
+    if type(c) is int and -2 ** 63 <= c < 2 ** 63:
+        return "i"
+    return "f" if type(c) is float else "a"
+
+
+def _weak(x):
+    """`x` weakly typed, as the Python scalar it was on the host: an
+    operand of another type then converts it, not the other way (JAX has
+    no public spelling of this conversion)."""
+    from jax._src.lax.lax import _convert_element_type
+    return _convert_element_type(x, x.dtype, weak_type=True)
+
+
+def unpack_scalars(arrays, scalars, lits, group, static_sums):
+    """Inside a program: the runtime scalars `prepare_launch` packed, as
+    the program `_build_kernel` makes takes them — (consts, read_ht,
+    sum_scales, domains).  `scalars` is (ints[, floats]): ints holds
+    `read_ht`'s two 32-bit words, the dictionary sizes, then the integer
+    literals; floats the static SUM scales, then the float literals;
+    `arrays` the literals that ride alone; `lits` a slot a literal
+    (`_literal_slot`)."""
+    ints = scalars[0]
+    floats = scalars[1] if len(scalars) > 1 else None
+    read_ht = Pair(ints[0].astype(jnp.uint32), ints[1].astype(jnp.uint32))
+    n_domains = len(group.cols) if isinstance(group, ResolvedDictGroup) \
+        else 0
+    domains = ints[2:2 + n_domains].astype(jnp.int32) if n_domains else ()
+    at = {"i": 2 + n_domains, "f": 0}
+    sum_scales = []
+    for static in static_sums:
+        sum_scales.append(floats[at["f"]].astype(jnp.float32)
+                          if static else None)
+        at["f"] += static
+    consts, alone = [], iter(arrays)
+    for slot in lits:
+        if slot == "a":
+            consts.append(next(alone))
+            continue
+        consts.append(_weak((ints if slot == "i" else floats)[at[slot]]))
+        at[slot] += 1
+    return consts, read_ht, sum_scales, domains
+
+
+def _wide(dtype) -> np.dtype:
+    """The element kind a result of `dtype` crosses as: an integer or a
+    bool as int64, a float as float64 — each exact — and a kind no wider
+    one holds exactly (uint64) as itself."""
+    dtype = np.dtype(dtype)
+    if dtype.kind == "f":
+        return np.dtype(np.float64)
+    if dtype.kind in "biu" and dtype != np.uint64:
+        return np.dtype(np.int64)
+    return dtype
+
+
+class ResultLayout:
+    """How a program's result crosses to the host: one vector per
+    element kind (`_wide`), whose layout — each leaf's vector, offset,
+    shape and dtype, and how the SUMs' scales are found — is recorded
+    when the program is traced (`pack`) and read after each call
+    (`unpack`, numpy views and slices of what the one `device_get`
+    brought)."""
+
+    def __init__(self):
+        self.value = None
+
+    def pack(self, fn, outs, scales, counts, rest):
+        """Inside a program: what the host reads of the result of `fn`
+        (a `_build_kernel` program) — its outs, counts and `rest` (a
+        hash group's values and count, a dictionary group's spill
+        count), and of its scales only a dynamic SUM's (scale, float
+        fallback): a static scale is the host's, a `_NOSCALE` none."""
+        kinds = tuple("dynamic" if isinstance(s, tuple)
+                      else "host" if i in fn.scaled_by_host else "exact"
+                      for i, s in enumerate(scales))
+        dynamic = tuple(s for s in scales if isinstance(s, tuple))
+        leaves, tree = jax.tree_util.tree_flatten(
+            (tuple(outs), dynamic, counts, tuple(rest)))
+        vectors: Dict[np.dtype, list] = {}
+        entries = []
+        for x in map(jnp.asarray, leaves):
+            kind = _wide(x.dtype)
+            parts = vectors.setdefault(kind, [])
+            entries.append((list(vectors).index(kind),
+                            sum(p.size for p in parts), x.shape, x.dtype))
+            parts.append(x.reshape(-1).astype(kind))
+        self.value = (tree, tuple(entries), kinds)
+        return tuple(jnp.concatenate(p) for p in vectors.values())
+
+    def unpack(self, vectors, host_scales):
+        """On the host: (outs rescaled, counts, rest) of the read-back
+        `vectors`, the static scales from `host_scales`."""
+        tree, entries, kinds = self.value
+        outs, dynamic, counts, rest = jax.tree_util.tree_unflatten(tree, [
+            vectors[v][at:at + math.prod(shape)].reshape(shape)
+            .astype(dtype, copy=False)
+            for v, at, shape, dtype in entries])
+        dynamic = iter(dynamic)
+        scales = [next(dynamic) if k == "dynamic"
+                  else host_scales[i] if k == "host" else 0.0
+                  for i, k in enumerate(kinds)]
+        return _rescale_outs(outs, scales), counts, rest
+
+
+def scan_program(where, aggs, group, mvcc_mode, static_sums, strategy,
+                 lits):
+    """(program, layout): the one-device program a `prepare_launch` key
+    names, traceable, and the `ResultLayout` its result is read by.  It
+    takes `prepare_launch`'s argument list and returns (the packed
+    result, the row mask) — the mask only where the launch has no
+    aggregates (a filter: `scan_filter`, the streamed filter route),
+    else None: no aggregate launch keeps a mask lane on the device."""
+    raw = _build_kernel(where, aggs, group, mvcc_mode,
+                        static_sums=static_sums, strategy=strategy)
+    layout = ResultLayout()
+
+    def program(cols, nulls, arrays, valid, ht, next_ht, tombstone,
+                scalars):
+        consts, read_ht, sum_scales, domains = unpack_scalars(
+            arrays, scalars, lits, group, static_sums)
+        outs, scales, counts, mask, *rest = raw(
+            cols, nulls, consts, valid, ht, next_ht, tombstone, read_ht,
+            sum_scales, domains)
+        return (layout.pack(raw, outs, scales, counts, rest),
+                None if aggs else mask)
+    # a stable program name: a kept trace's "XLA Modules" line reads
+    # jit_scan_linked..., not jit_program
+    program.__name__ = program.__qualname__ = "_".join(
+        ["scan", mvcc_mode] + ([type(group).__name__.lower()]
+                               if group is not None else []))
+    return program, layout
+
+
 class ScanKernel:
     """Signature-keyed cache of jitted scan kernels."""
 
@@ -656,23 +819,22 @@ class ScanKernel:
         # from the loop: one program a signature whoever asks first
         self._lock = threading.Lock()
 
-    def _get(self, sig, where_node, aggs, group, mvcc_mode, static_sums,
-             strategy):
+    def _get(self, sig, where, aggs, group, mvcc_mode, static_sums,
+             strategy, lits):
+        """The jitted program `sig` names; its `ResultLayout` is kept
+        beside it (`layout`)."""
         with self._lock:
-            fn = self._cache.get(sig)
-            if fn is None:
-                raw = _build_kernel(where_node, aggs, group, mvcc_mode,
-                                    static_sums=static_sums,
-                                    strategy=strategy)
-                # a stable program name: a kept trace's "XLA Modules"
-                # line reads jit_scan_linked..., not jit_fn
-                raw.__name__ = raw.__qualname__ = "_".join(
-                    ["scan", mvcc_mode] + ([type(group).__name__.lower()]
-                                           if group is not None else []))
-                fn = jax.jit(raw)
-                self._cache[sig] = fn
+            got = self._cache.get(sig)
+            if got is None:
+                program, layout = scan_program(
+                    where, aggs, group, mvcc_mode, static_sums, strategy,
+                    lits)
+                got = self._cache[sig] = (jax.jit(program), layout)
                 self.compiles += 1
-            return fn
+            return got[0]
+
+    def layout(self, sig) -> "ResultLayout":
+        return self._cache[sig][1]
 
     def run(self, batch: DeviceBatch,
             where: Optional[tuple] = None,
@@ -683,41 +845,58 @@ class ScanKernel:
         HashGroupSpec adds (group_values, n_groups); DictGroupSpec adds
         a trailing spill count (nonzero = slot overflow, the caller
         must revert to the interpreted GROUP BY).  Everything but the
-        mask is a host value (`launch`).  It reads the batch, which
-        nothing changes once it is built, and this kernel's own program
-        cache under its lock — nothing of a store — so a served read
-        calls it on a thread beside the event loop.  What it does before
-        the dispatch is the `launch.prepare` span."""
+        mask is a host value (`launch`); the mask is a device array
+        where the launch has no aggregates (a filter), else None.  It
+        reads the batch, which nothing changes once it is built, and
+        this kernel's own program cache under its lock — nothing of a
+        store — so a served read calls it on a thread beside the event
+        loop.  What it does before the dispatch is the `launch.prepare`
+        span."""
         with _trace.TRACES.span("launch.prepare", child_only=True,
                                 cpu=True):
-            sig, key, args = prepare_launch(batch, where, aggs, group,
-                                            read_ht)
+            job = prepare_launch(batch, where, aggs, group, read_ht)
             # (two threads that both find no program both wait for the
             # one compile, and both say so)
-            compiled = sig not in self._cache
-            fn = self._get(sig, *key)
-        return launch(fn, sig, key, args, batch, compiled, mask=True)
+            compiled = job.sig not in self._cache
+            fn = self._get(job.sig, *job.key)
+        return launch(fn, self.layout(job.sig), job, batch, compiled,
+                      mask=True)
 
 
-def prepare_launch(batch, where, aggs, group, read_ht, n_total=None):
-    """What one launch is made of, for either kernel — a `DeviceBatch`
-    here, a `ShardedBatch` in parallel/distributed_scan.py, whose SUMs
-    run over `n_total` = rows x shards.  Returns (sig, key, args):
+class Launch(NamedTuple):
+    """What one launch is made of (`prepare_launch`).
 
-    - key = (where, aggs, group, mvcc_mode, static_sums, strategy), what
-      a kernel's `_get` builds the program from: AVG expanded, a
+    - key = (where, aggs, group, mvcc_mode, static_sums, strategy, lits),
+      what a kernel's `_get` builds the program from: AVG expanded, a
       DictGroupSpec resolved against the batch's scan-global
       dictionaries (the pow2 slot bucket is static; KeyError = a group
-      column with no dictionary, the caller falls back);
+      column with no dictionary, the caller falls back), and where each
+      literal rides (`_literal_slot`);
     - sig, the structural signature that names the program in a cache;
-    - args, the jitted call's argument list.  Every runtime scalar in it
-      is a HOST value, placed by the one jitted call and by no program
-      of its own: the literals as the Python scalars they are (weakly
-      typed, so a compare runs in the lane's own type), array constants
-      (`dictlut` tables) as numpy arrays, `read_ht` as np.uint64 (all
-      ones does not fit a weak int64), the SUM scales as ONE float32
-      vector and the dictionary sizes as ONE int32 vector — so other
-      literals, bounds or dictionary sizes never recompile."""
+    - args, the jitted call's argument list: (cols, nulls, the literals
+      that ride alone, valid, ht, next_ht, tombstone, scalars);
+    - scales, the static SUM scales the host rescales the result with
+      (one float32 an aggregate, 0.0 where not static)."""
+    sig: tuple
+    key: tuple
+    args: tuple
+    scales: np.ndarray
+
+
+def prepare_launch(batch, where, aggs, group, read_ht,
+                   n_total=None) -> Launch:
+    """What one launch is made of, for either kernel — a `DeviceBatch`
+    here, a `ShardedBatch` in parallel/distributed_scan.py, whose SUMs
+    run over `n_total` = rows x shards.  Every runtime scalar is packed
+    into ONE host vector per element kind, placed by the one jitted call
+    and by no program of its own (`unpack_scalars` takes them apart in
+    the program): an int64 vector of `read_ht`'s two 32-bit words (all
+    ones = latest), the dictionary sizes and the integer literals, and —
+    where there is any — a float64 vector of the static SUM scales and
+    the float literals.  Array constants (`dictlut` tables) ride as
+    numpy arrays of their own.  Other literals, read points, bounds or
+    dictionary sizes never recompile; a literal's kind (and the type of
+    one that rides alone) is part of the signature."""
     aggs = tuple(_expand_avg(aggs))
     mvcc_mode, lanes = mvcc_lanes(batch, read_ht)
     consts: List = []
@@ -726,14 +905,20 @@ def prepare_launch(batch, where, aggs, group, read_ht, n_total=None):
     for a in aggs:
         if a.expr is not None:
             collect_constants(a.expr, consts)
-    domains = ()
+    read_ht = 0xFFFFFFFFFFFFFFFF if read_ht is None else int(read_ht)
+    ints = [read_ht >> 32, read_ht & 0xFFFFFFFF]
     if isinstance(group, DictGroupSpec):
         group, sizes = resolve_group(group, batch.dicts)
-        domains = np.asarray(sizes, np.int32)
+        ints.extend(sizes)
     col_sig = tuple(sorted(
         (cid, lane_sig(v)) for cid, v in batch.cols.items()))
     static_sums, scales = _static_scales(
         aggs, batch.col_bounds, n_total or batch.padded_rows, batch.cols)
+    floats = [float(s) for s, static in zip(scales, static_sums) if static]
+    lits = tuple(map(_literal_slot, consts))
+    alone = []
+    for c, slot in zip(consts, lits):
+        (ints if slot == "i" else floats if slot == "f" else alone).append(c)
     strategy = _group_strategy()
     sig = (
         expr_signature(where) if where is not None else None,
@@ -741,60 +926,67 @@ def prepare_launch(batch, where, aggs, group, read_ht, n_total=None):
         (type(group).__name__, group.cols,
          getattr(group, "max_groups", getattr(group, "num_slots", None)))
         if group else None,
-        mvcc_mode, batch.padded_rows, col_sig, static_sums, strategy,
+        mvcc_mode, batch.padded_rows, col_sig, static_sums, strategy, lits,
+        # a literal that rides alone is traced in its own type: so is the
+        # program's result, whose layout the program's name must fix
+        tuple((str(np.asarray(c).dtype), np.shape(c)) for c in alone),
     )
-    args = (batch.cols, batch.nulls, consts, batch.valid, *lanes,
-            np.uint64(0xFFFFFFFFFFFFFFFF if read_ht is None else read_ht),
-            scales, domains)
-    return sig, (where, aggs, group, mvcc_mode, static_sums, strategy), args
+    scalars = (np.asarray(ints, np.int64),) + (
+        (np.asarray(floats, np.float64),) if floats else ())
+    args = (batch.cols, batch.nulls, alone, batch.valid, *lanes, scalars)
+    return Launch(sig, (where, aggs, group, mvcc_mode, static_sums,
+                        strategy, lits), args, scales)
 
 
-def launch(fn, sig, key, args, batch, compiled: bool, mask: bool, tags=()):
-    """Dispatch `fn(*args)`, the program `prepare_launch`'s `key` names
-    (the `device.scan` span: tag `host_args` = how many host values the
-    call placed, `wide_lanes` = how many 64-bit arrays it was given —
-    each one the chip splits over the whole lane first, 0 with a
-    batch's `Pair`s — `tiles` = how many row tiles the program runs a
-    lane in, 1 = whole) and read its result back in ONE transfer
-    (`device.wait`: tag `reads`), `tags` on both spans.
-    `fn` returns (outs, scales, counts[, mask], ...): the fixed-point
-    sums are rescaled on the host values and the caller gets (outs,
+def launch(fn, layout: ResultLayout, job: Launch, batch, compiled: bool,
+           mask: bool, tags=()):
+    """Dispatch `fn(*job.args)`, the program `job.key` names (the
+    `device.scan` span: tag `host_args` = how many host values the call
+    placed, `wide_lanes` = how many 64-bit arrays it was given — each
+    one the chip splits over the whole lane first, 0 with a batch's
+    `Pair`s — `tiles` = how many row tiles the program runs a lane in,
+    1 = whole) and read its result back in ONE transfer (`device.wait`:
+    tag `reads`; `result_leaves` = the arrays it brought, one an element
+    kind), `tags` on both spans.
+    `fn` returns (the packed result, the row mask or None): `layout`
+    unpacks the result, the fixed-point sums are rescaled on the host
+    (static scales from `job.scales`) and the caller gets (outs,
     counts[, mask], ...) — all host values but the row mask, which stays
-    a device array (only the filter route and the spill merge read it).
+    a device array (only the filter launches return one); `mask` says
+    whether the caller's result has the mask's place.
     The read-back is the first host read of the result, so this is
     where the host waits for the device (`Device_BlockUntilReady` for
     ASH); a sampled span waits for every output first, so it holds the
     whole wait whatever the transfer covers."""
-    _, aggs, group, mvcc_mode, static_sums, _ = key
+    _, aggs, group, mvcc_mode, static_sums, _, _ = job.key
     scan_tags = tags
     if _trace.sampled():
         # counted before the span opens: its wall and CPU time are the
         # dispatch's alone
-        host_args, wide = launch_leaves(args)
+        host_args, wide = launch_leaves(job.args)
         scan_tags = (("host_args", host_args), ("wide_lanes", wide),
                      ("tiles", tile_count(batch.padded_rows, group, aggs,
                                           static_sums)), *tags)
-    with _trace.device_span("scan", signature=sig, compiled=compiled,
+    with _trace.device_span("scan", signature=job.sig, compiled=compiled,
                             bucket=batch.padded_rows, rows=batch.n_rows,
                             mvcc=mvcc_mode, tags=scan_tags):
-        raw = fn(*args)
+        packed, on_device = fn(*job.args)
     with _trace.wait_status("Device_BlockUntilReady",
                             component="device"), \
             _trace.TRACES.span("device.wait", child_only=True) as sp:
         if sp.sampled:
             sp.set_tag("thread", _thread_kind())
             sp.set_tag("reads", 1)
+            sp.set_tag("result_leaves", len(packed))
             for k, v in tags:
                 sp.set_tag(k, v)
-            jax.block_until_ready(raw)
-        on_device = raw[3:4] if mask else ()
-        outs, scales, counts, *rest = jax.device_get(
-            raw[:3] + raw[3 + len(on_device):])
-        outs = _rescale_outs(outs, scales)
+            jax.block_until_ready(packed)
+        outs, counts, rest = layout.unpack(jax.device_get(packed),
+                                           job.scales)
         # the result's device buffers go here, inside the read-back: their
         # release is a cost of the launch (~0.75 ms on a CPU backend)
-        del raw
-    return (outs, counts, *on_device, *rest)
+        del packed
+    return (outs, counts, *((on_device,) if mask else ()), *rest)
 
 
 def _thread_kind() -> str:
@@ -812,8 +1004,10 @@ def _static_scales(aggs: Sequence[AggSpec],
                    n_total: int, cols=None):
     """Per-agg static fixed-point scales from host column stats.
     Returns (static_flags, scales) — scales is ONE float32 vector on the
-    host, an entry an aggregate (0.0 for a non-static one) and a runtime
-    argument, so changing data bounds never recompiles the kernel.
+    host, an entry an aggregate (0.0 for a non-static one): the static
+    ones ride into the program as runtime values (`prepare_launch`), so
+    changing data bounds never recompiles the kernel, and the host
+    rescales the result with them.
     `cols` (col_id -> device array) supplies dtypes: expressions
     touching f32 columns cap every intermediate interval at the f32
     finite range, since an f32 product can overflow to Inf on device

@@ -23,8 +23,8 @@ from ..ops.device_batch import (HT_NONE, Pair, batch_bytes, bucket_rows,
                                 f64_conversion, f64_pair, f64_pairs,
                                 link_versions, u64_pair)
 from ..ops.grouped_scan import DictGroupSpec
-from ..ops.scan import (AggSpec, GroupSpec, _build_kernel, launch,
-                        prepare_launch)
+from ..ops.scan import (AggSpec, GroupSpec, ResultLayout, _build_kernel,
+                        launch, prepare_launch, unpack_scalars)
 from ..storage.columnar import ColumnarBlock
 from ..utils import trace as _trace
 from .mesh import ROW_AXES, TabletMesh
@@ -255,17 +255,21 @@ class DistributedScanKernel:
         self._lock = threading.Lock()
 
     def _get(self, sig, tm: TabletMesh, where, aggs, group, mvcc_mode,
-             static_sums, strategy):
-        # under the lock, as `ScanKernel._get`: `run` is called beside
-        # the event loop too
+             static_sums, strategy, lits):
+        """The jitted program `sig` names, its `ResultLayout` kept beside
+        it (`layout`) — under the lock, as `ScanKernel._get`: `run` is
+        called beside the event loop too."""
         with self._lock:
-            fn = self._cache.get(sig)
-            if fn is None:
-                fn = self._cache[sig] = self._build(
-                    sig, tm, where, aggs, group, mvcc_mode, static_sums,
-                    strategy)
+            got = self._cache.get(sig)
+            if got is None:
+                got = self._cache[sig] = self._build(
+                    tm, where, aggs, group, mvcc_mode, static_sums,
+                    strategy, lits)
                 self.compiles += 1
-            return fn
+            return got[0]
+
+    def layout(self, sig) -> ResultLayout:
+        return self._cache[sig][1]
 
     def forget(self, tm: TabletMesh) -> None:
         """Drop the programs compiled for `tm` (their signature begins
@@ -274,8 +278,8 @@ class DistributedScanKernel:
             for sig in [s for s in self._cache if s[0] == id(tm.mesh)]:
                 del self._cache[sig]
 
-    def _build(self, sig, tm: TabletMesh, where, aggs, group, mvcc_mode,
-               static_sums, strategy):
+    def _build(self, tm: TabletMesh, where, aggs, group, mvcc_mode,
+               static_sums, strategy, lits):
         axes = ROW_AXES
         S = tm.num_tablet_shards * tm.num_block_shards
         # static SUM scales derive from GLOBAL host-side column bounds,
@@ -286,18 +290,21 @@ class DistributedScanKernel:
         local = _build_kernel(where, aggs, group, mvcc_mode,
                               axis_names=axes, row_multiplier=S,
                               static_sums=static_sums, strategy=strategy)
+        layout = ResultLayout()
 
-        def shard_fn(cols, nulls, consts, valid, ht, next_ht, tombstone,
-                     read_ht, sum_scales, domains):
+        def shard_fn(cols, nulls, arrays, valid, ht, next_ht, tombstone,
+                     scalars):
             # the local shard view of a row lane is the [N] lane the
             # one-device kernel reads; a lane the mode does not read is
-            # None
+            # None; the runtime scalars are the replicated host vectors
+            consts, read_ht, sum_scales, domains = unpack_scalars(
+                arrays, scalars, lits, group, static_sums)
             got = local(cols, nulls, consts, valid, ht, next_ht, tombstone,
                         read_ht, sum_scales, domains)
             outs, scales, counts = got[:3]
             # a dictionary-grouped kernel also counts the rows whose
             # group fell past its slot budget: they add up like a count
-            spilled = got[4] if len(got) > 4 else jnp.int64(0)
+            spilled = got[4:]
             kinds = [_COMBINE["count" if a.expr is None else a.op]
                      for a in aggs]
             # every additive partial of the launch rides ONE psum: the
@@ -320,26 +327,26 @@ class DistributedScanKernel:
             # through replicated
             cscales = [(s[0], next(fbs)) if isinstance(s, tuple) else s
                        for s in scales]
-            return tuple(combined), tuple(cscales), added[1], added[2]
+            # what the host reads, packed once combined: replicated
+            return layout.pack(local, combined, cscales, added[1],
+                               added[2])
 
         rows = P(ROW_AXES)
-        in_specs = (
-            {k: rows for k in sig_cols(sig)}, {k: rows for k in sig_cols(sig)},
-            P(), rows, rows, rows, rows, P(), P(), P())
+        in_specs = (rows, rows, P(), rows, rows, rows, rows, P())
         smapped = jax.shard_map(
-            shard_fn, mesh=tm.mesh, in_specs=in_specs,
-            out_specs=(tuple(P() for _ in aggs), tuple(P() for _ in aggs),
-                       P(), P()), check_vma=False)
+            shard_fn, mesh=tm.mesh, in_specs=in_specs, out_specs=P(),
+            check_vma=False)
 
         def mesh_scan(*args):
-            """The argument list `ops.scan.prepare_launch` makes."""
-            return smapped(*args)
+            """The argument list `ops.scan.prepare_launch` makes; returns
+            (the packed result, no row mask)."""
+            return smapped(*args), None
         # a stable program name, as `ScanKernel._get` gives the
         # single-device program: jit_mesh_scan_linked_resolveddictgroup
         mesh_scan.__name__ = mesh_scan.__qualname__ = "_".join(
             ["mesh_scan", mvcc_mode] + ([type(group).__name__.lower()]
                                         if group is not None else []))
-        return jax.jit(mesh_scan)
+        return jax.jit(mesh_scan), layout
 
     def run(self, batch: ShardedBatch,
             where: Optional[tuple] = None,
@@ -354,24 +361,19 @@ class DistributedScanKernel:
         tm = batch.mesh
         with _trace.TRACES.span("launch.prepare", child_only=True,
                                 cpu=True):
-            sig, key, args = prepare_launch(
+            job = prepare_launch(
                 batch, where, aggs, group, read_ht,
                 n_total=batch.padded_rows * batch.num_shards)
-            sig = (id(tm.mesh),) + sig
-            compiled = sig not in self._cache
-            fn = self._get(sig, tm, *key)
-        outs, counts, spilled = launch(
-            fn, sig, key, args, batch, compiled,
-            mask=False,
+            job = job._replace(sig=(id(tm.mesh),) + job.sig)
+            compiled = job.sig not in self._cache
+            fn = self._get(job.sig, tm, *job.key)
+        outs, counts, *spilled = launch(
+            fn, self.layout(job.sig), job, batch, compiled, mask=False,
             tags=(("chips", tm.mesh.devices.size),
                   ("shards", batch.num_shards)))
         if isinstance(group, DictGroupSpec):
-            return outs, counts, int(spilled)
+            return outs, counts, int(spilled[0])
         return outs, counts
-
-
-def sig_cols(sig) -> Tuple[int, ...]:
-    return tuple(cid for cid, _ in sig[-3])
 
 
 _DEFAULT = DistributedScanKernel()
