@@ -1,7 +1,7 @@
-"""The least bytes a scan and a compaction have to move, from rows, query
-and file sizes alone — the same whatever kernel does the work and however
-it holds a column, so a later PR's kernel is held to the same count.  Both
-rooflines are memory-bound: the work per byte is a compare and an add."""
+"""The least bytes a scan has to move, from rows and query alone — the
+same whatever kernel does the work and however it holds a column, so a
+later PR's kernel is held to the same count.  The roofline is
+memory-bound: the work per byte is a compare and an add."""
 from __future__ import annotations
 
 from . import tpch
@@ -28,12 +28,6 @@ def scan_bytes(rows: int, query: str) -> int:
     """One pass over the columns `query` reads, for `rows` stored row
     versions.  The answer (a few sums) is nothing beside it."""
     return rows * scan_row_bytes(query)
-
-
-def merge_bytes(input_file_bytes: int, output_file_bytes: int) -> int:
-    """A compaction reads every input SST byte once and writes every
-    output byte once."""
-    return input_file_bytes + output_file_bytes
 
 
 def least_seconds(nbytes: float, peak: dict, chips: int = 1) -> float:

@@ -29,14 +29,6 @@ def load(root: str = ROOT) -> dict:
         return json.load(f)
 
 
-def with_deferred(m: dict, root: str = ROOT) -> dict:
-    """`m` and the entries `benchmark/deferred.json` keeps for a later PR:
-    cells whose files are here but which no chip run has proved yet.
-    Tests and rehearsals see them; `BENCHMARK.json` does not name them."""
-    extra = load_json(os.path.join(root, "benchmark", "deferred.json"))
-    return {k: v + extra[k] if k in extra else v for k, v in m.items()}
-
-
 def _line(text, what) -> None:
     if not (isinstance(text, str) and 1 <= len(text) <= 200
             and "\n" not in text and "\t" not in text):

@@ -232,8 +232,8 @@ def run_cell(argv=None) -> dict:
                     help="directory to copy the raw .xplane.pb into")
     args = ap.parse_args(argv)
     # this file's own checkout, so that a copy of the benchmark runs its own
-    # manifest and files; a deferred cell runs too
-    m = manifest.with_deferred(manifest.load(_ROOT), _ROOT)
+    # manifest and files
+    m = manifest.load(_ROOT)
     cell = manifest.Cell(m, args.workload, _ROOT)
     if args.seconds is None:
         args.seconds = float(m["run_seconds"])

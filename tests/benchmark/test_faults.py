@@ -3,7 +3,8 @@ chip skipped (`--rehearse`), one fault planted in the program, and
 `correct` has to come out false — once for each fault a cell can have:
 an answer altered where it is produced, part of the batch (a tablet's
 partial) left out, a step that returns its state unchanged (a compaction
-that compacts nothing), an acknowledged write that was never applied."""
+that compacts nothing), an acknowledged write that was never applied,
+a compaction that drops or doubles the rows it writes."""
 import numpy as np
 import pytest
 
@@ -83,9 +84,9 @@ def _around_the_window(monkeypatch, around) -> None:
 
 @pytest.mark.parametrize("which", ["every", "window_only"])
 def test_a_compaction_that_changes_nothing_is_not_correct(which, monkeypatch):
-    """`window_only`: set-up's compactions and the one the check sends
-    after the window are real; only the timed ones return having done
-    nothing, so it is their own SST counts that have to catch it."""
+    """`window_only`: set-up's compactions are real; only the timed ones
+    return having done nothing, so it is their own SST counts, and the
+    tablets' count when the check reads them, that have to catch it."""
     from yugabyte_db_tpu.tablet.tablet import Tablet
     real, in_window = Tablet.compact, [which == "every"]
     monkeypatch.setattr(
@@ -134,3 +135,101 @@ def test_an_acknowledged_insert_that_was_dropped_is_not_correct(monkeypatch):
     assert result["correct"] is False
     assert {"readback_missing", "q1_count_diff"} <= _bad(result)
     assert result["compared"]["readback_value_diff"] == [0.0, 0]
+
+
+def _window_cutter(monkeypatch, fault) -> None:
+    """The window's compactions hand the output writer what `fault(add,
+    self, piece)` does with every merged piece (`_BlockCutter.add`, which
+    the device and the native merge feed alike); set-up's are sound."""
+    from yugabyte_db_tpu.docdb import compaction
+    real, in_window = compaction._BlockCutter.add, [False]
+
+    def add(self, piece):
+        if in_window[0]:
+            return fault(real, self, piece)
+        return real(self, piece)
+
+    async def window(real_window, *a):
+        in_window[0] = True
+        try:
+            return await real_window(*a)
+        finally:
+            in_window[0] = False
+
+    monkeypatch.setattr(compaction._BlockCutter, "add", add)
+    _around_the_window(monkeypatch, window)
+
+
+def _route_merge(monkeypatch, merge) -> None:
+    """Every pipelined compaction through `merge`: `native`, as the CPU
+    routes it, or `device`, the merge kernel a chip routes to (which
+    drops exact duplicates)."""
+    from yugabyte_db_tpu.tablet import tablet
+    real = tablet.tpu_compact
+
+    def routed(*a, backend, **kw):
+        return real(*a, backend=backend if backend == "baseline" else merge,
+                    **kw)
+
+    monkeypatch.setattr(tablet, "tpu_compact", routed)
+
+
+@pytest.mark.parametrize("merge", ["native", "device"])
+def test_a_compaction_that_keeps_half_its_rows_is_not_correct(
+        merge, monkeypatch):
+    """Half of every merged piece.  The SST counts are right, so the
+    answers and the read-back have to catch it."""
+    def half(add, self, piece):
+        return add(self, piece.slice(0, piece.n // 2) if piece.n > 1
+                   else piece)
+
+    _route_merge(monkeypatch, merge)
+    _window_cutter(monkeypatch, half)
+    result = run.run_cell(["--workload", "refresh_compact", *ARGS])
+    assert result["correct"] is False
+    assert result["compared"]["ssts_after_compact"] == [0.0, 0]
+    assert {"q1_count_diff", "readback_missing"} <= _bad(result)
+
+
+@pytest.mark.parametrize("merge", ["native", "device"])
+def test_a_compaction_that_writes_every_row_twice_is_not_correct(
+        merge, monkeypatch):
+    """Every merged piece twice.  Nothing after the window merges the
+    tablets again, so the copies the last compaction wrote reach the
+    answers the check compares."""
+    def twice(add, self, piece):
+        add(self, piece)
+        add(self, piece)
+
+    _route_merge(monkeypatch, merge)
+    _window_cutter(monkeypatch, twice)
+    result = run.run_cell(["--workload", "refresh_compact", *ARGS])
+    assert result["correct"] is False
+    assert result["compared"]["ssts_after_compact"] == [0.0, 0]
+    assert "q1_count_diff" in _bad(result)
+
+
+def test_the_check_reads_the_tablets_the_window_wrote(monkeypatch):
+    """No flush or compaction RPC after the window: Q6, Q1 and the
+    read-back see the SSTs of the window's last compactions."""
+    from benchmark.cluster import Cluster
+    real, sent, phase = Cluster.maintenance, [], ["set-up"]
+
+    async def maintenance(self, method, *a):
+        sent.append((phase[0], method))
+        return await real(self, method, *a)
+
+    async def window(real_window, *a):
+        phase[0] = "window"
+        try:
+            return await real_window(*a)
+        finally:
+            phase[0] = "check"
+
+    monkeypatch.setattr(Cluster, "maintenance", maintenance)
+    _around_the_window(monkeypatch, window)
+    result = run.run_cell(["--workload", "refresh_compact", *ARGS])
+    assert result["correct"] is True
+    assert ("window", "compact") in sent
+    assert [m for p, m in sent if p == "check"] == []
+    assert sent[-1] == ("window", "compact")

@@ -22,13 +22,8 @@ def real():
     return manifest.load()
 
 
-def full():
-    """With the cells kept for a later PR (`benchmark/deferred.json`)."""
-    return manifest.with_deferred(manifest.load())
-
-
 def names(*keys):
-    return [x["name"] for key in keys for x in full()[key]]
+    return [x["name"] for key in keys for x in real()[key]]
 
 
 def check_chips(m):
@@ -89,12 +84,11 @@ def check_config(m, config, root=manifest.ROOT):
 def test_manifest_is_valid():
     m = real()
     manifest.validate(m)
-    manifest.validate(full())
     assert m["command"] == ["python3", "benchmark/run.py"]
     assert len(json.dumps(m)) < 64 * 1024
 
 
-@pytest.mark.parametrize("m", [real, full], ids=["manifest", "with_deferred"])
+@pytest.mark.parametrize("m", [real], ids=["manifest"])
 def test_four_chip_cells_are_at_most_half(m):
     check_chips(m())
 
@@ -113,7 +107,7 @@ def test_chips_rule_by_hand(chips, ok):
 
 @pytest.mark.parametrize("metric", names("end_to_end", "per_layer"))
 def test_metric_names_units_and_cells(metric):
-    ALL = full()
+    ALL = real()
     metric = next(x for x in ALL["end_to_end"] + ALL["per_layer"]
                   if x["name"] == metric)
     assert manifest._NAME.match(metric["name"])
@@ -132,12 +126,12 @@ def test_metric_names_units_and_cells(metric):
 
 @pytest.mark.parametrize("cell", names("workloads"))
 def test_cell_finds_its_files_by_name(cell):
-    check_cell(full(), cell)
+    check_cell(real(), cell)
 
 
 @pytest.mark.parametrize("config", names("configs"))
 def test_config_file_states_its_cut(config):
-    m = full()
+    m = real()
     check_config(m, next(c for c in m["configs"] if c["name"] == config))
 
 
@@ -146,7 +140,7 @@ def test_tpch_sf1_configurations_are_sf1(config):
     """What is true of the two TPC-H SF1 configurations and of no other:
     the scale factor's own counts, one chip, and the float64 the schema
     declares."""
-    entry = next(c for c in full()["configs"] if c["name"] == config)
+    entry = next(c for c in real()["configs"] if c["name"] == config)
     body = manifest.load_json(os.path.join(manifest.ROOT, entry["file"]))
     assert body["sizes"]["rows"] == 6_001_215     # SF1, clause 4.2.5
     assert body["sizes"]["orders"] == 1_500_000
@@ -167,7 +161,7 @@ def test_tpch_sf1_configurations_are_sf1(config):
         "moves_not_reported",
         "extra_key", "traffic_not_found", "bound_too_wide", "pair_twice"])
 def test_validation_refuses(breakage):
-    m = full()
+    m = real()
     breakage(m)
     with pytest.raises(manifest.ManifestError):
         manifest.validate(m)

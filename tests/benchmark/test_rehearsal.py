@@ -9,18 +9,12 @@ import pytest
 from benchmark import manifest, run
 
 
-
-def full():
-    """The manifest with the cells kept for a later PR."""
-    return manifest.with_deferred(manifest.load())
-
-
-CELLS = [w["name"] for w in full()["workloads"]]
+CELLS = [w["name"] for w in manifest.load()["workloads"]]
 
 
 def rehearse(cell, *more):
     """At the rows the cell's own configuration states for a rehearsal."""
-    rows = manifest.Cell(full(), cell).config["rehearsal"]["rows"]
+    rows = manifest.Cell(manifest.load(), cell).config["rehearsal"]["rows"]
     return run.run_cell(["--workload", cell, "--seed", "2147484001",
                          "--seconds", "2", "--rows", str(rows), "--rehearse",
                          *more])
@@ -47,7 +41,7 @@ def test_rehearsal_is_correct_and_reports_no_metric(cell, capsys):
     assert all(v is not None and v <= limit
                for v, limit in result["compared"].values())
     json.dumps(result, allow_nan=False)
-    limits = manifest.Cell(full(), cell).config["limits"]
+    limits = manifest.Cell(manifest.load(), cell).config["limits"]
     assert set(result["compared"]) == set(limits)
 
 

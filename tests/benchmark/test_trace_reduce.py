@@ -400,8 +400,7 @@ def test_least_seconds_over_chips():
 
 
 @pytest.mark.parametrize("metric, trace, prefix", [
-    ("scan_roofline", "tpu_scan_power", "stmt"),
-    ("merge_roofline", "tpu_refresh_compact", "compact")])
+    ("scan_roofline", "tpu_scan_power", "stmt")])
 def test_roofline_readers_spread_the_least_bytes_over_the_cells_chips(
         metric, trace, prefix):
     """On the recorded v5e trace: a four-chip cell that moved the same
@@ -410,12 +409,10 @@ def test_roofline_readers_spread_the_least_bytes_over_the_cells_chips(
     own share."""
     planes, _ = recorded(trace)
     read = manifest.load_module(manifest.layer_metric_file(metric)).read
-    rec = types.SimpleNamespace(of=lambda kind: [
-        {"input_bytes": 90_000_000, "output_bytes": 89_000_000}] * 2)
 
     def ctx(trace_chips, cell_chips):
         return types.SimpleNamespace(
-            trace=tr.reduce(planes, trace_chips), rec=rec,
+            trace=tr.reduce(planes, trace_chips),
             cell=types.SimpleNamespace(chips=cell_chips),
             peak=peaks.lookup("TPU v5 lite"),
             data=types.SimpleNamespace(table_rows=6_007_215))

@@ -163,11 +163,6 @@ def test_scan_bytes_by_hand():
         pytest.approx(0.000403, rel=2e-3)
 
 
-def test_merge_bytes_by_hand():
-    assert bytes_model.merge_bytes(90_000_000, 89_000_000) == 179_000_000
-    assert bytes_model.least_seconds(819e9, {"hbm_bytes_per_s": 819e9}) == 1
-
-
 def test_peaks_known_and_unknown_device():
     v5e = peaks.lookup("TPU v5 lite")
     assert v5e["hbm_bytes_per_s"] == 819e9 and v5e["source"]
