@@ -234,7 +234,7 @@ def _one_batch(columns):
 def test_pairs_hold_the_bytes_whole_lanes_held(tpu_pairs, build):
     """8 B a row either way: `batch_bytes` and the per-chip placement the
     cache accounts, of the batch, of Q6's narrowing of Q1's batch, and of
-    the entry `get_covering` hands Q6."""
+    the entry `lookup` hands Q6 by its covering key."""
     q1, q6 = sorted(model.TPCH_Q1.columns), sorted(model.TPCH_Q6.columns)
     batch = build(q1)
     assert isinstance(batch.cols[model.EXTPRICE], Pair)
@@ -249,7 +249,7 @@ def test_pairs_hold_the_bytes_whole_lanes_held(tpu_pairs, build):
         assert batch_bytes(six) == batch_bytes(whole.narrowed(q6))
     cache = DeviceBlockCache()
     cache.get_or_build(("t", tuple(q1), "ssts"), lambda: batch)
-    assert cache.get_covering(("t", tuple(q6), "ssts"), 1) is batch
+    assert cache.lookup(("t", tuple(q6), "ssts"), 1) is batch
     assert cache.bytes_by_chip() == batch_placement(whole)
 
 

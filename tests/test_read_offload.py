@@ -14,7 +14,7 @@ from yugabyte_db_tpu.ql.executor import SqlSession
 from yugabyte_db_tpu.tools.mini_cluster import MiniCluster
 from yugabyte_db_tpu.utils import fault_injection as fi
 from yugabyte_db_tpu.utils import flags, metrics
-from yugabyte_db_tpu.utils.trace import TRACES
+from yugabyte_db_tpu.utils.trace import TRACES, current_context
 
 SUM = "SELECT sum(v), count(*) FROM t WHERE v < {lit}"
 
@@ -57,16 +57,18 @@ def _want(n, lit=1e9):
 
 class _Hold:
     """`ScanKernel.run` held at its start, for the calls `when` picks:
-    `entered` says a pool thread is standing in it."""
+    `entered` says a pool thread is standing in it, and `ctx` is the
+    trace context that launch ran under."""
 
     def __init__(self, monkeypatch, when=lambda n: True):
         self.release, self.entered = threading.Event(), threading.Event()
-        self.calls = 0
+        self.calls, self.ctx = 0, None
         real, hold = ScanKernel.run, self
 
         def run(kernel, *a, **kw):
             hold.calls += 1
             if when(hold.calls) and not hold.release.is_set():
+                hold.ctx = current_context()
                 hold.entered.set()
                 assert hold.release.wait(60)
             return real(kernel, *a, **kw)
@@ -76,6 +78,19 @@ class _Hold:
     async def until_entered(self):
         while not self.entered.is_set():
             await asyncio.sleep(0)
+
+    def hop(self, spans):
+        """The `tserver.read_offload` hop of the held launch: the one
+        whose parent is the held launch's span or one of its ancestors
+        (a read's batch build hops first, so its launch's hop may begin
+        after another statement's reads have)."""
+        by_id = {s.span_id: s for s in spans}
+        hops = {s.parent_id: s for s in spans
+                if s.name == "tserver.read_offload"}
+        sid = self.ctx.span_id
+        while sid not in hops:
+            sid = by_id[sid].parent_id
+        return hops[sid]
 
 
 def test_a_second_statement_is_served_while_the_first_waits(tmp_path,
@@ -115,8 +130,7 @@ def test_a_second_statement_is_served_while_the_first_waits(tmp_path,
     spans = TRACES.finished()
     a = [s for s in spans if s.trace_id == root_a.trace_id]
     b = [s for s in spans if s.trace_id == root_b.trace_id]
-    held = max((s for s in a if s.name == "tserver.read_offload"),
-               key=lambda s: s.end_ns)
+    held = hold.hop(a)
     # all of B, from its first docdb.read to its answer, inside A's hop
     assert min(s.start_ns for s in b if s.name == "docdb.read") \
         > held.start_ns

@@ -379,10 +379,10 @@ def test_cache_get_covering():
     key = lambda cols: (("mesh", 1, 2), tuple(cols), "ssts", "float64", 4)
     cache._map[key((1, 2, 3))] = ("wide", 10)
     cache._map[key((7,))] = ("other", 10)
-    assert cache.get_covering(key((2, 3)), 1) == "wide"
-    assert cache.get_covering(key((1, 2, 3)), 1) == "wide"
-    assert cache.get_covering(key((3, 7)), 1) is None
-    assert cache.get_covering(key((2,))[:4] + (2,), 1) is None
+    assert cache.lookup(key((2, 3)), 1) == "wide"
+    assert cache.lookup(key((1, 2, 3)), 1) == "wide"
+    assert cache.lookup(key((3, 7)), 1) is None
+    assert cache.lookup(key((2,))[:4] + (2,), 1) is None
     assert cache.hits == 2 and next(reversed(cache._map)) == key((1, 2, 3))
 
 

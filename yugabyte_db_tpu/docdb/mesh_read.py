@@ -139,22 +139,18 @@ class MeshReader:
             raise MeshIneligible("no_columnar_form") from None
 
     def _batch(self, ops, needed):
-        miss = False
-
-        def build():
-            nonlocal miss
-            miss = True
-            return self._build(ops, needed)
-
         with _trace.TRACES.span("docdb.batch", child_only=True) as sp:
             key = self._cache_key(ops, needed)
             # a cached batch of these stores that holds more columns
             # serves this set by its lanes (Q6's are four of Q1's): no
             # second copy of them on the chips, and no build
-            wider = self.cache.get_covering(key, 1, chips=self.devices)
-            batch = (wider.narrowed(needed) if wider is not None else
-                     self.cache.get_or_build(key, build,
-                                             chips=self.devices))
+            batch = self.cache.lookup(key, 1, chips=self.devices)
+            miss = batch is None
+            if miss:
+                batch = self._build(ops, needed)
+                self.cache.admit(key, batch, 1, chips=self.devices)
+            else:
+                batch = batch.narrowed(needed)
             if sp.sampled:
                 from ..ops.device_batch import batch_bytes
                 sp.set_tag("cache", "miss" if miss else "hit")

@@ -23,7 +23,7 @@ import numpy as np
 
 from ..dockv.key_encoding import ValueType
 from ..dockv.value import PrimitiveValue, ValueKind, unwrap_ttl
-from ..ops.device_batch import batch_bytes, build_batch
+from ..ops.device_batch import VersionLink, batch_bytes, build_batch
 from ..ops.grouped_scan import DictGroupSpec
 from ..ops.scan import AggSpec, GroupSpec, HashGroupSpec, ScanKernel
 from ..ops.stream_scan import chunk_safe_mvcc
@@ -912,6 +912,26 @@ class StoreFacts:
     #: table id -> the streaming route's kept dictionary plans, by
     #: dictionary-column set (`ops.stream_scan._kept_plan`)
     plans: Dict[str, dict] = field(default_factory=dict)
+    #: the rows' `next_ht` link, made by the first batch build of these
+    #: contents and shared by every column set built from them
+    link: VersionLink = field(default_factory=VersionLink)
+
+
+class BatchBuild:
+    """A step of a read that forms what its device batch is made of on a
+    miss — the batch (`_cached_batch_steps`), or the facts of a store
+    of SSTs (`_facts_or_blocks_steps`) —, yielded as a launch is: a call
+    of no arguments whose result the read takes back.
+    `tablet/tablet.py serve_read` runs it on the launch pool, beside the
+    event loop, inside the server's bound on builds in flight;
+    `run_steps` calls it in place."""
+    __slots__ = ("fn",)
+
+    def __init__(self, fn):
+        self.fn = fn
+
+    def __call__(self):
+        return self.fn()
 
 
 def run_steps(steps):
@@ -1545,6 +1565,12 @@ class DocReadOperation:
             sp.set_tag("blocks", len(blocks))
             return blocks
 
+    def _contents_key(self) -> tuple:
+        """The store's contents as the facts and the cached batches name
+        them: its SST set and write generation."""
+        store = self.store
+        return (tuple(r.path for r in store.ssts), store.write_generation())
+
     def _collect_with_facts(self):
         """``(blocks, facts)`` for a device read: the store's whole block
         list and the :class:`StoreFacts` of its contents — looked up
@@ -1556,7 +1582,7 @@ class DocReadOperation:
         # between leaves facts of newer contents under the older name,
         # which no later read asks for — never older facts under a
         # newer name
-        key = (tuple(r.path for r in store.ssts), store.write_generation())
+        key = self._contents_key()
         blocks = self._collect_blocks()
         if not blocks:
             return None, None
@@ -1567,11 +1593,57 @@ class DocReadOperation:
                 key, max((int(b.ht.max()) for b in blocks if b.n),
                          default=0),
                 chunk_safe_mvcc(blocks))
+        self._note_facts(hit)
+        return blocks, facts
+
+    def _note_facts(self, hit: bool) -> None:
         (self._m_facts_hits if hit else self._m_facts_misses).increment()
         sp = _trace.current_span()
         if sp.sampled:
             sp.set_tag("facts", "hit" if hit else "miss")
-        return blocks, facts
+
+    def _facts_or_blocks_steps(self):
+        """Steps (`execute_steps`) to ``(blocks, facts)`` for a monolithic
+        device read.  Where the store's rows are all in SSTs, its facts
+        are made where they do not stand by a `BatchBuild` step, off the
+        event loop, from the blocks' key and MVCC lanes alone; then, if
+        the rows may hold several versions of a key (not `chunk_safe`:
+        no streamed route and no zone prune can serve them), nothing the
+        read does before its batch needs a block: ``(None, facts)``, and
+        a batch build collects the blocks itself, projected to its
+        columns (`_cached_batch_steps`).  Otherwise the blocks are
+        collected here, `_collect_with_facts`."""
+        store = self.store
+        if (not store._mem.empty() or store._frozen
+                or getattr(self.codec, "shred_cols", ())):
+            return self._collect_with_facts()
+        key = self._contents_key()
+        facts = store.read_facts
+        hit = facts is not None and facts.key == key
+        if not hit:
+            ssts = list(store.ssts)
+            if not ssts:
+                return None, None
+            try:
+                facts = yield BatchBuild(lambda: self._facts_of(key, ssts))
+            except KeyError:
+                return None, None       # a block without columnar form
+            store.read_facts = facts
+        self._note_facts(hit)
+        if facts.chunk_safe:
+            blocks = self._collect_blocks()
+            return (blocks, facts) if blocks else (None, None)
+        return None, facts
+
+    @classmethod
+    def _facts_of(cls, key: tuple, ssts) -> StoreFacts:
+        """The facts of the contents `key` names, whose SSTs are `ssts`:
+        from their blocks' key and MVCC lanes (`batch.collect`, step
+        `facts`)."""
+        blocks = cls._projected_blocks(ssts, (), "facts")
+        return StoreFacts(key, max((int(b.ht.max()) for b in blocks if b.n),
+                                   default=0),
+                          chunk_safe_mvcc(blocks))
 
     # --- string predicates on device (dictionary rewrite) -----------------
     class _Unrewritable(Exception):
@@ -1717,49 +1789,93 @@ class DocReadOperation:
                 out.append(c)
         return tuple(out)
 
-    def _batch_cache_key(self, needed) -> tuple:
-        """THE device-cache key for batches over this store's current
-        contents. Every flag that affects batch formation must be in
-        here: device_float_dtype is runtime-settable and baked into the
-        batch dtype at build time. Shared by the monolithic and
-        streaming paths (the streaming path appends its chunk plan), so
-        a new formation-affecting flag is added in exactly one place."""
-        return (id(self.store), tuple(sorted(needed)),
-                tuple(r.path for r in self.store.ssts),
-                self.store.write_generation(),
+    def _batch_cache_key(self, needed, facts: StoreFacts) -> tuple:
+        """THE device-cache key for batches over the store's contents that
+        `facts` were taken of (`facts.key`: the SST set and the
+        `write_generation`, named before any block was collected). Every
+        flag that affects batch formation must be in here:
+        device_float_dtype is runtime-settable and baked into the batch
+        dtype at build time. Shared by the monolithic and streaming
+        paths (the streaming path appends its chunk plan), so a new
+        formation-affecting flag is added in exactly one place."""
+        return (id(self.store), tuple(sorted(needed)), *facts.key,
                 flags.get("device_float_dtype"))
 
-    def _cached_batch(self, blocks, needed, extra: tuple,
-                      facts: StoreFacts):
-        """Build (or fetch from the device cache) the columnar batch for
-        `needed` columns. `extra` extends the cache key — the zone-map
-        prune signature rides here so a batch built from one predicate's
-        pruned block set never serves another predicate.
+    def _cached_batch_steps(self, blocks, needed, extra: tuple,
+                            facts: StoreFacts):
+        """Steps (`execute_steps`) to the columnar batch of `needed`
+        columns: the device cache's — a cached batch of more columns of
+        the same contents serves fewer —, or on a miss one built by a
+        `BatchBuild` step and offered to the cache (`admit` on the
+        `docdb.batch` span: `resident`, or `passed` — used by this read
+        alone).  `extra` extends the cache key — the zone-map prune
+        signature rides here so a batch built from one predicate's
+        pruned block set never serves another predicate.  `blocks` None
+        (`_facts_or_blocks_steps`): the build collects them from the SSTs
+        the facts were taken of, projected to `needed`, and links their
+        row versions once for every column set (`facts.link`).
         Unless the store's whole block list is proved one version a key
         (`facts.chunk_safe`: it is not with several SSTs or a memtable
         overlay), the batch links its row versions when it is built
         (`next_ht`) and is served `linked`; a property of the store's
-        contents, which the key already names (SST paths, write
-        generation)."""
-        miss = False
+        contents, which the key already names."""
+        cols = sorted(needed)
+        ssts = None
+        if blocks is None:
+            ssts = list(self.store.ssts)
+            if tuple(r.path for r in ssts) != facts.key[0]:
+                # a flush or a compaction landed since the facts were
+                # taken: the blocks there are now, unlinked
+                ssts, blocks = None, self._collect_blocks()
+                if not blocks:
+                    raise KeyError("no columnar form")
+        key = self._batch_cache_key(needed, facts) + extra
 
         def build():
-            nonlocal miss
-            miss = True
-            return build_batch(blocks, sorted(needed),
-                               multi_version=not facts.chunk_safe)
+            if ssts is None:
+                return build_batch(blocks, cols,
+                                   multi_version=not facts.chunk_safe)
+            return build_batch(self._projected_blocks(ssts, cols), cols,
+                               multi_version=True, link=facts.link)
 
         with _trace.TRACES.span("docdb.batch", child_only=True) as sp:
-            if self.device_cache is None:
-                batch = build()
+            cache = self.device_cache
+            batch = None if cache is None else cache.lookup(key, 1)
+            miss = batch is None
+            if miss:
+                batch = yield BatchBuild(build)
+                admitted = cache is not None and cache.admit(key, batch, 1)
             else:
-                batch = self.device_cache.get_or_build(
-                    self._batch_cache_key(needed) + extra, build)
+                batch = batch.narrowed(needed)
             if sp.sampled:
                 sp.set_tag("cache", "miss" if miss else "hit")
+                if miss:
+                    sp.set_tag("admit", "resident" if admitted else "passed")
                 sp.set_tag("rows", batch.n_rows)
                 sp.set_tag("bytes", batch_bytes(batch))
             return batch
+
+    @staticmethod
+    def _projected_blocks(ssts, columns,
+                          step: str = "batch") -> List[ColumnarBlock]:
+        """Every block of `ssts` with the value columns in `columns` alone
+        (`SstReader.projected_block`), as `_collect_blocks` orders and
+        marks them; on a batch build's thread: the SSTs are immutable.
+        Raises KeyError where a block has no columnar form."""
+        with _trace.TRACES.span("batch.collect", child_only=True) as sp:
+            sp.set_tag("step", step)
+            blocks = []
+            for r in ssts:
+                for i in range(r.num_blocks()):
+                    cb = r.projected_block(i, columns)
+                    if cb is None:
+                        raise KeyError("block without columnar form")
+                    blocks.append(cb)
+            if len(ssts) > 1:
+                for b in blocks:
+                    b.unique_keys = False
+            sp.set_tag("blocks", len(blocks))
+            return blocks
 
     def _zone_prune(self, blocks, where, read_ht, chunk_safe: bool):
         """Zone-map block pruning for the monolithic pushdown paths:
@@ -1808,7 +1924,7 @@ class DocReadOperation:
         from ..ops.stream_scan import streaming_scan_aggregate
         from ..ops.scan import _expand_avg
         cache = self.device_cache
-        key = (self._batch_cache_key(needed)
+        key = (self._batch_cache_key(needed, facts)
                if cache is not None else None)
         expanded = tuple(_expand_avg(req.aggregates))
         minmax = [i for i, a in enumerate(expanded)
@@ -2029,7 +2145,8 @@ class DocReadOperation:
             return
         if facts is not None and facts.max_ht <= read_ht:
             return      # no record newer than the read: none in the window
-        self._walk_restart_window(blocks, read_ht)
+        self._walk_restart_window(
+            self._collect_blocks() if blocks is None else blocks, read_ht)
 
     @staticmethod
     def _walk_restart_window(blocks, read_ht: int) -> None:
@@ -2046,10 +2163,11 @@ class DocReadOperation:
                                      allow_restart: bool):
         """Steps (`execute_steps`) to the response, or None where the
         device cannot serve the aggregate."""
-        blocks, facts = self._collect_with_facts()
-        if not blocks:
+        blocks, facts = yield from self._facts_or_blocks_steps()
+        if facts is None:
             return None
-        req = self._maybe_doc_rewrite(req, blocks)
+        if blocks is not None:
+            req = self._maybe_doc_rewrite(req, blocks)
         if req is None:
             return None     # typed doc fallback: interpreted row path
         needed = set()
@@ -2067,20 +2185,24 @@ class DocReadOperation:
                 and not flags.get("grouped_pushdown_enabled"):
             return None     # interpreted GROUP BY (the flag-off path)
         read_ht = req.read_ht if req.read_ht is not None else _MAX_HT
-        resp = self._try_streaming_aggregate(req, blocks, needed, read_ht,
-                                             facts, allow_restart)
-        if resp is _SPILLED:
-            return None     # over-cardinality: interpreted GROUP BY
-        if resp is not None:
-            return self._served("streaming", resp)
-        # zone-map pruning ahead of the monolithic batch build; the
-        # restart window below still checks the FULL block list (a
-        # pruned block's ambiguous-HT rows keep today's restart
-        # behavior)
-        kept, prune_key = self._zone_prune(blocks, req.where, read_ht,
-                                           facts.chunk_safe)
+        kept, prune_key = None, ()
+        if blocks is not None:
+            resp = self._try_streaming_aggregate(req, blocks, needed,
+                                                 read_ht, facts,
+                                                 allow_restart)
+            if resp is _SPILLED:
+                return None     # over-cardinality: interpreted GROUP BY
+            if resp is not None:
+                return self._served("streaming", resp)
+            # zone-map pruning ahead of the monolithic batch build; the
+            # restart window below still checks the FULL block list (a
+            # pruned block's ambiguous-HT rows keep today's restart
+            # behavior)
+            kept, prune_key = self._zone_prune(blocks, req.where, read_ht,
+                                               facts.chunk_safe)
         try:
-            batch = self._cached_batch(kept, needed, prune_key, facts)
+            batch = yield from self._cached_batch_steps(kept, needed,
+                                                        prune_key, facts)
         except KeyError:
             return None   # some column lacks columnar form → CPU path
         self._check_restart_window(blocks, read_ht, allow_restart, facts)
@@ -2089,7 +2211,9 @@ class DocReadOperation:
             lambda where, aggs, group: self.kernel.run(
                 batch, where, aggs, group, read_ht),
             lambda *partials: self._monolithic_spill_merge(
-                req, req.group_by, batch, kept, *partials)))
+                req, req.group_by, batch,
+                self._collect_blocks() if kept is None else kept,
+                *partials)))
 
     @classmethod
     def aggregate_on_batch_steps(cls, req: ReadRequest, batch, run,
@@ -2257,7 +2381,7 @@ class DocReadOperation:
                                     for i in minmax)
         kernel = default_plan_kernel()
         cache = self.device_cache
-        key = (self._batch_cache_key(needed)
+        key = (self._batch_cache_key(needed, facts)
                if cache is not None else None)
         gout: Optional[dict] = {} if dict_group else None
         got = None
@@ -2426,7 +2550,8 @@ class DocReadOperation:
         try:
             # same device cache as the aggregate path: repeated string-
             # predicate scans must not rebuild dictionaries per query
-            batch = self._cached_batch(blocks, needed, prune_key, facts)
+            batch = yield from self._cached_batch_steps(
+                blocks, needed, prune_key, facts)
         except KeyError:
             return None
         where = req.where
@@ -2467,7 +2592,7 @@ class DocReadOperation:
                     return None
         from ..ops.stream_scan import streaming_scan_filter
         cache = self.device_cache
-        key = (self._batch_cache_key(needed) + ("rows",)
+        key = (self._batch_cache_key(needed, facts) + ("rows",)
                if cache is not None else None)
 
         def materialize(chunk_blocks, sel):

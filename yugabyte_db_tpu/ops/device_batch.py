@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import threading
 from collections import OrderedDict
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -167,6 +167,22 @@ class DeviceBatch:
     def padded_rows(self) -> int:
         return int(self.valid.shape[0])
 
+    def narrowed(self, columns) -> "DeviceBatch":
+        return narrowed(self, columns)
+
+
+def narrowed(batch, columns):
+    """`batch` (a `DeviceBatch` or a sharded one) as a batch of `columns`
+    alone, all of them among its own: the same arrays on the chips, so a
+    scan of fewer columns runs the program it would run on a batch built
+    for them."""
+    if set(columns) == set(batch.cols):
+        return batch
+    keep = lambda d: {c: d[c] for c in sorted(columns) if c in d}
+    return replace(batch, cols=keep(batch.cols), nulls=keep(batch.nulls),
+                   col_bounds=keep(batch.col_bounds),
+                   dicts=keep(batch.dicts))
+
 
 def _float64_device_dtype() -> np.dtype:
     """Device dtype for genuinely fractional f64 columns. CPU backends
@@ -271,13 +287,37 @@ def link_versions(key_hash: np.ndarray, ht: np.ndarray,
     return next_ht, int(has_next.sum())
 
 
+class VersionLink:
+    """`link_versions` of one store's rows, made by the first batch build
+    that needs it and kept with the store's facts for every later build
+    of the same contents, whatever its column set: the link reads only
+    the key hash, the write time and the write id.  Builds on several
+    threads wait for the one that makes it."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._value: Optional[Tuple[np.ndarray, int]] = None
+
+    def of(self, blocks, ht: np.ndarray) -> Tuple[np.ndarray, int, bool]:
+        """(next_ht, superseded, made): the link of `blocks`' rows, whose
+        write times are `ht`, and whether this call made it."""
+        with self._lock:
+            made = self._value is None
+            if made:
+                self._value = link_versions(
+                    np.concatenate([b.key_hash for b in blocks]), ht,
+                    np.concatenate([b.write_id for b in blocks]))
+            return (*self._value, made)
+
+
 def build_batch(blocks: Sequence[ColumnarBlock],
                 columns: Sequence[int],
                 with_mvcc: bool = True,
                 pad_to: Optional[int] = None,
                 bounds_blocks: Optional[Sequence[ColumnarBlock]] = None,
                 dict_plan=None,
-                multi_version: bool = False) -> DeviceBatch:
+                multi_version: bool = False,
+                link: Optional[VersionLink] = None) -> DeviceBatch:
     """Concatenate columnar blocks and ship the requested columns to
     device, padded to a row bucket.
 
@@ -286,7 +326,9 @@ def build_batch(blocks: Sequence[ColumnarBlock],
     unique-keyed by itself.  Such a batch — and any batch with a block
     that is not unique-keyed — gets the ``next_ht`` lane
     (:func:`link_versions`, span ``batch.version_link``); a batch
-    proved single-version ships without it.
+    proved single-version ships without it.  ``link``: where the
+    blocks are a store's whole contents, the link kept with them
+    (:class:`VersionLink`), made here if nothing made it yet.
 
     Batch formation is a single fused pass: every column (and MVCC
     lane) fills its padded host buffer directly — per-block segments of
@@ -442,12 +484,11 @@ def build_batch(blocks: Sequence[ColumnarBlock],
             with TRACES.span("batch.version_link",
                              child_only=True) as sp:
                 next_host = np.full(padded, HT_NONE, np.uint64)
-                next_host[:n], superseded = link_versions(
-                    np.concatenate([b.key_hash for b in blocks]),
-                    ht_host[:n],
-                    np.concatenate([b.write_id for b in blocks]))
+                next_host[:n], superseded, made = (
+                    link or VersionLink()).of(blocks, ht_host[:n])
                 sp.set_tag("rows", n)
                 sp.set_tag("superseded", superseded)
+                sp.set_tag("kept", not made)
         # every 64-bit lane as the two 32-bit lanes the chip computes
         # with, split once here and not at each launch (`Pair`)
         for cid in pair_cols:
@@ -507,6 +548,12 @@ def varlen_strings(b: ColumnarBlock, cid: int) -> np.ndarray:
     (raises on non-UTF8 payloads — the caller falls back to the CPU row
     path for such blocks)."""
     ends, heap, _nulls = b.varlen[cid]
+    if heap is None:
+        # a projected block keeps a dictionary-coded lane as its stored
+        # dictionary and codes, and rebuilds no row heap
+        uniq, codes = b.dict_varlen(cid)
+        return uniq[codes]
+    heap = bytes(heap)         # a projected block's heap is a view
     out = np.empty(b.n, object)
     lo = 0
     for i in range(b.n):
@@ -525,13 +572,27 @@ def _pad(arr: np.ndarray, n: int) -> np.ndarray:
 
 
 class DeviceBlockCache:
-    """LRU cache of device-resident batches keyed by (store, column set,
-    SST set, ...).  An entry is a `DeviceBatch` on one chip or a
+    """Cache of device-resident batches keyed by (store, column set, SST
+    set, ...).  An entry is a `DeviceBatch` on one chip or a
     `parallel.distributed_scan.ShardedBatch` whose lanes cover several;
     bytes are accounted per chip, by the shards of the entry's lanes,
-    against a capacity that is per chip, and eviction frees the least
-    recently used entry that holds bytes on a chip over it.  With every
-    entry on one chip that is the plain LRU by padded byte size."""
+    against a capacity that is per chip.
+
+    A batch that does not fit evicts the least recently used entries that
+    hold bytes on a chip over it — if they may go: any batch may evict
+    the entries it covers (the same key but for a column set that holds
+    fewer columns); a batch asked for before, those idle for more than
+    `IDLE_FACTOR` times the lookups since it was last asked for; a batch
+    asked for the first time, those not hit since they were admitted.
+    So a scan that cycles over more batches than fit keeps the ones it
+    holds, which it uses every cycle, and the rest pass through: built,
+    used by the read that built them and released (`passed`); and a
+    statement seen once (a warm-up, an ANALYZE) does not push out what
+    another statement has already hit."""
+
+    IDLE_FACTOR = 2
+    #: keys asked for and not resident whose last lookups are remembered
+    ASKED = 4096
 
     def __init__(self, capacity_bytes: int = 2 << 30):
         self.capacity = capacity_bytes          # per chip
@@ -541,8 +602,16 @@ class DeviceBlockCache:
         self._placed: Dict[tuple, Dict[object, int]] = {}  # key -> chip -> B
         self._bytes = 0
         self._bytes_by_chip: Dict[object, int] = {}
+        # the lookup clock: resident key -> its last use; a key missed ->
+        # (its last miss, the one before)
+        self._clock = 0
+        self._used: Dict[tuple, int] = {}
+        self._hit: set = set()      # resident keys hit since admitted
+        self._asked: OrderedDict[tuple, Tuple[int, Optional[int]]] = \
+            OrderedDict()
         self.hits = 0
         self.misses = 0
+        self.passed = 0
         # invalidations arrive from flush/compaction executor threads
         # while the event loop serves lookups — the map needs a lock
         # (the bare-dict iterate-while-pop race the background flush
@@ -560,8 +629,9 @@ class DeviceBlockCache:
             "device_cache", f"chip-{getattr(chip, 'id', chip)}")
 
     def _count(self, what: str, chips) -> None:
-        """`/metrics`: hits and misses of each chip an entry covers."""
-        for d in chips:
+        """`/metrics`: hits, misses and passed batches of each chip an
+        entry covers (`chips`; the default device where None)."""
+        for d in jax.devices()[:1] if chips is None else chips:
             self._chip_metrics(d).counter(what).increment()
 
     def _publish_bytes(self) -> None:
@@ -570,25 +640,53 @@ class DeviceBlockCache:
 
     def _drop(self, key: tuple) -> None:
         _, size = self._map.pop(key)
+        # its last use is when it was last asked for
+        self._asked[key] = (self._used.pop(key), None)
+        self._hit.discard(key)
         self._bytes -= size
         for d, b in self._placed.pop(key).items():
             self._bytes_by_chip[d] -= b
 
-    def get_or_build(self, key: tuple, builder, chips=None):
-        """`chips`: the devices the entry covers when it is not on the
-        default one alone — named so that a hit or a miss is counted on
-        `/metrics` for each of them."""
+    @staticmethod
+    def _covers(k: tuple, key: tuple, part: Optional[int]) -> bool:
+        """Entry `k` serves `key`: the same but for element `part`, whose
+        members are all among `k[part]`'s."""
+        return (k == key or part is not None and len(k) == len(key)
+                and k[:part] == key[:part] and k[part + 1:] == key[part + 1:]
+                and set(key[part]) <= set(k[part]))
+
+    def lookup(self, key: tuple, part: Optional[int] = None, chips=None):
+        """The batch cached under `key` — with `part`, the most recently
+        used one whose key covers it (`_covers`): a batch of more columns
+        serves fewer — or None.  `chips`: the devices the entry covers
+        when it is not on the default one alone."""
         with self._lock:
-            if key in self._map:
+            self._clock += 1
+            found = key if key in self._map else None
+            if found is None and part is not None:
+                found = next((k for k in reversed(self._map)
+                              if self._covers(k, key, part)), None)
+            if found is not None:
                 self.hits += 1
-                self._map.move_to_end(key)
-                if chips is not None:
-                    self._count("hits", chips)
-                return self._map[key][0]
-            self.misses += 1
-        if chips is not None:
-            self._count("misses", chips)
-        batch = builder()
+                self._map.move_to_end(found)
+                self._used[found] = self._clock
+                self._hit.add(found)
+                batch = self._map[found][0]
+            else:
+                self.misses += 1
+                last = self._asked.pop(key, (None, None))[0]
+                self._asked[key] = (self._clock, last)
+                while len(self._asked) > self.ASKED:
+                    self._asked.popitem(last=False)
+        self._count("hits" if found is not None else "misses", chips)
+        return None if found is None else batch
+
+    def admit(self, key: tuple, batch, part: Optional[int] = None,
+              chips=None) -> bool:
+        """Make `batch`, built after `lookup(key)` missed, the entry of
+        `key` if the policy lets it in (class doc; `part` as in
+        `lookup`); False where it passes: the caller holds the only
+        reference, and the batch leaves the chip with it."""
         placed = batch_placement(batch)
         size = sum(placed.values())
         with self._lock:
@@ -596,44 +694,54 @@ class DeviceBlockCache:
                 # a racing builder (flush thread vs loop) landed the
                 # same key while we built off-lock: keep the resident
                 # entry — inserting ours would double-count _bytes
-                self._map.move_to_end(key)
-                return self._map[key][0]
-            self._map[key] = (batch, size)
-            self._placed[key] = placed
-            self._bytes += size
-            for d, b in placed.items():
-                self._bytes_by_chip[d] = self._bytes_by_chip.get(d, 0) + b
-            while len(self._map) > 1:
-                full = {d for d, b in self._bytes_by_chip.items()
-                        if b > self.capacity}
-                # least recently used entry with bytes on a full chip;
-                # never the one just inserted
-                victim = next((k for k in self._map if k != key
-                               and full & set(self._placed[k])), None)
-                if not full or victim is None:
+                return True
+            asked, before = self._asked.get(key, (self._clock, None))
+            over = {d: self._bytes_by_chip.get(d, 0) + b - self.capacity
+                    for d, b in placed.items()}
+            victims = []
+            for k in self._map:             # least recently used first
+                if max(over.values(), default=0) <= 0:
                     break
-                self._drop(victim)
-            if chips is not None:
+                if not any(over.get(d, 0) > 0 for d in self._placed[k]):
+                    continue
+                if not self._covers(key, k, part) and (
+                        k in self._hit if before is None
+                        else self._clock - self._used[k]
+                        <= self.IDLE_FACTOR * (asked - before)):
+                    continue
+                victims.append(k)
+                for d, b in self._placed[k].items():
+                    if d in over:
+                        over[d] -= b
+            admitted = max(over.values(), default=0) <= 0
+            if admitted:
+                for k in victims:
+                    self._drop(k)
+                self._asked.pop(key, None)
+                self._map[key] = (batch, size)
+                # its last use is its own lookup, not its admission: the
+                # reads of one statement look up before any build lands
+                self._used[key] = asked
+                self._placed[key] = placed
+                self._bytes += size
+                for d, b in placed.items():
+                    self._bytes_by_chip[d] = \
+                        self._bytes_by_chip.get(d, 0) + b
                 self._publish_bytes()
-        return batch
+            else:
+                self.passed += 1
+        if not admitted:
+            self._count("passed", chips)
+        return admitted
 
-    def get_covering(self, key: tuple, part: int, chips=None):
-        """The most recently used entry whose key is `key` but for
-        element `part`, a tuple that holds every member of `key[part]`
-        — a batch of more columns than asked for serves the fewer — or
-        None.  Counted as a hit."""
-        want = set(key[part])
-        with self._lock:
-            for k in reversed(self._map):
-                if (len(k) == len(key) and k[:part] == key[:part]
-                        and k[part + 1:] == key[part + 1:]
-                        and want <= set(k[part])):
-                    self.hits += 1
-                    self._map.move_to_end(k)
-                    if chips is not None:
-                        self._count("hits", chips)
-                    return self._map[k][0]
-        return None
+    def get_or_build(self, key: tuple, builder, chips=None):
+        """The cached batch of `key`, or `builder()`'s, offered to the
+        cache (`admit`)."""
+        batch = self.lookup(key, chips=chips)
+        if batch is None:
+            batch = builder()
+            self.admit(key, batch, chips=chips)
+        return batch
 
     def invalidate_prefix(self, prefix: tuple) -> None:
         """Drop entries whose key starts with prefix (e.g. an SST was
@@ -651,6 +759,9 @@ class DeviceBlockCache:
         with self._lock:
             self._map.clear()
             self._placed.clear()
+            self._used.clear()
+            self._hit.clear()
+            self._asked.clear()
             self._bytes = 0
             self._bytes_by_chip.clear()
 

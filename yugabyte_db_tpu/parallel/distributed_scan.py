@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import threading
 from concurrent.futures import ThreadPoolExecutor
-from dataclasses import dataclass, field, replace
+from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -21,7 +21,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.device_batch import (HT_NONE, Pair, batch_bytes, bucket_rows,
                                 f64_conversion, f64_pair, f64_pairs,
-                                link_versions, u64_pair)
+                                link_versions, narrowed, u64_pair)
 from ..ops.grouped_scan import DictGroupSpec
 from ..ops.scan import (AggSpec, GroupSpec, ResultLayout, _build_kernel,
                         launch, prepare_launch, unpack_scalars)
@@ -63,15 +63,7 @@ class ShardedBatch:
     max_ht: int = 0
 
     def narrowed(self, columns) -> "ShardedBatch":
-        """This batch as one of `columns` alone (all of them among its
-        own): the same arrays on the chips, so a scan of fewer columns
-        runs the program it would run on a batch built for them."""
-        if set(columns) == set(self.cols):
-            return self
-        keep = lambda d: {c: d[c] for c in sorted(columns) if c in d}
-        return replace(
-            self, cols=keep(self.cols), nulls=keep(self.nulls),
-            col_bounds=keep(self.col_bounds), dicts=keep(self.dicts))
+        return narrowed(self, columns)
 
     @property
     def n_rows(self) -> int:

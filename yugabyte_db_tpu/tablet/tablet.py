@@ -28,8 +28,8 @@ from ..docdb.compaction import (
     DocDbCompactionFeed, RepackingCompactionFeed, tpu_compact,
 )
 from ..docdb.operations import (
-    DocReadOperation, DocWriteOperation, ReadRequest, ReadResponse,
-    ReadRestartError, WriteRequest, WriteResponse, run_steps,
+    BatchBuild, DocReadOperation, DocWriteOperation, ReadRequest,
+    ReadResponse, ReadRestartError, WriteRequest, WriteResponse, run_steps,
 )
 from ..docdb.table_codec import TableCodec, TableInfo
 from ..ops.device_batch import DeviceBlockCache
@@ -51,13 +51,18 @@ _FLUSH_POOL = ThreadPoolExecutor(max_workers=2,
                                  thread_name_prefix="bg-flush")
 
 # threads that make a served read's launches — `ScanKernel.run`:
-# dispatch, the wait for the device, the read-back — while the event
-# loop goes on with the other reads and with everything else
-# (`serve_read`).  Most of a launch is a wait that holds no lock and no
-# core; eight is two statements' four tablet reads each, more than a
-# chip runs at a time.
+# dispatch, the wait for the device, the read-back — and the batch
+# builds of its cache misses, while the event loop goes on with the
+# other reads and with everything else (`serve_read`).  Most of a launch
+# is a wait that holds no lock and no core; eight is two statements'
+# four tablet reads each, more than a chip runs at a time.
 _READ_LAUNCH_POOL = ThreadPoolExecutor(max_workers=8,
                                        thread_name_prefix="read-launch")
+
+#: a server's reads that may hold a batch they built at once: each holds
+#: it from its build to its end, so at most this many batches that the
+#: device cache did not admit are on the chip beside its capacity
+BUILDS_IN_FLIGHT = 4
 
 #: blocks a bulk load makes and serializes at a time, ahead of the file
 #: write
@@ -69,7 +74,8 @@ class ServedReads:
     many are open (`serve_read` counts on the event loop), and on the
     server's `/metrics` the most that were (`reads_in_flight_max`) and
     the time a launch stood in the pool's queue
-    (`read_offload_queue_us`).  A `TabletServer` makes one and hands it
+    (`read_offload_queue_us`); and the slots of the reads that build a
+    batch (`BUILDS_IN_FLIGHT`).  A `TabletServer` makes one and hands it
     to its tablets; a tablet with no server has its own."""
 
     def __init__(self, owner: str):
@@ -77,6 +83,7 @@ class ServedReads:
         self.open = 0
         self.m_max = ent.gauge("reads_in_flight_max")
         self.m_queue = ent.histogram("read_offload_queue_us")
+        self.build_slots = asyncio.Semaphore(BUILDS_IN_FLIGHT)
 
 
 def _launch_in_pool(call, tctx):
@@ -100,16 +107,20 @@ async def serve_read(steps, served: ServedReads):
     The hop is the `tserver.read_offload` span, child of the span that
     was ambient when the read began (`tserver.read:<tablet>`): `queue_ms`
     from handing the launch over to a thread taking it, `in_flight` the
-    server's reads in the pool's hands with this one.  From the launch's
-    return to the read's resumption on the loop is `tserver.read_resume`
-    (a child of the read's own span, `in_flight` as the hop's); the
-    loop's time inside the read's steps is tag `steps_ms` of the span
-    that was ambient when it began — a tag, since the steps open and
-    close spans of their own across the yields."""
+    server's reads in the pool's hands with this one.  A batch build
+    (`BatchBuild`) takes the same hop as `tserver.build_offload`, once
+    the read holds one of the server's build slots, which it keeps to
+    its end; its `queue_ms` includes the wait for the slot.  From the
+    launch's return to the read's resumption on the loop is
+    `tserver.read_resume` (a child of the read's own span, `in_flight`
+    as the hop's); the loop's time inside the read's steps is tag
+    `steps_ms` of the span that was ambient when it began — a tag, since
+    the steps open and close spans of their own across the yields."""
     parent = _trace.current_context()
     read_span = _trace.current_span()
     loop = asyncio.get_running_loop()
     steps_ns, step_began = 0, _perf_counter_ns()
+    slot = False
     try:
         call = next(steps)
         while True:
@@ -119,14 +130,20 @@ async def serve_read(steps, served: ServedReads):
                 served.m_max.set(served.open)
             try:
                 tctx = _trace.current_context()     # the read's own span
+                build = isinstance(call, BatchBuild)
                 with _trace.TRACES.span(
-                        "tserver.read_offload", parent=parent,
+                        "tserver.build_offload" if build
+                        else "tserver.read_offload", parent=parent,
                         child_only=True,
                         tags={"in_flight": served.open}) as sp:
                     handed = _perf_counter_ns()
+                    if build and not slot:
+                        await served.build_slots.acquire()
+                        slot = True
                     began, got, returned = await loop.run_in_executor(
                         _READ_LAUNCH_POOL, _launch_in_pool, call, tctx)
-                    served.m_queue.increment((began - handed) / 1e3)
+                    if not build:
+                        served.m_queue.increment((began - handed) / 1e3)
                     sp.set_tag("queue_ms", (began - handed) / 1e6)
                 _trace.TRACES.record("tserver.read_resume", returned,
                                      _perf_counter_ns(), tctx,
@@ -147,6 +164,8 @@ async def serve_read(steps, served: ServedReads):
             read_span.set_tag("steps_ms", read_span.tags.get("steps_ms", 0.0)
                               + steps_ns / 1e6)
         steps.close()
+        if slot:
+            served.build_slots.release()
 
 
 class _VectorIndexState:

@@ -184,10 +184,7 @@ class TabletServer:
         # aggregate read of all of them is one mesh launch
         # (docdb/mesh_read.py); with one, nothing of that exists
         self.mesh_reader = None
-        self._cache_capacity_before = None
-        chips = int(flags.get("tserver_device_chips"))
-        if chips > 1:
-            self._own_chips(chips)
+        self._own_chips(int(flags.get("tserver_device_chips")))
 
     def _own_chips(self, chips: int) -> None:
         import jax
@@ -198,12 +195,15 @@ class TabletServer:
         if len(devices) < chips:
             raise RuntimeError(
                 f"tserver_device_chips={chips}, JAX has {len(devices)}")
-        self.mesh_reader = MeshReader(devices[:chips], _DEVICE_CACHE,
-                                      owner=f"ts-{self.uuid}")
-        # the cache's capacity is a chip's, from the chip's own memory
+        if chips > 1:
+            self.mesh_reader = MeshReader(devices[:chips], _DEVICE_CACHE,
+                                          owner=f"ts-{self.uuid}")
+        # the cache's capacity a chip, as the deployment states it, or
+        # from the chip's own memory
+        stated = int(flags.get("tserver_device_cache_bytes"))
         self._cache_capacity_before = _DEVICE_CACHE.capacity
-        _DEVICE_CACHE.capacity = chip_capacity(
-            devices[:chips], default=_DEVICE_CACHE.capacity)
+        _DEVICE_CACHE.capacity = stated if stated > 0 else chip_capacity(
+            devices[:chips])
 
     # --- lifecycle --------------------------------------------------------
     async def start(self, host: str = "127.0.0.1", port: int = 0):
@@ -232,13 +232,14 @@ class TabletServer:
         self._ash_providers = []
         await self.scheduler.shutdown()
         if self._cache_capacity_before is not None:
-            # the chips go back as they were taken: this server's
-            # batches leave them, and the cache its capacity
+            # the chips go back as they were taken: the cache its
+            # capacity, and a mesh's batches leave them
             from ..tablet.tablet import _DEVICE_CACHE
-            for p in self.peers.values():
-                p.tablet.drop_device_state()
             _DEVICE_CACHE.capacity = self._cache_capacity_before
             self._cache_capacity_before = None
+        if self.mesh_reader is not None:
+            for p in self.peers.values():
+                p.tablet.drop_device_state()
             # and the programs compiled for this server's mesh with them:
             # they are named by `id(mesh)`, which a later mesh may reuse
             self.mesh_reader.kernel.forget(self.mesh_reader.mesh)

@@ -176,6 +176,14 @@ DEFINE_RUNTIME("device_float_dtype", "auto",
                "exact via the scan kernel's int64 fixed-point "
                "accumulation); 'float32'/'float64' force one (tests use "
                "float32 to exercise the TPU-representative path on CPU).")
+DEFINE_RUNTIME("tserver_device_cache_bytes", -1,
+               "The device block cache's capacity a chip, in bytes, as "
+               "the deployment states it (upstream's "
+               "db_block_cache_size_bytes): applied when a tablet server "
+               "starts.  -1 = half of the smallest owned chip's memory "
+               "(its bytes_limit), 2 GiB where the device does not say "
+               "(a CPU).  Batches the cache does not admit pass through: "
+               "built, used by their read and released.")
 DEFINE_RUNTIME("tserver_device_chips", 1,
                "Chips a tablet server owns: it takes the first N of "
                "jax.devices() when it starts.  1 serves every tablet read "
